@@ -37,6 +37,7 @@ device compute, CPU-safe (the CI contract).
 from __future__ import annotations
 
 import re
+import sysconfig
 from collections import Counter
 from typing import Iterable, Iterator, List, Optional, Tuple, Union
 
@@ -59,12 +60,14 @@ HLO_COLLECTIVES = (
     "reduce-scatter", "collective-broadcast",
 )
 
-# an HLO instruction is `%name = <shape> <opcode>(...)`; async collectives
-# carry TUPLE result shapes `(f32[..], f32[..])`, so the shape matcher must
-# accept both forms or -start lines silently drop out of the census
+# an HLO instruction is `%name = <shape> <opcode>(...)`. The shape is not
+# parsed: async collectives carry TUPLE shapes, and the TPU compiler writes
+# layouts with their own parentheses and spaces inside them
+# (`(bf16[1,2,1024,3]{2,3,1,0:T(4,128)(2,1)S(1)}, u32[]{:S(2)})`) — a
+# shape matcher dropped every collective of a v5e program from the census
+# (PR 21). Operand references (`%all-reduce.1`) never follow whitespace.
 _HLO_OP_RE = re.compile(
-    r"=\s+(?:\([^)]*\)|\S+)\s+(" + "|".join(HLO_COLLECTIVES)
-    + r")(-start)?\(")
+    r"=\s+.*?\s(" + "|".join(HLO_COLLECTIVES) + r")(-start)?\(")
 _HLO_SHAPE_RE = re.compile(r"\w+\[([\d,]+)\]")
 _CALLBACK_PRIMITIVES = frozenset({
     "pure_callback", "io_callback", "debug_callback", "debug_print",
@@ -73,9 +76,11 @@ _CALLBACK_PRIMITIVES = frozenset({
 
 
 def normalize_primitive(name: str) -> str:
-    """Strip jax's versioning suffix from a primitive name (``psum2`` →
-    ``psum``) so call sites pin semantics, not jax-internal renames."""
-    return name.rstrip("0123456789")
+    """Strip jax's versioning/typing suffixes from a primitive name
+    (``psum2`` → ``psum``; jax 0.9's vma-typed ``psum_invariant`` /
+    ``all_gather_invariant`` → ``psum`` / ``all_gather``) so call sites
+    pin semantics, not jax-internal renames."""
+    return name.rstrip("0123456789").removesuffix("_invariant")
 
 
 def sub_jaxprs(params) -> Iterator:
@@ -102,18 +107,24 @@ def iter_eqns(jaxpr) -> Iterator:
             yield from iter_eqns(sub)
 
 
-def eqn_location(eqn) -> Tuple[Optional[str], Optional[int]]:
-    """(file, line) of the user frame that created an eqn, or (None, None).
-    Best-effort over jax's private source-info API — a jax upgrade that
-    moves it degrades findings to location-less, never crashes the lint."""
-    try:
-        from jax._src import source_info_util
+_SITE_PACKAGES = tuple(
+    {sysconfig.get_paths()[k] for k in ("purelib", "platlib")})
 
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is not None:
+
+def eqn_location(eqn) -> Tuple[Optional[str], Optional[int]]:
+    """(file, line) of the PROGRAM's frame that created an eqn — the
+    innermost frame outside jax, the stdlib AND installed third-party
+    packages (flax's ``linen/linear.py`` issues the conv, but the line a
+    pragma can waive is the model's ``nn.Conv(...)(x)`` call site).
+    (None, None) only when the traceback holds no such frame. Reads jax
+    0.9's ``source_info_util.user_frames`` (takes the TRACEBACK, not the
+    SourceInfo); a jax that moves it fails loudly here rather than
+    quietly degrading every finding to location-less."""
+    from jax._src import source_info_util
+
+    for frame in source_info_util.user_frames(eqn.source_info.traceback):
+        if not frame.file_name.startswith(_SITE_PACKAGES):
             return frame.file_name, int(frame.start_line)
-    except Exception:
-        pass
     return None, None
 
 

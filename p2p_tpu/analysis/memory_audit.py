@@ -44,6 +44,7 @@ holds as the serving path evolves.
 from __future__ import annotations
 
 import re
+from collections import Counter
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -339,21 +340,61 @@ def memory_budget_table(hbm_gb: Optional[float] = None,
 
 
 _MAIN_SIG_RE = re.compile(
-    r"func\.func public @main\((.*?)\)\s*->", re.S)
+    r"func\.func public @main\((.*?)\)\s*->\s*(.*?)\s*\{\s*$", re.S | re.M)
+_TENSOR_RE = re.compile(r"tensor<((?:\d+x)*)[a-zA-Z]+?(\d+)\w*>")
+_ALIAS_RE = re.compile(r"tf\.aliasing_output\s*=\s*(\d+)")
+
+
+def _tensor_nbytes(entry: str) -> Optional[int]:
+    """Byte size of the first ``tensor<...>`` type in a signature entry
+    (``tensor<64x64xbf16>`` -> 8192); None when the entry has none."""
+    m = _TENSOR_RE.search(entry)
+    if m is None:
+        return None
+    n = 1
+    for d in m.group(1).split("x")[:-1]:
+        n *= int(d)
+    return n * max(1, int(m.group(2)) // 8)
 
 
 def lowered_donation_markers(lowered_text: str) -> Optional[List[bool]]:
-    """Per-argument donation marker flags from a lowered program's text:
-    True where the arg carries ``tf.aliasing_output`` (single-device
-    lowering: donation RESOLVED to an output) or ``jax.buffer_donor``
-    (multi-device lowering: donation requested, XLA resolves at compile).
+    """Per-argument "this buffer is reused" flags from a lowered
+    program's main signature, as jax 0.9 writes it:
+
+    - ``tf.aliasing_output = N``: donation RESOLVED at lowering to
+      result N (same shape and dtype) — True;
+    - ``jax.buffer_donor = true``: donation handed to XLA, which jax
+      does when the exact match is deferred (sharded/auto layouts) OR
+      when only the ELEMENT COUNT of some result matches (an
+      ``astype``'d leaf). XLA reuses a donor only for a result of the
+      same BYTE size, so the flag is True iff such a result is still
+      unclaimed (aliased results and earlier donors claim theirs);
+    - neither: False.
+
     None when the main signature cannot be parsed."""
     m = _MAIN_SIG_RE.search(lowered_text)
     if m is None:
         return None
     entries = re.split(r",\s*(?=%arg\d+)", m.group(1))
-    return [("tf.aliasing_output" in e or "jax.buffer_donor" in e)
-            for e in entries]
+    results = re.split(r",\s*(?=tensor<|!)", m.group(2).strip("()"))
+    aliased = {int(a.group(1)) for e in entries
+               for a in _ALIAS_RE.finditer(e)}
+    free: Counter = Counter(
+        _tensor_nbytes(r) for i, r in enumerate(results)
+        if i not in aliased)
+    flags = []
+    for e in entries:
+        if "tf.aliasing_output" in e:
+            flags.append(True)
+        elif "jax.buffer_donor" in e:
+            nbytes = _tensor_nbytes(e)
+            reusable = nbytes is not None and free[nbytes] > 0
+            if reusable:
+                free[nbytes] -= 1
+            flags.append(reusable)
+        else:
+            flags.append(False)
+    return flags
 
 
 def _jaxpr_used_invars(jaxpr) -> List[bool]:

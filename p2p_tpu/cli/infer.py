@@ -113,8 +113,11 @@ def main(argv=None) -> int:
 
     import dataclasses
 
+    from p2p_tpu.core.cache import enable_compilation_cache
     from p2p_tpu.core.config import get_preset
     from p2p_tpu.data.pipeline import PairedImageDataset, make_loader
+
+    enable_compilation_cache(args.compilation_cache)
     from p2p_tpu.serve import engine_from_checkpoint
 
     from p2p_tpu.cli import apply_overrides as over

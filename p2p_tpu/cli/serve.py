@@ -282,6 +282,9 @@ def _serve_http(args, buckets) -> int:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    from p2p_tpu.core.cache import enable_compilation_cache
+
+    enable_compilation_cache(args.compilation_cache)
 
     buckets = ([int(b) for b in args.buckets.split(",")] if args.buckets
                else default_buckets(args.max_batch))

@@ -47,38 +47,18 @@ ALL_AXES = (DATA_AXIS, FSDP_AXIS, SPATIAL_AXIS, TIME_AXIS, MODEL_AXIS,
 BATCH_AXES = (DATA_AXIS, FSDP_AXIS)
 
 
-# --------------------------------------------------------------- jax compat
-# The TPU image ships a vma-era jax (public ``jax.shard_map`` with
-# varying-manual-axes typing); CPU-only CI containers may carry a 0.4.x
-# jax where shard_map is experimental and typed by the older rep-checker.
-# Every manual-sharding region in the repo goes through these two shims so
-# both environments run the same programs.
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    On vma-era jax this is the public API verbatim; on 0.4.x it falls back
-    to ``jax.experimental.shard_map`` with ``check_rep=False`` (the old
-    rep-checker cannot type the axis_index-dependent carries the pipeline
-    and halo programs build — the vma system can)."""
-    try:
-        from jax import shard_map as sm
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
 def pcast_varying(x, axes):
-    """``jax.lax.pcast(..., to='varying')`` where it exists — the vma
-    system needs replicated constants cast to the varying type before they
-    enter stage-varying control flow; identity on pre-vma jax, where the
-    check_rep=False fallback above disables that tracking entirely."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axes, to="varying")
-    return x
+    """Cast ``x`` to vary over ``axes`` inside a ``jax.shard_map`` body.
+
+    The vma type system needs replicated constants cast to the varying
+    type before they enter axis-varying control flow (scan carries that
+    ``axis_index`` flows into). ``lax.pcast`` rejects an axis the value
+    already varies over — e.g. ``zeros_like`` of a pipe-sharded quant
+    leaf is born ``{V:pipe}`` — so only the missing axes are cast."""
+    missing = tuple(a for a in axes if a not in jax.typeof(x).vma)
+    if not missing:
+        return x
+    return jax.lax.pcast(x, missing, to="varying")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -81,8 +81,8 @@ class UNetGenerator(nn.Module):
     # with thin_head: Pallas fused kernel for the head's k2 conv
     head_pallas: bool = False
     # k4-s2 RGB stem as strided patches + dense matmul (PatchesConv):
-    # the zero-padded 3-ch stem's wgrad collapses XLA to 0.7 TF/s at
-    # bs=1 (profiles/prof_r5_facades_bs1.txt); the patch form makes
+    # the zero-padded 3-ch stem's wgrad collapses XLA to a fraction of
+    # a TF/s at bs=1; the patch form makes
     # fwd AND dW full-rate dot_generals (dx is dead — input is the
     # image). Param tree identical to nn.Conv (kernel HWIO + bias).
     thin_stem: bool = False

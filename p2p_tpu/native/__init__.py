@@ -3,7 +3,8 @@
 Built lazily with g++ on first use and cached next to the source (no
 pybind11 in this image — plain C ABI + ctypes, per the environment
 constraints). Everything has a pure-Python fallback: ``available()`` tells
-you which path you're on, and the public helpers raise nothing at import
+you which path you're on (``require()`` turns the fallback into an error
+that says why), and the public helpers raise nothing at import
 time on machines without a toolchain.
 """
 
@@ -24,12 +25,16 @@ _LIB_PATH = os.path.join(_DIR, "_fastimage.so")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_error: Optional[str] = None   # why the native path is off, once known
 
 
-def _build() -> bool:
-    # compile to a private temp path and rename into place: atomic on
-    # POSIX, so concurrent dataloader worker processes never dlopen a
-    # half-written .so
+def build() -> str:
+    """Compile ``fastimage.cpp`` to ``_fastimage.so`` and return its path.
+
+    Compiles to a private temp path and renames into place: atomic on
+    POSIX, so concurrent dataloader worker processes never dlopen a
+    half-written .so. Raises ``RuntimeError`` carrying the compiler's
+    own message when the toolchain is missing or the build fails."""
     tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
     cmd = [
         "g++", "-O3", "-shared", "-fPIC", "-std=c++17",
@@ -38,29 +43,32 @@ def _build() -> bool:
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, _LIB_PATH)
-        return True
-    except Exception:
-        try:
+    except (OSError, subprocess.SubprocessError) as exc:
+        if os.path.exists(tmp):
             os.unlink(tmp)
-        except OSError:
-            pass
-        return False
+        detail = getattr(exc, "stderr", b"") or b""
+        raise RuntimeError(
+            f"building {_LIB_PATH} failed: {exc!r} "
+            f"{detail.decode(errors='replace')[-2000:]}") from exc
+    return _LIB_PATH
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+    global _lib, _tried, _error
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_LIB_PATH) or (
-            os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC)
-        ):
-            if not _build():
-                return None
         try:
+            if not os.path.exists(_LIB_PATH) or (
+                os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC)
+            ):
+                build()
             lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
+        except (RuntimeError, OSError) as exc:
+            # no toolchain / unloadable .so: the PIL path serves, and
+            # require() says why
+            _error = str(exc)
             return None
         lib.png_decode.restype = ctypes.c_int
         lib.png_decode.argtypes = [
@@ -81,6 +89,13 @@ def _load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return _load() is not None
+
+
+def require() -> None:
+    """Raise unless the native decoder is loaded — for callers (the chip
+    smoke) that must not run on the PIL fallback unnoticed."""
+    if _load() is None:
+        raise RuntimeError(f"native fastimage unavailable: {_error}")
 
 
 def png_decode(data: bytes) -> Optional[np.ndarray]:

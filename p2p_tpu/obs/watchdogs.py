@@ -19,12 +19,12 @@ MemoryWatchdog
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
+from typing import Any, Dict
 
 import jax
 
-# Fires once per XLA backend compile (empirically present on the CPU and TPU
-# runtimes of the pinned jax; registration is version-guarded regardless).
+# Fires once per XLA backend compile (present on the CPU and TPU runtimes
+# of the installed jax).
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # Persistent-compilation-cache outcome events (jax/_src/compiler.py): one
 # per backend-compile request once a cache dir is set (core/cache.py).
@@ -49,21 +49,11 @@ class RetraceWatchdog:
         self.cache_hits = 0             # persistent-cache loads (no compile)
         self.cache_misses = 0           # persistent-cache misses (compiled)
         self.armed = False
-        self._registered = False
-        self._event_registered = False
-        try:
-            from jax._src import monitoring as _mon
-
-            self._mon = _mon
-            _mon.register_event_duration_secs_listener(self._on_event)
-            self._registered = True
-            try:
-                _mon.register_event_listener(self._on_plain_event)
-                self._event_registered = True
-            except Exception:
-                pass
-        except Exception:               # jax moved the private API: degrade
-            self._mon = None
+        # jax 0.9's public monitoring API (register + unregister by
+        # callback); a jax that moves it fails here, loudly
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+        jax.monitoring.register_event_listener(self._on_plain_event)
+        self._registered = True
 
     # NOTE: listener signature is (event, duration, **kwargs) in the pinned
     # jax; absorb extras so minor-version drift doesn't raise in a callback.
@@ -111,20 +101,11 @@ class RetraceWatchdog:
         self.armed = False
 
     def close(self) -> None:
-        if self._registered and self._mon is not None:
-            try:
-                self._mon._unregister_event_duration_listener_by_callback(
-                    self._on_event)
-            except Exception:
-                pass
+        """Unhook the process-global listeners (safe to call twice)."""
+        if self._registered:
+            jax.monitoring.unregister_event_duration_listener(self._on_event)
+            jax.monitoring.unregister_event_listener(self._on_plain_event)
             self._registered = False
-        if self._event_registered and self._mon is not None:
-            try:
-                self._mon._unregister_event_listener_by_callback(
-                    self._on_plain_event)
-            except Exception:
-                pass
-            self._event_registered = False
 
 
 class MemoryWatchdog:

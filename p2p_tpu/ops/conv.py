@@ -493,7 +493,11 @@ class _PallasHeadConv(nn.Module):
         dt = self.dtype or jnp.float32
         import os
 
-        interpret = jax.devices()[0].platform != "tpu"
+        from p2p_tpu.ops.pallas import kernel_dispatch
+
+        # CPU: the kernel program interpreted (tests); TPU: compiled;
+        # any other backend raises inside kernel_dispatch
+        _, interpret = kernel_dispatch(force=True)
         if not interpret and os.environ.get("P2P_HPAL_FORCE", "") != "1":
             # The v3 kernel COMPILES and RUNS on this runtime but measures
             # 1130 img/s vs 1708 for the XLA deconv head at 256²/bs=128
@@ -594,9 +598,8 @@ class _NearestUp2Conv(nn.Module):
     the LOW-RES grid, so the whole layer is ONE 3×3 conv ci→4·co at half
     resolution + :func:`depth_to_space_2x`: the same FLOPs land on full
     128-lane MXU tiles (vs a 32-lane-wide conv over the 4×-materialized
-    upsampled tensor) and the activation traffic drops ~4× — the
-    round-4 profile has this layer at 4.2 TF/s / ~4.7 ms of the
-    pix2pixHD step (BASELINE.md). Boundary: reflect-padding the UPSAMPLED
+    upsampled tensor) and the activation traffic drops ~4×.
+    Boundary: reflect-padding the UPSAMPLED
     image equals EDGE-padding the low-res input for the single ring a 3×3
     needs (up[-1]=up[0]=x[0], up[2H]=up[2H-2]=x[H-1]); k≥5 needs a second
     ring where that identity breaks — hence the k==3 gate in the

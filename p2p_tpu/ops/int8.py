@@ -54,21 +54,19 @@ Pads = Tuple[Tuple[int, int], Tuple[int, int]]
 
 _DN = ("NHWC", "HWIO", "NHWC")
 
-# Dispatch bounds for the unrolled int8 wgrad (see _int8_bwd_core):
-# output spatial sizes in [MIN, MAX] use the k²-unrolled int8
-# dot_general form; the rest fall back to the bf16 CHWN conv.
-# - MIN = 0 (round 4): the round-2/3 runtime kernel-faulted the int8
-#   strided slices below ~16² output positions (MIN was 256 then); the
-#   round-4 runtime upgrade FIXED it — verified by the on-TPU repro
-#   (tests/test_int8.py::test_tiny_spatial_wgrad_guard_on_tpu, which ran
-#   the unguarded 2×2-output wgrad successfully). The env knob stays for
-#   older runtimes: set P2P_INT8_WGRAD_SLICE_MIN=256 to restore the
-#   guard if the fault reappears.
+# Dispatch bound for the unrolled int8 wgrad (see _int8_bwd_core): output
+# spatial sizes up to MAX use the k²-unrolled int8 dot_general form; the
+# rest fall back to the bf16 CHWN conv.
+# - no lower bound: an early runtime kernel-faulted the int8 strided
+#   slices below ~16² output positions and a guard routed those to bf16.
+#   The unguarded path was run on the attached v5e (libtpu 0.0.34) at 2×2
+#   and 1×1 outputs — the U-Net bottom of facades_int8_full — and passed
+#   (chip run, PR 21); the guard and its env knob are gone.
+#   tests/test_int8.py::test_tiny_spatial_wgrad_on_tpu is the standing
+#   probe.
 # - MAX = 4096 (64²): above it the k² slices of the padded input
 #   materialize more HBM traffic than the int8 MXU rate buys back (the
-#   round-2 "decoder int8 loses" finding).
-_INT8_WGRAD_SLICE_MIN = int(
-    os.environ.get("P2P_INT8_WGRAD_SLICE_MIN", "0"))
+#   "decoder int8 loses" finding).
 _INT8_WGRAD_SLICE_MAX = int(
     os.environ.get("P2P_INT8_WGRAD_SLICE_MAX", "4096"))
 
@@ -204,15 +202,12 @@ def _int8_bwd_core(strides, padding, lhs_dilation, res, g):
 
     # ---- wgrad --------------------------------------------------------
     ho, wo = out_hw
-    # Static spatial dispatch window. The round-2/3 runtime kernel-faulted
-    # the int8 strided slices below ~16² output positions (MIN was 256);
-    # the round-4 runtime fixed it and the default window now starts at 0
-    # (see _INT8_WGRAD_SLICE_MIN above). The UPPER bound stands: above
-    # ~64² output positions the k² strided slices of the (already large)
-    # padded input materialize more HBM traffic than the int8 MXU rate
-    # buys back (the round-2 "decoder int8 loses" finding) — those
-    # big-spatial wgrads take the bf16 CHWN conv below.
-    if plain and _INT8_WGRAD_SLICE_MIN <= ho * wo <= _INT8_WGRAD_SLICE_MAX:
+    # Static spatial dispatch bound (see _INT8_WGRAD_SLICE_MAX above):
+    # above ~64² output positions the k² strided slices of the (already
+    # large) padded input materialize more HBM traffic than the int8 MXU
+    # rate buys back — those big-spatial wgrads take the bf16 CHWN conv
+    # below.
+    if plain and ho * wo <= _INT8_WGRAD_SLICE_MAX:
         sg = absmax_scale(gf)
         gq = quantize_int8(gf, sg)
         (plo_h, phi_h), (plo_w, phi_w) = padding

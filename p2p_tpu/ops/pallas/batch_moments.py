@@ -98,10 +98,7 @@ def eligible_block(x: jax.Array) -> int:
     """
     from p2p_tpu.core.mesh import current_mesh
 
-    try:
-        if jax.default_backend() != "tpu":
-            return 0
-    except Exception:  # pragma: no cover - backend probing never fatal
+    if jax.default_backend() != "tpu":
         return 0
     mesh = current_mesh()
     if mesh is not None and mesh.size > 1:

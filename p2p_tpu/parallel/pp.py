@@ -50,7 +50,6 @@ from p2p_tpu.core.mesh import (
     DATA_AXIS,
     PIPE_AXIS,
     pcast_varying,
-    shard_map_compat as shard_map,
 )
 
 # (block_vars, y) -> y — or -> (y, quant_proposal) for the delayed-int8
@@ -234,7 +233,7 @@ def gpipe_trunk(block_apply: BlockApply, stacked: Dict[str, Any],
             lambda a: jax.lax.pmax(a, DATA_AXIS)[None], qacc)
         return y_full, q_new
 
-    y_out, q_new = shard_map(
+    y_out, q_new = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(PIPE_AXIS), act_spec),
         out_specs=(act_spec, P(PIPE_AXIS)),

@@ -31,9 +31,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from p2p_tpu.core.mesh import SPATIAL_AXIS, shard_map_compat as shard_map
+from p2p_tpu.core.mesh import SPATIAL_AXIS
 from p2p_tpu.parallel.halo import halo_exchange
 
 _DIMNUMS = ("NHWC", "HWIO", "NHWC")

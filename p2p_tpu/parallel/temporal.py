@@ -25,9 +25,9 @@ from __future__ import annotations
 import functools
 
 import jax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from p2p_tpu.core.mesh import TIME_AXIS, shard_map_compat as shard_map
+from p2p_tpu.core.mesh import TIME_AXIS
 from p2p_tpu.parallel.halo import halo_exchange
 
 _DIMNUMS3D = ("NDHWC", "DHWIO", "NDHWC")

@@ -361,19 +361,15 @@ class CheckpointManager:
         reconciliation. Goes through a ``PyTreeCheckpointer`` aimed at
         the step's item directory — the manager's own ``item_metadata``
         only works after a same-process save registered the handler.
-        Best-effort: None (unreadable/absent) disables reconciliation
-        for the step, restoring the plain structure-error behavior."""
+        Orbax 0.11 returns a ``StepMetadata`` whose ``item_metadata.tree``
+        is that dict. None when the step has no item directory (the
+        restore then raises its own not-found error)."""
         item_dir = os.path.join(str(self._mgr.directory), str(step),
                                 "default")
         if not os.path.isdir(item_dir):
             return None
-        try:
-            with ocp.PyTreeCheckpointer() as ckptr:
-                meta = ckptr.metadata(item_dir)
-            meta = getattr(meta, "tree", meta)
-            return meta if isinstance(meta, dict) else None
-        except Exception:
-            return None
+        with ocp.PyTreeCheckpointer() as ckptr:
+            return ckptr.metadata(item_dir).item_metadata.tree
 
     def restore(self, state_template: TrainState,
                 step: Optional[int] = None, verify: bool = True,
@@ -436,13 +432,19 @@ class CheckpointManager:
             # forward-compat quant reconciliation (module comment above):
             # intersect the template's quant trees with THIS step's saved
             # structure; missing leaves restore from the template's init
-            # values after the read. Metadata failures (or genuinely
-            # unreconcilable structures) fall back to the plain template
-            # — and the plain structure error, which stays the loud
-            # failure for every non-quant mismatch.
+            # values after the read. Genuinely unreconcilable structures
+            # fall back to the plain template — and the plain structure
+            # error, which stays the loud failure for every non-quant
+            # mismatch. Unreadable metadata marks the step unreadable,
+            # exactly like a failed array read below.
             tmpl_s, shards_s = state_template, shardings
             missing: List[Tuple[str, ...]] = []
-            meta = self._saved_structure(s)
+            try:
+                meta = self._saved_structure(s)
+            except Exception as exc:  # noqa: BLE001 — fallback ladder
+                self._note_corrupt(s, f"metadata unreadable: {exc!r}")
+                last_exc = exc
+                continue
             if isinstance(meta, dict):
                 try:
                     tmpl_s, shards_s, missing = reconcile_quant_template(
@@ -701,26 +703,17 @@ class CheckpointManager:
                 if jax.tree_util.tree_leaves(v)}
         abstract = jax.tree_util.tree_map(_abstract, want)
         restore_args = jax.tree_util.tree_map(_restore_arg, abstract)
-        import logging
-
-        absl_logger = logging.getLogger("absl")
-        prev_level = absl_logger.level
-        # orbax deprecation-warns (via absl) about the transformations API
-        # on every partial restore; one serving process may restore many
-        # times — silence just this call.
-        absl_logger.setLevel(logging.ERROR)
-        try:
-            with ocp.PyTreeCheckpointer() as ckptr:
-                restored = ckptr.restore(
-                    item_dir,
-                    args=ocp.args.PyTreeRestore(
-                        item=abstract,
-                        transforms={},  # keep template entries, drop rest
-                        restore_args=restore_args,
-                    ),
-                )
-        finally:
-            absl_logger.setLevel(prev_level)
+        # Orbax 0.11: partial_restore reads exactly the leaves ``item``
+        # names and never touches the rest of the on-disk tree
+        with ocp.PyTreeCheckpointer() as ckptr:
+            restored = ckptr.restore(
+                item_dir,
+                args=ocp.args.PyTreeRestore(
+                    item=abstract,
+                    restore_args=restore_args,
+                    partial_restore=True,
+                ),
+            )
         out = dict(fields)
         out.update({k: restored[k] for k in want})
         return type(template)(**out) if is_node else out

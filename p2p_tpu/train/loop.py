@@ -972,7 +972,7 @@ class Trainer:
             self.steps_per_epoch, dtype,
         )
         self.state_sharding = None
-        if self.mesh is not None and self.mesh.size > 1:
+        if self.mesh is not None:
             if self._tp or self._fsdp:
                 # The ONE partitioner (parallel/rules.py): Megatron
                 # channel shards on the TP conv pairs when model>1, ZeRO
@@ -992,6 +992,8 @@ class Trainer:
                 # Replicate the state over the mesh (as VideoTrainer does):
                 # batches arrive committed to all mesh devices, and jit
                 # refuses to mix them with single-device state arrays.
+                # A ONE-device mesh too: the step is jitted with explicit
+                # shardings on it (_build_step_fns).
                 from p2p_tpu.core.mesh import replicated
 
                 self.state = jax.device_put(self.state, replicated(self.mesh))
@@ -1046,10 +1048,14 @@ class Trainer:
 
     def _build_step_fns(self) -> None:
         cfg = self.cfg
-        if self.state_sharding is not None:
-            # CLI-TP path: the jit carries explicit in/out shardings so
-            # the TP-annotated state round-trips sharded and GSPMD plans
-            # the channel-shard collectives (parallel/dp.py + tp.py).
+        if self.mesh is not None:
+            # Every mesh run jits with EXPLICIT in/out shardings
+            # (parallel/dp.py): the state round-trips in the layout it
+            # came in — replicated, or the TP/FSDP rule tree — and the
+            # batch arrives in batch_sharding. Left to propagation, GSPMD
+            # handed back some updated params sharded over 'spatial', so
+            # the SECOND step was a different program and the whole
+            # train step compiled twice (found on bring-up, PR 21).
             from p2p_tpu.parallel.dp import (
                 make_parallel_multi_train_step,
                 make_parallel_train_step,
@@ -1066,16 +1072,16 @@ class Trainer:
                     self._dtype, state_sharding=self.state_sharding,
                 )
         else:
-            self.train_step = self._with_mesh(build_train_step(
+            self.train_step = build_train_step(
                 cfg, self.vgg_params, self.steps_per_epoch, self._dtype
-            ))
+            )
             self.multi_step = None
             if cfg.train.scan_steps > 1:
                 from p2p_tpu.train.step import build_multi_train_step
 
-                self.multi_step = self._with_mesh(build_multi_train_step(
+                self.multi_step = build_multi_train_step(
                     cfg, self.vgg_params, self.steps_per_epoch, self._dtype
-                ))
+                )
         self.eval_step = self._with_mesh(build_eval_step(cfg, self._dtype))
         # Sample-dump-only helper: the reference saves the QUANTIZED
         # compressed intermediate next to input/target/pred each epoch

@@ -9,6 +9,7 @@ step function is pure state-in/state-out — Q4/Q5 are unrepresentable.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -92,6 +93,27 @@ class InferState(struct.PyTreeNode):
     ema_g: Any = None
 
 
+# State init runs as ONE jitted program per (config, dtype). Run eagerly,
+# flax's ``module.init`` dispatches the whole forward op by op: on the
+# chip that was ~400 tiny XLA compiles per trainer (~0.3-0.6 s each —
+# two to four minutes of a cold start, found on bring-up, PR 21). The
+# jitted builders are memoised so repeated inits of one config (tests,
+# hot-swap templates) reuse the executable.
+
+
+@functools.lru_cache(maxsize=16)
+def _jitted_infer_init(cfg: Config, train_dtype):
+    return jax.jit(functools.partial(
+        _init_infer_state, cfg, train_dtype=train_dtype))
+
+
+@functools.lru_cache(maxsize=16)
+def _jitted_train_init(cfg: Config, steps_per_epoch: int, train_dtype):
+    return jax.jit(functools.partial(
+        _init_train_state, cfg, steps_per_epoch=steps_per_epoch,
+        train_dtype=train_dtype))
+
+
 def create_infer_state(
     cfg: Config,
     rng: jax.Array,
@@ -103,6 +125,11 @@ def create_infer_state(
     preset has one): no discriminator, no optimizer state, so the template
     itself is ~1/5 the size of a ``create_train_state`` template and needs
     no D hyperparameters (ndf) or pool sizing to match the checkpoint."""
+    return _jitted_infer_init(cfg, train_dtype)(
+        rng, {"input": sample_batch["input"]})
+
+
+def _init_infer_state(cfg, rng, sample_batch, train_dtype=None):
     g = define_G(cfg.model, dtype=train_dtype, remat=cfg.parallel.remat)
     c = (define_C(cfg.model, dtype=train_dtype)
          if cfg.model.use_compression_net else None)
@@ -346,6 +373,12 @@ def create_train_state(
     steps_per_epoch: int = 1,
     train_dtype=None,
 ) -> TrainState:
+    return _jitted_train_init(cfg, steps_per_epoch, train_dtype)(
+        rng, {k: sample_batch[k] for k in ("input", "target")})
+
+
+def _init_train_state(cfg, rng, sample_batch, steps_per_epoch=1,
+                      train_dtype=None):
     g, d, c = build_models(cfg, train_dtype)
     opt_g, opt_d, opt_c = make_optimizers(cfg, steps_per_epoch)
 

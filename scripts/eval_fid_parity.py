@@ -1,5 +1,5 @@
 """Compute VFID for torch-reference and JAX predictions with the IDENTICAL
-feature extractor — the controlled FID-parity comparison of BASELINE.md.
+feature extractor — the controlled FID-parity comparison (PARITY_fid.json).
 
 Both runners dump test-set predictions as PNGs named after the ground-truth
 files; this script embeds (ground truth, torch preds, jax preds) with the

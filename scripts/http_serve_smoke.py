@@ -12,6 +12,10 @@ ISSUE 12 / docs/SERVING.md "HTTP API":
    batch occupancy, tenant-tagged;
 5. SIGTERM → graceful drain → exit 0.
 
+One process per device: this parent never initialises jax. Checkpoints
+are written by short CPU-pinned children (``--save-step``), and the server
+child runs on whatever backend the environment names.
+
 Run: JAX_PLATFORMS=cpu python scripts/http_serve_smoke.py [workdir]
 """
 
@@ -26,19 +30,23 @@ import time
 import urllib.error
 import urllib.request
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def main() -> int:
-    workdir = sys.argv[1] if len(sys.argv) > 1 else "serve_smoke"
-    os.makedirs(workdir, exist_ok=True)
+def _child_env(**extra):
+    return {**os.environ, **extra,
+            "PYTHONPATH": _REPO + os.pathsep
+            + os.environ.get("PYTHONPATH", "")}
 
+
+def _save_step_child(name: str, step: int, seed: int, workdir: str) -> int:
+    """``--save-step`` mode: init a tiny facades TrainState from ``seed``
+    and save it as ``step`` — run in a child of its own (see
+    :func:`save_step`); prints the checkpoint directory."""
     import dataclasses
 
     import jax
-    import numpy as np  # noqa: F401 — synthetic_batch returns arrays
-    from PIL import Image
 
     from p2p_tpu.core.config import get_preset
     from p2p_tpu.data.synthetic import synthetic_batch
@@ -46,26 +54,45 @@ def main() -> int:
     from p2p_tpu.train.checkpoint import CheckpointManager
     from p2p_tpu.train.state import create_train_state
 
-    def make_cfg(name):
-        cfg = get_preset("facades")
-        return dataclasses.replace(
-            cfg, name=name,
-            model=dataclasses.replace(cfg.model, ngf=4),
-            data=dataclasses.replace(cfg.data, dataset="synth",
-                                     image_size=16))
+    cfg = get_preset("facades")
+    cfg = dataclasses.replace(
+        cfg, name=name,
+        model=dataclasses.replace(cfg.model, ngf=4),
+        data=dataclasses.replace(cfg.data, dataset="synth", image_size=16))
+    batch = synthetic_batch(1, 16, dtype="uint8")
+    state = create_train_state(cfg, jax.random.key(seed), batch, 1)
+    d = checkpoint_dir(cfg, workdir)
+    mgr = CheckpointManager(d)
+    mgr.save(step, state, wait=True)
+    mgr.close()
+    print(d, flush=True)
+    return 0
 
-    def save_step(cfg, step, seed):
-        batch = synthetic_batch(1, 16, dtype="uint8")
-        state = create_train_state(cfg, jax.random.key(seed), batch, 1)
-        d = checkpoint_dir(cfg, workdir)
-        mgr = CheckpointManager(d)
-        mgr.save(step, state, wait=True)
-        mgr.close()
-        return d
 
-    cfg1, cfg2 = make_cfg("m1"), make_cfg("m2")
-    d1 = save_step(cfg1, 1, seed=0)
-    save_step(cfg2, 1, seed=7)
+def save_step(name: str, step: int, seed: int, workdir: str) -> str:
+    """Write a checkpoint from a CPU-pinned child that exits. This
+    process never initialises jax: a device belongs to one process at a
+    time, and the SERVER child below must be the one that gets it."""
+    out = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), "--save-step",
+         name, str(step), str(seed), workdir],
+        env=_child_env(JAX_PLATFORMS="cpu"), check=True,
+        capture_output=True, text=True, timeout=300)
+    return out.stdout.strip().splitlines()[-1]
+
+
+def main() -> int:
+    if len(sys.argv) > 1 and sys.argv[1] == "--save-step":
+        name, step, seed, workdir = sys.argv[2:6]
+        return _save_step_child(name, int(step), int(seed), workdir)
+    workdir = sys.argv[1] if len(sys.argv) > 1 else "serve_smoke"
+    os.makedirs(workdir, exist_ok=True)
+
+    import numpy as np
+    from PIL import Image
+
+    d1 = save_step("m1", 1, 0, workdir)
+    save_step("m2", 1, 7, workdir)
     print("checkpoints saved for tenants m1, m2", flush=True)
 
     # ephemeral port, then hand it to the subprocess (tiny race window —
@@ -87,9 +114,7 @@ def main() -> int:
                     "image_size=16,ngf=4",
         "--workdir", workdir, "--max_batch", "2", "--dtype", "f32",
         "--linger_ms", "5", "--retry_delay_ms", "20",
-    ], env={**os.environ, "JAX_PLATFORMS": "cpu",
-            "PYTHONPATH": sys.path[0] + os.pathsep
-            + os.environ.get("PYTHONPATH", "")})
+    ], env=_child_env())   # the backend the caller's environment names
 
     def get(path, timeout=10):
         with urllib.request.urlopen(base + path, timeout=timeout) as r:
@@ -120,7 +145,8 @@ def main() -> int:
         assert up, "server never became healthy"
         print("server healthy", flush=True)
 
-        img = synthetic_batch(1, 16, seed=3, dtype="uint8")["input"][0]
+        img = np.random.default_rng(3).integers(
+            0, 256, (16, 16, 3), dtype=np.uint8)
         buf = io.BytesIO()
         Image.fromarray(img).save(buf, format="PNG")
         body = buf.getvalue()
@@ -144,7 +170,7 @@ def main() -> int:
             c.start()
         time.sleep(1.0)
 
-        save_step(cfg1, 2, seed=1)  # new weights land on disk
+        save_step("m1", 2, 1, workdir)  # new weights land on disk
         st, out = post("/admin/reload",
                        json.dumps({"tenant": "m1"}).encode())
         assert st == 200 and json.loads(out)["step"] == 2, (st, out)
@@ -171,7 +197,7 @@ def main() -> int:
               flush=True)
 
         # -- phase 3: corrupt-manifest swap rejected, old engine serves on
-        save_step(cfg1, 3, seed=2)
+        save_step("m1", 3, 2, workdir)
         integ = f"{d1}.aux/3.integrity.json"
         m = json.load(open(integ))
         leaf = next(iter(m["leaves"]))

@@ -1,5 +1,5 @@
 """CPU torch runner reproducing the reference training loop for the
-FID-parity baseline (BASELINE.md: the CUDA-side baseline "must be measured
+FID-parity baseline (the CUDA-side baseline "must be measured
 during the build").
 
 This is a from-spec reimplementation of /root/reference/train.py's live

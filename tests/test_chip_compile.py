@@ -1,0 +1,161 @@
+"""The Pallas kernels of the main path, compiled for a DESCRIBED TPU v5e.
+
+No chip is attached in CI, but the TPU compiler is installed: it compiles
+for a topology that is described (``v5e:2x2``), and refuses what the chip
+would refuse — a block shape Mosaic cannot tile, a scalar store to VMEM,
+a scoped-VMEM overflow — which interpret mode never sees. One real shape
+per kernel family, forward and grad, plus one ``shard_map`` variant on the
+2x2 mesh. About a second each; nothing runs, so nothing here is a time.
+
+The topology is described inside a module-scoped fixture of THIS file (one
+process may hold libtpu; see the on-chip-measurement guide) and the
+persistent compile cache is off around the compiles — an entry written
+for a described device cannot be read back without one.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any refusal means "skip"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh2x2(topo):
+    from p2p_tpu.core.mesh import MeshSpec, make_mesh
+
+    return make_mesh(MeshSpec(data=2, spatial=2), devices=topo.devices)
+
+
+def _compiled_text(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _sum_grad(fn, argnums=0):
+    return jax.grad(
+        lambda *a: jnp.sum(fn(*a).astype(jnp.float32)), argnums=argnums)
+
+
+def _assert_kernel(text):
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+
+
+# pix2pixHD local-enhancer activation at 1024x512 (narrow C: the padded
+# tile is what _pick_h_block must budget) and the PatchGAN's odd pad-2
+# inner extent at 256^2
+HD_SHAPE = (1, 512, 1024, 32)
+PATCH_SHAPE = (1, 65, 65, 256)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_instance_norm_fused_compiles(one_chip, grad):
+    from p2p_tpu.ops.pallas.instance_norm_kernel import instance_norm_fused
+
+    def fn(x):
+        return instance_norm_fused(x, None, None, 1e-5)
+
+    x = jax.ShapeDtypeStruct(HD_SHAPE, jnp.bfloat16, sharding=one_chip)
+    _assert_kernel(_compiled_text(_sum_grad(fn) if grad else fn, x))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_instance_norm_act_fused_with_residual_compiles(one_chip, grad):
+    from p2p_tpu.ops.pallas.norm_act import instance_norm_act_fused
+
+    def fn(x, r):
+        return instance_norm_act_fused(x, None, None, r, act="relu")
+
+    x = jax.ShapeDtypeStruct((1, 128, 256, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    _assert_kernel(_compiled_text(
+        _sum_grad(fn, argnums=(0, 1)) if grad else fn, x, x))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_instance_norm_act_quant_kernel_compiles(one_chip, grad):
+    """The quantizing epilogue (--norm_d pallas_instance
+    --int8_fused_epilogue) at a PatchGAN inner shape. Its forward was
+    refused outright before PR 21 (scalar store to VMEM, (1,1) block)."""
+    from p2p_tpu.ops.pallas.norm_act import instance_norm_act_quant
+
+    def fn(x, sx):
+        return instance_norm_act_quant(x, sx, act="leaky", slope=0.2,
+                                       use_kernel=True)[0]
+
+    x = jax.ShapeDtypeStruct(PATCH_SHAPE, jnp.bfloat16, sharding=one_chip)
+    sx = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    _assert_kernel(_compiled_text(_sum_grad(fn) if grad else fn, x, sx))
+
+
+def test_dual_moments_compiles(one_chip):
+    """BatchNorm's one-pass sum/sumsq at a facades bs128 activation."""
+    from p2p_tpu.ops.pallas.batch_moments import (
+        _pick_m_block,
+        pallas_dual_moments,
+    )
+
+    m, c = 128 * 64 * 64, 128
+    x = jax.ShapeDtypeStruct((m, c), jnp.bfloat16, sharding=one_chip)
+    _assert_kernel(_compiled_text(
+        lambda a: pallas_dual_moments(a, _pick_m_block(m, c)), x))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_subpixel_head_conv_compiles(one_chip, grad):
+    from p2p_tpu.ops.pallas.subpixel_head import subpixel_head_conv
+
+    def fn(x, w):
+        return subpixel_head_conv(x, w)
+
+    x = jax.ShapeDtypeStruct((8, 128, 128, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((2, 2, 128, 12), jnp.bfloat16,
+                             sharding=one_chip)
+    _assert_kernel(_compiled_text(
+        _sum_grad(fn, argnums=(0, 1)) if grad else fn, x, w))
+
+
+def test_sharded_instance_norm_keeps_the_shard(mesh2x2):
+    """The shard_map variant on data=2 x spatial=2 (H split in two): the
+    kernel runs on local shards and no all-gather of the activation
+    surrounds it."""
+    from p2p_tpu.analysis.jaxpr_lint import assert_no_collective_as_large_as
+    from p2p_tpu.ops.pallas.instance_norm import sharded_pallas_instance_norm
+
+    shape = (2, 128, 256, 128)
+    x = jax.ShapeDtypeStruct(
+        shape, jnp.bfloat16, sharding=NamedSharding(
+            mesh2x2, P(("data", "fsdp"), "spatial", None, None)))
+
+    def fn(a):
+        return sharded_pallas_instance_norm(a, None, None, 1e-5, mesh2x2)
+
+    text = _compiled_text(_sum_grad(fn), x)
+    _assert_kernel(text)
+    n, h, w, c = shape
+    assert_no_collective_as_large_as(text, n * h * w * c // 4)

@@ -482,39 +482,37 @@ def test_int8_multiscale_d_lsgan_stability_band():
         tail_q, tail_f)
 
 
-# ------------------------------------------- tiny-spatial wgrad guard
+# ------------------------------------------- tiny-spatial wgrad on TPU
 TINY_WGRAD_SNIPPET = """
-import os, jax, jax.numpy as jnp, numpy as np
+import jax, jax.numpy as jnp, numpy as np
 from p2p_tpu.ops.int8 import int8_conv
-# 4x4 input, k4 s2 p1 -> 2x2 output: ho*wo = 4 — the shape whose int8
-# strided-slice wgrad kernel-faulted the v5e runtime (round 2 repro).
-x = jnp.asarray(np.random.default_rng(0).normal(size=(2, 4, 4, 8)),
-                jnp.float32)
-w = jnp.asarray(np.random.default_rng(1).normal(size=(4, 4, 8, 16)),
-                jnp.float32)
-def f(x, w):
-    return jnp.sum(int8_conv(x, w, (2, 2), ((1, 1), (1, 1))) ** 2)
-gx, gw = jax.grad(f, (0, 1))(x, w)
-assert np.isfinite(np.asarray(gx)).all() and np.isfinite(np.asarray(gw)).all()
-print("OK", os.environ.get("P2P_INT8_WGRAD_SLICE_MIN", "default"))
+# k4 s2 p1 on 4x4 and 2x2 inputs -> 2x2 and 1x1 outputs: the extents
+# whose int8 strided-slice wgrad kernel-faulted an early v5e runtime,
+# and that facades_int8_full's U-Net bottom reaches.
+for hw in (4, 2):
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(2, hw, hw, 8)),
+                    jnp.float32)
+    w = jnp.asarray(np.random.default_rng(1).normal(size=(4, 4, 8, 16)),
+                    jnp.float32)
+    def f(x, w):
+        return jnp.sum(int8_conv(x, w, (2, 2), ((1, 1), (1, 1))) ** 2)
+    gx, gw = jax.grad(f, (0, 1))(x, w)
+    assert np.isfinite(np.asarray(gx)).all()
+    assert np.isfinite(np.asarray(gw)).all()
+    print("OK", hw)
 """
 
 
 @pytest.mark.slow
-def test_tiny_spatial_wgrad_guard_on_tpu():
+def test_tiny_spatial_wgrad_on_tpu():
     """Pins the ops/int8.py tiny-spatial int8 wgrad on REAL TPU hardware
-    (invisible on the CPU backend this suite pins).
-
-    History: the round-2/3 runtime kernel-faulted the int8 strided-slice
-    wgrad below ~16² output positions, guarded by
-    _INT8_WGRAD_SLICE_MIN=256; the round-4 runtime fixed it (verified by
-    this test's former P2P_RUN_FAULT_REPRO branch failing with its
-    retire-the-guard message) and the default window now starts at 0.
-    Default mode runs the tiny-spatial backward through the DEFAULT
-    dispatch — now the previously-faulting int8 slice path — and requires
-    success; if a future runtime regresses, this fails and the guard env
-    (P2P_INT8_WGRAD_SLICE_MIN=256) is the mitigation.
-    """
+    (invisible on the CPU backend this suite pins): the default dispatch
+    sends 2x2- and 1x1-output wgrads down the int8 strided-slice path,
+    which an early runtime kernel-faulted. It passed on the attached v5e
+    (libtpu 0.0.34, PR 21), so there is no guard and no knob; if a future
+    runtime regresses, this fails and ops/int8.py needs a lower bound
+    again. Probes the chip from a CHILD of this CPU-pinned process (the
+    parent never touches the device, so the child can have it)."""
     import subprocess
     import sys
 
@@ -528,25 +526,14 @@ def test_tiny_spatial_wgrad_guard_on_tpu():
     if "tpu" not in probe.stdout:
         pytest.skip(f"no TPU visible outside the CPU-pinned suite "
                     f"(got {probe.stdout.strip()!r})")
-    default = subprocess.run(
+    run = subprocess.run(
         [sys.executable, "-c", TINY_WGRAD_SNIPPET],
         capture_output=True, text=True, env=env, timeout=600,
     )
-    assert default.returncode == 0, (
-        "tiny-spatial int8 wgrad FAILED on this TPU runtime — the round-2 "
-        "kernel-fault may be back; mitigate with "
-        "P2P_INT8_WGRAD_SLICE_MIN=256 and restore the guard default in "
-        f"ops/int8.py:\n{default.stderr[-2000:]}"
-    )
-    # the bf16 fallback window must also stay healthy
-    env2 = dict(env, P2P_INT8_WGRAD_SLICE_MIN="256")
-    guarded = subprocess.run(
-        [sys.executable, "-c", TINY_WGRAD_SNIPPET],
-        capture_output=True, text=True, env=env2, timeout=600,
-    )
-    assert guarded.returncode == 0, (
-        f"guarded (bf16-fallback) tiny-spatial backward failed on TPU:\n"
-        f"{guarded.stderr[-2000:]}"
+    assert run.returncode == 0, (
+        "tiny-spatial int8 wgrad FAILED on this TPU runtime — the early "
+        "kernel-fault may be back; restore a lower spatial bound in "
+        f"ops/int8.py:\n{run.stderr[-2000:]}"
     )
 
 

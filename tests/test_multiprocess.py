@@ -51,7 +51,7 @@ def _launch_cluster(tmp_path, worker_name, root, extra_args=()):
     # 2 local CPU devices per process (the parent conftest exports 8; the
     # workers must agree on a fresh value BEFORE their jax import)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-    env.pop("JAX_PLATFORMS", None)  # worker pins cpu via jax.config
+    env["JAX_PLATFORMS"] = "cpu"
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
 

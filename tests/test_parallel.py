@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from p2p_tpu.core.config import get_preset
 from p2p_tpu.core.mesh import (
@@ -17,7 +17,6 @@ from p2p_tpu.core.mesh import (
     batch_sharding,
     make_mesh,
     replicated,
-    shard_map_compat as shard_map,
 )
 from p2p_tpu.parallel import (
     halo_exchange,
