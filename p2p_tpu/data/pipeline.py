@@ -29,6 +29,7 @@ import numpy as np
 from PIL import Image
 
 from p2p_tpu.data.generate import is_image_file
+from p2p_tpu.obs.spans import timed_annotation
 
 
 def load_image(path: str, h: int, w: int,
@@ -480,6 +481,7 @@ def device_prefetch(
     sharding=None,
     buffer_size: int = 2,
     with_aux: bool = False,
+    registry=None,
 ):
     """Double-buffered host→device transfer.
 
@@ -496,20 +498,39 @@ def device_prefetch(
 
     ``with_aux``: the iterator yields ``(batch, aux)`` pairs; the batch is
     device-put, the aux rides along untouched.
+
+    Two phases carry a ``TraceAnnotation`` each: ``loader_next`` (the
+    ``next()`` on the host iterator: batch assembly) and ``h2d_put`` (the
+    transfer's enqueue). This generator runs in its consumer's thread, so
+    both nest inside whatever span the consumer holds around its own
+    ``next()``. With a ``registry`` their durations also land in its
+    ``loader_next_secs`` / ``h2d_put_secs`` histograms; with none nothing
+    is recorded.
     """
+    loader_hist = put_hist = None
+    if registry is not None:
+        loader_hist = registry.histogram("loader_next_secs")
+        put_hist = registry.histogram("h2d_put_secs")
     queue = collections.deque()
+    exhausted = object()
 
     def _put(batch):
         if sharding is None:
             return jax.tree_util.tree_map(jax.numpy.asarray, batch)
         return place_global(batch, sharding)
 
-    for item in iterator:
-        if with_aux:
-            batch, aux = item
-            queue.append((_put(batch), aux))
-        else:
-            queue.append(_put(item))
+    iterator = iter(iterator)
+    while True:
+        with timed_annotation("loader_next", loader_hist):
+            item = next(iterator, exhausted)
+        if item is exhausted:
+            break
+        with timed_annotation("h2d_put", put_hist):
+            if with_aux:
+                batch, aux = item
+                queue.append((_put(batch), aux))
+            else:
+                queue.append(_put(item))
         if len(queue) >= buffer_size:
             yield queue.popleft()
     while queue:

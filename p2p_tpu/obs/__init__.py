@@ -41,7 +41,6 @@ from p2p_tpu.obs.sinks import (
 )
 from p2p_tpu.obs.spans import (
     SpanRecorder,
-    annotate,
     get_recorder,
     span,
     timed_annotation,
@@ -80,7 +79,6 @@ __all__ = [
     "StepTimer",
     "TensorBoardSink",
     "add_sentinel_handler",
-    "annotate",
     "build_manifest",
     "combine_host_snapshots",
     "config_hash",

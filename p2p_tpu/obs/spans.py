@@ -1,7 +1,7 @@
 """Span tracing: wall-clock phases paired with device-trace annotations.
 
-A span is a named host-side interval (epoch, eval, dispatch, checkpoint).
-Each ``span(...)`` does three things at once:
+A span is a named host-side interval (epoch, eval, checkpoint). Each
+``span(...)`` does three things at once:
 
 1. times the block on the host clock and keeps the (name, ts, dur, depth)
    tuple in a :class:`SpanRecorder` ring;
@@ -45,25 +45,33 @@ def trace(logdir: str):
         jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Bare named region on the device trace timeline (no host timing)."""
-    return jax.profiler.TraceAnnotation(name)
+class timed_annotation:
+    """The hot-path span: a ``TraceAnnotation`` of ``name`` (so the name
+    lands on the profiler's clock beside the device ops whenever a capture
+    runs) plus an optional histogram observation (so the number exists
+    when none does), and NO entry in a recorder ring — a per-step span
+    there would flood the exported trace. After the block ``t0`` holds
+    its start on the span clock and ``secs`` its duration; the trainers
+    sum those into the epoch record."""
 
+    __slots__ = ("_annotation", "_histogram", "t0", "secs")
 
-@contextlib.contextmanager
-def timed_annotation(name: str, histogram=None):
-    """Lightweight hot-path variant of a span: TraceAnnotation + an
-    optional histogram observation, but NO entry in a recorder ring —
-    for per-dispatch use, where recording every interval would flood the
-    exported trace (the trainers sample only each epoch's first dispatches
-    into the ring and route the rest here)."""
-    t0 = _perf_counter()
-    try:
-        with jax.profiler.TraceAnnotation(name):
-            yield
-    finally:
-        if histogram is not None:
-            histogram.observe(_perf_counter() - t0)
+    def __init__(self, name: str, histogram=None):
+        self._annotation = jax.profiler.TraceAnnotation(name)
+        self._histogram = histogram
+        self.t0 = self.secs = 0.0
+
+    def __enter__(self):
+        self.t0 = _perf_counter()
+        self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._annotation.__exit__(*exc)
+        self.secs = _perf_counter() - self.t0
+        if self._histogram is not None:
+            self._histogram.observe(self.secs)
+        return False
 
 
 class SpanRecorder:
@@ -93,15 +101,16 @@ class SpanRecorder:
         """Time the block; pair with a TraceAnnotation; record on exit.
 
         ``attrs`` (e.g. epoch=3) ride along into the span record and the
-        optional registry record; ``histogram`` additionally receives the
-        duration."""
+        optional registry record, and the block may add to them: they are
+        the dict the ``with`` statement binds. ``histogram`` additionally
+        receives the duration."""
         depth = self._depth()
         self._tls.depth = depth + 1
         ts = _wall_clock()
         t0 = _perf_counter()
         try:
             with jax.profiler.TraceAnnotation(name):
-                yield self
+                yield attrs
         finally:
             dur = _perf_counter() - t0
             self._tls.depth = depth

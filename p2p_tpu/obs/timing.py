@@ -1,7 +1,7 @@
 """Fenced step timing — the one img/sec/chip definition.
 
-:class:`StepTimer` (moved from ``utils/profiling.py``) measures wall-clock
-over FENCED step boundaries two ways:
+:class:`StepTimer` measures wall-clock over FENCED step boundaries two
+ways:
 
 - ``tick()`` per step with ``block_until_ready`` on the metrics pytree —
   the loop-style API the seed had;

@@ -57,6 +57,7 @@ from p2p_tpu.obs import (
     SpanRecorder,
     add_sentinel_handler,
     crosscheck_hbm_budget,
+    timed_annotation,
     write_manifest,
 )
 from p2p_tpu.resilience import Preempted, PreemptionGuard
@@ -1226,29 +1227,49 @@ class Trainer:
     def train_epoch(self, seed: Optional[int] = None,
                     skip_batches: int = 0,
                     skip_samples: int = 0) -> Dict[str, float]:
+        """One pass over the training split; returns the epoch's metric
+        means. Every second of it falls into one phase, each a
+        ``TraceAnnotation`` of that name and a ``<name>_secs`` histogram
+        on ``self.obs`` (docs/OBSERVABILITY.md has the table), and the
+        call leaves ONE ``train_epoch`` record in the span ring and the
+        metrics stream: the sum of each phase, the time to the first
+        dispatch and the slowest single wait, dispatch and bookkeeping
+        with the step each fell on."""
+        with self.spans.span(
+                "train_epoch", registry=self.obs, force=True,
+                histogram=self.obs.histogram("train_epoch_secs"),
+                epoch=self.epoch) as record:
+            return self._train_epoch(record, seed, skip_batches,
+                                     skip_samples)
+
+    def _train_epoch(self, record: Dict, seed: Optional[int],
+                     skip_batches: int, skip_samples: int
+                     ) -> Dict[str, float]:
         cfg = self.cfg
-        # Per-epoch entropy (shuffle order + augmentation crops),
-        # reproducible across same-seed runs. Defaults to the current
-        # epoch so bare train_epoch() loops still see fresh crops each
-        # epoch rather than a frozen augmented stream. A rollback
-        # (perform_rollback) perturbs the jitter so the diverging batch
-        # order is not replayed verbatim.
-        seed = self.epoch if seed is None else seed
-        seed = seed + getattr(self, "_seed_jitter", 0)
-        self.train_ds.aug_seed = cfg.train.seed + seed
-        # Worker processes are pickled a FRESH copy of the dataset each
-        # epoch, which would empty the decode memo and re-decode every
-        # image — when the split is cached, in-process loading keeps the
-        # memo hot (decode cost is paid exactly once, epoch 1).
-        workers = 0 if self.train_ds.cache_enabled else (
-            cfg.data.threads if len(self.train_ds) > 64 else 0
-        )
-        loader = make_loader(
-            self.train_ds, self.local_bs, shuffle=True,
-            seed=cfg.train.seed + seed, num_workers=workers,
-            skip_batches=skip_batches, skip_samples=skip_samples,
-            registry=self.obs,
-        )
+        hist = self.obs.histogram
+        with timed_annotation("epoch_setup", hist("epoch_setup_secs")) as setup:
+            # Per-epoch entropy (shuffle order + augmentation crops),
+            # reproducible across same-seed runs. Defaults to the current
+            # epoch so bare train_epoch() loops still see fresh crops each
+            # epoch rather than a frozen augmented stream. A rollback
+            # (perform_rollback) perturbs the jitter so the diverging batch
+            # order is not replayed verbatim.
+            seed = self.epoch if seed is None else seed
+            seed = seed + getattr(self, "_seed_jitter", 0)
+            self.train_ds.aug_seed = cfg.train.seed + seed
+            # Worker processes are pickled a FRESH copy of the dataset each
+            # epoch, which would empty the decode memo and re-decode every
+            # image — when the split is cached, in-process loading keeps
+            # the memo hot (decode cost is paid exactly once, epoch 1).
+            workers = 0 if self.train_ds.cache_enabled else (
+                cfg.data.threads if len(self.train_ds) > 64 else 0
+            )
+            loader = make_loader(
+                self.train_ds, self.local_bs, shuffle=True,
+                seed=cfg.train.seed + seed, num_workers=workers,
+                skip_batches=skip_batches, skip_samples=skip_samples,
+                registry=self.obs,
+            )
         # Keep a device-side running sum (no host sync mid-epoch, no buffer
         # pile-up) and transfer ONCE at epoch end, so averages cover EVERY
         # step regardless of log_every.
@@ -1260,26 +1281,32 @@ class Trainer:
         compile_skew = 0.0  # later first-compiles excluded from throughput
         seen_kinds: set = set()
         last_logged = 0
-        n_disp = 0
-        disp_hist = self.obs.histogram("dispatch_secs")
+        feed_hist = hist("feed_next_secs")
+        disp_hist = hist("dispatch_secs")
+        book_hist = hist("step_bookkeeping_secs")
+        # device_prefetch observes these two inside feed_next
+        nested = (hist("loader_next_secs"), hist("h2d_put_secs"))
+        nested_before = [h.sum for h in nested]
+        # this epoch's seconds in each per-step phase, and the slowest
+        # single one as (seconds, steps done when it began); the first
+        # feed_next holds the prefetch fill and is kept apart
+        phase_s = {"feed_next": 0.0, "train_dispatch": 0.0,
+                   "step_bookkeeping": 0.0}
+        slowest = dict.fromkeys(phase_s, (0.0, 0))
 
-        def run(batch_or_stack, k):
-            nonlocal sums, count, t0, first_k, compile_skew, last_logged, \
-                n_disp
+        def note(phase, span, at):
+            phase_s[phase] += span.secs
+            if span.secs > slowest[phase][0]:
+                slowest[phase] = (span.secs, at)
+
+        def run(batch_or_stack, k) -> bool:
+            """One dispatch and its bookkeeping; True = stop feeding."""
+            nonlocal sums, count, t0, first_k, compile_skew, last_logged
             t_call = time.perf_counter()
-            # Every dispatch feeds the duration histogram and carries a
-            # TraceAnnotation; only each epoch's FIRST few land in the
-            # exported span ring — per-step spans would flood the 200k
-            # ring on long runs and evict the epoch/eval spans.
-            if n_disp < 4:
-                cm = self.spans.span("train_dispatch", steps=k,
-                                     histogram=disp_hist)
-            else:
-                from p2p_tpu.obs import timed_annotation
-
-                cm = timed_annotation("train_dispatch", disp_hist)
-            n_disp += 1
-            with cm:
+            at = count
+            # every dispatch takes the one light path: annotation +
+            # histogram; the ring holds the epoch's record, not its steps
+            with timed_annotation("train_dispatch", disp_hist) as disp:
                 if k > 1:
                     self.state, metrics = self.multi_step(
                         self.state, batch_or_stack
@@ -1292,67 +1319,92 @@ class Trainer:
                     self.state, last = self.train_step(
                         self.state, batch_or_stack)
                     step_metrics = last
-            self._img_rate.mark(k * cfg.data.batch_size)
-            # divergence sentinel: queue THIS dispatch, read the previous
-            # one (already retired — no fence); scanned dispatches feed
-            # their per-step stacked metrics so no step escapes
-            queue_health_observation(self, metrics if k > 1 else last, k)
-            if self._quant_freeze_remaining:
-                # --recalibrate_steps warmup after a TP amax migration:
-                # re-pin the migrated scales (resilience/reshape.py)
-                from p2p_tpu.resilience.reshape import hold_frozen_quant
+            if at == 0:
+                record["epoch_start_s"] = round(disp.t0 - setup.t0, 6)
+            note("train_dispatch", disp, at)
+            stop = False
+            with timed_annotation("step_bookkeeping", book_hist) as book:
+                self._img_rate.mark(k * cfg.data.batch_size)
+                # divergence sentinel: queue THIS dispatch, read the
+                # previous one (a wait on the device when the host runs
+                # ahead, not idle time); scanned dispatches feed their
+                # per-step stacked metrics so no step escapes
+                queue_health_observation(self, metrics if k > 1 else last, k)
+                if self._quant_freeze_remaining:
+                    # --recalibrate_steps warmup after a TP amax migration:
+                    # re-pin the migrated scales (resilience/reshape.py)
+                    from p2p_tpu.resilience.reshape import hold_frozen_quant
 
-                hold_frozen_quant(self)
-            if cfg.debug.check_finite:
-                # host-side guard (fences this dispatch): the nonfinite
-                # record lands in the metrics stream BEFORE the raise.
-                # Checked on the scan-axis SUM, not the last step's slice —
-                # summing propagates any intermediate step's NaN/Inf, so a
-                # transient blowup inside a K-step dispatch can't slip past
-                from p2p_tpu.core.debug import check_finite
+                    hold_frozen_quant(self)
+                if cfg.debug.check_finite:
+                    # host-side guard (fences this dispatch): the nonfinite
+                    # record lands in the metrics stream BEFORE the raise.
+                    # Checked on the scan-axis SUM, not the last step's
+                    # slice — summing propagates any intermediate step's
+                    # NaN/Inf, so a transient blowup inside a K-step
+                    # dispatch can't slip past
+                    from p2p_tpu.core.debug import check_finite
 
-                check_finite(step_metrics, "step_metrics", registry=self.obs)
-            # a skipped step's NaN losses must not poison the epoch-sum
-            # averages (or the plateau controller fed from them): mask
-            # skipped steps out of the ACCUMULATOR only — the raw values
-            # still reach the sentinel/check_finite/log paths above
-            step_metrics = mask_skipped_metrics(
-                metrics if k > 1 else last, k)
-            if count > 0 and k not in seen_kinds:
-                # first use of this dispatch shape mid-epoch (e.g. the
-                # single-step remainder after scanned dispatches): the call
-                # blocked on trace+compile — keep it out of img_per_sec
-                compile_skew += time.perf_counter() - t_call
-            seen_kinds.add(k)
-            sums = step_metrics if sums is None else jax.tree_util.tree_map(
-                jax.numpy.add, sums, step_metrics
-            )
-            first = count == 0
-            count += k
-            if first:
-                # the first call blocks on trace+XLA compile; exclude it
-                # from the throughput figure (first epoch only, in practice)
-                first_k = k
-                t0 = time.perf_counter()
-            if count - last_logged >= cfg.train.log_every:
-                last_logged = count
-                host = {kk: float(v) for kk, v in last.items()}
-                self.logger.log(
-                    {"kind": "train", "epoch": self.epoch,
-                     "step": int(self.state.step),
-                     # cumulative samples through this dispatch — the
-                     # evidence the cross-BATCH elastic rehearsals tile
-                     # for gaplessness (a host counter, no device sync)
-                     "samples": int(self._samples_seen), **host},
-                    force=True,
-                )
+                    check_finite(step_metrics, "step_metrics",
+                                 registry=self.obs)
+                # a skipped step's NaN losses must not poison the epoch-sum
+                # averages (or the plateau controller fed from them): mask
+                # skipped steps out of the ACCUMULATOR only — the raw values
+                # still reach the sentinel/check_finite/log paths above
+                step_metrics = mask_skipped_metrics(
+                    metrics if k > 1 else last, k)
+                if count > 0 and k not in seen_kinds:
+                    # first use of this dispatch shape mid-epoch (e.g. the
+                    # single-step remainder after scanned dispatches): the
+                    # call blocked on trace+compile — keep it out of
+                    # img_per_sec
+                    compile_skew += time.perf_counter() - t_call
+                seen_kinds.add(k)
+                sums = step_metrics if sums is None else \
+                    jax.tree_util.tree_map(jax.numpy.add, sums, step_metrics)
+                first = count == 0
+                count += k
+                if first:
+                    # the first call blocks on trace+XLA compile; exclude it
+                    # from the throughput figure (first epoch only, in
+                    # practice)
+                    first_k = k
+                    t0 = time.perf_counter()
+                if count - last_logged >= cfg.train.log_every:
+                    last_logged = count
+                    host = {kk: float(v) for kk, v in last.items()}
+                    self.logger.log(
+                        {"kind": "train", "epoch": self.epoch,
+                         "step": int(self.state.step),
+                         # cumulative samples through this dispatch — the
+                         # evidence the cross-BATCH elastic rehearsals tile
+                         # for gaplessness (a host counter, no device sync)
+                         "samples": int(self._samples_seen), **host},
+                        force=True,
+                    )
+                # recovery ladder rung 3: stop feeding batches — fit() owns
+                # the restore-and-reenter policy (perform_rollback)
+                if self.health is not None and self.health.rollback_pending:
+                    stop = True
+                # Preemption poll at the step boundary (cross-host agreed —
+                # every process runs the same dispatch count, so the
+                # agreement collective stays aligned), fronted by the
+                # `elastic` chaos seam. The flag is only SET here; fit()
+                # owns the save-and-exit policy.
+                # p2p-lint: disable=collective-divergent-branch -- the rollback branch above is host-uniform: the ladder consumes device-REPLICATED metrics (identical float conversions on every host), so rollback_pending flips on the same dispatch everywhere
+                elif poll_preempt(self):
+                    self._preempted = True
+                    stop = True
+            note("step_bookkeeping", book, at)
+            return stop
 
         def dispatch_batches():
             """Yield (device_batch, n_steps): host batches K-stacked for the
             scan path (stacked on HOST, then placed with the K-extended
             sharding — stacking already-sharded device arrays would gather)."""
             if K <= 1:
-                for b in device_prefetch(loader, self.batch_sharding):
+                for b in device_prefetch(loader, self.batch_sharding,
+                                         registry=self.obs):
                     yield b, 1
                 return
             stacked_sh = None
@@ -1385,31 +1437,50 @@ class Trainer:
                              for kk, v in b.items()}
                     yield b, 1
 
-            yield from device_prefetch(gen(), None, with_aux=True)
+            yield from device_prefetch(gen(), None, with_aux=True,
+                                       registry=self.obs)
 
-        for batch, k in dispatch_batches():
-            run(batch, k)
-            # recovery ladder rung 3: stop feeding batches — fit() owns
-            # the restore-and-reenter policy (perform_rollback)
-            if self.health is not None and self.health.rollback_pending:
+        batches = dispatch_batches()
+        first_feed_s = 0.0
+        while True:
+            # what the loop WAITED for a device batch. The terminal call
+            # (the feed is exhausted) is in the epoch's sum but is no
+            # step's wait: the histogram counts one wait a dispatch
+            with timed_annotation("feed_next") as feed:
+                item = next(batches, None)
+            if item is None:
+                phase_s["feed_next"] += feed.secs
                 break
-            # Preemption poll at the step boundary (cross-host agreed —
-            # every process runs the same dispatch count, so the agreement
-            # collective stays aligned), fronted by the `elastic` chaos
-            # seam. The flag is only SET here; fit() owns the
-            # save-and-exit policy.
-            # p2p-lint: disable=collective-after-divergent-exit -- the rollback break above is host-uniform: the ladder consumes device-REPLICATED metrics (identical float conversions on every host), so rollback_pending flips on the same dispatch everywhere
-            if poll_preempt(self):
-                self._preempted = True
+            feed_hist.observe(feed.secs)
+            if count == 0:
+                first_feed_s = feed.secs
+                phase_s["feed_next"] += feed.secs
+            else:
+                note("feed_next", feed, count)
+            if run(*item):
                 break
-        # drain the delayed sentinel slot: the epoch's last dispatch must
-        # not escape classification (it may be the diverging one)
-        flush_health_observations(self)
+        with timed_annotation("epoch_drain", hist("epoch_drain_secs")) as drain:
+            # drain the delayed sentinel slot: the epoch's last dispatch
+            # must not escape classification (it may be the diverging one)
+            flush_health_observations(self)
+            if sums is not None:
+                # p2p-lint: disable=ast-host-sync-hot-loop -- epoch boundary, once per epoch: the epoch record needs the sums and the fence doubles as the img/sec stop-clock
+                host_sums = jax.device_get(sums)  # fences the last step
+                elapsed = time.perf_counter() - t0 - compile_skew
+        record.update(
+            steps=count,
+            epoch_setup_s=round(setup.secs, 6),
+            epoch_drain_s=round(drain.secs, 6),
+            first_feed_next_s=round(first_feed_s, 6),
+            loader_next_s=round(nested[0].sum - nested_before[0], 6),
+            h2d_put_s=round(nested[1].sum - nested_before[1], 6),
+        )
+        for phase, secs in phase_s.items():
+            record[f"{phase}_s"] = round(secs, 6)
+            record[f"slowest_{phase}_s"] = round(slowest[phase][0], 6)
+            record[f"slowest_{phase}_step"] = slowest[phase][1]
         if sums is None:
             return {}
-        # p2p-lint: disable=ast-host-sync-hot-loop -- epoch boundary, once per epoch: the epoch record needs the sums and the fence doubles as the img/sec stop-clock
-        host_sums = jax.device_get(sums)  # fences the epoch's last step
-        elapsed = time.perf_counter() - t0 - compile_skew
         out = epoch_metric_means(host_sums, count)
         if count > first_k:
             out["img_per_sec"] = (

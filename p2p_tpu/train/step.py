@@ -72,6 +72,22 @@ from p2p_tpu.train.state import TrainState, build_models, make_optimizers
 from p2p_tpu.utils.images import ingest
 
 
+#: Whose work an op of the step program is: every net, loss and optimizer
+#: update runs under ONE of these ``jax.named_scope`` names, so the first
+#: of them in an op's ``op_name`` (``jit(step)/jvp(G)/...`` forward,
+#: ``.../transpose(jvp(G))/...`` backward) names its owner in the compiled
+#: text, and through it in a device trace (benchmark/scope_time.py).
+#: ``compress`` is C + the quantizer; D has two forwards, ``D_fake`` (whose
+#: residuals also serve the G-loss pull through D) and ``D_real``;
+#: ``loss_vgg`` holds the style loss too (the same VGG features);
+#: ``loss_pix`` the pixel-space terms (L1, angular, sobel); ``C_branch`` is
+#: the compression branch's whole pass against the updated G; the ``opt_*``
+#: hold the skip guard's selects on what they update, ``opt_g`` G's EMA.
+STEP_SCOPES = ("compress", "G", "D_fake", "D_real", "loss_gan", "loss_fm",
+               "loss_vgg", "loss_tv", "loss_pix", "C_branch", "opt_g",
+               "opt_d", "opt_c")
+
+
 def _concat_pair(a: jax.Array, b: jax.Array) -> jax.Array:
     return jnp.concatenate([a, b], axis=-1)
 
@@ -93,21 +109,26 @@ def single_forward_d_losses(d_apply, dvars0, params_d, fake_pair,
     (2 spectral power iterations per step; deviation documented above).
     """
     def fake_primal(params, pair):
-        pred, v1 = d_apply(params, dvars0, pair)
+        with jax.named_scope("D_fake"):
+            pred, v1 = d_apply(params, dvars0, pair)
         return pred, v1
+
+    def d_gan_loss(pred, is_real):
+        with jax.named_scope("loss_gan"):
+            return 0.5 * gan_loss(pred, is_real, gan_mode)
 
     pred_fake, d_vjp, dvars1 = jax.vjp(
         fake_primal, params_d, fake_pair, has_aux=True
     )
     loss_fake, ct_fake = jax.value_and_grad(
-        lambda p: 0.5 * gan_loss(p, False, gan_mode)
+        lambda p: d_gan_loss(p, False)
     )(pred_fake)
     gd_fake = d_vjp(ct_fake)[0]  # pair cotangent dead → DCE
 
     def real_fn(params):
-        pred_real, v2 = d_apply(params, dvars1, real_pair)
-        loss = 0.5 * gan_loss(pred_real, True, gan_mode)
-        return loss, (v2, pred_real)
+        with jax.named_scope("D_real"):
+            pred_real, v2 = d_apply(params, dvars1, real_pair)
+        return d_gan_loss(pred_real, True), (v2, pred_real)
 
     (loss_real, (dvars2, pred_real)), gd_real = jax.value_and_grad(
         real_fn, has_aux=True
@@ -133,34 +154,45 @@ def make_g_loss_fn(cfg: Config, vgg_params: Optional[Any] = None,
     need_vgg = (L.lambda_vgg > 0) and vgg_params is not None
 
     def g_losses(fake_b, pred_fake_g, pred_real, real_a, real_b, step):
-        l_gan = gan_loss(pred_fake_g, True, L.gan_mode,
-                         for_discriminator=False)
+        with jax.named_scope("loss_gan"):
+            l_gan = gan_loss(pred_fake_g, True, L.gan_mode,
+                             for_discriminator=False)
         parts = {"g_gan": l_gan}
         total = l_gan
         if L.lambda_feat > 0:
-            l_feat = feature_matching_loss(
-                pred_fake_g, pred_real, cfg.model.n_layers_D, L.lambda_feat
-            )
+            with jax.named_scope("loss_fm"):
+                l_feat = feature_matching_loss(
+                    pred_fake_g, pred_real, cfg.model.n_layers_D,
+                    L.lambda_feat
+                )
             parts["g_feat"] = l_feat
             total = total + l_feat
         if need_vgg:
-            l_vgg = vgg_loss(
-                vgg_params, fake_b, real_b, L.vgg_imagenet_norm
-            ) * L.lambda_vgg
+            with jax.named_scope("loss_vgg"):
+                l_vgg = vgg_loss(
+                    vgg_params, fake_b, real_b, L.vgg_imagenet_norm
+                ) * L.lambda_vgg
             parts["g_vgg"] = l_vgg
             total = total + l_vgg
         if L.lambda_style > 0 and vgg_params is not None:
             from p2p_tpu.losses.style import style_loss
 
-            l_style = style_loss(
-                vgg_params, fake_b, real_b, L.vgg_imagenet_norm
-            ) * L.lambda_style
+            with jax.named_scope("loss_vgg"):
+                l_style = style_loss(
+                    vgg_params, fake_b, real_b, L.vgg_imagenet_norm
+                ) * L.lambda_style
             parts["g_style"] = l_style
             total = total + l_style
         if L.lambda_tv > 0:
-            l_tv = total_variation_loss(fake_b) * L.lambda_tv
+            with jax.named_scope("loss_tv"):
+                l_tv = total_variation_loss(fake_b) * L.lambda_tv
             parts["g_tv"] = l_tv
             total = total + l_tv
+        with jax.named_scope("loss_pix"):
+            total = pixel_terms(total, parts, fake_b, real_a, real_b, step)
+        return total, parts
+
+    def pixel_terms(total, parts, fake_b, real_a, real_b, step):
         if L.lambda_angular > 0:
             from p2p_tpu.ops.sobel import angular_loss
 
@@ -201,7 +233,7 @@ def make_g_loss_fn(cfg: Config, vgg_params: Optional[Any] = None,
             ) * L.lambda_l1
             parts["g_l1"] = l_l1
             total = total + l_l1
-        return total, parts
+        return total
 
     return g_losses
 
@@ -264,7 +296,8 @@ def build_train_step(
         if use_quant:
             variables["quant"] = quant
             mut.append("quant")
-        out, v = g.apply(variables, x, True, mutable=mut, rngs=rngs)
+        with jax.named_scope("G"):
+            out, v = g.apply(variables, x, True, mutable=mut, rngs=rngs)
         return out, v["batch_stats"], (v.get("quant", {}) if use_quant
                                        else None)
 
@@ -297,7 +330,8 @@ def build_train_step(
                     vc.get("quant") if use_qc else state.quant_c)
 
         if use_c:
-            compressed, bs_c1, quant_c1 = compressed_fn(state.params_c)
+            with jax.named_scope("compress"):
+                compressed, bs_c1, quant_c1 = compressed_fn(state.params_c)
         else:
             compressed, bs_c1, quant_c1 = (real_a, state.batch_stats_c,
                                            state.quant_c)
@@ -407,12 +441,15 @@ def build_train_step(
                 dvars0["quant"] = state.quant_d
 
             def loss_d_fn(params_d):
-                pred_fake, v1 = d_fwd(params_d, dvars0, fake_pair)
-                pred_real, v2 = d_fwd(params_d, v1, real_pair)
-                loss = 0.5 * (
-                    gan_loss(pred_fake, False, L.gan_mode)
-                    + gan_loss(pred_real, True, L.gan_mode)
-                )
+                with jax.named_scope("D_fake"):
+                    pred_fake, v1 = d_fwd(params_d, dvars0, fake_pair)
+                with jax.named_scope("D_real"):
+                    pred_real, v2 = d_fwd(params_d, v1, real_pair)
+                with jax.named_scope("loss_gan"):
+                    loss = 0.5 * (
+                        gan_loss(pred_fake, False, L.gan_mode)
+                        + gan_loss(pred_real, True, L.gan_mode)
+                    )
                 return loss, (v2, pred_real)
 
             (loss_d, (dvars1, pred_real)), grads_d = jax.value_and_grad(
@@ -423,11 +460,12 @@ def build_train_step(
             )
 
             def loss_g_fn(fake_b):
-                pred_fake_g, v3 = d_fwd(
-                    jax.lax.stop_gradient(state.params_d),
-                    dvars1,
-                    _concat_pair(real_a, fake_b),
-                )
+                with jax.named_scope("D_fake"):
+                    pred_fake_g, v3 = d_fwd(
+                        jax.lax.stop_gradient(state.params_d),
+                        dvars1,
+                        _concat_pair(real_a, fake_b),
+                    )
                 total, parts = g_losses(fake_b, pred_fake_g)
                 return total, (v3, parts)
 
@@ -449,8 +487,10 @@ def build_train_step(
             )
 
             ok = losses_finite(loss_g, loss_d)
-            grads_g = zero_if_unhealthy(ok, grads_g)
-            grads_d = zero_if_unhealthy(ok, grads_d)
+            with jax.named_scope("opt_g"):
+                grads_g = zero_if_unhealthy(ok, grads_g)
+            with jax.named_scope("opt_d"):
+                grads_d = zero_if_unhealthy(ok, grads_d)
 
         # ---- 4. apply G then D updates (reference order) ----------------
         # lr_scale: Adam updates are linear in lr, so the host-driven
@@ -462,17 +502,21 @@ def build_train_step(
         scale_tree = lambda ups: jax.tree_util.tree_map(  # noqa: E731
             lambda u: u * scale.astype(u.dtype), ups
         )
-        up_g, opt_g1 = opt_g.update(grads_g, state.opt_g, state.params_g)
-        params_g1 = optax.apply_updates(state.params_g, scale_tree(up_g))
-        up_d, opt_d1 = opt_d.update(grads_d, state.opt_d, state.params_d)
-        params_d1 = optax.apply_updates(state.params_d, scale_tree(up_d))
+        with jax.named_scope("opt_g"):
+            up_g, opt_g1 = opt_g.update(grads_g, state.opt_g, state.params_g)
+            params_g1 = optax.apply_updates(state.params_g, scale_tree(up_g))
+        with jax.named_scope("opt_d"):
+            up_d, opt_d1 = opt_d.update(grads_d, state.opt_d, state.params_d)
+            params_d1 = optax.apply_updates(state.params_d, scale_tree(up_d))
         if ok is not None:
             # a skipped step must not advance the optimizer moments/count
             # (zeroed grads still decay them) or absorb the step's NaN-
             # tainted collection updates
-            opt_g1 = health_select(ok, opt_g1, state.opt_g)
-            opt_d1 = health_select(ok, opt_d1, state.opt_d)
-            spectral2 = health_select(ok, spectral2, state.spectral_d)
+            with jax.named_scope("opt_g"):
+                opt_g1 = health_select(ok, opt_g1, state.opt_g)
+            with jax.named_scope("opt_d"):
+                opt_d1 = health_select(ok, opt_d1, state.opt_d)
+                spectral2 = health_select(ok, spectral2, state.spectral_d)
             if use_quant:
                 quant_g1 = health_select(ok, quant_g1, state.quant_g)
                 quant_d1 = health_select(ok, quant_d1, state.quant_d)
@@ -485,16 +529,19 @@ def build_train_step(
         if ema_decay is not None and state.ema_g is not None:
             from p2p_tpu.train.state import ema_update
 
-            ema_g1 = ema_update(state.ema_g, params_g1, ema_decay)
+            with jax.named_scope("opt_g"):
+                ema_g1 = ema_update(state.ema_g, params_g1, ema_decay)
             if ok is not None:
                 from p2p_tpu.train.state import health_select
 
-                ema_g1 = health_select(ok, ema_g1, state.ema_g)
+                with jax.named_scope("opt_g"):
+                    ema_g1 = health_select(ok, ema_g1, state.ema_g)
 
         # ---- 5. compression branch vs the UPDATED generator -------------
         loss_c = jnp.zeros((), jnp.float32)
         params_c1, opt_c1, bs_g2 = state.params_c, state.opt_c, bs_g1
         if use_c:
+            @jax.named_scope("C_branch")
             def loss_c_fn(params_c):
                 cq, _, _ = compressed_fn(params_c)
                 c_rng = (jax.random.fold_in(drop_rng, 1)
@@ -513,8 +560,11 @@ def build_train_step(
                 loss_c_fn, has_aux=True
             )(state.params_c)
             if cfg.optim.train_compression_net:
-                up_c, opt_c1 = opt_c.update(grads_c, state.opt_c, state.params_c)
-                params_c1 = optax.apply_updates(state.params_c, scale_tree(up_c))
+                with jax.named_scope("opt_c"):
+                    up_c, opt_c1 = opt_c.update(
+                        grads_c, state.opt_c, state.params_c)
+                    params_c1 = optax.apply_updates(
+                        state.params_c, scale_tree(up_c))
 
         ok_all = ok
         if ok is not None:
@@ -524,8 +574,10 @@ def build_train_step(
             # gate them all on the combined verdict
             if use_c:
                 ok_all = ok & jnp.isfinite(loss_c)
-                params_c1 = health_select(ok_all, params_c1, state.params_c)
-                opt_c1 = health_select(ok_all, opt_c1, state.opt_c)
+                with jax.named_scope("opt_c"):
+                    params_c1 = health_select(ok_all, params_c1,
+                                              state.params_c)
+                    opt_c1 = health_select(ok_all, opt_c1, state.opt_c)
                 if use_qc:
                     quant_c1 = health_select(ok_all, quant_c1,
                                              state.quant_c)

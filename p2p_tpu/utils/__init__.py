@@ -1,12 +1,10 @@
+from p2p_tpu.obs.timing import StepTimer
 from p2p_tpu.utils.images import save_img, to_uint8_img
 from p2p_tpu.utils.pool import ImagePool
-from p2p_tpu.utils.profiling import StepTimer, annotate, trace
 
 __all__ = [
     "save_img",
     "to_uint8_img",
     "ImagePool",
     "StepTimer",
-    "annotate",
-    "trace",
 ]

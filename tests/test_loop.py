@@ -1,5 +1,7 @@
-import pytest
 import os
+import time
+
+import pytest
 
 import numpy as np
 
@@ -210,12 +212,12 @@ def _fake_steps():
     return train_step, multi_step
 
 
-def _accounting_trainer(tmp_path, n_train, batch_size, scan_steps,
-                        monkeypatch):
+def _tiny_trainer(tmp_path, n_train, batch_size, scan_steps):
+    """A 16x16 facades Trainer over a synthetic split, its step fns
+    replaced by the fakes: the loop, loader and prefetch are real."""
     import dataclasses
 
     from p2p_tpu.core.config import get_preset
-    from p2p_tpu.train import loop as loop_mod
 
     root = str(tmp_path / "ds")
     make_synthetic_dataset(root, n_train=n_train, n_test=2, size=16)
@@ -228,11 +230,18 @@ def _accounting_trainer(tmp_path, n_train, batch_size, scan_steps,
                                   scan_steps=scan_steps, log_every=1000),
     )
     tr = Trainer(cfg, data_root=root, workdir=str(tmp_path))
-    clock = _FakeClock()
-    monkeypatch.setattr(loop_mod.time, "perf_counter", clock)
     train_step, multi_step = _fake_steps()
     tr.train_step = train_step
     tr.multi_step = multi_step if scan_steps > 1 else None
+    return tr
+
+
+def _accounting_trainer(tmp_path, n_train, batch_size, scan_steps,
+                        monkeypatch):
+    from p2p_tpu.train import loop as loop_mod
+
+    tr = _tiny_trainer(tmp_path, n_train, batch_size, scan_steps)
+    monkeypatch.setattr(loop_mod.time, "perf_counter", _FakeClock())
     return tr
 
 
@@ -419,3 +428,109 @@ def test_cli_tp_trainer_matches_single_device_pix2pixhd(tmp_path, devices8):
         ("global", "ConvLayer_3", "Conv_0", "kernel"),
         ("global", "ConvLayer_4", "Conv_0", "kernel"),
     ], tol=5e-3)
+
+
+# ------------------------------------------------------- the epoch record
+def _phase_trainer(tmp_path, monkeypatch, scan_steps=1):
+    """The tiny Trainer on the real clock with dispatches that take a
+    while, so that an epoch is long beside the microseconds between two
+    phases; on the plain loader, whose batches are assembled when asked
+    for (Grain reads ahead in threads, which would hide a slow record in
+    the prefetch fill)."""
+    monkeypatch.setenv("P2P_TPU_NO_GRAIN", "1")
+    tr = _tiny_trainer(tmp_path, n_train=12, batch_size=2,
+                       scan_steps=scan_steps)
+
+    def lasting(step):
+        def slept(*a):
+            time.sleep(0.03)
+            return step(*a)
+
+        return slept
+
+    tr.train_step = lasting(tr.train_step)
+    tr.multi_step = tr.multi_step and lasting(tr.multi_step)
+    return tr
+
+
+def _epoch_records(tr):
+    return [s for s in tr.spans.spans if s["name"] == "train_epoch"]
+
+
+@pytest.mark.parametrize("scan_steps, dispatches", [(1, 6), (2, 3)])
+def test_train_epoch_leaves_one_record_whose_phases_tile_it(
+        tmp_path, monkeypatch, scan_steps, dispatches):
+    """Every second of train_epoch falls into one phase: the record's
+    direct children sum to its duration (to the few microseconds between
+    two ``with`` blocks), the per-step phases are counted once a dispatch,
+    and the same record is in the metrics stream."""
+    import json
+
+    tr = _phase_trainer(tmp_path, monkeypatch, scan_steps)
+    try:
+        tr.train_epoch()          # decodes; the memo serves the second
+        tr.epoch += 1
+        tr.train_epoch()
+        first, rec = _epoch_records(tr)
+        assert (first["epoch"], rec["epoch"]) == (1, 2)
+        assert rec["steps"] == 6 and rec["depth"] == 0
+        children = sum(rec[f"{p}_s"] for p in (
+            "epoch_setup", "feed_next", "train_dispatch",
+            "step_bookkeeping", "epoch_drain"))
+        assert 0.95 * rec["dur_s"] <= children <= rec["dur_s"]
+        # loader_next and h2d_put nest inside feed_next
+        assert rec["loader_next_s"] + rec["h2d_put_s"] <= rec["feed_next_s"]
+        assert rec["first_feed_next_s"] <= rec["feed_next_s"]
+        assert rec["epoch_setup_s"] + rec["first_feed_next_s"] <= \
+            rec["epoch_start_s"] <= rec["dur_s"]
+        for name in ("feed_next_secs", "dispatch_secs",
+                     "step_bookkeeping_secs"):
+            assert tr.obs.histogram(name).count == 2 * dispatches, name
+        for name in ("train_epoch_secs", "epoch_setup_secs",
+                     "epoch_drain_secs"):
+            assert tr.obs.histogram(name).count == 2, name
+        assert tr.obs.histogram("h2d_put_secs").count == 2 * dispatches
+        tr.logger.registry.flush()
+        stream = [json.loads(x) for x in open(
+            os.path.join(str(tmp_path), f"metrics_{tr.cfg.name}.jsonl"))]
+        spans = [r for r in stream if r["kind"] == "span"]
+        assert [r["span"] for r in spans] == ["train_epoch"] * 2
+        assert spans[1]["sec"] == pytest.approx(rec["dur_s"], abs=1e-5)
+        assert spans[1]["slowest_train_dispatch_step"] in range(0, 6, scan_steps)
+    finally:
+        tr.close()
+
+
+def test_slow_record_is_the_epochs_slowest_feed_next(tmp_path, monkeypatch):
+    """A dataset whose ``__getitem__`` stalls on one index: the epoch
+    record names the step that waited for it, and the wait lies in the
+    loader's ``next()``, not in the transfer."""
+    from p2p_tpu.data.pipeline import PairedImageDataset
+
+    tr = _phase_trainer(tmp_path, monkeypatch)
+    order, slow = [], []
+    getitem = PairedImageDataset.__getitem__
+
+    def recording(self, idx):
+        order.append(int(idx))
+        if int(idx) in slow:
+            time.sleep(0.25)
+        return getitem(self, idx)
+
+    monkeypatch.setattr(PairedImageDataset, "__getitem__", recording)
+    try:
+        tr.train_epoch(seed=5)
+        # the same seed shuffles the same way: stall the first record of
+        # the fourth batch. The prefetch keeps two batches in flight, so
+        # batch 3 is assembled while the loop waits before step 2
+        slow.append(order[3 * 2])
+        del order[:]
+        tr.train_epoch(seed=5)
+        rec = _epoch_records(tr)[-1]
+        assert order.index(slow[0]) == 6
+        assert rec["slowest_feed_next_step"] == 2
+        assert 0.25 <= rec["slowest_feed_next_s"] <= rec["feed_next_s"]
+        assert rec["loader_next_s"] >= 0.25 > rec["h2d_put_s"]
+        assert rec["first_feed_next_s"] < 0.25
+    finally:
+        tr.close()

@@ -170,9 +170,9 @@ def test_span_ring_drops_oldest_and_timed_annotation_feeds_histogram():
     assert [s["name"] for s in rec.spans] == ["s2", "s3", "s4"]
     assert rec.dropped == 2
     h = obs.MetricsRegistry().histogram("d")
-    with obs.timed_annotation("hot", h):
+    with obs.timed_annotation("hot", h) as hot:
         pass
-    assert h.count == 1 and h.sum >= 0
+    assert h.count == 1 and h.sum == hot.secs >= 0
 
 
 # ----------------------------------------------------------------- sentinel
@@ -371,7 +371,15 @@ def test_trainer_obs_wiring(tmp_path, monkeypatch):
         trace_doc = json.load(open(tmp_path / "trace_obswire.json"))
         names = {e["name"] for e in trace_doc["traceEvents"]
                  if e.get("ph") == "X"}
-        assert {"epoch", "train_dispatch", "checkpoint_save"} <= names
+        # the ring holds epochs, not steps: one train_epoch record whose
+        # per-step phases were counted once per dispatch
+        assert {"epoch", "train_epoch", "checkpoint_save"} <= names
+        assert "train_dispatch" not in names
+        (span,) = [r for r in recs if r["kind"] == "span"]
+        assert span["span"] == "train_epoch" and span["steps"] == 2
+        assert 0 < span["epoch_start_s"] <= span["sec"]
+        assert tr.obs.histogram("feed_next_secs").count == 2
+        assert tr.obs.histogram("step_bookkeeping_secs").count == 2
         # the dispatch-rate EWMA saw the epoch's dispatches (2 marks: the
         # first pins the clock epoch, the second produces a rate), and
         # every dispatch fed the duration histogram

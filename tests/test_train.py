@@ -692,3 +692,75 @@ def test_count_nonfinite_counts_exactly():
     }
     assert int(count_nonfinite(tree)) == 4
     assert int(count_nonfinite({})) == 0
+
+
+# ------------------------------------------------ named scopes of the step
+@pytest.fixture(scope="module")
+def scoped_step(batch):
+    """The toy step with every kind of loss live (the seeded VGG19 too),
+    lowered once for the scope tests."""
+    import dataclasses
+
+    from p2p_tpu.models.vgg import load_vgg19_params
+
+    cfg = tiny_config()
+    cfg = cfg.replace(loss=dataclasses.replace(
+        cfg.loss, lambda_vgg=10.0, lambda_l1=1.0))
+    state = create_train_state(cfg, jax.random.key(0), batch, 1)
+    return build_train_step(cfg, load_vgg19_params(None)).lower(state, batch)
+
+
+def test_compiled_step_names_every_scope(scoped_step):
+    """Scopes survive into the COMPILED text, where a device trace's
+    instruction names can be joined with them (benchmark/scope_time.py):
+    every scope of the shared tuple owns instructions there."""
+    from benchmark import scope_time
+    from p2p_tpu.train.step import STEP_SCOPES
+
+    owners = scope_time.instruction_scopes(
+        scoped_step.compile().as_text(), STEP_SCOPES)
+    assert set(STEP_SCOPES) <= set(owners.values())
+    assert scope_time.program_scopes() == STEP_SCOPES
+
+
+def test_no_convolution_or_dot_of_the_step_is_unscoped(scoped_step):
+    """As the program hands the step to the compiler, every convolution
+    and matmul, forward and backward, lies under a net's or a loss's
+    scope. (The compiler may still drop the name from an op it rewrites;
+    what that leaves unscoped on the chip is ``step.unscoped_share``.)"""
+    from benchmark import scope_time
+    from jax._src.lib.mlir import ir
+    from p2p_tpu.train.step import STEP_SCOPES
+
+    heavy = []
+
+    def visit(op):
+        if op.name in ("stablehlo.convolution", "stablehlo.dot_general"):
+            heavy.append(str(op.location).split('"')[1])
+        return ir.WalkResult.ADVANCE
+
+    scoped_step.compiler_ir().operation.walk(visit)
+    owners = [scope_time.first_scope(name, STEP_SCOPES) for name in heavy]
+    assert len(heavy) > 100
+    assert [n for n, o in zip(heavy, owners) if o is None] == []
+    assert {"G", "D_fake", "D_real", "loss_vgg", "compress",
+            "C_branch"} <= set(owners)
+    # backward ops are named through their transforms
+    assert any(n.startswith("jit(step)/transpose(jvp(G))/") for n in heavy)
+
+
+def test_scopes_change_location_info_only(batch, monkeypatch):
+    """The program the compiler is given is the same text with the scopes
+    as without them, location info apart."""
+    import contextlib
+
+    cfg = tiny_config()
+    state = create_train_state(cfg, jax.random.key(0), batch, 1)
+    scoped = build_train_step(cfg).lower(state, batch).as_text()
+
+    @contextlib.contextmanager
+    def no_scope(name):
+        yield
+
+    monkeypatch.setattr(jax, "named_scope", no_scope)
+    assert build_train_step(cfg).lower(state, batch).as_text() == scoped
