@@ -101,6 +101,60 @@ def _thin_stem_eligible(x, features: int, stride: int) -> bool:
             and x.shape[1] * x.shape[2] >= _THIN_DISPATCH_MIN_PIXELS)
 
 
+# The blocked form (BlockedConv) below this padded area was never measured
+# on the chip (ExpandNetwork's 264x264 is the smallest): what was not
+# measured is not rerouted.
+_BLOCKED_MIN_PIXELS = 65_000
+
+
+def blocked_conv_block(x, features: int, kernel_size: int,
+                       stride: int) -> int:
+    """The block s (pixels along W) the blocked form takes for this
+    layer, or 0 where the layer keeps its other form (x is the PADDED
+    input).
+
+    A stride-1 k>=7 conv whose thin side (min of C_in, C_out) has at most
+    16 channels against at least twice that on the other side keeps 3-16
+    of the MXU's 128 lanes busy; on blocks of s pixels the thin side has
+    s times the channels. s is the smallest power of two, 4 or more, that
+    brings it to 24 or more (3 channels: 8; 12: 4), and has to divide the
+    output's width. Set from readings on one v5e at the extents the
+    benchmark's cells hold (PERF.md section 6, PR 24); the k5 3->64 stem
+    is no faster blocked than plain."""
+    thin, wide = sorted((x.shape[-1], features))
+    if not (stride == 1 and kernel_size >= 7 and thin <= 16
+            and wide >= 2 * thin
+            and x.shape[1] * x.shape[2] >= _BLOCKED_MIN_PIXELS):
+        return 0
+    s = 4
+    while thin * s < 24:
+        s *= 2
+    return s if (x.shape[2] - kernel_size + 1) % s == 0 else 0
+
+
+#: the non-plain forms a ConvLayer / UpsampleConvLayer call site can take
+CONV_FORMS = ("blocked", "patches", "thin_head")
+
+
+def _count_form(form: str) -> None:
+    """One call site took ``form``: counted at TRACE time in the process
+    registry as ``conv_form_sites_total{form=...}`` (a module cannot be
+    handed a run's registry; ``conv_form_sites`` reads it back)."""
+    from p2p_tpu.obs.registry import get_registry
+
+    get_registry().counter("conv_form_sites_total", form=form).inc()
+
+
+def conv_form_sites() -> dict:
+    """form -> call sites traced into it so far in this process (every
+    trace of a site counts: a net's init, each pass of the step)."""
+    from p2p_tpu.obs.registry import get_registry
+
+    reg = get_registry()
+    return {f: int(reg.counter("conv_form_sites_total", form=f).value)
+            for f in CONV_FORMS}
+
+
 def reflect_pad_2d(x: jax.Array, pad: int) -> jax.Array:
     """Reflection-pad H and W of an NHWC tensor."""
     if pad == 0:
@@ -111,6 +165,42 @@ def reflect_pad_2d(x: jax.Array, pad: int) -> jax.Array:
 def normal_init(stddev: float = 0.02):
     """Reference default weight init: N(0, 0.02) (networks.py:131)."""
     return nn.initializers.normal(stddev=stddev)
+
+
+def _routed_conv(layer, x, stems: bool):
+    """The VALID conv of ``ConvLayer`` / ``UpsampleConvLayer`` on their
+    padded input, in the form the shapes call for; every form keeps the
+    param tree of the plain ``nn.Conv`` under the name ``Conv_0``.
+    Called inside the layer's compact ``__call__``."""
+    kw = dict(use_bias=layer.use_bias, dtype=layer.dtype,
+              kernel_init=layer.kernel_init)
+    k, stride = layer.kernel_size, layer.stride
+    block = blocked_conv_block(x, layer.features, k, stride)
+    if block:
+        # the thin k7/k9 image stems and heads (3-16 channels on one side)
+        _count_form("blocked")
+        return BlockedConv(layer.features, kernel_size=k, block=block,
+                           name="Conv_0", **kw)(x)
+    if stems and _thin_stem_eligible(x, layer.features, stride):
+        # thin-INPUT stems the blocked form does not take (k < 7, or a
+        # width its block does not divide): XLA's conv/wgrad collapse to
+        # 0.5-0.6 TF/s at these shapes — one materialized patch tensor
+        # turns fwd and wgrad into dense matmuls (PatchesConv)
+        _count_form("patches")
+        return PatchesConv(layer.features, kernel_size=k, name="Conv_0",
+                           **kw)(x)
+    if _thin_head_eligible(x, layer.features, k, stride):
+        # thin image heads the blocked form does not take. ThinHeadConv,
+        # NOT KN2RowConv: the kn2row forward is right, but its naive
+        # autodiff backward is k² sequential pad+adds (profiled
+        # 296 ms/step at k7 — the hand-written VJP through patches of dz
+        # is the fix)
+        _count_form("thin_head")
+        return ThinHeadConv(layer.features, kernel_size=k, name="Conv_0",
+                            **kw)(x)
+    return save_conv_out(nn.Conv(
+        features=layer.features, kernel_size=(k, k),
+        strides=(stride, stride), padding="VALID", **kw)(x))
 
 
 class ConvLayer(nn.Module):
@@ -143,40 +233,7 @@ class ConvLayer(nn.Module):
                 dtype=self.dtype, kernel_init=self.kernel_init,
                 name="Conv_0", delayed=self.int8_delayed,
             )(x)
-        if _thin_stem_eligible(x, self.features, self.stride):
-            # thin-INPUT stems (RGB → ngf at full res, e.g. the pix2pixHD
-            # enhancer's k7 stem): XLA's conv/wgrad collapse to
-            # 0.5-0.6 TF/s at these shapes — one materialized patch
-            # tensor turns fwd and wgrad into dense matmuls (PatchesConv)
-            return PatchesConv(
-                self.features, kernel_size=self.kernel_size,
-                use_bias=self.use_bias, dtype=self.dtype,
-                kernel_init=self.kernel_init, name="Conv_0",
-            )(x)
-        if _thin_head_eligible(x, self.features, self.kernel_size,
-                               self.stride):
-            # thin image heads (e.g. the ResNet/Expand generators' k9→3
-            # and the pix2pixHD enhancer's k7→3): XLA's conv runs the MXU
-            # at ~4.5 TF/s with 3 of 128 output lanes live (profiled
-            # 2.3 ms/step fwd on cityscapes 512×256). ThinHeadConv, NOT
-            # KN2RowConv: the kn2row forward is right, but its naive
-            # autodiff backward is k² sequential pad+adds (profiled
-            # 296 ms/step at k7 — the hand-written VJP through patches
-            # of dz is the fix). Param tree unchanged (Conv_0).
-            return ThinHeadConv(
-                self.features, kernel_size=self.kernel_size,
-                use_bias=self.use_bias, dtype=self.dtype,
-                kernel_init=self.kernel_init, name="Conv_0",
-            )(x)
-        return save_conv_out(nn.Conv(
-            features=self.features,
-            kernel_size=(self.kernel_size, self.kernel_size),
-            strides=(self.stride, self.stride),
-            padding="VALID",
-            use_bias=self.use_bias,
-            dtype=self.dtype,
-            kernel_init=self.kernel_init,
-        )(x))
+        return _routed_conv(self, x, stems=True)
 
 
 def kn2row_thin_conv(x: jax.Array, w: jax.Array, pad: int) -> jax.Array:
@@ -393,6 +450,83 @@ class ThinHeadConv(nn.Module):
             bias = self.param("bias", nn.initializers.zeros,
                               (self.features,), jnp.float32)
             y = y + bias.astype(y.dtype)
+        return save_conv_out(y)
+
+
+def blocked_conv(xp: jax.Array, w: jax.Array, s: int) -> jax.Array:
+    """VALID stride-1 conv of the pre-padded ``xp`` (N,Hp,Wp,C) with the
+    HWIO kernel ``w`` (k,k,C,O), computed on blocks of s pixels along W:
+    the same sum of the same products with s times the channels on both
+    sides. With ``k' = (s + k - 2) // s + 1`` taps along W:
+
+        xb[n, i, J, (q,c)]     = xp[n, i, s*J + q, c]
+        wb[a, B, (q,c), (v,o)] = w[a, s*B + q - v, c, o]  (0 outside [0, k))
+        y[n, i, s*J + v, o]    = conv(xb, wb)[n, i, J, (v,o)]
+
+    Both block maps are reshapes (q and c, v and o are neighbours in
+    memory); blocks of ROWS as well would cost a transpose each way and
+    read slower on the chip at every extent tried (PERF.md section 6,
+    PR 24). ``xp`` is zero-filled on the right up to whole blocks (the
+    fill only ever meets the zeros of ``wb``). ``wb`` is an einsum of
+    ``w`` with a constant 0/1 tensor (as ``_NearestUp2Conv`` builds its
+    phase kernels), so autodiff carries its gradient back to ``w``; the
+    three convolutions (forward, input and weight gradient) are XLA's own
+    on dense operands. The output's width has to divide by s."""
+    k, _, cin, cout = w.shape
+    n, hp, wp, _ = xp.shape
+    wo = wp - k + 1
+    if wo % s:
+        raise ValueError(f"blocked_conv: the output width {wo} does not "
+                         f"divide by the block {s}")
+    kb = (s + k - 2) // s + 1
+    wb_ = wo // s + kb - 1
+    xb = jnp.pad(xp, ((0, 0), (0, 0), (0, s * wb_ - wp), (0, 0)))
+    xb = xb.reshape(n, hp, wb_, s * cin)
+    # M[B, q, v, b] = 1 where b = s*B + q - v is a tap of w
+    m = np.zeros((kb, s, s, k), np.float32)
+    for blk in range(kb):
+        for q in range(s):
+            for v in range(s):
+                if 0 <= s * blk + q - v < k:
+                    m[blk, q, v, s * blk + q - v] = 1.0
+    wb = jnp.einsum("cqvb,abio->acqivo", jnp.asarray(m),
+                    w.astype(jnp.float32))
+    wb = wb.reshape(k, kb, s * cin, s * cout).astype(xp.dtype)
+    yb = jax.lax.conv_general_dilated(
+        xb, wb, window_strides=(1, 1), padding="VALID",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+    )
+    return yb.reshape(n, hp - k + 1, wo, cout)
+
+
+class BlockedConv(nn.Module):
+    """Stride-1 conv for thin image-side layers (the k7/k9 stems and
+    heads) on blocks of ``block`` pixels along W (see
+    :func:`blocked_conv`), under the named scope ``blocked_conv``. Input
+    arrives pre-padded (VALID) as with the other ConvLayer branches; param
+    tree ("kernel" (k,k,C_in,C_out) float32 + "bias") matches ``nn.Conv``
+    and callers name it ``Conv_0``, so checkpoints, the TP rules and the
+    optimizer see nothing new."""
+
+    features: int
+    kernel_size: int
+    block: int
+    use_bias: bool = True
+    dtype: Optional[jnp.dtype] = None
+    kernel_init: Callable = normal_init()
+
+    @nn.compact
+    def __call__(self, x):
+        k = self.kernel_size
+        kernel = self.param("kernel", self.kernel_init,
+                            (k, k, x.shape[-1], self.features), jnp.float32)
+        bias = (self.param("bias", nn.initializers.zeros, (self.features,),
+                           jnp.float32) if self.use_bias else None)
+        dt = self.dtype or jnp.float32
+        with jax.named_scope("blocked_conv"):
+            y = blocked_conv(x.astype(dt), kernel, self.block)
+            if bias is not None:
+                y = y + bias.astype(y.dtype)
         return save_conv_out(y)
 
 
@@ -675,22 +809,6 @@ class UpsampleConvLayer(nn.Module):
             x = upsample_nearest(x, self.upsample)
         pad = self.kernel_size // 2
         x = reflect_pad_2d(x, pad)
-        if _thin_head_eligible(x, self.features, self.kernel_size,
-                               self.stride):
-            # thin image heads (ExpandNetwork's k9→3 lives HERE, not in
-            # ConvLayer — networks.py:518-520): same ThinHeadConv
-            # dispatch as ConvLayer, same param tree (Conv_0)
-            return ThinHeadConv(
-                self.features, kernel_size=self.kernel_size,
-                use_bias=self.use_bias, dtype=self.dtype,
-                kernel_init=self.kernel_init, name="Conv_0",
-            )(x)
-        return save_conv_out(nn.Conv(
-            features=self.features,
-            kernel_size=(self.kernel_size, self.kernel_size),
-            strides=(self.stride, self.stride),
-            padding="VALID",
-            use_bias=self.use_bias,
-            dtype=self.dtype,
-            kernel_init=self.kernel_init,
-        )(x))
+        # ExpandNetwork's k9 head 32→3 lives HERE, not in ConvLayer
+        # (networks.py:518-520)
+        return _routed_conv(self, x, stems=False)

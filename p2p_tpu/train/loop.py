@@ -60,6 +60,7 @@ from p2p_tpu.obs import (
     timed_annotation,
     write_manifest,
 )
+from p2p_tpu.ops.conv import conv_form_sites
 from p2p_tpu.resilience import Preempted, PreemptionGuard
 from p2p_tpu.resilience.chaos import FaultInjected, chaos_point
 from p2p_tpu.resilience.health import DivergenceError
@@ -1479,6 +1480,15 @@ class Trainer:
             record[f"{phase}_s"] = round(secs, 6)
             record[f"slowest_{phase}_s"] = round(slowest[phase][0], 6)
             record[f"slowest_{phase}_step"] = slowest[phase][1]
+        # which form the thin convolutions took (ops/conv.py counts call
+        # sites as they are traced): one record whenever a trace added some
+        forms = conv_form_sites()
+        if forms != getattr(self, "_conv_forms_logged", None) \
+                and any(forms.values()):
+            self._conv_forms_logged = forms
+            self.logger.log({"kind": "conv_forms", **{
+                f"conv_form_sites_total.{k}": v for k, v in forms.items()}},
+                force=True)
         if sums is None:
             return {}
         out = epoch_metric_means(host_sums, count)

@@ -1,0 +1,140 @@
+"""Device time of a train cell's step by LAYER: one ``trace_phases.py``
+run (the cell's own ``--trace 1`` run, read by the program's names) whose
+join of the trace with the compiled step's text is also read per flax
+module path, per net and per direction.
+
+    chiprun -- python scripts/conv_layer_trace.py --workload reference_256.train --seed 2147483659
+
+An instruction's ``op_name`` holds the module path under the step's scope
+(``jit(step)/transpose(jvp(C_branch))/G/UpsampleConvLayer_2/Conv_0/...``),
+so for every layer whose path matches ``--layers`` this prints, after
+``trace_phases.py``'s own lines, one ``{"layer_ms": ...}`` line: per
+(net scope, layer, forward | backward) the milliseconds a step and the
+costliest ops with their result shapes (an input gradient and a weight
+gradient are both "backward"; their shapes tell them apart), and
+``blocked_conv_ms``, the device milliseconds a step under the
+``blocked_conv`` scope of ``ops/conv.py`` (0 on a program without it).
+A fusion is counted where its root instruction's ``op_name`` points, so a
+norm's backward fused into a convolution's gradient counts as that layer.
+"""
+
+import bisect
+import json
+import os
+import re
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+
+def layer_keys(hlo_text, scopes, layers):
+    """Instruction name -> (net scope, layer path, "fwd" | "bwd", under
+    ``blocked_conv``) for the instructions whose ``op_name`` holds a
+    component matching ``layers``; the layer path runs from the component
+    after the scope to the matching one."""
+    # the join's own two patterns: one text, one way to read it
+    from benchmark.scope_time import _INSTRUCTION, _OP_NAME, first_scope
+
+    layer_re = re.compile(layers)
+    out = {}
+    for line in hlo_text.splitlines():
+        m, op = _INSTRUCTION.match(line), _OP_NAME.search(line)
+        if not (m and op):
+            continue
+        parts = op.group(1).split("/")
+        words = [(re.findall(r"[\w.\-]+", p) or [""])[-1] for p in parts]
+        hit = next((i for i, w in enumerate(words) if layer_re.fullmatch(w)),
+                   None)
+        if hit is None:
+            continue
+        net = first_scope(op.group(1), scopes)
+        start = words.index(net) + 1 if net in words else 1
+        out[m.group(1)] = (net or "unscoped", "/".join(words[start:hit + 1]),
+                           "bwd" if "transpose(" in op.group(1) else "fwd",
+                           "blocked_conv" in words)
+    return out
+
+
+def by_layer(xplane_path, hlo_text, layers, top=4):
+    """Milliseconds a step by (net, layer, direction) over the executions
+    of the module ``hlo_text`` describes, with each key's costliest ops."""
+    from jax.profiler import ProfileData
+
+    from benchmark import scope_time, trace_reduce
+
+    keys = layer_keys(hlo_text, scope_time.program_scopes(), layers)
+    module = scope_time.module_name(hlo_text)
+    total, ops_s, blocked, executions, chips = {}, {}, 0.0, 0, 0
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if not trace_reduce.DEVICE_PLANE.match(plane.name):
+            continue
+        runs, events = [], []
+        for line in plane.lines:
+            if line.name == scope_time.MODULE_LINE:
+                runs += [(int(e.start_ns), int(e.start_ns + e.duration_ns))
+                         for e in line.events
+                         if e.name.split("(", 1)[0] == module]
+            elif line.name == trace_reduce.OP_LINE:
+                events += list(line.events)
+        if not events:
+            continue
+        chips += 1
+        runs.sort()
+        executions += len(runs)
+        starts = [s for s, _ in runs]
+        for ev in events:
+            i = bisect.bisect_right(starts, int(ev.start_ns)) - 1
+            if i < 0 or ev.start_ns >= runs[i][1]:
+                continue
+            name, _, opcode = trace_reduce.parse_op(ev.name)
+            if opcode in trace_reduce.CONTAINER_OPCODES or name not in keys:
+                continue
+            net, layer, direction, in_blocked = keys[name]
+            key = f"{net}|{layer}|{direction}"
+            total[key] = total.get(key, 0.0) + ev.duration_ns
+            per_op = ops_s.setdefault(key, {})
+            label = trace_reduce.op_label(ev.name)
+            per_op[label] = per_op.get(label, 0.0) + ev.duration_ns
+            if in_blocked:
+                blocked += ev.duration_ns
+    if not executions:
+        raise ValueError(f"{xplane_path}: no execution of {module}")
+    per_step = 1e-6 / executions    # ns over all chips -> ms a step a chip
+    return {
+        "steps": executions // chips,
+        "blocked_conv_ms": blocked * per_step,
+        "layer_ms": {
+            key: {"ms": ns * per_step,
+                  "ops": [[label, v * per_step] for label, v in sorted(
+                      ops_s[key].items(), key=lambda kv: -kv[1])[:top]]}
+            for key, ns in sorted(total.items(), key=lambda kv: -kv[1])},
+    }
+
+
+def main(argv=None) -> int:
+    argv = list(sys.argv[1:] if argv is None else argv)
+    layers = r"(Upsample)?ConvLayer_\d+"
+    if "--layers" in argv:
+        i = argv.index("--layers")
+        layers = argv[i + 1]
+        del argv[i:i + 2]
+
+    from benchmark import harness, scope_time
+    from benchmark.tools import trace_phases
+
+    by_scope = scope_time.by_scope
+
+    def by_scope_and_layer(xplane, text, *a, **kw):
+        harness.say(**by_layer(xplane, text, layers))
+        return by_scope(xplane, text, *a, **kw)
+
+    scope_time.by_scope = by_scope_and_layer
+    try:
+        return trace_phases.main(argv)
+    finally:
+        scope_time.by_scope = by_scope
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -14,6 +14,7 @@ for a described device cannot be read back without one.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -159,3 +160,42 @@ def test_sharded_instance_norm_keeps_the_shard(mesh2x2):
     _assert_kernel(text)
     n, h, w, c = shape
     assert_no_collective_as_large_as(text, n * h * w * c // 4)
+
+
+# the thin image-side layers of the two benchmark cells, at their own
+# extents and batch: ExpandNetwork's k9 head 32->3 (bs32 256x256) and the
+# pix2pixHD enhancer's k7 stem 3->32 (bs2 1024x512)
+@pytest.mark.parametrize("shape,features,k", [
+    ((32, 256, 256, 32), 3, 9), ((2, 512, 1024, 3), 32, 7),
+], ids=["expand_head_k9_32to3", "hd_stem_k7_3to32"])
+def test_blocked_conv_layer_compiles_dense(one_chip, shape, features, k):
+    """``ConvLayer`` on pixel blocks, forward and both gradients, through
+    the chip's compiler: the program convolves with the blocked kernel's
+    k x k' window, never with the thin layer's own k x k, and fits the
+    chip."""
+    from p2p_tpu.ops.conv import ConvLayer, blocked_conv_block
+
+    layer = ConvLayer(features, kernel_size=k, dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    variables = jax.eval_shape(layer.init, jax.random.key(0), x)
+    variables = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), variables)
+    pad = k // 2
+    padded = (shape[0], shape[1] + 2 * pad, shape[2] + 2 * pad, shape[3])
+    s = blocked_conv_block(jax.ShapeDtypeStruct(padded, jnp.bfloat16),
+                           features, k, 1)
+
+    def loss(v, xx):
+        return jnp.sum(layer.apply(v, xx).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        variables, x).compile()
+    text = compiled.as_text()
+    kb = (s + k - 2) // s + 1
+    windows = re.findall(r"convolution\([^)]*\), window=\{size=(\d+x\d+)",
+                         text)
+    # forward, input gradient and weight gradient: k x k' taps, or the
+    # feature map as the window; never the thin layer's own k x k
+    assert f"{k}x{kb}" in windows and f"{k}x{k}" not in windows, windows
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 8 * 2 ** 30

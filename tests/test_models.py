@@ -502,3 +502,34 @@ def test_discriminator_norm_d_composes_with_int8():
     assert jax.tree_util.tree_leaves(mut["quant"])
     for leaf in jax.tree_util.tree_leaves(out):
         assert np.isfinite(np.asarray(leaf)).all()
+
+
+@pytest.mark.parametrize("net,shape", [
+    (ExpandNetwork(dtype=jnp.bfloat16), (2, 256, 256, 3)),
+    (Pix2PixHDGenerator(dtype=jnp.bfloat16), (1, 512, 1024, 3)),
+], ids=["expand_256", "pix2pixhd_1024x512"])
+def test_param_tree_is_the_same_whichever_conv_form(net, shape, monkeypatch):
+    """The thin stems and heads take the blocked / patches / thin-head
+    forms at the cells' extents and the plain conv with every gate shut:
+    the variables are the same leaf for leaf (path, shape, dtype), so a
+    checkpoint, the TP rules and the optimizer see one tree. Abstract
+    evaluation only, no compute."""
+    from p2p_tpu.ops import conv
+
+    def tree():
+        v = jax.eval_shape(net.init, jax.random.key(0),
+                           jax.ShapeDtypeStruct(shape, jnp.float32))
+        return {jax.tree_util.keystr(path): (leaf.shape, leaf.dtype)
+                for path, leaf in jax.tree_util.tree_leaves_with_path(v)}
+
+    before = conv.conv_form_sites()
+    routed = tree()
+    after = conv.conv_form_sites()
+    assert sum(after.values()) - sum(before.values()) >= 2   # stem + head
+    monkeypatch.setattr(conv, "_BLOCKED_MIN_PIXELS", 10 ** 12)
+    monkeypatch.setattr(conv, "_THIN_DISPATCH_MIN_PIXELS", 10 ** 12)
+    plain = tree()
+    assert conv.conv_form_sites() == after      # every site took nn.Conv
+    assert routed == plain
+    assert routed["['params']['ConvLayer_0']['Conv_0']['kernel']"][1] \
+        == jnp.float32

@@ -567,3 +567,40 @@ def test_crosscheck_hbm_budget_record_and_warn(capsys):
     assert rec["out_of_band"] and rec["drift"] > 0.10
     assert reg.counter("hbm_budget_drift_total").value == 1
     assert "static memory model" in capsys.readouterr().out
+
+
+def test_conv_layer_trace_reads_device_time_by_layer_and_direction():
+    """``scripts/conv_layer_trace.py`` on the benchmark's recorded trace
+    and the text its step compiles to (a convolution under the scope
+    ``net_a``, a tanh under ``net_b``; three executions): an instruction
+    is keyed by the first ``op_name`` component that matches, and the
+    keys' milliseconds a step add up to what ``scope_time.by_scope`` sums
+    under the same names."""
+    import importlib.util
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "conv_layer_trace", os.path.join(root, "scripts",
+                                         "conv_layer_trace.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    from benchmark import scope_time
+
+    data = os.path.join(root, "benchmark", "tests", "data")
+    trace = os.path.join(data, "small_trace.xplane.pb")
+    with open(os.path.join(data, "small_trace.hlo.txt")) as f:
+        text = f.read()
+
+    keys = tool.layer_keys(text, ("G",), "net_a|net_b")
+    assert keys["fusion.3"] == ("unscoped", "net_a", "fwd", False)
+    assert keys["copy.9"] == ("unscoped", "net_b", "fwd", False)
+    assert "copy.6" not in keys       # named after a parameter: no layer
+    got = tool.by_layer(trace, text, "net_a|net_b", top=2)
+    want = scope_time.by_scope(trace, text, ("net_a", "net_b"))
+    assert got["steps"] == want["executions"] == 3
+    assert got["blocked_conv_ms"] == 0.0
+    for net in ("net_a", "net_b"):
+        row = got["layer_ms"][f"unscoped|{net}|fwd"]
+        assert row["ms"] == pytest.approx(
+            1000.0 * want["scope_s"][net] / 3, rel=1e-9)
+        assert len(row["ops"]) == 2 and row["ops"][0][1] >= row["ops"][1][1]

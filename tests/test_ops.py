@@ -688,41 +688,129 @@ def test_nearest_up2_conv_matches_upsample_conv(monkeypatch):
     assert "66" in jaxpr_small
 
 
-def test_thin_conv_dispatch_routing():
-    """The spatial gate routes as measured: >=300k-pixel thin shapes go to
-    the patches/kn2row forms (no conv_general_dilated in the jaxpr); small
-    shapes stay on the plain conv path. Abstract eval only — no compute."""
-    import jax
+# (k, C_in, C_out, block, H, W): the layers the blocked form is for, at toy
+# extents. k9 on 4 and 8 and k5 on 4 meet whole blocks exactly; k7 on 8
+# needs the zero fill (the HD enhancer's 1030 columns -> 1032, here cut
+# down in proportion)
+BLOCKED_CASES = {
+    "k9_12to32_s4": (9, 12, 32, 4, 16, 24),
+    "k9_32to3_s8": (9, 32, 3, 8, 16, 24),
+    "k7_3to32_s8": (7, 3, 32, 8, 16, 16),
+    "k7_32to3_s8": (7, 32, 3, 8, 16, 16),
+    "k5_3to64_s4": (5, 3, 64, 4, 16, 16),
+    "k7_32to3_s8_zero_fill_32x64": (7, 32, 3, 8, 32, 64),
+}
 
-    from p2p_tpu.ops.conv import ConvLayer, UpsampleConvLayer
 
-    def jaxpr_of(layer, shape):
-        x = jnp.zeros(shape, jnp.float32)
-        v = jax.eval_shape(lambda: layer.init(jax.random.key(0), x))
-        # init abstractly, then trace apply with concrete-free params
-        v = jax.tree_util.tree_map(
-            lambda a: jnp.zeros(a.shape, a.dtype),
-            layer.init(jax.random.key(0), jnp.zeros(
-                (1,) + shape[1:], jnp.float32)),
-        )
-        return str(jax.make_jaxpr(lambda p, xx: layer.apply(p, xx))(v, x))
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-4),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", list(BLOCKED_CASES))
+def test_blocked_conv_equals_conv(case, dtype, tol):
+    """The convolution on blocks of pixels along W is the same sum of the same
+    products: forward, input gradient and weight gradient against
+    ``lax.conv_general_dilated`` on the same (pre-padded) input and HWIO
+    kernel, relative to each tensor's largest entry."""
+    from p2p_tpu.ops.conv import blocked_conv
 
-    # thin HEAD, big extent (600·512 = 307k > gate): kn2row path
-    big_head = jaxpr_of(ConvLayer(3, kernel_size=7), (1, 600, 512, 64))
-    assert "conv_general_dilated" not in big_head
-    # same layer, small extent: plain conv
-    small_head = jaxpr_of(ConvLayer(3, kernel_size=7), (1, 64, 64, 64))
-    assert "conv_general_dilated" in small_head
+    k, cin, cout, block, h, w = BLOCKED_CASES[case]
+    r = np.random.default_rng(k * cin + cout)
+    xp = jnp.asarray(r.normal(size=(2, h + k - 1, w + k - 1, cin)), dtype)
+    wt = jnp.asarray(0.1 * r.normal(size=(k, k, cin, cout)), jnp.float32)
+    ct = jnp.asarray(r.normal(size=(2, h, w, cout)), dtype)
 
-    # thin STEM, big extent: patches path (dot_general, no conv)
-    big_stem = jaxpr_of(ConvLayer(32, kernel_size=7), (1, 600, 512, 3))
-    assert "conv_general_dilated" not in big_stem
-    small_stem = jaxpr_of(ConvLayer(32, kernel_size=7), (1, 64, 64, 3))
-    assert "conv_general_dilated" in small_stem
+    def plain(xp, wt):
+        return jax.lax.conv_general_dilated(
+            xp, wt.astype(xp.dtype), (1, 1), "VALID",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
 
-    # UpsampleConvLayer shares the head predicate (Expand's k9→3)
-    big_up = jaxpr_of(UpsampleConvLayer(3, kernel_size=9), (1, 600, 512, 32))
-    assert "conv_general_dilated" not in big_up
+    want, want_vjp = jax.vjp(plain, xp, wt)
+    got, got_vjp = jax.vjp(lambda a, b: blocked_conv(a, b, block), xp, wt)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    for a, b in zip((got,) + got_vjp(ct), (want,) + want_vjp(ct)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= tol * np.abs(b).max()
+
+
+def _traced_convs(layer, shape):
+    """(kernel shape, under the ``blocked_conv`` scope) of every
+    ``conv_general_dilated`` a layer traces to on an input of ``shape``,
+    and the ``conv_form_sites_total`` ticks the trace made. Abstract
+    evaluation only, no compute."""
+    from p2p_tpu.ops.conv import conv_form_sites
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "conv_general_dilated":
+                yield (eqn.invars[1].aval.shape,
+                       "blocked_conv" in str(eqn.source_info.name_stack))
+            for param in eqn.params.values():
+                for sub in (param if isinstance(param, (list, tuple))
+                            else [param]):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        yield from walk(inner)
+
+    x = jax.ShapeDtypeStruct(shape, jnp.float32)
+    variables = jax.eval_shape(layer.init, jax.random.key(0), x)
+    before = conv_form_sites()
+    convs = list(walk(jax.make_jaxpr(layer.apply)(variables, x).jaxpr))
+    after = conv_form_sites()
+    return convs, {f: after[f] - before[f] for f in after if
+                   after[f] != before[f]}
+
+
+# layer, input shape -> (form counted, the one conv's kernel or None where
+# the form is matmuls). The shapes of the two benchmark cells, routed as
+# they read on the chip (PERF.md section 6, PR 24), and toy / odd shapes.
+ROUTING_CASES = {
+    # reference_256.train: ExpandNetwork's stem and head, C's k5 stem
+    "ref_stem_k9_12to32": (ConvLayer(32, kernel_size=9), (32, 256, 256, 12),
+                           "blocked", (9, 3, 48, 128)),
+    "ref_head_k9_32to3": (UpsampleConvLayer(3, kernel_size=9),
+                          (32, 256, 256, 32), "blocked", (9, 2, 256, 24)),
+    "ref_cstem_k5_3to64": (ConvLayer(64, kernel_size=5), (32, 256, 256, 3),
+                           None, (5, 5, 3, 64)),
+    # pix2pixhd_1024x512.train: the enhancer's stem and head, G1's stem
+    "hd_stem_k7_3to32": (ConvLayer(32, kernel_size=7), (2, 512, 1024, 3),
+                         "blocked", (7, 2, 24, 256)),
+    "hd_head_k7_32to3": (ConvLayer(3, kernel_size=7), (2, 512, 1024, 32),
+                         "blocked", (7, 2, 256, 24)),
+    "g1_stem_k7_3to64": (ConvLayer(64, kernel_size=7), (2, 256, 512, 3),
+                         "blocked", (7, 2, 24, 512)),
+    # a width no block of 8 divides keeps the hand-made forms
+    "odd_head_k7_64to3": (ConvLayer(3, kernel_size=7), (1, 600, 516, 64),
+                          "thin_head", None),
+    "odd_stem_k7_3to32": (ConvLayer(32, kernel_size=7), (1, 600, 516, 3),
+                          "patches", None),
+    # below the smallest extent measured: the plain conv
+    "toy_head_k7_64to3": (ConvLayer(3, kernel_size=7), (1, 64, 64, 64),
+                          None, (7, 7, 64, 3)),
+    "toy_stem_k7_3to32": (ConvLayer(32, kernel_size=7), (1, 64, 64, 3),
+                          None, (7, 7, 3, 32)),
+    "toy_head_k9_32to3": (UpsampleConvLayer(3, kernel_size=9),
+                          (1, 64, 64, 32), None, (9, 9, 32, 3)),
+    # a wide trunk conv never leaves the plain path
+    "trunk_k3_128to128": (ConvLayer(128, kernel_size=3), (2, 512, 512, 128),
+                          None, (3, 3, 128, 128)),
+}
+
+
+@pytest.mark.parametrize("case", list(ROUTING_CASES))
+def test_thin_conv_dispatch_routing(case):
+    """Which form ``ConvLayer`` / ``UpsampleConvLayer`` take follows from
+    the shapes they see: the blocked form is a ``conv_general_dilated``
+    too, so the forms are told apart by the ``blocked_conv`` scope, the
+    kernel's shape (s times the channels, k' taps along W) and the
+    ``conv_form_sites_total`` counter, which ticks once a traced site."""
+    layer, shape, form, kernel = ROUTING_CASES[case]
+    convs, ticks = _traced_convs(layer, shape)
+    assert ticks == ({form: 1} if form else {})
+    if kernel is None:          # patches / kn2row: matmuls, no conv
+        assert convs == []
+    else:
+        assert convs == [(kernel, form == "blocked")]
 
 
 def test_patches_conv_strided_stem_equals_conv():

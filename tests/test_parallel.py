@@ -122,6 +122,30 @@ def test_gspmd_stride2_conv_matches_unsharded(devices8):
                                rtol=1e-5, atol=1e-5)
 
 
+def test_blocked_conv_layer_under_spatial_sharding_matches_unsharded(devices8):
+    """ExpandNetwork's k9 head 32->3 at the smallest extent the blocked
+    form takes (256x256; ops/conv.py), H sharded over ``spatial``: the
+    rule sees the global extent, the blocks lie along W, and GSPMD's
+    result equals the single-device one, forward and both gradients."""
+    from p2p_tpu.ops.conv import ConvLayer, conv_form_sites
+
+    mesh = _axis_mesh(devices8, 2, "spatial")
+    layer = ConvLayer(3, kernel_size=9)
+    x = jax.random.normal(jax.random.key(5), (1, 256, 256, 32))
+    before = conv_form_sites()["blocked"]
+    variables = layer.init(jax.random.key(6), x)
+    assert conv_form_sites()["blocked"] == before + 1
+
+    f = jax.jit(jax.value_and_grad(
+        lambda v, a: jnp.sum(jnp.sin(layer.apply(v, a))), argnums=(0, 1)))
+    xs = jax.device_put(x, NamedSharding(mesh, P(None, "spatial", None, None)))
+    got = jax.tree.leaves(f(variables, xs))
+    want = jax.tree.leaves(f(variables, x))
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+
+
 # ---------------------------------------------------------------- temporal
 
 @pytest.mark.slow
