@@ -20,7 +20,12 @@ One process, one TPU chip, the CLIs' own ``main(argv)``:
 
 ``--multichip`` (four chips, run by hand) runs ONLY the cross-chip path and
 its reference: the pix2pixhd step at bs2 on ``--mesh data=2,spatial=2`` and
-on ``--mesh data=1``, same seed and data; the logged losses must agree.
+on ``--mesh data=1``, same seed and data; the logged losses must agree, the
+compiled step must hold halo ``collective-permute``s and neither an
+all-gather nor an all-to-all as large as its smallest normed activation.
+Run and passed on one four-chip "TPU v5 lite" host (2x2, one process) in
+PR 25: 446 s cold, 600 collective-permutes, 78 all-reduces, no all-gather,
+72 kernel calls, loss parity at 0.40 of the tolerance (PERF.md section 6).
 
 Every phase prints one JSON line; any failure raises and the script exits
 non-zero at once. It refuses to start unless ``jax.devices()[0].platform``
@@ -60,8 +65,10 @@ PARITY_RTOL = PARITY_ATOL = 8e-4
 
 @dataclasses.dataclass(frozen=True)
 class Sizes:
-    """Run sizes. The defaults ARE the chip run (preset widths, native
-    resolutions); the CPU rehearsal test shrinks them."""
+    """Run sizes. The defaults ARE the chip run (preset widths, the
+    presets' default extents: pix2pixhd's 1024x512 is half the paper's
+    2048x1024 each way, which takes four chips: PERF.md section 4); the
+    CPU rehearsal test shrinks them."""
 
     image_size: int = 256                 # reference phases (square)
     hd_hw: Sequence[int] = (512, 1024)    # pix2pixhd H, W
@@ -424,8 +431,8 @@ def _hd_dataset(out: str, seed: int, sizes: Sizes, batch: int) -> str:
 
 def phase_pallas(result: dict, out: str, seed: int, sizes: Sizes,
                  kernel_marker: Optional[str] = KERNEL_MARKER) -> None:
-    """Two pix2pixhd steps at the native extent on ONE device; the lowered
-    step must carry ``kernel_marker`` (None: the CPU rehearsal, where the
+    """Two pix2pixhd steps at the preset's default extent on ONE device;
+    the lowered step must carry ``kernel_marker`` (None: the CPU rehearsal, where the
     kernels run interpreted and there is no custom call to find)."""
     data_root = _hd_dataset(out, seed, sizes, batch=1)
     with _dump_ir_to(os.path.join(out, "ir_hd")) as dump:
@@ -509,20 +516,25 @@ def phase_multichip(result: dict, out: str, seed: int, sizes: Sizes,
         with open(max(hlos, key=os.path.getsize)) as f:
             hlo = f.read()
         census = dict(collect_collectives(hlo))
-        gathered = max(
-            (n for n, _ in hlo_collective_shapes(hlo, "all-gather")),
-            default=0)
+        gathered, resharded = (
+            max((n for n, _ in hlo_collective_shapes(hlo, kind)), default=0)
+            for kind in ("all-gather", "all-to-all"))
         # the smallest Pallas-normed activation of the step (bs2 at 1/16
-        # extent, 1024 ch): an all-gather that large undid a shard
+        # extent, 1024 ch): an all-gather that large undid a shard, and so
+        # did an all-to-all (GSPMD's answer to a reverse along H moved the
+        # whole tensor from H to W and back: the k7 layers' reflect pad
+        # until PR 25)
         h, w = sizes.hd_hw
         bound = 2 * (h // 16) * (w // 16) * 1024
         if not census.get("collective-permute"):
             failures.append(f"no halo collective-permute: {census}")
-        if gathered >= bound:
-            failures.append(f"all-gather of {gathered} elements >= the "
-                            f"activation bound {bound}")
+        for kind, n in (("all-gather", gathered), ("all-to-all", resharded)):
+            if n >= bound:
+                failures.append(f"{kind} of {n} elements >= the "
+                                f"activation bound {bound}")
         result.update(collectives=census,
                       largest_all_gather_elements=gathered,
+                      largest_all_to_all_elements=resharded,
                       kernel_calls_compiled=hlo.count(KERNEL_MARKER))
     single = _hd_train(out, "ref1", seed, sizes, 2, "data=1", data_root)
     worst = 0.0

@@ -187,6 +187,41 @@ def hlo_collective_shapes(text: str,
     return out
 
 
+_HLO_ARRAY_RE = re.compile(r"\b([a-z]+\d+|pred)\[([\d,]*)\]")
+_HLO_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
+                    "f16": 2, "bf16": 2, "s32": 4, "u32": 4, "f32": 4,
+                    "s64": 8, "u64": 8, "f64": 8}
+
+
+def hlo_collective_bytes(text: str) -> Counter:
+    """Bytes each kind of collective moves in one run of the compiled
+    program, summed over its instructions: the arrays of an instruction's
+    RESULT type (between ``=`` and the opcode), per partition. The async
+    ``-start`` of a collective-permute or an all-gather carries a tuple
+    ``(operand, result, ...)``: its second array is counted; an
+    all-reduce's start carries its results only. ``-done`` lines are
+    not counted (they move nothing of their own)."""
+    out: Counter = Counter()
+    for ln in text.splitlines():
+        m = _HLO_OP_RE.search(ln)
+        if not m:
+            continue
+        kind, is_start = m.group(1), bool(m.group(2))
+        sizes = []
+        for dtype, dims in _HLO_ARRAY_RE.findall(ln[:m.start(1)]):
+            n = _HLO_DTYPE_BYTES.get(dtype)
+            if n is None:
+                continue
+            for d in dims.split(","):
+                n *= int(d) if d else 1
+            sizes.append(n)
+        if is_start and kind in ("collective-permute", "all-gather") \
+                and len(sizes) >= 2:
+            sizes = sizes[1:2]
+        out[kind] += sum(sizes)
+    return out
+
+
 def assert_no_collective_as_large_as(text: str, numel: int,
                                      kind: str = "all-gather") -> None:
     """Pin: no ``kind`` line in the compiled text touches a shape with

@@ -495,7 +495,10 @@ _register(
     )
 )
 
-# 4. pix2pixHD multi-scale G/D at 1024×512 (Pallas InstanceNorm + conv)
+# 4. pix2pixHD multi-scale G/D (Pallas InstanceNorm + conv). The default
+#    extent 1024×512 is what one chip holds; the paper's 2048×1024 is
+#    ``--image_size 1024 --image_width 2048 --mesh data=2,spatial=2
+#    --batch_size 2`` on a four-chip host (PERF.md section 4)
 _register(
     Config(
         name="pix2pixhd",

@@ -434,3 +434,23 @@ def local_batch_size(global_batch: int, mesh: Mesh) -> int:
         raise ValueError(f"global batch {global_batch} not divisible by {n_proc} hosts")
     del mesh
     return global_batch // n_proc
+
+
+def spatial_shard_mesh(x) -> Optional[Mesh]:
+    """The visible mesh (:func:`current_mesh`) when it spans several
+    devices and the NHWC tensor ``x`` can be laid out
+    ``P((data, fsdp), spatial, None, None)`` on it — the one test every op
+    asks before it wraps itself in a ``shard_map`` over the mesh (the
+    Pallas norm kernels; the reflect pad's halo where ``spatial`` > 1);
+    else None. A mesh whose ``spatial`` axis is 1 qualifies: Mosaic
+    refuses a kernel outside a ``shard_map`` in ANY multi-device
+    program, and the moments' psum over an axis of one is free."""
+    mesh = current_mesh()
+    if mesh is None or mesh.size <= 1:
+        return None
+    d = 1
+    for a in BATCH_AXES:
+        d *= mesh.shape.get(a, 1)
+    if x.shape[0] % d or x.shape[1] % mesh.shape.get(SPATIAL_AXIS, 1):
+        return None
+    return mesh

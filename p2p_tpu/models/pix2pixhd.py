@@ -1,4 +1,5 @@
-"""pix2pixHD coarse-to-fine generator (BASELINE configs[3]: 1024×512).
+"""pix2pixHD coarse-to-fine generator (BASELINE configs[3]: 1024×512 on
+one chip; the paper's 2048×1024 on data=2 × spatial=2, PERF.md section 4).
 
 Global generator G1 (a deeper ResnetGenerator: 4 stride-2 downsamples, 9
 blocks, channels capped at 1024) learns at half resolution; a local enhancer

@@ -155,10 +155,57 @@ def conv_form_sites() -> dict:
             for f in CONV_FORMS}
 
 
+def _reflect_pad_h_sharded(x: jax.Array, pad: int, mesh) -> jax.Array:
+    """Reflection-pad H of an NHWC tensor whose H is sharded over the
+    ``spatial`` axis of ``mesh``, without moving the shard.
+
+    ``jnp.pad(mode="reflect")`` with ``pad`` >= 2 REVERSES the border rows,
+    and GSPMD has no rule for a reverse along a sharded dimension: in the
+    pix2pixhd step on data=2 x spatial=2 it re-sharded the whole
+    activation from H to W and back around every k7 layer's pad, two
+    all-to-alls of the full tensor each (67 MB each for the enhancer
+    head at 2048x1024; found compiling the step for a described v5e:2x2,
+    PERF.md section 4, PR 25). Here every shard builds its own rows of
+    the padded tensor: its halo'd shard (``parallel.halo.halo_exchange``:
+    neighbour rows inside, reflected rows at the image's two edges), cut
+    to the ``(H + 2*pad) / spatial`` rows that are its equal share of the
+    padded tensor. The result is laid out like ``x``; the convolution that
+    follows is GSPMD's, as for the k3 layers."""
+    from jax.sharding import PartitionSpec as P
+
+    from p2p_tpu.core.mesh import BATCH_AXES, SPATIAL_AXIS
+    from p2p_tpu.parallel.halo import halo_exchange
+
+    grow = 2 * pad // mesh.shape[SPATIAL_AXIS]
+
+    def local(xl):
+        rows = xl.shape[1] + grow
+        xl = halo_exchange(xl, dim=1, halo=pad, axis_name=SPATIAL_AXIS,
+                           edge_mode="reflect")
+        return jax.lax.dynamic_slice_in_dim(
+            xl, jax.lax.axis_index(SPATIAL_AXIS) * grow, rows, axis=1)
+
+    spec = P(BATCH_AXES, SPATIAL_AXIS, None, None)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec,),
+                         out_specs=spec)(x)
+
+
 def reflect_pad_2d(x: jax.Array, pad: int) -> jax.Array:
-    """Reflection-pad H and W of an NHWC tensor."""
+    """Reflection-pad H and W of an NHWC tensor. Inside a step whose mesh
+    shards H (``core.mesh.current_mesh`` with ``spatial`` > 1) a pad of two
+    rows or more is built shard by shard (:func:`_reflect_pad_h_sharded`)
+    where the padded rows split evenly; one row needs no reverse and
+    stays GSPMD's."""
     if pad == 0:
         return x
+    from p2p_tpu.core.mesh import SPATIAL_AXIS, spatial_shard_mesh
+
+    mesh = spatial_shard_mesh(x) if pad >= 2 else None
+    s = mesh.shape[SPATIAL_AXIS] if mesh is not None else 1
+    if s > 1 and (2 * pad) % s == 0 and x.shape[1] // s > pad:
+        x = _reflect_pad_h_sharded(x, pad, mesh)
+        return jnp.pad(x, ((0, 0), (0, 0), (pad, pad), (0, 0)),
+                       mode="reflect")
     return jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)), mode="reflect")
 
 

@@ -101,7 +101,9 @@ def eligible_block(x: jax.Array) -> int:
     if jax.default_backend() != "tpu":
         return 0
     mesh = current_mesh()
-    if mesh is not None and mesh.size > 1:
+    # a Mosaic kernel outside a shard_map is refused in any multi-device
+    # program, and with no mesh visible nothing says what this one spans
+    if (mesh.size if mesh is not None else jax.device_count()) > 1:
         return 0
     if x.ndim < 2 or x.size < (1 << 20):
         return 0

@@ -111,23 +111,26 @@ def sharded_pallas_instance_norm(
     return fn(x, scale, bias)
 
 
-def _sharding_mesh_for(x: jax.Array):
-    """The active mesh when x is shardable over (data×fsdp, spatial),
-    else None."""
-    from p2p_tpu.core.mesh import BATCH_AXES, SPATIAL_AXIS, current_mesh
+def _sharding_mesh_for(x: jax.Array, interpret: bool = False):
+    """``(mesh, multi)``: the visible mesh when it spans several devices
+    and x can be laid out over (data×fsdp, spatial) on it, else None
+    (core/mesh.spatial_shard_mesh); and whether the program being traced
+    may span several devices. In such a program the compiled kernel runs
+    inside a ``shard_map`` or not at all (Mosaic refuses to partition it):
+    an x that cannot be laid out takes the XLA norm, which GSPMD
+    partitions. With NO mesh visible in a process that has several
+    devices nothing at trace time says what the program spans — a jit on
+    a mesh Trainer's replicated state from outside its ``mesh_context``
+    (the benchmark's generator check) is a multi-device program — so the
+    compiled kernel is not taken there either; the step, the evaluation
+    and the server all trace inside ``mesh_context`` and are not touched.
+    (The interpreted kernel is plain XLA ops and needs no such care.)"""
+    from p2p_tpu.core.mesh import current_mesh, spatial_shard_mesh
 
     mesh = current_mesh()
     if mesh is None:
-        return None
-    d = 1
-    for a in BATCH_AXES:
-        d *= mesh.shape.get(a, 1)
-    s = mesh.shape.get(SPATIAL_AXIS, 1)
-    if s <= 1:
-        return None
-    if x.shape[0] % (d or 1) or x.shape[1] % s:
-        return None
-    return mesh
+        return None, not interpret and jax.device_count() > 1
+    return spatial_shard_mesh(x), mesh.size > 1
 
 
 def pallas_instance_norm(
@@ -138,9 +141,10 @@ def pallas_instance_norm(
     force_pallas: bool = False,
     interpret: bool = False,
 ) -> jax.Array:
-    """InstanceNorm on NHWC. Uses the Pallas kernel on TPU backends; inside
-    a spatial-sharded parallel step (core.mesh.mesh_context) it switches to
-    the shard_map variant so the activations never get all-gathered."""
+    """InstanceNorm on NHWC. Uses the Pallas kernel on TPU backends; in a
+    program over several devices (core.mesh.current_mesh: the parallel
+    step) it switches to the shard_map variant so the activations never
+    get all-gathered."""
     use_kernel, interp = kernel_dispatch(force_pallas, interpret)
     if not use_kernel:
         # off-TPU: XLA norm — fast, and GSPMD partitions it natively (no
@@ -148,9 +152,11 @@ def pallas_instance_norm(
         # shard_map + interpret-mode program via force_pallas=True or
         # P2P_TPU_FORCE_PALLAS=1.
         return _xla_instance_norm(x, scale, bias, eps)
-    mesh = _sharding_mesh_for(x)
+    mesh, multi = _sharding_mesh_for(x, interp)
     if mesh is not None:
         return sharded_pallas_instance_norm(x, scale, bias, eps, mesh, interp)
+    if multi:
+        return _xla_instance_norm(x, scale, bias, eps)
     from p2p_tpu.ops.pallas.instance_norm_kernel import instance_norm_fused
 
     return instance_norm_fused(x, scale, bias, eps, interpret=interp)
@@ -164,12 +170,15 @@ def sharded_pallas_instance_norm_act(
     from jax.sharding import PartitionSpec as P
 
     from p2p_tpu.core.mesh import (
-        DATA_AXIS,
+        BATCH_AXES,
         SPATIAL_AXIS,
     )
     from p2p_tpu.ops.pallas.norm_act import instance_norm_act_fused_sharded
 
-    x_spec = P(DATA_AXIS, SPATIAL_AXIS, None, None)
+    # N over (data, fsdp) like the plain variant above and like
+    # _sharding_mesh_for's divisibility test: with DATA_AXIS alone a
+    # data x fsdp x spatial mesh would gather the fsdp shards of N
+    x_spec = P(BATCH_AXES, SPATIAL_AXIS, None, None)
     affine = scale is not None
     has_res = residual is not None
     in_specs = [x_spec] + ([P(), P()] if affine else []) + (
@@ -215,10 +224,13 @@ def pallas_instance_norm_act(
     if not use_kernel:
         return _xla_instance_norm_act(x, scale, bias, residual, act, slope,
                                       eps)
-    mesh = _sharding_mesh_for(x)
+    mesh, multi = _sharding_mesh_for(x, interp)
     if mesh is not None:
         return sharded_pallas_instance_norm_act(
             x, scale, bias, residual, act, slope, eps, mesh, interp)
+    if multi:
+        return _xla_instance_norm_act(x, scale, bias, residual, act, slope,
+                                      eps)
     from p2p_tpu.ops.pallas.norm_act import instance_norm_act_fused
 
     return instance_norm_act_fused(x, scale, bias, residual, act=act,
@@ -250,7 +262,7 @@ def pallas_instance_norm_act_quant(
     from p2p_tpu.ops.pallas.norm_act import instance_norm_act_quant
 
     use_kernel, interp = kernel_dispatch(force_pallas, interpret)
-    use_kernel = use_kernel and _sharding_mesh_for(x) is None
+    use_kernel = use_kernel and not _sharding_mesh_for(x, interp)[1]
     return instance_norm_act_quant(
         x, sx, scale, bias, act=act, slope=slope, eps=eps,
         use_kernel=use_kernel, interpret=interp)

@@ -121,10 +121,11 @@ def _fwd_impl(x, scale, bias, eps: float, interpret: bool, axis_name=None):
     if axis_name is None:
         count = jnp.float32(h * w)
     else:
-        s1 = jax.lax.psum(s1, axis_name)
-        s2 = jax.lax.psum(s2, axis_name)
-        count = float(h * w) * jax.lax.psum(
-            jnp.ones((), jnp.float32), axis_name)
+        with jax.named_scope("norm_psum"):
+            s1 = jax.lax.psum(s1, axis_name)
+            s2 = jax.lax.psum(s2, axis_name)
+            count = float(h * w) * jax.lax.psum(
+                jnp.ones((), jnp.float32), axis_name)
     mean = s1 / count
     var = jnp.maximum(s2 / count - mean * mean, 0.0)
     rstd = jax.lax.rsqrt(var + eps)
@@ -161,8 +162,9 @@ def _in_fused_bwd(eps, interpret, axis_name, res, g):
     m1 = jnp.sum(dxhat, axis=(1, 2), keepdims=True)
     m2 = jnp.sum(dxhat * xhat, axis=(1, 2), keepdims=True)
     if axis_name is not None:
-        m1 = jax.lax.psum(m1, axis_name)
-        m2 = jax.lax.psum(m2, axis_name)
+        with jax.named_scope("norm_psum"):
+            m1 = jax.lax.psum(m1, axis_name)
+            m2 = jax.lax.psum(m2, axis_name)
     m1 = m1 / count
     m2 = m2 / count
     dx = (rstd * (dxhat - m1 - xhat * m2)).astype(x.dtype)

@@ -57,6 +57,13 @@ def halo_exchange(
             f"local shard extent {x.shape[dim]} along dim {dim} too small for "
             f"halo {halo} (need at least halo+1 rows per shard)"
         )
+    # one scope for every explicit exchange: device time is joined to it
+    # as to the nets' scopes (docs/OBSERVABILITY.md)
+    with jax.named_scope("halo"):
+        return _exchange(x, dim, halo, axis_name, edge_mode)
+
+
+def _exchange(x, dim, halo, axis_name, edge_mode):
     n = lax.psum(1, axis_name)
     idx = lax.axis_index(axis_name)
 

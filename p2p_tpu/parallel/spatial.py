@@ -1,6 +1,7 @@
 """GSPMD spatial sharding — large images split along H over the ``spatial``
 mesh axis (BASELINE configs[2] Cityscapes 512×256, configs[3] pix2pixHD
-1024×512).
+1024×512; pix2pixHD at the paper's 2048×1024 on data=2 × spatial=2 is the
+one layout that has run on chips, PERF.md section 4).
 
 Two complementary paths, per the scaling-book recipe ("annotate shardings,
 let XLA insert collectives, profile, hand-optimize what's left"):

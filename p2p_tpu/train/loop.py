@@ -141,6 +141,54 @@ def close_trainer_obs(tr) -> None:
         tr._sentinel_handler = None
 
 
+def note_step_collectives(tr, batch) -> None:
+    """What the compiled SHARDED train step moves between chips, read
+    once from its text: one ``kind="collectives"`` record in the run's
+    stream and the gauges ``step_collective_ops{op=}``,
+    ``step_collective_bytes{op=}`` (bytes a partition, a run of the step)
+    and ``step_largest_all_gather_elements`` (the largest all-gather or
+    all-to-all) — the number that shows a shard silently undone (an
+    activation gathered or re-sharded along H reads millions there;
+    docs/OBSERVABILITY.md). Called after the step's first
+    dispatch with the batch as it was fed: lowered from the same avals
+    and shardings jax hands back the executable that is running, so
+    nothing compiles (tests/test_spatial4.py pins that)."""
+    from p2p_tpu.analysis.jaxpr_lint import (
+        HLO_COLLECTIVES,
+        collect_collectives,
+        hlo_collective_bytes,
+        hlo_collective_shapes,
+    )
+
+    step, tr._collectives_of = tr._collectives_of, None
+
+    def aval(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding) \
+            if isinstance(a, jax.Array) else a
+
+    text = step.lower(*jax.tree_util.tree_map(
+        aval, (tr.state, batch))).compile().as_text()
+    counts, nbytes = collect_collectives(text), hlo_collective_bytes(text)
+    # an all-to-all undoes a shard as an all-gather does (GSPMD re-sharded
+    # whole activations H -> W -> H around the reflect pad that way, PR 25):
+    # the record holds both, the gauge the larger
+    gathered, resharded = (
+        max((n for n, _ in hlo_collective_shapes(text, kind)), default=0)
+        for kind in ("all-gather", "all-to-all"))
+    largest = max(gathered, resharded)
+    record = {"kind": "collectives",
+              "mesh": {a: int(n) for a, n in tr.mesh.shape.items() if n > 1},
+              "largest_all_gather_elements": int(gathered),
+              "largest_all_to_all_elements": int(resharded)}
+    for op in HLO_COLLECTIVES:
+        tr.obs.gauge("step_collective_ops", op=op).set(counts[op])
+        tr.obs.gauge("step_collective_bytes", op=op).set(nbytes[op])
+        record[f"{op}.count"] = int(counts[op])
+        record[f"{op}.bytes"] = int(nbytes[op])
+    tr.obs.gauge("step_largest_all_gather_elements").set(largest)
+    tr.logger.log(record, force=True)
+
+
 def trainer_topology(tr) -> Dict:
     """The topology block recorded in the sidecar AND reconciled against
     on relaunch (core/mesh.classify_topology_delta): mesh axis sizes +
@@ -1067,6 +1115,10 @@ class Trainer:
                 cfg, self.mesh, self.vgg_params, self.steps_per_epoch,
                 self._dtype, state_sharding=self.state_sharding,
             )
+            # the jitted step itself, until its collectives are noted
+            # (note_step_collectives); a caller may wrap self.train_step
+            self._collectives_of = (
+                self.train_step if self.mesh.size > 1 else None)
             self.multi_step = None
             if cfg.train.scan_steps > 1:
                 self.multi_step = make_parallel_multi_train_step(
@@ -1077,6 +1129,7 @@ class Trainer:
             self.train_step = build_train_step(
                 cfg, self.vgg_params, self.steps_per_epoch, self._dtype
             )
+            self._collectives_of = None
             self.multi_step = None
             if cfg.train.scan_steps > 1:
                 from p2p_tpu.train.step import build_multi_train_step
@@ -1325,6 +1378,8 @@ class Trainer:
             note("train_dispatch", disp, at)
             stop = False
             with timed_annotation("step_bookkeeping", book_hist) as book:
+                if k == 1 and self._collectives_of is not None:
+                    note_step_collectives(self, batch_or_stack)
                 self._img_rate.mark(k * cfg.data.batch_size)
                 # divergence sentinel: queue THIS dispatch, read the
                 # previous one (a wait on the device when the host runs
