@@ -15,8 +15,23 @@ import jax
 import jax.numpy as jnp
 
 from p2p_tpu.models.vgg import VGG19Features
+from p2p_tpu.obs.registry import get_registry
 
 VGG_SLICE_WEIGHTS = (1.0 / 32, 1.0 / 16, 1.0 / 8, 1.0 / 4, 1.0)
+
+
+#: the dtypes VGG19's activations can be stored in between its layers
+VGG_ACT_DTYPES = ("float32", "bfloat16")
+
+
+def vgg_loss_traces() -> dict:
+    """activation dtype -> ``vgg_loss`` calls traced with it so far in
+    this process (``vgg_loss_traces_total{act_dtype=...}``, counted like
+    ``ops.conv.conv_form_sites``: a module cannot be handed a run's
+    registry)."""
+    reg = get_registry()
+    return {d: int(reg.counter("vgg_loss_traces_total", act_dtype=d).value)
+            for d in VGG_ACT_DTYPES}
 
 
 def vgg_loss(
@@ -24,12 +39,27 @@ def vgg_loss(
     x: jax.Array,
     y: jax.Array,
     imagenet_norm: bool = False,
-    dtype=None,
 ) -> jax.Array:
-    """Perceptual distance between x and y (target y stop-gradiented)."""
-    model = VGG19Features(dtype=dtype, imagenet_norm=imagenet_norm)
-    feats_x = model.apply({"params": vgg_params}, x)
-    feats_y = model.apply({"params": vgg_params}, jax.lax.stop_gradient(y))
+    """Perceptual distance between x and y (target y stop-gradiented).
+
+    bf16 images (mixed precision) keep VGG19's activations in bf16, which
+    is what its convolutions read of them on the MXU anyway; any other
+    ``x`` runs the trunk as ``nn.Conv`` promotes it to the float32
+    parameters. The taps' difference and its mean are float32 either way.
+    """
+    store = jnp.bfloat16 if x.dtype == jnp.bfloat16 else None
+    get_registry().counter(
+        "vgg_loss_traces_total",
+        act_dtype="float32" if store is None else "bfloat16").inc()
+    model = VGG19Features(imagenet_norm=imagenet_norm, store_dtype=store)
+    return tap_distance(
+        model.apply({"params": vgg_params}, x),
+        model.apply({"params": vgg_params}, jax.lax.stop_gradient(y)))
+
+
+def tap_distance(feats_x, feats_y) -> jax.Array:
+    """The weighted L1 between two sets of VGG19 taps, in float32 (the
+    second set stop-gradiented)."""
     total = jnp.zeros((), jnp.float32)
     for w, fx, fy in zip(VGG_SLICE_WEIGHTS, feats_x, feats_y):
         fy = jax.lax.stop_gradient(fy)

@@ -45,11 +45,76 @@ _IMAGENET_MEAN = np.array([0.485, 0.456, 0.406], np.float32)
 _IMAGENET_STD = np.array([0.229, 0.224, 0.225], np.float32)
 
 
-class VGG19Features(nn.Module):
-    """Frozen VGG19 trunk; returns the 5 tap activations (NHWC)."""
+def _conv3x3(x, w, preferred_element_type=None):
+    return jax.lax.conv_general_dilated(
+        x, w, (1, 1), ((1, 1), (1, 1)),
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        preferred_element_type=preferred_element_type)
 
-    dtype: Optional[jnp.dtype] = None
+
+@jax.custom_vjp
+def conv3x3_relu_stored(x, w, b):
+    """``relu(conv3x3(x, w) + b)`` stored in ``x.dtype``: the products of
+    the operands as the MXU reads them (``x`` and ``w`` in ``x.dtype``),
+    their sum, the bias and the ReLU in float32, ONE rounding at the store.
+
+    A float32 ``nn.Conv`` at the default precision computes the same sum
+    on the chip and keeps a float32 copy that the next convolution rounds
+    to bf16 as it reads it; this stores what is read and nothing wider.
+    The backward takes its mask from the stored output (as ``relu_y``) and
+    the cotangents in the stored dtype: the input gradient is one
+    convolution with a float32 sum inside and one rounding. ``w`` and
+    ``b`` are float32 parameters; their gradients (VGG19 is frozen, no
+    step asks for them) come out of operands in the stored dtype."""
+    z = _conv3x3(x, w.astype(x.dtype), jnp.float32)
+    return jnp.maximum(z + b.astype(jnp.float32), 0).astype(x.dtype)
+
+
+def _stored_fwd(x, w, b):
+    y = conv3x3_relu_stored(x, w, b)
+    return y, (x, w, b, y)
+
+
+def _stored_bwd(res, ct):
+    x, w, b, y = res
+    dz = jnp.where(y > 0, ct, jnp.zeros_like(ct))
+    # transposing a convolution that widens its result is not something
+    # lax does (operands of two dtypes); the same-dtype one is the same
+    # linear map
+    dx, dw = jax.vjp(_conv3x3, x, w.astype(x.dtype))[1](dz)
+    db = jnp.sum(dz, (0, 1, 2), dtype=jnp.float32)
+    return dx, dw.astype(w.dtype), db.astype(b.dtype)
+
+
+conv3x3_relu_stored.defvjp(_stored_fwd, _stored_bwd)
+
+
+class _StoredConvRelu(nn.Module):
+    """``nn.Conv`` 3x3 + ReLU with ``nn.Conv``'s parameter tree, through
+    :func:`conv3x3_relu_stored`."""
+
+    features: int
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (3, 3, x.shape[-1], self.features), jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros,
+                          (self.features,), jnp.float32)
+        return conv3x3_relu_stored(x, kernel, bias)
+
+
+class VGG19Features(nn.Module):
+    """Frozen VGG19 trunk; returns the 5 tap activations (NHWC).
+
+    ``store_dtype`` (the perceptual loss sets it to the dtype of bf16
+    images, nothing else sets it) keeps every activation between the
+    layers in that dtype, rounded once from the float32 epilogue of its
+    convolution (:func:`conv3x3_relu_stored`); ``None`` is the float32
+    trunk as ``nn.Conv`` promotes it."""
+
     imagenet_norm: bool = False
+    store_dtype: Optional[jnp.dtype] = None
 
     @nn.compact
     def __call__(self, x) -> List[jax.Array]:
@@ -58,15 +123,17 @@ class VGG19Features(nn.Module):
             x = (x + 1.0) * 0.5
             x = (x - _IMAGENET_MEAN) / _IMAGENET_STD
         outs = []
-        y = x
+        y = x if self.store_dtype is None else x.astype(self.store_dtype)
         for name, ch in _CFG:
             if name == "M":
                 y = nn.max_pool(y, (2, 2), strides=(2, 2))
                 continue
-            y = save_conv_out(nn.Conv(
-                ch, kernel_size=(3, 3), padding=1, dtype=self.dtype, name=name
-            )(y))
-            y = relu_y(y)
+            if self.store_dtype is None:
+                y = save_conv_out(nn.Conv(
+                    ch, kernel_size=(3, 3), padding=1, name=name)(y))
+                y = relu_y(y)
+            else:
+                y = _StoredConvRelu(ch, name=name)(y)
             if name in _TAPS:
                 outs.append(y)
         return outs

@@ -60,6 +60,7 @@ from p2p_tpu.obs import (
     timed_annotation,
     write_manifest,
 )
+from p2p_tpu.losses.perceptual import vgg_loss_traces
 from p2p_tpu.ops.conv import conv_form_sites
 from p2p_tpu.resilience import Preempted, PreemptionGuard
 from p2p_tpu.resilience.chaos import FaultInjected, chaos_point
@@ -1000,6 +1001,7 @@ class Trainer:
         )
         self.fid_feature_fn = None
         self.vgg_source = None
+        self._trace_counts_logged = {}  # kind -> counts last written
         if cfg.train.eval_fid and self.vgg_params is not None:
             from p2p_tpu.losses.fid import make_vgg_feature_fn
             from p2p_tpu.models.vgg import vgg19_params_source
@@ -1535,15 +1537,19 @@ class Trainer:
             record[f"{phase}_s"] = round(secs, 6)
             record[f"slowest_{phase}_s"] = round(slowest[phase][0], 6)
             record[f"slowest_{phase}_step"] = slowest[phase][1]
-        # which form the thin convolutions took (ops/conv.py counts call
-        # sites as they are traced): one record whenever a trace added some
-        forms = conv_form_sites()
-        if forms != getattr(self, "_conv_forms_logged", None) \
-                and any(forms.values()):
-            self._conv_forms_logged = forms
-            self.logger.log({"kind": "conv_forms", **{
-                f"conv_form_sites_total.{k}": v for k, v in forms.items()}},
-                force=True)
+        # which form the thin convolutions took and which dtype VGG19's
+        # activations were stored in (ops/conv.py and losses/perceptual.py
+        # count as they are traced): one record each whenever a trace
+        # added some
+        logged = self._trace_counts_logged
+        for kind, name, counts in (
+                ("conv_forms", "conv_form_sites_total", conv_form_sites()),
+                ("vgg_loss", "vgg_loss_traces_total", vgg_loss_traces())):
+            if counts != logged.get(kind) and any(counts.values()):
+                logged[kind] = counts
+                self.logger.log({"kind": kind, **{
+                    f"{name}.{k}": v for k, v in counts.items()}},
+                    force=True)
         if sums is None:
             return {}
         out = epoch_metric_means(host_sums, count)

@@ -32,3 +32,28 @@ def pytest_configure(config):
         "markers",
         "slow: compile-heavy test (>~10 s on CPU); quick gate: -m 'not slow'",
     )
+
+
+@pytest.fixture(scope="session")
+def parent_vgg_loss():
+    """``vgg_loss`` as it stood before VGG19 stored bf16 activations for
+    bf16 images: the plain trunk on whatever it is handed, the taps' L1 in
+    float32. What float32 images must still lower to, and the program the
+    bf16 path is compared with."""
+    import jax.numpy as jnp
+
+    from p2p_tpu.losses import VGG_SLICE_WEIGHTS
+    from p2p_tpu.models.vgg import VGG19Features
+
+    def loss(params, x, y):
+        model = VGG19Features()
+        feats_x = model.apply({"params": params}, x)
+        feats_y = model.apply({"params": params}, jax.lax.stop_gradient(y))
+        total = jnp.zeros((), jnp.float32)
+        for w, fx, fy in zip(VGG_SLICE_WEIGHTS, feats_x, feats_y):
+            fy = jax.lax.stop_gradient(fy)
+            total = total + w * jnp.mean(
+                jnp.abs(fx.astype(jnp.float32) - fy.astype(jnp.float32)))
+        return total
+
+    return loss

@@ -199,3 +199,45 @@ def test_blocked_conv_layer_compiles_dense(one_chip, shape, features, k):
     assert f"{k}x{kb}" in windows and f"{k}x{k}" not in windows, windows
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 8 * 2 ** 30
+
+
+def _entry_and_types(text):
+    """The ENTRY computation's lines, and name -> result type of every
+    instruction of the module."""
+    types = dict(re.findall(r"%([\w.\-]+) = \(?(\w+\[[\d,]*\])", text))
+    return text[text.index("ENTRY"):].splitlines(), types
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_vgg_loss_stores_what_its_convolutions_read(
+        one_chip, parent_vgg_loss, dtype):
+    """``vgg_loss`` and its image gradient at 256x256 through the chip's
+    compiler. bf16 images: no float32 tensor of conv1's extent outside a
+    fusion body (the parent keeps some) and every convolution reads a
+    bf16 activation; float32 images: the compiler is handed the parent's
+    program, text for text."""
+    from p2p_tpu.losses import vgg_loss
+    from p2p_tpu.models.vgg import load_vgg19_params
+
+    params = load_vgg19_params()
+    x = jax.ShapeDtypeStruct((2, 256, 256, 3), dtype, sharding=one_chip)
+
+    def lowered(loss):
+        return jax.jit(jax.value_and_grad(
+            lambda a, b: loss(params, a, b))).lower(x, x)
+
+    if dtype == "float32":
+        # (the compiled text carries file names and line numbers)
+        assert lowered(vgg_loss).as_text() == lowered(
+            parent_vgg_loss).as_text()
+        return
+    new, old = (lowered(f).compile().as_text()
+                for f in (vgg_loss, parent_vgg_loss))
+    wide = r"= \(?f32\[2,256,256,64\]"
+    entry, types = _entry_and_types(new)
+    assert [ln for ln in entry if re.search(wide, ln)] == []
+    assert [ln for ln in _entry_and_types(old)[0] if re.search(wide, ln)]
+    reads = [types[a] for a in re.findall(
+        r" convolution\(%([\w.\-]+), ", new)]
+    assert len(reads) >= 16 + 15     # 16 forward x 2 images, 15 backward
+    assert all(t.startswith("bf16[") for t in reads), reads

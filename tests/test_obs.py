@@ -353,6 +353,13 @@ def test_trainer_obs_wiring(tmp_path, monkeypatch):
 
         tr.train_step = train_step
         tr.multi_step = None
+        # a program of this run traces the perceptual loss on bf16 images
+        from p2p_tpu.losses import vgg_loss
+        from p2p_tpu.models.vgg import load_vgg19_params
+
+        jax.eval_shape(
+            lambda p, a: vgg_loss(p, a, a), jax.eval_shape(load_vgg19_params),
+            jax.ShapeDtypeStruct((1, 16, 16, 3), jnp.bfloat16))
         tr.fit()
 
         manifest = json.load(open(tmp_path / "manifest_obswire.json"))
@@ -367,6 +374,10 @@ def test_trainer_obs_wiring(tmp_path, monkeypatch):
         assert "train" in kinds and "epoch" in kinds
         epoch = next(r for r in recs if r["kind"] == "epoch")
         assert epoch["epoch"] == 1 and math.isfinite(epoch["loss_g"])
+        # ... and the run's stream says which dtype VGG19 stored
+        (vgg,) = [r for r in recs if r["kind"] == "vgg_loss"]
+        assert vgg["vgg_loss_traces_total.bfloat16"] >= 1
+        assert set(vgg) >= {"vgg_loss_traces_total.float32"}
 
         trace_doc = json.load(open(tmp_path / "trace_obswire.json"))
         names = {e["name"] for e in trace_doc["traceEvents"]

@@ -695,10 +695,13 @@ def test_count_nonfinite_counts_exactly():
 
 
 # ------------------------------------------------ named scopes of the step
-@pytest.fixture(scope="module")
-def scoped_step(batch):
+@pytest.fixture(scope="module", params=[None, jnp.bfloat16],
+                ids=["f32", "bf16"])
+def scoped_step(batch, request):
     """The toy step with every kind of loss live (the seeded VGG19 too),
-    lowered once for the scope tests."""
+    lowered once a train dtype for the scope tests: under bf16 the
+    perceptual loss takes its stored-dtype convolutions, whose
+    hand-written backward has to carry the scope's name like autodiff's."""
     import dataclasses
 
     from p2p_tpu.models.vgg import load_vgg19_params
@@ -707,7 +710,9 @@ def scoped_step(batch):
     cfg = cfg.replace(loss=dataclasses.replace(
         cfg.loss, lambda_vgg=10.0, lambda_l1=1.0))
     state = create_train_state(cfg, jax.random.key(0), batch, 1)
-    return build_train_step(cfg, load_vgg19_params(None)).lower(state, batch)
+    return build_train_step(
+        cfg, load_vgg19_params(None), train_dtype=request.param
+    ).lower(state, batch)
 
 
 def test_compiled_step_names_every_scope(scoped_step):
