@@ -37,8 +37,9 @@ with every other analyzer here) and produces:
 The numbers are a COST MODEL, not a measurement: bands are pinned on the
 fixed tiny-config trace shapes (deterministic — jaxpr-based, immune to
 XLA version drift), and their job is to catch structural regressions,
-not to predict img/sec. BENCH rows remain the measurement of record;
-``bench.py --sweep`` records link here via :func:`roofline_row_for`.
+not to predict img/sec. The measurement of record is
+``benchmark/run.py`` (PERF.md, ``PERF_LEDGER.jsonl``);
+:func:`roofline_row_for` names the row that models a preset.
 """
 
 from __future__ import annotations
@@ -354,12 +355,12 @@ PERF_BOUNDS: Dict[str, Dict[str, float]] = {
     },
 }
 
-#: sweep-preset → canonical budget row (bench.py links each sweep record
-#: to the roofline row that models its config; None = not yet traced)
+#: preset → the canonical budget row that models its config (None = not
+#: yet traced). No caller but its test since PR 27 (ROADMAP D5).
 _SWEEP_ROOFLINE = {
     "facades": "train_step[facades]",
     "facades_int8": "train_step[facades_int8]",
-    # the facades_int8_full sweep row's key (a first-class preset on the
+    # the facades_int8_full preset's key (a first-class preset on the
     # facades_int8 preset — core.config.int8_full_coverage)
     "facades_int8_full": "train_step[facades_int8_full]",
     "edges2shoes_dp": "train_step[facades]",     # same U-Net family

@@ -48,8 +48,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "presets still restore the FULL state and need "
                         "the trained value")
     p.add_argument("--n_blocks", type=int, default=None)
-    p.add_argument("--upsample_mode", type=str, default=None,
-                   choices=["deconv", "resize"])
     p.add_argument("--metrics", action="store_true",
                    help="also print mean/max PSNR+SSIM vs the targets")
     p.add_argument("--ema_decay", type=float, default=None,
@@ -125,8 +123,7 @@ def main(argv=None) -> int:
     cfg = get_preset(args.preset)
     data = over(cfg.data, dataset=args.dataset, direction=args.direction,
                 test_batch_size=args.batch_size, image_size=args.image_size)
-    model = over(cfg.model, ngf=args.ngf, n_blocks=args.n_blocks,
-                 upsample_mode=args.upsample_mode)
+    model = over(cfg.model, ngf=args.ngf, n_blocks=args.n_blocks)
     health = over(cfg.health, ema_decay=args.ema_decay)
     cfg = dataclasses.replace(cfg, data=data, model=model, health=health,
                               name=args.name or cfg.name)

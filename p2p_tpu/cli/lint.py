@@ -405,10 +405,9 @@ def _int8_train_program(full: bool = False):
 
     ``full=True`` traces the FULL-COVERAGE variant
     (``core.config.int8_full_coverage`` — every ISSUE-14 knob on, the
-    same override set ``bench.py``'s ``facades_int8_full`` row measures):
-    the program the drained int8-coverage worklist audits. The plain
-    variant stays the roofline row for the shipping preset (the headline
-    bench row's program)."""
+    override set of the ``facades_int8_full`` preset): the program the
+    drained int8-coverage worklist audits. The plain variant stays the
+    roofline row for the shipping preset."""
     import jax
     import jax.numpy as jnp
     import numpy as np

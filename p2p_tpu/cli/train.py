@@ -53,10 +53,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "the preset sets a width)")
     p.add_argument("--n_blocks", type=int, default=None,
                    help="override generator residual block count")
-    p.add_argument("--upsample_mode", type=str, default=None,
-                   choices=["deconv", "subpixel", "resize"],
-                   help="U-Net decoder upsampling (deconv = torch-parity "
-                        "ConvTranspose; resize = nearest+conv)")
     p.add_argument("--augment", action="store_true", default=None,
                    help="paired resize-286/random-crop/flip augmentation")
     p.add_argument("--int8", action="store_true", default=None,
@@ -110,10 +106,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "ppermute is double-buffered so the transfer "
                         "overlaps stage compute (parallel/pp.py; costs S-1 "
                         "extra fill/drain ticks — see docs/PARALLELISM.md)")
-    p.add_argument("--thin_head", action="store_true", default=None,
-                   help="U-Net image head as the subpixel form (k2s1 "
-                        "conv + interleave; measured a wash on v5e, "
-                        "1708 vs 1715 img/s; see ModelConfig.thin_head)")
     p.add_argument("--legacy_layout", action="store_true", default=None,
                    help="keep the dead conv biases in front of norm "
                         "layers (round-2 checkpoint layout; see "
@@ -290,14 +282,13 @@ def config_from_flags(args: argparse.Namespace) -> Config:
 
     model = over(model, input_nc=args.input_nc, output_nc=args.output_nc,
                  ngf=args.ngf, ndf=args.ndf, n_blocks=args.n_blocks,
-                 upsample_mode=args.upsample_mode, int8=args.int8,
+                 int8=args.int8,
                  int8_generator=args.int8_generator,
                  int8_delayed=args.int8_delayed,
                  int8_stem=args.int8_stem, int8_head=args.int8_head,
                  int8_compression=args.int8_compression,
                  int8_fused_epilogue=args.int8_fused_epilogue,
-                 legacy_layout=args.legacy_layout,
-                 thin_head=args.thin_head, norm_d=args.norm_d)
+                 legacy_layout=args.legacy_layout, norm_d=args.norm_d)
     loss = over(loss, lambda_l1=args.lamb, lambda_vgg=args.lambda_vgg,
                 lambda_feat=args.lambda_feat, lambda_tv=args.lambda_tv,
                 lambda_sobel=args.lambda_sobel,

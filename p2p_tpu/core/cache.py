@@ -1,7 +1,7 @@
 """Persistent XLA compilation cache — cold-start pays compile ONCE ever.
 
 A pix2pixHD-scale XLA compile is minute-scale, so every entry point
-(``cli.train`` / ``cli.infer`` / ``cli.serve`` ``main``, ``bench.py``,
+(``cli.train`` / ``cli.infer`` / ``cli.serve`` ``main``,
 ``chip_smoke.py``) turns the cache on through
 :func:`enable_compilation_cache`. Where it lives is decided by ONE rule
 (:func:`resolve_cache_dir`):

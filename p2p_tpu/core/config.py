@@ -58,13 +58,6 @@ class ModelConfig:
     # U-Net decoder dropout (the pix2pix noise source). The train step
     # threads a per-step dropout rng when this is on.
     use_dropout: bool = False
-    # U-Net decoder upsampling: "deconv" (ConvTranspose k4 s2 — torch
-    # parameter layout; the default), "subpixel" (conv k2s1 +
-    # depth-to-space — same operator family/FLOPs, but the shifted
-    # interleave costs an extra memory-bound pass per level: measured
-    # SLOWER than deconv on v5e, kept as an option), or "resize"
-    # (nearest + conv k3).
-    upsample_mode: str = "deconv"
     init_type: str = "normal"   # normal | xavier | kaiming | orthogonal
     init_gain: float = 0.02
     # int8 QAT path (ops/int8.py): run the MXU-dominant inner convs of G
@@ -74,7 +67,7 @@ class ModelConfig:
     # critical). v5e: 2× MXU peak vs bf16. Applies to all discriminator
     # families (spectral norm composes: the power iteration tracks the
     # true f32 weight, only w/σ is quantized) and — via int8_generator —
-    # to the "unet" encoder (deconv mode) and the ResNet-trunk families
+    # to the "unet" encoder and the ResNet-trunk families
     # (resnet / pix2pixhd / pix2pixhd_global k3-s1 blocks).
     int8: bool = False
     # Extend int8 to the generator too. Off by default: measured on v5e,
@@ -139,23 +132,6 @@ class ModelConfig:
     # zero-channel-mean cotangents), yet computing those zero gradients
     # re-read full-size cotangents (~3 ms/step at bs=128/256²).
     legacy_layout: bool = False
-    # U-Net image head as the subpixel form (plain k2s1 conv to 4·F
-    # channels + shifted interleave) instead of ConvTranspose. Measured
-    # a wash on v5e at 256²/bs=128 (1708 vs 1715 img/s; the kn2row
-    # variant of the inner conv was distinctly slower, 1538 — see
-    # ops/conv.py SubpixelDeconv.thin). Kept reachable for other
-    # chips/shapes; the exact weight mapping between the layouts is
-    # pinned in tests/test_models.py.
-    thin_head: bool = False
-    # With thin_head: run the head's k2 conv through the Pallas fused
-    # kernel (ops/pallas/subpixel_head.py — x read once per sample
-    # block, tap matmuls accumulated in VMEM) instead of the XLA conv.
-    head_pallas: bool = False
-    # U-Net k4-s2 RGB stem (down0) as strided im2col patches + one dense
-    # matmul (ops/conv.py PatchesConv with stride) — targets the bs=1
-    # profile's 0.7 TF/s / 17 GB/s down0 wgrad. Off by default pending
-    # an on-chip win; A/B via BENCH_STEM=1.
-    thin_stem: bool = False
     # Feed D the UNCONCATENATED (a, b) conditional pair (the split-stem
     # form, models/patchgan._SplitStemConv): no materialized 6-channel
     # full-res pair tensors, conv(a, W_a) CSE-shared across the fake/real
@@ -337,7 +313,7 @@ class HealthConfig:
     generator. ``enabled`` default True: the sentinel consumes metrics the
     loop already computes (one delayed small D2H per dispatch) and the
     in-jit skip guard folds into the existing update-scale multiply —
-    measured-in-band on the healthy path (bench.py --chaos)."""
+    measured-in-band on the healthy path (pre-round reading)."""
 
     enabled: bool = True
     # Sentinel: robust z-score over the last `window` HEALTHY steps per
@@ -545,9 +521,9 @@ def int8_full_coverage(cfg: Config) -> Config:
     coverage knob the --int8-diff worklist drained, on top of ``cfg``.
 
     Shared by the lint CLI (the ``train_step[facades_int8_full]`` traced
-    program the coverage worklist audits) and ``bench.py``'s
-    ``facades_int8_full`` band-pending sweep row, so the statically audited
-    program and the measured one can never drift apart. Deliberately NOT
+    program the coverage worklist audits) and the ``facades_int8_full``
+    preset, so the statically audited program and the trained one can
+    never drift apart. Deliberately NOT
     flipped: ``int8_stem`` (HBM-bound 3/6-ch stems — the measured-rejected
     verdict carried by dated in-source waivers) and the U-Net image head
     (quality + HBM critical, no knob)."""
@@ -566,12 +542,10 @@ def int8_full_coverage(cfg: Config) -> Config:
     )
 
 
-# The full-coverage int8 config as a FIRST-CLASS preset (ISSUE 15): the
-# on-TPU measurement of record for the ROADMAP item-2 band decision rides
-# the default sweep as a plain --preset/BENCH_PRESET row — no opt-out env
-# gate between the measurement and the round. Same override set the lint
-# CLI traces as train_step[facades_int8_full], so the static and measured
-# programs still cannot drift.
+# The full-coverage int8 config as a FIRST-CLASS preset (ISSUE 15): a
+# plain --preset row. Same override set the lint CLI traces as
+# train_step[facades_int8_full], so the static and trained programs
+# cannot drift.
 _register(int8_full_coverage(_PRESETS["facades_int8"]).replace(
     name="facades_int8_full"))
 

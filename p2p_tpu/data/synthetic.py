@@ -1,10 +1,10 @@
-"""Synthetic paired data for tests and benchmarks.
+"""Synthetic paired data for tests and smoke runs.
 
 Procedurally generated RGB images (smooth gradients + random rectangles and
 disks — enough structure that quantization visibly banding-degrades them),
 run through the same quantizer as real data. Used by the integration tests
-(SURVEY §4.4: tiny synthetic set driven N steps) and by bench.py when no
-real dataset is mounted.
+(SURVEY §4.4: tiny synthetic set driven N steps), ``train/graft.py`` and
+``chip_smoke.py``.
 """
 
 from __future__ import annotations

@@ -50,15 +50,11 @@ def define_G(cfg: ModelConfig, dtype=None, remat=False) -> nn.Module:
 
         return UNetGenerator(
             ngf=cfg.ngf, out_channels=cfg.output_nc, norm=cfg.norm,
-            use_dropout=cfg.use_dropout, upsample_mode=cfg.upsample_mode,
-            int8=int8_g and cfg.upsample_mode == "deconv",
+            use_dropout=cfg.use_dropout, int8=int8_g,
             int8_decoder=cfg.int8_decoder,
             int8_delayed=delayed,
             int8_stem=cfg.int8_stem,
             legacy_layout=cfg.legacy_layout,
-            thin_head=cfg.thin_head,
-            head_pallas=cfg.head_pallas,
-            thin_stem=cfg.thin_stem,
             dtype=dtype,
         )
     if cfg.generator == "resnet":

@@ -7,10 +7,12 @@ convs (k4) with LeakyReLU(0.2), channel growth ngf→8·ngf (capped), skip
 connections at every resolution, decoder mirrors with norm+ReLU, tanh head.
 Innermost and outermost levels carry no norm, as in the original.
 
-TPU-first deviations from the torch lineage (semantics, not translation):
-- Decoder upsampling is nearest-resize + conv k3 (MXU-friendly, no
-  checkerboard) instead of ConvTranspose2d k4 s2 — the same choice the
-  reference made for its own decoder (networks.py:408-423).
+Decoder upsampling is ConvTranspose k4 s2 (the torch pix2pix parameter
+layout): the fastest form measured on v5e despite XLA's reverse-heavy
+transposed-conv backward. The conv k2s1 + depth-to-space and the
+nearest-resize + conv k3 forms it was measured against lost and are gone.
+
+TPU-first deviation from the torch lineage (semantics, not translation):
 - Dropout (the pix2pix noise source, 0.5 on the three innermost decoder
   levels) is off by default; when ``use_dropout`` is set the caller passes
   an ``rngs={'dropout': ...}`` to apply().
@@ -23,12 +25,7 @@ from typing import Optional
 import jax.numpy as jnp
 from flax import linen as nn
 
-from p2p_tpu.ops.conv import (
-    SubpixelDeconv,
-    UpsampleConvLayer,
-    normal_init,
-    save_conv_out,
-)
+from p2p_tpu.ops.conv import normal_init, save_conv_out
 from p2p_tpu.ops.activations import leaky_relu_y, relu_y, tanh_y
 from p2p_tpu.ops.norm import make_norm
 
@@ -39,20 +36,10 @@ class UNetGenerator(nn.Module):
     num_downs: int = 8         # 256x256 → 1x1 bottleneck
     norm: str = "batch"
     use_dropout: bool = False
-    # "deconv": ConvTranspose k4 s2 (torch pix2pix parameter layout); the
-    #   default — fastest measured on v5e despite XLA's reverse-heavy
-    #   transposed-conv backward.
-    # "subpixel": conv k2s1 + depth-to-space — same operator family
-    #   (identical FLOPs/receptive field), clean conv backward, but the
-    #   shifted interleave costs an extra memory-bound pass per level.
-    # "resize": nearest-resize + conv k3 (no checkerboard risk; 2.25×
-    #   decoder FLOPs).
-    upsample_mode: str = "deconv"
     # int8 QAT MXU path (ops/int8.py) for the encoder convs (all except
     # the 3-ch stem down0). int8_decoder additionally switches the
     # decoder deconvs (except the image head up0) to the quantized
     # subpixel form — measured a net loss on v5e, kept as an option.
-    # Requires upsample_mode == "deconv".
     int8: bool = False
     int8_decoder: bool = False
     int8_delayed: bool = False
@@ -72,20 +59,6 @@ class UNetGenerator(nn.Module):
     # function, same training dynamics — they initialize at 0 and never
     # move). True restores the round-2 checkpoint param layout.
     legacy_layout: bool = False
-    # Image head as the subpixel form (plain k2s1 conv + interleave)
-    # instead of ConvTranspose. Measured a wash on v5e at 256²/bs=128
-    # (1708 vs 1715 img/s; the kn2row inner-conv variant was slower,
-    # 1538). Kept as an option for other chips/shapes;
-    # tests/test_models.py pins the exact weight mapping.
-    thin_head: bool = False
-    # with thin_head: Pallas fused kernel for the head's k2 conv
-    head_pallas: bool = False
-    # k4-s2 RGB stem as strided patches + dense matmul (PatchesConv):
-    # the zero-padded 3-ch stem's wgrad collapses XLA to a fraction of
-    # a TF/s at bs=1; the patch form makes
-    # fwd AND dW full-rate dot_generals (dx is dead — input is the
-    # image). Param tree identical to nn.Conv (kernel HWIO + bias).
-    thin_stem: bool = False
     dtype: Optional[jnp.dtype] = None
 
     @nn.compact
@@ -105,10 +78,6 @@ class UNetGenerator(nn.Module):
                         pow2_levels(x.shape[2]))
 
         normed = self.norm != "none" and not self.legacy_layout
-        if self.head_pallas and (not self.thin_head or self.legacy_layout):
-            raise ValueError(
-                "head_pallas requires thin_head (the subpixel head form) "
-                "and the default (non-legacy) layout")
 
         def down_conv(y, features, name, int8=False, norm_after=False,
                       stem=False):
@@ -123,17 +92,6 @@ class UNetGenerator(nn.Module):
                     use_bias=bias, dtype=self.dtype,
                     kernel_init=normal_init(), name=name,
                     delayed=self.int8_delayed,
-                )(y)
-            # stem only: PatchesConv's input cotangent is the slow
-            # k²-pad accumulation — dead for the image stem, live (and
-            # pathological) anywhere deeper
-            if self.thin_stem and stem and y.shape[-1] <= 8:
-                from p2p_tpu.ops.conv import PatchesConv
-
-                return PatchesConv(
-                    features, kernel_size=4, stride=2, zero_pad=1,
-                    use_bias=bias, dtype=self.dtype,
-                    kernel_init=normal_init(), name=name,
                 )(y)
             # p2p-lint: disable=perf-int8-coverage-gap -- 2026-08-04 measured-rejected: only the 3-ch stem (down0) reaches this line under delayed-int8 (encoder i>0 takes the QuantConv branch above); its k4·3-wide contraction leaves the MXU idle in ANY dtype — the conv is HBM-bound, int8 buys nothing and costs the quantize pass (rounds 2-5 doctrine). ModelConfig.int8_stem keeps the form measurable per chip.
             return save_conv_out(nn.Conv(
@@ -163,60 +121,31 @@ class UNetGenerator(nn.Module):
         for i in reversed(range(num_downs)):
             f = self.out_channels if i == 0 else feats[i - 1]
             y = relu_y(y)
-            if self.upsample_mode == "subpixel":
+            if self.int8 and self.int8_decoder and i > 0:
+                # conv-k2s1 subpixel form: the ConvTranspose family
+                # member whose int8 lowering wins in all three
+                # contractions (see ops/int8.py). Off by default:
+                # measured on v5e the interleave + large-spatial
+                # wgrad slices cost more than the MXU gain.
+                from p2p_tpu.ops.int8 import QuantSubpixelDeconv
+
                 # bias kept: after the shifted interleave it is a per-
                 # PHASE (2×2-periodic) offset, which a norm's global mean
                 # only partially absorbs — not dead, unlike plain convs
-                y = SubpixelDeconv(
-                    f, dtype=self.dtype, name=f"up{i}",
-                )(y)
-            elif self.upsample_mode == "deconv":
-                if self.int8 and self.int8_decoder and i > 0:
-                    # conv-k2s1 subpixel form: the ConvTranspose family
-                    # member whose int8 lowering wins in all three
-                    # contractions (see ops/int8.py). Off by default:
-                    # measured on v5e the interleave + large-spatial
-                    # wgrad slices cost more than the MXU gain.
-                    from p2p_tpu.ops.int8 import QuantSubpixelDeconv
-
-                    # bias kept — per-phase offset, see subpixel note
-                    y = QuantSubpixelDeconv(
-                        f, dtype=self.dtype, delayed=self.int8_delayed,
-                        kernel_init=normal_init(), name=f"up{i}",
-                    )(y)
-                elif (i == 0 and self.thin_head
-                      and not self.legacy_layout and 16 * f <= y.shape[-1]):
-                    # image head as the subpixel form (see thin_head doc).
-                    # Plain k2s1 conv, NOT the kn2row variant: the dense
-                    # 128→4F conv reads x once at full HBM rate and its
-                    # backward is a regular conv backward (no deconv
-                    # `reverse` kernels); kn2row's z round-trip measured
-                    # slower here (1538).
-                    y = SubpixelDeconv(
-                        f, pallas=self.head_pallas, dtype=self.dtype,
-                        kernel_init=normal_init(), name=f"up{i}",
-                    )(y)
-                else:
-                    # bias dropped when a norm follows (i>0): the norm's
-                    # mean subtraction cancels it exactly (see legacy_layout)
-                    # p2p-lint: disable=perf-int8-coverage-gap -- 2026-08-04 measured-rejected: under delayed-int8 with int8_decoder only the IMAGE head (up0) reaches this line (i>0 takes QuantSubpixelDeconv above); the tanh-facing head is quality-critical AND HBM-bound (3 live output lanes) — it stays bf16 by doctrine, deliberately without a knob (ops/int8.py module docstring).
-                    y = save_conv_out(nn.ConvTranspose(
-                        f, kernel_size=(4, 4), strides=(2, 2),
-                        padding="SAME", use_bias=not (normed and i > 0),
-                        dtype=self.dtype,
-                        kernel_init=normal_init(), name=f"up{i}",
-                    )(y))
-            elif self.upsample_mode == "resize":
-                y = UpsampleConvLayer(
-                    f, kernel_size=3, upsample=2,
-                    use_bias=not (normed and i > 0), dtype=self.dtype,
-                    name=f"up{i}",
+                y = QuantSubpixelDeconv(
+                    f, dtype=self.dtype, delayed=self.int8_delayed,
+                    kernel_init=normal_init(), name=f"up{i}",
                 )(y)
             else:
-                raise ValueError(
-                    f"unknown upsample_mode {self.upsample_mode!r}; "
-                    "expected 'deconv', 'subpixel', or 'resize'"
-                )
+                # bias dropped when a norm follows (i>0): the norm's
+                # mean subtraction cancels it exactly (see legacy_layout)
+                # p2p-lint: disable=perf-int8-coverage-gap -- 2026-08-04 measured-rejected: under delayed-int8 with int8_decoder only the IMAGE head (up0) reaches this line (i>0 takes QuantSubpixelDeconv above); the tanh-facing head is quality-critical AND HBM-bound (3 live output lanes) — it stays bf16 by doctrine, deliberately without a knob (ops/int8.py module docstring).
+                y = save_conv_out(nn.ConvTranspose(
+                    f, kernel_size=(4, 4), strides=(2, 2),
+                    padding="SAME", use_bias=not (normed and i > 0),
+                    dtype=self.dtype,
+                    kernel_init=normal_init(), name=f"up{i}",
+                )(y))
             if i > 0:
                 y = mk()(y)
                 # dropout on the three decoder levels after the innermost

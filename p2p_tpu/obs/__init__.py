@@ -14,7 +14,7 @@ One import surface for everything a production trainer reports through:
 - **watchdogs** (:mod:`.watchdogs`): unexpected-recompile detection off the
   ``jax.monitoring`` compile events; per-device HBM sampling;
 - **timing** (:mod:`.timing`): the fenced ``StepTimer`` with the chained
-  single-fence mode ``bench.py`` uses — one img/sec/chip definition;
+  single-fence mode the serving engine uses — one img/sec/chip definition;
 - **manifest** (:mod:`.manifest`): the per-run provenance JSON (config hash,
   git SHA, mesh shape, dtype policy).
 """

@@ -6,14 +6,14 @@ ways:
 - ``tick()`` per step with ``block_until_ready`` on the metrics pytree —
   the loop-style API the seed had;
 - ``chain()`` around K chained dispatches fenced ONCE by a host fetch at the
-  end, the methodology ``bench.py`` uses: the host never syncs per step,
+  end: the host never syncs per step,
   so the device queue stays full, and the one fence's own cost (a trivial
   dispatch + fetch, :func:`measure_rtt`) is subtracted. On the attached
   chip ``block_until_ready`` fences just as well; the host fetch is kept
-  because the benchmark reads the final loss anyway.
+  because its callers read the final result anyway.
 
 Both paths feed the same accumulator, so ``images_per_sec`` means the same
-thing in ``bench.py`` records and in the metrics stream.
+thing in the serving engine's statistics and in the metrics stream.
 """
 
 from __future__ import annotations

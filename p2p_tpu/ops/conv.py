@@ -12,8 +12,6 @@ same choice the reference made to avoid checkerboard artifacts.
 
 from __future__ import annotations
 
-import os
-from functools import partial
 from typing import Callable, Optional
 
 import jax
@@ -66,39 +64,13 @@ def save_conv_out(y: jax.Array) -> jax.Array:
     return checkpoint_name(y, "conv_out")
 
 
-# Spatial gate for the thin-conv dispatches (PatchesConv / ThinHeadConv):
-# XLA's thin-channel conv collapse is catastrophic at LARGE spatial extents
-# (pix2pixHD 1024×512: 0.5-1 TF/s, +14% step win from the dispatches) but
-# at small extents the dispatches' own overheads win instead — measured:
-# ExpandNetwork's k9 head at 256²/bs=1 regressed 0.059 → 0.087 s/step
-# (the k²-tap tensor + slice-adds), cityscapes 512×256 was a wash. Gate on
-# the padded spatial area; 300k ≈ "bigger than 512×512".
-_THIN_DISPATCH_MIN_PIXELS = 300_000
-
-
-def _thin_head_eligible(x, features: int, kernel_size: int,
-                        stride: int) -> bool:
-    """Shared ConvLayer/UpsampleConvLayer predicate for the ThinHeadConv
-    dispatch (x is the PADDED input).
-
-    The tap-channel bound ``F·k² ≤ 8·C_in`` keeps the dispatch inside the
-    measured-winning regime (HD k7 64→3: 147 ≤ 512; Expand k9 32→3:
-    243 ≤ 256) and excludes shapes like 16→4 at k7/k9 where the kn2row
-    tap tensor would carry 12-20× the input's channels at full res —
-    far outside anything profiled, risking a memory/perf regression for
-    small-ngf configs at big extents."""
-    in_c = x.shape[-1]
-    return (stride == 1
-            and x.shape[1] * x.shape[2] >= _THIN_DISPATCH_MIN_PIXELS
-            and (features * 16 <= in_c
-                 or (features <= 4 and in_c >= 16))
-            and features * kernel_size * kernel_size <= 8 * in_c)
-
-
-def _thin_stem_eligible(x, features: int, stride: int) -> bool:
-    """Shared predicate for the PatchesConv thin-INPUT stem dispatch."""
-    return (stride == 1 and x.shape[-1] <= 8 and features >= 16
-            and x.shape[1] * x.shape[2] >= _THIN_DISPATCH_MIN_PIXELS)
+# UpsampleConvLayer(k3, upsample=2) takes the subpixel form
+# (_NearestUp2Conv) from this POST-upsample area up ("bigger than
+# 512x512": the pix2pixHD enhancer's 64->32 at 1024x512). Below it the
+# layer keeps the plain upsample -> pad -> conv chain: ExpandNetwork's
+# 256x256 site was never read on the chip in the subpixel form (PERF.md
+# section 7).
+_NEAREST_UP2_MIN_PIXELS = 300_000
 
 
 # The blocked form (BlockedConv) below this padded area was never measured
@@ -110,7 +82,7 @@ _BLOCKED_MIN_PIXELS = 65_000
 def blocked_conv_block(x, features: int, kernel_size: int,
                        stride: int) -> int:
     """The block s (pixels along W) the blocked form takes for this
-    layer, or 0 where the layer keeps its other form (x is the PADDED
+    layer, or 0 where the layer keeps the plain conv (x is the PADDED
     input).
 
     A stride-1 k>=7 conv whose thin side (min of C_in, C_out) has at most
@@ -133,7 +105,7 @@ def blocked_conv_block(x, features: int, kernel_size: int,
 
 
 #: the non-plain forms a ConvLayer / UpsampleConvLayer call site can take
-CONV_FORMS = ("blocked", "patches", "thin_head")
+CONV_FORMS = ("blocked",)
 
 
 def _count_form(form: str) -> None:
@@ -214,11 +186,24 @@ def normal_init(stddev: float = 0.02):
     return nn.initializers.normal(stddev=stddev)
 
 
-def _routed_conv(layer, x, stems: bool):
+def _routed_conv(layer, x):
     """The VALID conv of ``ConvLayer`` / ``UpsampleConvLayer`` on their
-    padded input, in the form the shapes call for; every form keeps the
-    param tree of the plain ``nn.Conv`` under the name ``Conv_0``.
-    Called inside the layer's compact ``__call__``."""
+    padded input: the blocked form where :func:`blocked_conv_block` says
+    so, else ``nn.Conv``. Both keep the param tree of the plain
+    ``nn.Conv`` under the name ``Conv_0``. Called inside the layer's
+    compact ``__call__``.
+
+    A thin layer whose width the block does not divide, and a thin stem
+    with k < 7, take the plain conv. Two hand-made forms used to catch
+    them on 300k pixels and more (im2col patches + matmul for 3-channel
+    stems, kn2row with a hand-written VJP for 3-channel heads); both lost
+    on the chip at every shape read (PERF.md section 6, PR 24, one layer
+    alone, fwd+bwd ms: HD k7 stem plain 9.41 / patches 6.43 / blocked
+    2.02; HD k7 head plain 16.44 / kn2row 18.38 / blocked 4.25; k9 head
+    at 256x256 bs32 plain 35.96 / kn2row refused by the compiler at
+    44.1 GB / blocked 9.64; k5 3->64 stem plain 4.60 / patches 7.15) and
+    went in PR 27. No preset has such a shape: every generator here
+    downsamples by 4 to 32, so its width is a multiple of the block."""
     kw = dict(use_bias=layer.use_bias, dtype=layer.dtype,
               kernel_init=layer.kernel_init)
     k, stride = layer.kernel_size, layer.stride
@@ -228,23 +213,6 @@ def _routed_conv(layer, x, stems: bool):
         _count_form("blocked")
         return BlockedConv(layer.features, kernel_size=k, block=block,
                            name="Conv_0", **kw)(x)
-    if stems and _thin_stem_eligible(x, layer.features, stride):
-        # thin-INPUT stems the blocked form does not take (k < 7, or a
-        # width its block does not divide): XLA's conv/wgrad collapse to
-        # 0.5-0.6 TF/s at these shapes — one materialized patch tensor
-        # turns fwd and wgrad into dense matmuls (PatchesConv)
-        _count_form("patches")
-        return PatchesConv(layer.features, kernel_size=k, name="Conv_0",
-                           **kw)(x)
-    if _thin_head_eligible(x, layer.features, k, stride):
-        # thin image heads the blocked form does not take. ThinHeadConv,
-        # NOT KN2RowConv: the kn2row forward is right, but its naive
-        # autodiff backward is k² sequential pad+adds (profiled
-        # 296 ms/step at k7 — the hand-written VJP through patches of dz
-        # is the fix)
-        _count_form("thin_head")
-        return ThinHeadConv(layer.features, kernel_size=k, name="Conv_0",
-                            **kw)(x)
     return save_conv_out(nn.Conv(
         features=layer.features, kernel_size=(k, k),
         strides=(stride, stride), padding="VALID", **kw)(x))
@@ -280,7 +248,7 @@ class ConvLayer(nn.Module):
                 dtype=self.dtype, kernel_init=self.kernel_init,
                 name="Conv_0", delayed=self.int8_delayed,
             )(x)
-        return _routed_conv(self, x, stems=True)
+        return _routed_conv(self, x)
 
 
 def kn2row_thin_conv(x: jax.Array, w: jax.Array, pad: int) -> jax.Array:
@@ -330,174 +298,24 @@ def kn2row_thin_conv(x: jax.Array, w: jax.Array, pad: int) -> jax.Array:
     return y.astype(x.dtype)
 
 
-def im2col_patches(x: jax.Array, k: int, stride: int = 1) -> jax.Array:
-    """VALID im2col: (N, H, W, C) → (N, (H−k)//s+1, (W−k)//s+1, k²·C),
+def im2col_patches(x: jax.Array, k: int) -> jax.Array:
+    """VALID stride-1 im2col: (N, H, W, C) → (N, H−k+1, W−k+1, k²·C),
     feature order (kh, kw, c) — i.e. an HWIO kernel flattens to the
-    matching matrix with a plain ``w.reshape(k·k·C, F)``.
+    matching matrix with a plain ``w.reshape(k·k·C, F)``. Used by the
+    int8 kn2row backward (ops/int8.py) on the thin cotangent.
 
-    Built from k² static (strided) slices + one channel concat (pure HBM
-    movement at full rate) — NOT ``lax.conv_general_dilated_patches``,
-    whose lowering is itself a thin-input conv and inherits the 3 TF/s
-    pathology this path exists to avoid (measured on the pix2pixHD
-    enhancer stem).
+    Built from k² static slices + one channel concat (pure HBM movement
+    at full rate) — NOT ``lax.conv_general_dilated_patches``, whose
+    lowering is itself a thin-input conv (3 TF/s, measured on the
+    pix2pixHD enhancer stem).
     """
     n, h, w, c = x.shape
-    ho = (h - k) // stride + 1
-    wo = (w - k) // stride + 1
+    ho, wo = h - k + 1, w - k + 1
     cols = [
-        jax.lax.slice(
-            x, (0, kh, kw, 0),
-            (n, kh + stride * (ho - 1) + 1, kw + stride * (wo - 1) + 1, c),
-            (1, stride, stride, 1))
+        jax.lax.slice(x, (0, kh, kw, 0), (n, kh + ho, kw + wo, c))
         for kh in range(k) for kw in range(k)
     ]
     return jnp.concatenate(cols, axis=-1)
-
-
-class PatchesConv(nn.Module):
-    """Conv for THIN-INPUT stems (C_in ≤ 8, e.g. the pix2pixHD enhancer's
-    RGB stem at 1024×512; optionally strided/zero-padded for the U-Net's
-    k4-s2 stem) as explicit im2col patches + one dense matmul. The
-    ConvLayer auto-dispatch (`_thin_stem_eligible`) covers only the
-    stride-1 pre-padded form; strided use is opt-in via
-    ``ModelConfig.thin_stem``.
-
-    XLA's conv kernels collapse on 3-input-channel convs at big spatial
-    extents: the pix2pixHD enhancer stem profiled 0.6 TF/s forward and
-    its weight gradient 0.5 TF/s / 4 GB/s (~11 ms/step of a 141 ms step).
-    The patch tensor is materialized once (~150 MB bf16 at 1024×512 —
-    C_in is tiny, so the k² blow-up is bounded), after which forward AND
-    weight-gradient are plain full-rate ``dot_general``s.
-
-    The INPUT cotangent transposes through the slice-concat as a k²-pad
-    accumulation — slow at big k, but for the stems this dispatch targets
-    it is dead code (the input is the image) and XLA removes it; a
-    learned input would be correct but slow (use ThinHeadConv's dz-side
-    patches instead if that ever matters).
-
-    Param tree ("kernel" HWIO + "bias") matches ``nn.Conv``; callers name
-    it ``Conv_0`` so checkpoints interchange. Input arrives pre-padded
-    (VALID), as with the other ConvLayer branches — except when
-    ``zero_pad`` is set (the U-Net's zero-padded k4-s2 stem, whose bs=1
-    wgrad profiles at 0.7 TF/s / 17 GB/s — utilization-bound, exactly
-    this dispatch's target).
-    """
-
-    features: int
-    kernel_size: int
-    stride: int = 1
-    zero_pad: int = 0
-    use_bias: bool = True
-    dtype: Optional[jnp.dtype] = None
-    kernel_init: Callable = normal_init()
-
-    @nn.compact
-    def __call__(self, x):
-        k = self.kernel_size
-        cin = x.shape[-1]
-        kernel = self.param("kernel", self.kernel_init,
-                            (k, k, cin, self.features), jnp.float32)
-        dt = self.dtype or jnp.float32
-        if self.zero_pad:
-            p = self.zero_pad
-            x = jnp.pad(x, ((0, 0), (p, p), (p, p), (0, 0)))
-        patches = im2col_patches(x.astype(dt), k, self.stride)
-        wmat = kernel.reshape(k * k * cin, self.features)
-        y = jax.lax.dot_general(
-            patches, wmat.astype(dt), (((3,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(dt)
-        if self.use_bias:
-            bias = self.param("bias", nn.initializers.zeros,
-                              (self.features,), jnp.float32)
-            y = y + bias.astype(y.dtype)
-        return save_conv_out(y)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=())
-def thin_head_conv(x: jax.Array, w: jax.Array) -> jax.Array:
-    """VALID stride-1 conv for THIN-OUTPUT heads (F ≤ 4 from a wide
-    trunk, e.g. ResNet-G's k9→3 and the pix2pixHD enhancer's k7→3 image
-    heads), with a hand-written VJP.
-
-    Forward is the kn2row tap decomposition (one full-rate matmul + k²
-    shifted slice-adds on the tiny tap tensor). The NAIVE autodiff of
-    that forward transposes the slice-adds into k² sequential full-size
-    pad+add kernels — profiled 296 ms/step (0 TF/s, 1 GB/s) on the
-    pix2pixHD head, 2/3 of the whole step — so the backward here is
-    derived by hand THROUGH PATCHES OF dz (which is the thin tensor, so
-    its k²·F-channel patch tensor stays small):
-
-      dx = patches(pad(dz, k−1)) @ flip(w)ᵀ          (one matmul)
-      dw = xpadᵀ ⋅ patches(pad(dz, k−1))             (one matmul, then
-                                                      unflip/reorder)
-
-    using that patches(pad(dz, k−1)) at position q holds
-    dz[q − (k−1) + (kh′,kw′)], i.e. every shifted dz view both
-    cotangents need. x arrives pre-padded (VALID), matching ConvLayer.
-    """
-    return kn2row_thin_conv(x, w, 0)
-
-
-def _thin_head_fwd(x, w):
-    return kn2row_thin_conv(x, w, 0), (x, w)
-
-
-def _thin_head_bwd(res, dz):
-    x, w = res
-    kh, kw_, cin, f = w.shape
-    assert kh == kw_, "square kernels only"
-    k = kh
-    dzf = dz.astype(x.dtype)
-    # patches of the (k−1)-padded dz: position q (over xpad coords) holds
-    # dz[q − (k−1) + (kh′, kw′)] at feature (kh′, kw′, f)
-    dzp = jnp.pad(dzf, ((0, 0), (k - 1, k - 1), (k - 1, k - 1), (0, 0)))
-    pz = im2col_patches(dzp, k)            # (N, Hp, Wp, k²·f)
-    # dx[q, c] = Σ_{kh,kw} dz[q − (kh,kw)] · w[kh,kw,c]
-    #          = Σ_{kh′=k−1−kh} pz[q, (kh′,kw′,f)] · w[kh,kw,c,f]
-    wd = jnp.flip(w, (0, 1)).transpose(0, 1, 3, 2).reshape(
-        k * k * f, cin)                    # [(kh′,kw′,f), c]
-    dx = jax.lax.dot_general(
-        pz, wd.astype(pz.dtype), (((3,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).astype(x.dtype)
-    # dw[kh,kw,c,f] = Σ_p xpad[p + (kh,kw), c] · dz[p, f]
-    #              = Σ_q xpad[q, c] · pz[q, (k−1−kh, k−1−kw, f)]
-    dwm = jax.lax.dot_general(
-        x, pz, (((0, 1, 2), (0, 1, 2)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                      # (c, k²·f) in (kh′,kw′,f) order
-    dw = jnp.flip(
-        dwm.reshape(cin, k, k, f), (1, 2)
-    ).transpose(1, 2, 0, 3)
-    return dx, dw.astype(w.dtype)
-
-
-thin_head_conv.defvjp(_thin_head_fwd, _thin_head_bwd)
-
-
-class ThinHeadConv(nn.Module):
-    """Stride-1 thin-OUTPUT conv module on the custom-VJP kn2row path
-    (see :func:`thin_head_conv`). Param tree matches ``nn.Conv``."""
-
-    features: int
-    kernel_size: int
-    use_bias: bool = True
-    dtype: Optional[jnp.dtype] = None
-    kernel_init: Callable = normal_init()
-
-    @nn.compact
-    def __call__(self, x):
-        k = self.kernel_size
-        kernel = self.param("kernel", self.kernel_init,
-                            (k, k, x.shape[-1], self.features), jnp.float32)
-        dt = self.dtype or jnp.float32
-        y = thin_head_conv(x.astype(dt), kernel.astype(dt))
-        if self.use_bias:
-            bias = self.param("bias", nn.initializers.zeros,
-                              (self.features,), jnp.float32)
-            y = y + bias.astype(y.dtype)
-        return save_conv_out(y)
 
 
 def blocked_conv(xp: jax.Array, w: jax.Array, s: int) -> jax.Array:
@@ -641,10 +459,14 @@ def upsample_nearest(x: jax.Array, factor: int) -> jax.Array:
 
 
 def subpixel_interleave(out: jax.Array, features: int) -> jax.Array:
-    """The shifted depth-to-space of SubpixelDeconv: maps the k2-s1 conv
-    output (N, H+1, W+1, 4F) to (N, 2H, 2W, F) via
-    ``y[2i+u, 2j+v] = out[i+u, j+v, (u,v)]``. Shared by the bf16 and
-    int8 (ops/int8.py QuantSubpixelDeconv) variants."""
+    """The shifted depth-to-space that makes a ConvTranspose(k4, s2,
+    'SAME') out of a conv(k2, s1, pad 1) producing 4F channels: maps that
+    conv's output (N, H+1, W+1, 4F) to (N, 2H, 2W, F) via
+    ``y[2i+u, 2j+v] = out[i+u, j+v, (u,v)]``. With k=4, s=2 every output
+    pixel receives contributions from exactly a 2x2 input window; the
+    weight mapping from a flax ConvTranspose kernel is
+    ``W'[dh, dw, (u,v)·F] = W[2·dh+u, 2·dw+v]`` (tests/test_ops.py
+    holds it against flax). Used by ops/int8.py QuantSubpixelDeconv."""
     n, h1, w1, c4 = out.shape
     h, w, f = h1 - 1, w1 - 1, features
     out = out.reshape(n, h1, w1, 2, 2, f)
@@ -654,106 +476,6 @@ def subpixel_interleave(out: jax.Array, features: int) -> jax.Array:
         rows.append(jnp.stack(cols, axis=3))          # (N,H,W,2,F)
     y = jnp.stack(rows, axis=2)                       # (N,H,2,W,2,F)
     return y.reshape(n, 2 * h, 2 * w, f)
-
-
-class _PallasHeadConv(nn.Module):
-    """k2-s1 pad-1 conv via the Pallas subpixel-head kernel; param tree
-    ("kernel" HWIO (2,2,C,F) + optional "bias") matches ``nn.Conv``."""
-
-    features: int
-    use_bias: bool = True
-    dtype: Optional[jnp.dtype] = None
-    kernel_init: Callable = normal_init()
-
-    @nn.compact
-    def __call__(self, x):
-        from p2p_tpu.ops.pallas.subpixel_head import subpixel_head_conv
-
-        kernel = self.param("kernel", self.kernel_init,
-                            (2, 2, x.shape[-1], self.features), jnp.float32)
-        dt = self.dtype or jnp.float32
-        import os
-
-        from p2p_tpu.ops.pallas import kernel_dispatch
-
-        # CPU: the kernel program interpreted (tests); TPU: compiled;
-        # any other backend raises inside kernel_dispatch
-        _, interpret = kernel_dispatch(force=True)
-        if not interpret and os.environ.get("P2P_HPAL_FORCE", "") != "1":
-            # The v3 kernel COMPILES and RUNS on this runtime but measures
-            # 1130 img/s vs 1708 for the XLA deconv head at 256²/bs=128
-            # (sublane-shift chains per band + lost fusions around the
-            # custom call — ops/pallas/subpixel_head.py STATUS). Gated
-            # until a future Mosaic makes it competitive; P2P_HPAL_FORCE=1
-            # (the bench's BENCH_HPAL path) re-measures.
-            raise NotImplementedError(
-                "SubpixelDeconv(pallas=True) measures SLOWER than the XLA "
-                "deconv head on this TPU runtime (1130 vs 1708 img/s); "
-                "use the default head, or set P2P_HPAL_FORCE=1 to force")
-        y = subpixel_head_conv(x.astype(dt), kernel.astype(dt), interpret)
-        if self.use_bias:
-            bias = self.param("bias", nn.initializers.zeros,
-                              (self.features,), jnp.float32)
-            y = y + bias
-        return save_conv_out(y.astype(dt))
-
-
-class SubpixelDeconv(nn.Module):
-    """ConvTranspose(k4, s2, 'SAME') re-expressed as conv(k2, s1) + shifted
-    depth-to-space — the TPU-friendly learned 2× upsample.
-
-    Mathematically the SAME operator family: with k=4, s=2 every output
-    pixel receives contributions from exactly a 2×2 input window, so
-    ``y[2i+u, 2j+v] = Σ_{dh,dw∈{0,1}} W'[dh,dw,(u,v)] · x[i+u-1+dh, j+v-1+dw]``
-    — one dense stride-1 k2 conv producing 4·F channels on the 1-padded
-    input, then a (u,v)-shifted interleave. (Exact weight mapping from a
-    flax ConvTranspose kernel: ``W'[dh, dw, (u,v)·F] = W[2·dh+u, 2·dw+v]``;
-    tested against flax ConvTranspose in tests/test_ops.py.)
-
-    Why: XLA TPU's backward for transposed convs materializes full spatial
-    ``reverse`` of activations in the weight-gradient path (~2.4 ms/step on
-    the 256² pix2pix profile) and its strided-deconv kernels run well below
-    conv peak; the k2s1 formulation has byte-identical FLOPs and a clean
-    conv backward.
-    """
-
-    features: int
-    use_bias: bool = True
-    # kn2row for the inner k2 conv (see kn2row_thin_conv). Measured
-    # SLOWER than the plain conv on v5e as the U-Net image head (1538
-    # vs 1708 img/s at 256²/bs=128 — the z-tensor round-trip loses);
-    # kept as an op-level variant for thin-output experiments, pinned
-    # equivalent to the plain path in tests/test_ops.py.
-    thin: bool = False
-    # Pallas fused path for the inner k2 conv: the 4 tap matmuls
-    # accumulate in VMEM, x is read once per sample block
-    # (ops/pallas/subpixel_head.py). Param tree unchanged (Conv_0).
-    pallas: bool = False
-    dtype: Optional[jnp.dtype] = None
-    kernel_init: Callable = normal_init()
-
-    @nn.compact
-    def __call__(self, x):
-        n, h, w, c = x.shape
-        f = self.features
-        if self.pallas:
-            out = _PallasHeadConv(
-                4 * f, use_bias=self.use_bias, dtype=self.dtype,
-                kernel_init=self.kernel_init, name="Conv_0",
-            )(x)                                # (N, H+1, W+1, 4F)
-        elif self.thin and 16 * f <= c:
-            out = KN2RowConv(
-                4 * f, kernel_size=2, padding=1, use_bias=self.use_bias,
-                dtype=self.dtype, kernel_init=self.kernel_init,
-                name="Conv_0",
-            )(x)                                # (N, H+1, W+1, 4F)
-        else:
-            out = save_conv_out(nn.Conv(
-                4 * f, kernel_size=(2, 2), strides=(1, 1),
-                padding=((1, 1), (1, 1)), use_bias=self.use_bias,
-                dtype=self.dtype, kernel_init=self.kernel_init,
-            )(x))                               # (N, H+1, W+1, 4F)
-        return subpixel_interleave(out, self.features)
 
 
 def depth_to_space_2x(out: jax.Array, features: int) -> jax.Array:
@@ -811,8 +533,7 @@ class _NearestUp2Conv(nn.Module):
         wc = jnp.einsum("ura,vcb,abio->rciuvo", m, m, kernel)
         wc = wc.reshape(3, 3, ci, 4 * co)
         # house convention for dispatch targets (cf. _SplitStemConv):
-        # dtype=None computes in f32, keeping the P2P_UP2SP A/B
-        # numerically comparable with the plain nn.Conv path
+        # dtype=None computes in f32, as the plain nn.Conv path does
         dt = self.dtype or jnp.float32
         xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)), mode="edge")
         y = jax.lax.conv_general_dilated(
@@ -842,12 +563,10 @@ class UpsampleConvLayer(nn.Module):
     @nn.compact
     def __call__(self, x):
         if (self.upsample == 2 and self.kernel_size == 3 and self.stride == 1
-                and 4 * x.shape[1] * x.shape[2] >= _THIN_DISPATCH_MIN_PIXELS
-                and os.environ.get("P2P_UP2SP", "1") == "1"):
+                and 4 * x.shape[1] * x.shape[2] >= _NEAREST_UP2_MIN_PIXELS):
             # subpixel decomposition of upsample→conv at big extents (the
             # pix2pixHD enhancer's 64→32 at 1024×512 — see _NearestUp2Conv;
-            # gated on the POST-upsample extent with the same constant as
-            # the thin dispatches; P2P_UP2SP=0 opts out for A/B measurement)
+            # gated on the POST-upsample extent)
             return _NearestUp2Conv(
                 self.features, use_bias=self.use_bias, dtype=self.dtype,
                 kernel_init=self.kernel_init, name="Conv_0",
@@ -858,4 +577,4 @@ class UpsampleConvLayer(nn.Module):
         x = reflect_pad_2d(x, pad)
         # ExpandNetwork's k9 head 32→3 lives HERE, not in ConvLayer
         # (networks.py:518-520)
-        return _routed_conv(self, x, stems=False)
+        return _routed_conv(self, x)

@@ -335,9 +335,9 @@ int8_conv_ds.defvjp(_int8_conv_ds_fwd, _int8_conv_ds_bwd)
 #                                                        surrogate keeps the
 #                                                        exact-VJP law
 #
-# The backward is the hand-derived patches-of-dz form (ops/conv.py
-# thin_head_conv — pz = im2col(pad(dz, k−1)) holds every shifted dz view
-# both cotangents need), generalized to the zero-padded stride-1 case:
+# The backward is the hand-derived patches-of-dz form
+# (pz = im2col(pad(dz, k−1)) holds every shifted dz view both cotangents
+# need), for the zero-padded stride-1 case:
 # pz spans the PADDED input coordinates, dx crops the ring, dw reads the
 # int8-padded xq (zero padding is exact in int8).
 
@@ -702,15 +702,15 @@ class QuantConv(nn.Module):
 
 
 class QuantSubpixelDeconv(nn.Module):
-    """``SubpixelDeconv`` (ops/conv.py — ConvTranspose k4 s2 re-expressed
-    as conv k2 s1 + shifted depth-to-space) with the inner conv on the
-    int8 path. The k2-s1 plain conv is the form where ALL THREE int8
+    """ConvTranspose k4 s2 re-expressed as conv k2 s1 + shifted
+    depth-to-space (ops/conv.py ``subpixel_interleave``) with the inner
+    conv on the int8 path. The k2-s1 plain conv is the form where ALL THREE int8
     contractions win on v5e (fwd 2×, dgrad 2×, wgrad dot_general 1.5×),
     unlike the lhs-dilated ConvTranspose forward where int8 loses —
     which is why the int8 U-Net decoder uses this instead of
-    ``QuantConvTranspose``. Param tree matches ``SubpixelDeconv``
-    (kernel (2,2,C,4F)); the exact weight mapping from a ConvTranspose
-    checkpoint is documented there.
+    ``QuantConvTranspose``. Param tree: ``Conv_0`` with kernel
+    (2,2,C,4F); the exact weight mapping from a ConvTranspose checkpoint
+    is documented at ``subpixel_interleave``.
     """
 
     features: int

@@ -19,7 +19,6 @@ regions pass ``axis_name='data'`` to opt in explicitly.
 
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -42,23 +41,12 @@ def dual_moments(xc):
     still READS the activation twice (534 MB moved for a 268 MB tensor —
     the round-3 BatchNorm_12 'add' kernel). A variadic ``lax.reduce`` with
     the square fused as an elementwise producer is a single pass at the
-    HLO level — but the round-4 profile shows XLA's reduce kernel STILL
-    reads each operand separately, so ``P2P_PALLAS_BN=1`` routes eligible
-    shapes through the hand-fused Pallas kernel
-    (ops/pallas/batch_moments.py) that genuinely reads x once. The VJP
-    is the same closed form XLA derives for sum/sumsq:
-    ``dxc = ds + 2·xc·dss`` (broadcast over channels).
+    HLO level. (XLA's reduce kernel still reads each operand separately;
+    a hand-fused Pallas kernel that reads x once measured 6% SLOWER on
+    the 256² step and was deleted in PR 27.) The VJP is the same closed
+    form XLA derives for sum/sumsq: ``dxc = ds + 2·xc·dss`` (broadcast
+    over channels).
     """
-    if os.environ.get("P2P_PALLAS_BN", "0") == "1":
-        from p2p_tpu.ops.pallas.batch_moments import (
-            eligible_block,
-            pallas_dual_moments,
-        )
-
-        mb = eligible_block(xc)
-        if mb:
-            return pallas_dual_moments(
-                xc.reshape(-1, xc.shape[-1]), mb)
     xf = xc.astype(jnp.float32)
     dims = tuple(range(xc.ndim - 1))
     return jax.lax.reduce(

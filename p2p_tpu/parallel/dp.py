@@ -100,7 +100,6 @@ def make_parallel_multi_train_step(
     steps_per_epoch: int = 1,
     train_dtype=None,
     state_sharding: Optional[Any] = None,
-    unroll: int = 1,
 ):
     """``build_multi_train_step`` (K steps per dispatch via lax.scan) jitted
     over ``mesh`` with explicit state/batch shardings — the scan-path twin
@@ -117,7 +116,7 @@ def make_parallel_multi_train_step(
 
     def multi_step(state, batches):
         with mesh_context(mesh):
-            return jax.lax.scan(inner, state, batches, unroll=unroll)
+            return jax.lax.scan(inner, state, batches)
 
     rep = replicated(mesh)
     stacked_bsh = NamedSharding(

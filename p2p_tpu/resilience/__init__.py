@@ -13,8 +13,8 @@ an afterthought per call site. Four pillars:
   classification and deadlines, wrapped around checkpoint I/O and image
   decode.
 - :mod:`.chaos` — config/env-driven fault injection (``P2P_CHAOS``) at
-  those same seams, so tests, CI, and ``bench.py --chaos`` exercise the
-  recovery paths on purpose.
+  those same seams, so tests and CI exercise the recovery paths on
+  purpose.
 - :mod:`.queue` — serve hardening: bounded request queue with load
   shedding, per-request deadlines, poison-input quarantine.
 - :mod:`.health` — self-healing training: divergence sentinel (EWMA +

@@ -6,8 +6,7 @@ recovery paths in :mod:`p2p_tpu.resilience` is to fire them on purpose.
 This module plants *chaos points* at the seams the retry/backoff layer
 wraps — checkpoint save/restore, image decode, serve output writes — and
 arms them from a config string or the ``P2P_CHAOS`` environment variable,
-so a test, a CI stage, or a ``bench.py --chaos`` run can make those seams
-fail on demand.
+so a test or a CI stage can make those seams fail on demand.
 
 Spec grammar (comma-separated entries)::
 

@@ -35,7 +35,7 @@ Every rung counts on the obs registry (``health_spikes_total``,
 ``health_rollbacks_total``) and logs a ``kind="health"`` record, so a
 recovered run is auditable after the fact. The ``nan`` chaos seam
 (``P2P_CHAOS=nan@50x3`` — fail steps 50..52) rehearses the whole ladder
-in tests, CI, and ``bench.py --chaos``.
+in tests and CI.
 """
 
 from __future__ import annotations
@@ -60,10 +60,8 @@ DEFAULT_WATCH = ("loss_g", "loss_d", "loss_dt", "loss_c",
 
 def poison_nan_observation(step: int,
                            metrics: Dict[str, float]) -> Dict[str, float]:
-    """Apply the ``nan`` chaos seam to one step's HOST metrics — the ONE
-    poisoning definition shared by the train loop's delayed read and
-    ``bench.py``'s sentinel row, so the rehearsal path and the measured
-    path cannot drift apart. Returns the (possibly poisoned) metrics."""
+    """Apply the ``nan`` chaos seam to one step's HOST metrics (the train
+    loop's delayed read). Returns the (possibly poisoned) metrics."""
     from p2p_tpu.resilience.chaos import FaultInjected, chaos_point
 
     try:

@@ -301,7 +301,7 @@ class InferenceEngine:
         mirrors the obs StepTimer chained methodology: the dispatch loop
         is fenced ONCE by a host fetch on the last device result, minus
         the measured RTT (obs/timing.py), then credited into a StepTimer
-        so img/s means the same thing here as in bench.py.
+        so img/s means the same thing here as in the metrics stream.
         """
         from p2p_tpu.obs import StepTimer, measure_rtt
 

@@ -967,7 +967,6 @@ def build_multi_train_step(
     vgg_params: Optional[Any] = None,
     steps_per_epoch: int = 1,
     train_dtype=None,
-    unroll: int = 1,
 ):
     """``multi_step(state, batches) -> (state, metrics)`` scanning K train
     steps in ONE dispatch.
@@ -983,7 +982,7 @@ def build_multi_train_step(
     )
 
     def multi_step(state: TrainState, batches: Dict[str, jax.Array]):
-        return jax.lax.scan(inner, state, batches, unroll=unroll)
+        return jax.lax.scan(inner, state, batches)
 
     return jax.jit(multi_step, donate_argnums=0)
 

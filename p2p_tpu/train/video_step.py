@@ -317,7 +317,6 @@ def build_multi_video_train_step(
     vgg_params: Optional[Any] = None,
     steps_per_epoch: int = 1,
     train_dtype=None,
-    unroll: int = 1,
 ):
     """K video steps per dispatch via lax.scan (the video analogue of
     ``p2p_tpu.train.step.build_multi_train_step``); ``batches`` carry a
@@ -327,7 +326,7 @@ def build_multi_video_train_step(
     )
 
     def multi_step(state: VideoTrainState, batches: Dict[str, jax.Array]):
-        return jax.lax.scan(inner, state, batches, unroll=unroll)
+        return jax.lax.scan(inner, state, batches)
 
     return jax.jit(multi_step, donate_argnums=0)
 

@@ -1,0 +1,158 @@
+"""Hash of the train step's lowered program for one benchmark cell, for a
+DESCRIBED v5e (no chip, nothing compiled, nothing run).
+
+A PR that says "the step's program is the same" shows it with this: run it
+on the parent (``git archive <parent> | tar -x -C <dir>``) and on the
+change, and compare the two lines.
+
+    python scripts/step_program_hash.py --cell reference_256.train
+    python scripts/step_program_hash.py --cell reference_256.train \
+        --root /root/scratch/parent --text /root/scratch/parent_ref.txt
+
+The configuration is built as ``benchmark/drivers/train.py`` builds it
+(the CLI's parser and ``config_from_flags`` on the cell's flags); the state
+is abstract (``jax.eval_shape``), the batch two uint8 images per example
+at the cell's extent, the mesh the cell's own over the described chips
+with the Pallas branch taken as on the chip. ``steps_per_epoch`` is the
+cell's ``dataset_pairs // batch_size``. VGG19's seeded weights are
+closed-over constants of the step: they are part of the text.
+
+The StableHLO text carries no source locations, but the Mosaic kernels
+inside it do: each ``tpu_custom_call`` holds its kernel as serialized MLIR
+whose debug locations name files and LINES of the call stack (moving
+``ops/norm.py`` by eleven lines changed all sixteen payloads of the
+pix2pixhd step and nothing else, PR 27). ``sha256`` is therefore taken
+with every payload replaced by the hash of its location-free text;
+``sha256_raw`` is the text as lowered.
+"""
+
+from __future__ import annotations
+
+import argparse
+import hashlib
+import json
+import os
+import re
+import sys
+
+_BODY = re.compile(r'(body\\22: \\22)([A-Za-z0-9+/=]+)(\\22)')
+
+
+def without_kernel_locations(text: str) -> str:
+    """``text`` with each Mosaic kernel payload (base64 MLIR bytecode in a
+    ``tpu_custom_call``'s ``backend_config``) replaced by the sha256 of
+    the kernel printed without debug locations."""
+    import base64
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    ctx = jax_mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+
+    def digest(m):
+        with ctx:
+            asm = ir.Module.parse(base64.b64decode(m.group(2))
+                                  ).operation.get_asm(enable_debug_info=False)
+        return m.group(1) + hashlib.sha256(asm.encode()).hexdigest() \
+            + m.group(3)
+
+    return _BODY.sub(digest, text)
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--cell", required=True,
+                    help="a workload of BENCHMARK.json, e.g. "
+                         "pix2pixhd_1024x512.train")
+    ap.add_argument("--root", default=os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), help="the tree to import from")
+    ap.add_argument("--text", default=None,
+                    help="also write the lowered text to this file")
+    args = ap.parse_args()
+    root = os.path.abspath(args.root)
+    sys.path.insert(0, root)
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+
+    import p2p_tpu
+    from p2p_tpu.cli import train as cli_train
+    from p2p_tpu.core.mesh import (
+        batch_sharding,
+        make_mesh,
+        parse_mesh_arg,
+        replicated,
+    )
+    from p2p_tpu.models.vgg import load_vgg19_params
+    from p2p_tpu.ops import pallas
+    from p2p_tpu.ops.pallas import instance_norm
+    from p2p_tpu.parallel.dp import make_parallel_train_step
+    from p2p_tpu.train.state import create_train_state
+
+    assert os.path.abspath(p2p_tpu.__file__).startswith(root + os.sep), (
+        p2p_tpu.__file__, root)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        workload = next(w for w in json.load(f)["workloads"]
+                        if w["name"] == args.cell)
+    with open(os.path.join(root, "benchmark", "configs",
+                           f"{workload['config']}.json")) as f:
+        cfgf = json.load(f)
+    argv = ["--preset", cfgf["preset"], "--batch_size",
+            str(cfgf["batch_size"]), "--seed", "0"]
+    for flag, value in cfgf.get("flags", {}).items():
+        argv += [f"--{flag}", str(value)]
+    cfg = cli_train.config_from_flags(
+        cli_train.build_parser().parse_args(argv))
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    spec = parse_mesh_arg(cfgf.get("flags", {}).get("mesh", "data=1"))
+    chips = int(workload["chips"])
+    mesh = make_mesh(spec, devices=topo.devices[:chips])
+    # the backend is the CPU here: take the chip's branch of the dispatcher
+    for mod in (pallas, instance_norm):
+        mod.kernel_dispatch = lambda force=False, interpret=False: (True, False)
+    # a program traced outside a mesh context asks this whether it may span
+    # devices (.claude/skills/verify/SKILL.md): answer for the topology
+    jax.device_count = lambda *a, **k: chips
+
+    bs = int(cfgf["batch_size"])
+    h, w = int(cfgf["image_height"]), int(cfgf["image_width"])
+    steps_per_epoch = max(1, int(cfgf["dataset_pairs"]) // bs)
+    dtype = jnp.bfloat16 if cfg.train.mixed_precision else None
+    image = jax.ShapeDtypeStruct((bs, h, w, 3), jnp.uint8)
+    state = jax.eval_shape(
+        lambda: create_train_state(
+            cfg, jax.random.key(0),
+            {"input": jnp.zeros(image.shape, image.dtype),
+             "target": jnp.zeros(image.shape, image.dtype)},
+            steps_per_epoch, dtype))
+    vgg = load_vgg19_params() if cfg.loss.lambda_vgg > 0 else None
+    step = make_parallel_train_step(cfg, mesh, vgg, steps_per_epoch, dtype)
+    rep, bsh = replicated(mesh), batch_sharding(mesh)
+
+    def on(tree, sharding):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=sharding), tree)
+
+    text = step.lower(on(state, rep),
+                      on({"input": image, "target": image}, bsh)).as_text()
+    plain = without_kernel_locations(text)
+    if args.text:
+        with open(args.text, "w") as f:
+            f.write(plain)
+    print(json.dumps({
+        "cell": args.cell, "root": root, "mesh": dict(mesh.shape),
+        "batch": bs, "extent": [h, w], "text_bytes": len(text),
+        "tpu_custom_calls": text.count("@tpu_custom_call"),
+        "sha256": hashlib.sha256(plain.encode()).hexdigest(),
+        "sha256_raw": hashlib.sha256(text.encode()).hexdigest()}))
+
+
+if __name__ == "__main__":
+    main()

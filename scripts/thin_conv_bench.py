@@ -1,7 +1,8 @@
 """Time the forms a thin image-side convolution can take, one layer at a
-time on the attached chip: XLA's plain conv, the two hand-made forms
-(``PatchesConv``'s im2col + matmul, ``ThinHeadConv``'s kn2row + custom
-VJP) and the blocked form on blocks of 2-16 pixels along W (``ops/conv.py``).
+time on the attached chip: XLA's plain conv and the blocked form on blocks
+of 2-16 pixels along W (``ops/conv.py``). (The im2col + matmul and kn2row
++ custom-VJP forms PR 24 read beside them lost and went in PR 27; their
+readings are in PERF.md section 6, PR 24.)
 
     chiprun -- python scripts/thin_conv_bench.py [--only ref_head,hd_stem]
 
@@ -40,7 +41,7 @@ CASES = {
 }
 
 
-def forms_for(cin, cout, k, h, w):
+def forms_for(cin, cout, w):
     import jax
 
     from p2p_tpu.ops import conv as C
@@ -50,21 +51,7 @@ def forms_for(cin, cout, k, h, w):
             xp, wt.astype(xp.dtype), (1, 1), "VALID",
             dimension_numbers=("NHWC", "HWIO", "NHWC"))
 
-    def patches(xp, wt):
-        p = C.im2col_patches(xp, k)
-        y = jax.lax.dot_general(
-            p, wt.reshape(k * k * cin, cout).astype(xp.dtype),
-            (((3,), (0,)), ((), ())), preferred_element_type="float32")
-        return y.astype(xp.dtype)
-
-    def thin_head(xp, wt):
-        return C.thin_head_conv(xp, wt.astype(xp.dtype))
-
     out = {"plain": plain}
-    if cin <= 8:        # im2col of more channels does not fit the chip
-        out["patches"] = patches
-    elif cout < cin:
-        out["thin_head"] = thin_head
     for s in (2, 4, 8, 16):
         if w % s == 0 and min(cin, cout) * s <= 96:
             out[f"blocked_s{s}"] = (
@@ -146,7 +133,7 @@ def main(argv=None) -> int:
         x = jax.random.normal(kx, (n, h, w, cin), jnp.bfloat16)
         wt = 0.02 * jax.random.normal(kw_, (k, k, cin, cout), jnp.float32)
         g = jax.random.normal(kg, (n, h, w, cout), jnp.bfloat16)
-        for form, conv in forms_for(cin, cout, k, h, w).items():
+        for form, conv in forms_for(cin, cout, w).items():
             if forms and form not in forms:
                 continue
 

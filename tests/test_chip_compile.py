@@ -113,34 +113,6 @@ def test_instance_norm_act_quant_kernel_compiles(one_chip, grad):
     _assert_kernel(_compiled_text(_sum_grad(fn) if grad else fn, x, sx))
 
 
-def test_dual_moments_compiles(one_chip):
-    """BatchNorm's one-pass sum/sumsq at a facades bs128 activation."""
-    from p2p_tpu.ops.pallas.batch_moments import (
-        _pick_m_block,
-        pallas_dual_moments,
-    )
-
-    m, c = 128 * 64 * 64, 128
-    x = jax.ShapeDtypeStruct((m, c), jnp.bfloat16, sharding=one_chip)
-    _assert_kernel(_compiled_text(
-        lambda a: pallas_dual_moments(a, _pick_m_block(m, c)), x))
-
-
-@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
-def test_subpixel_head_conv_compiles(one_chip, grad):
-    from p2p_tpu.ops.pallas.subpixel_head import subpixel_head_conv
-
-    def fn(x, w):
-        return subpixel_head_conv(x, w)
-
-    x = jax.ShapeDtypeStruct((8, 128, 128, 128), jnp.bfloat16,
-                             sharding=one_chip)
-    w = jax.ShapeDtypeStruct((2, 2, 128, 12), jnp.bfloat16,
-                             sharding=one_chip)
-    _assert_kernel(_compiled_text(
-        _sum_grad(fn, argnums=(0, 1)) if grad else fn, x, w))
-
-
 def test_sharded_instance_norm_keeps_the_shard(mesh2x2):
     """The shard_map variant on data=2 x spatial=2 (H split in two): the
     kernel runs on local shards and no all-gather of the activation

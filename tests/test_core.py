@@ -68,10 +68,9 @@ def test_rng_stream_deterministic():
 
 
 def test_facades_int8_preset_ships_delayed_scaling():
-    """The headline preset pins the round-3 measured-fastest path (BENCH
-    runs `python bench.py` with no env knobs — the default must BE the
-    headline); --no-int8_delayed is the documented escape for resuming
-    pre-round-3 checkpoints."""
+    """The preset pins the round-3 measured-fastest path with no flag set;
+    --no-int8_delayed is the documented escape for resuming pre-round-3
+    checkpoints."""
     cfg = get_preset("facades_int8")
     assert cfg.model.int8 and cfg.model.int8_delayed
     assert not cfg.model.legacy_layout  # dead-bias layout is the default

@@ -124,17 +124,22 @@ def test_spectral_conv_int8_close_and_same_power_iteration():
 
 
 def test_quant_subpixel_deconv_matches_subpixel():
-    from p2p_tpu.ops.conv import SubpixelDeconv
+    """QuantSubpixelDeconv against its float form written out: conv(k2,
+    s1, pad 1) to 4F channels + ``subpixel_interleave``, same params."""
+    from flax import linen as nn
+
+    from p2p_tpu.ops.conv import subpixel_interleave
     from p2p_tpu.ops.int8 import QuantSubpixelDeconv
 
     x = jax.random.normal(jax.random.key(0), (2, 8, 8, 16))
-    ref = SubpixelDeconv(features=12)
+    ref = nn.Conv(4 * 12, (2, 2), padding=1)
     mod = QuantSubpixelDeconv(features=12)
-    pr = ref.init(jax.random.key(1), x)
+    pr = {"params": {"Conv_0": ref.init(jax.random.key(1), x)["params"]}}
     p = mod.init(jax.random.key(1), x)
     assert jax.tree_util.tree_structure(p) == jax.tree_util.tree_structure(pr)
     y = mod.apply(pr, x)
-    yr = ref.apply(pr, x)
+    yr = subpixel_interleave(ref.apply({"params": pr["params"]["Conv_0"]}, x),
+                             12)
     assert y.shape == yr.shape == (2, 16, 16, 12)
     rel = (jnp.linalg.norm(y - yr) / jnp.linalg.norm(yr)).item()
     assert rel < 0.03, rel

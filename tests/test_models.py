@@ -369,56 +369,6 @@ def test_dead_bias_removal_forward_exact():
         np.testing.assert_array_equal(np.asarray(yn), np.asarray(yo))
 
 
-def test_unet_thin_head_swap_equivalent_under_weight_mapping():
-    """The up0 image head swap (legacy ConvTranspose k4s2 → kn2row
-    subpixel, models/unet.py) computes the SAME function under the
-    documented weight mapping W'[dh,dw,(u,v)·F] = W[2dh+u,2dw+v] and a
-    per-phase tile of the bias. Uses ngf=32 so 16·out_channels=48 ≤
-    2·ngf=64 actually triggers the swap (the production ngf=64 ratio)."""
-    import flax
-    import jax
-    import jax.numpy as jnp
-
-    from p2p_tpu.models.unet import UNetGenerator
-
-    x = jnp.asarray(
-        np.random.default_rng(2).uniform(-1, 1, (2, 64, 64, 3)), jnp.float32
-    )
-    new = UNetGenerator(ngf=32, thin_head=True)
-    old = UNetGenerator(ngf=32, legacy_layout=True)
-    vn = new.init(jax.random.PRNGKey(0), x, True)
-    vo = old.init(jax.random.PRNGKey(0), x, True)
-    fn = flax.traverse_util.flatten_dict(vn["params"])
-    fo = flax.traverse_util.flatten_dict(vo["params"])
-    assert ("up0", "Conv_0", "kernel") in fn          # swap engaged
-    assert ("up0", "kernel") in fo                    # legacy layout
-
-    mapped = {}
-    for k in fn:
-        if k[0] == "up0":
-            continue
-        mapped[k] = fo[k]                             # shared (biases dropped)
-    wt = np.asarray(fo[("up0", "kernel")])            # (4,4,cin,f)
-    cin, f = wt.shape[2], wt.shape[3]
-    w2 = np.zeros((2, 2, 4, cin, f), np.float32)
-    for dh in range(2):
-        for dw in range(2):
-            for u in range(2):
-                for v in range(2):
-                    w2[dh, dw, u * 2 + v] = wt[2 * dh + u, 2 * dw + v]
-    mapped[("up0", "Conv_0", "kernel")] = jnp.asarray(
-        np.moveaxis(w2, 2, 3).reshape(2, 2, cin, 4 * f))
-    mapped[("up0", "Conv_0", "bias")] = jnp.tile(
-        jnp.asarray(fo[("up0", "bias")]), 4)          # same bias every phase
-    params = flax.traverse_util.unflatten_dict(mapped)
-
-    yn, _ = new.apply({"params": params, "batch_stats": vn["batch_stats"]},
-                      x, True, mutable=["batch_stats"])
-    yo, _ = old.apply(vo, x, True, mutable=["batch_stats"])
-    np.testing.assert_allclose(np.asarray(yn), np.asarray(yo),
-                               rtol=1e-5, atol=1e-5)
-
-
 def test_split_stem_pair_path_equals_concat():
     """_SplitStemConv: D applied to an UNCONCATENATED (a, b) pair equals D
     on concat(a, b) — same params (Conv_0 holds the full 6-ch kernel), all
@@ -509,8 +459,9 @@ def test_discriminator_norm_d_composes_with_int8():
     (Pix2PixHDGenerator(dtype=jnp.bfloat16), (1, 512, 1024, 3)),
 ], ids=["expand_256", "pix2pixhd_1024x512"])
 def test_param_tree_is_the_same_whichever_conv_form(net, shape, monkeypatch):
-    """The thin stems and heads take the blocked / patches / thin-head
-    forms at the cells' extents and the plain conv with every gate shut:
+    """The thin stems and heads take the blocked form (and the enhancer's
+    upsample the subpixel form) at the cells' extents and the plain conv
+    with every gate shut:
     the variables are the same leaf for leaf (path, shape, dtype), so a
     checkpoint, the TP rules and the optimizer see one tree. Abstract
     evaluation only, no compute."""
@@ -527,7 +478,7 @@ def test_param_tree_is_the_same_whichever_conv_form(net, shape, monkeypatch):
     after = conv.conv_form_sites()
     assert sum(after.values()) - sum(before.values()) >= 2   # stem + head
     monkeypatch.setattr(conv, "_BLOCKED_MIN_PIXELS", 10 ** 12)
-    monkeypatch.setattr(conv, "_THIN_DISPATCH_MIN_PIXELS", 10 ** 12)
+    monkeypatch.setattr(conv, "_NEAREST_UP2_MIN_PIXELS", 10 ** 12)
     plain = tree()
     assert conv.conv_form_sites() == after      # every site took nn.Conv
     assert routed == plain

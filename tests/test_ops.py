@@ -3,6 +3,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from p2p_tpu.core.config import list_presets
 from p2p_tpu.ops import (
     angular_loss,
     pixel_shuffle,
@@ -133,33 +134,6 @@ def test_batch_norm_train_matches_torch():
     # running stats updated toward batch stats with flax momentum 0.9
     rm = updated["batch_stats"]["BatchNorm_0"]["mean"]
     np.testing.assert_allclose(rm, np.asarray(tbn.running_mean), rtol=1e-4, atol=1e-5)
-
-
-def test_pallas_dual_moments_matches_xla_path():
-    """The single-pass Pallas BN stats kernel (interpret mode on CPU)
-    matches the variadic-reduce XLA path of ops/norm.dual_moments, in
-    bf16 and f32, including non-trivial grid accumulation (M/block > 2),
-    and its block picker stays inside divisors of M."""
-    from p2p_tpu.ops.norm import dual_moments
-    from p2p_tpu.ops.pallas.batch_moments import (
-        _pick_m_block,
-        pallas_dual_moments,
-    )
-
-    for dtype in (jnp.float32, jnp.bfloat16):
-        x = jnp.asarray(rng(4, 16, 8, 24), dtype)   # M = 512 rows, C = 24
-        x2d = x.reshape(-1, x.shape[-1])
-        s1, s2 = pallas_dual_moments(x2d, block_m=128, interpret=True)
-        r1, r2 = dual_moments(x)
-        # different (both-valid) f32 accumulation orders: block-partials
-        # in the kernel vs XLA's tree reduce
-        tol = dict(rtol=1e-5, atol=1e-4)
-        np.testing.assert_allclose(np.asarray(s1), np.asarray(r1), **tol)
-        np.testing.assert_allclose(np.asarray(s2), np.asarray(r2), **tol)
-
-    for m in (512, 768, 12 * 97):
-        mb = _pick_m_block(m, 64)
-        assert m % mb == 0 and mb >= 1
 
 
 # ----------------------------------------------------------- spectral norm
@@ -343,13 +317,14 @@ def test_pallas_instance_norm_narrow_channels_wide_rows():
 
 # ------------------------------------------------------- subpixel deconv
 def test_subpixel_deconv_matches_conv_transpose():
-    """SubpixelDeconv(k2s1 + shifted depth-to-space) is the exact same
+    """conv(k2, s1, pad 1) to 4F channels + ``subpixel_interleave`` (the
+    float form of ops/int8.QuantSubpixelDeconv) is the exact same
     operator as flax ConvTranspose(k4, s2, 'SAME') under the weight mapping
-    W'[dh, dw, (u,v)·F] = W[2dh+u, 2dw+v] (ops/conv.py docstring)."""
+    W'[dh, dw, (u,v)·F] = W[2dh+u, 2dw+v] (subpixel_interleave docstring)."""
     import numpy as np
     from flax import linen as nn
 
-    from p2p_tpu.ops.conv import SubpixelDeconv
+    from p2p_tpu.ops.conv import subpixel_interleave
 
     rng = np.random.default_rng(0)
     n, h, w, cin, f = 2, 6, 5, 7, 4
@@ -367,15 +342,14 @@ def test_subpixel_deconv_matches_conv_transpose():
             for u in range(2):
                 for v in range(2):
                     w2[dh, dw, u * 2 + v] = wt[2 * dh + u, 2 * dw + v]
-    sub = SubpixelDeconv(f)
-    vs = sub.init(jax.random.key(0), x)
-    # params: Conv_0/kernel (2,2,cin,4f) with out channel order (u,v,f)
-    vs = {"params": {"Conv_0": {
+    sub = nn.Conv(4 * f, (2, 2), padding=1)
+    # kernel (2,2,cin,4f) with out channel order (u,v,f)
+    vs = {"params": {
         "kernel": jnp.asarray(
             np.moveaxis(w2, 2, 3).reshape(2, 2, cin, 4 * f)),
-        "bias": vs["params"]["Conv_0"]["bias"],
-    }}}
-    got = sub.apply(vs, x)
+        "bias": jnp.zeros((4 * f,), jnp.float32),
+    }}
+    got = subpixel_interleave(sub.apply(vs, x), f)
     assert got.shape == want.shape == (n, 2 * h, 2 * w, f)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -492,180 +466,34 @@ def test_kn2row_thin_conv_matches_conv_fwd_and_grad():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
 
 
-def test_subpixel_deconv_thin_variant_matches_plain():
-    """SubpixelDeconv(thin=True) — the kn2row inner conv — computes the
-    same function as the plain-conv path from the same params (kept as
-    an op-level variant; measured slower on v5e as the image head)."""
-    import jax
-
-    from p2p_tpu.ops.conv import SubpixelDeconv
-
-    rng = np.random.default_rng(5)
-    x = jnp.asarray(rng.normal(size=(2, 8, 8, 64)), jnp.float32)
-    plain, thin = SubpixelDeconv(3), SubpixelDeconv(3, thin=True)
-    v = plain.init(jax.random.key(0), x)
-    v2 = thin.init(jax.random.key(0), x)
-    assert jax.tree_util.tree_structure(v) == jax.tree_util.tree_structure(v2)
-    np.testing.assert_allclose(
-        np.asarray(thin.apply(v, x)), np.asarray(plain.apply(v, x)),
-        rtol=1e-5, atol=1e-5)
-
-
-def test_pallas_subpixel_head_matches_xla_fwd_and_grad():
-    """ops/pallas/subpixel_head.py (interpret mode) == the XLA k2-s1 conv
-    it replaces, forward and both gradients, and the SubpixelDeconv
-    pallas=True module path shares the plain path's param tree."""
-    import jax
-
-    if jax.devices()[0].platform == "tpu":  # conftest pins tests to CPU;
-        pytest.skip("module path is interpret-only (Mosaic gate)")
-
-    from p2p_tpu.ops.conv import SubpixelDeconv
-    from p2p_tpu.ops.pallas.subpixel_head import subpixel_head_conv
-
-    rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.normal(size=(2, 12, 10, 32)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(2, 2, 32, 12)), jnp.float32) * 0.1
-
-    def xla_ref(x, k):
-        return jax.lax.conv_general_dilated(
-            x, k, (1, 1), ((1, 1), (1, 1)),
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-
-    np.testing.assert_allclose(
-        np.asarray(subpixel_head_conv(x, k, True)),
-        np.asarray(xla_ref(x, k)), atol=1e-4)
-    f1 = lambda x, k: jnp.sum(jnp.sin(subpixel_head_conv(x, k, True)))
-    f2 = lambda x, k: jnp.sum(jnp.sin(xla_ref(x, k)))
-    for a, b in zip(jax.grad(f1, (0, 1))(x, k), jax.grad(f2, (0, 1))(x, k)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
-
-    xm = jnp.asarray(rng.normal(size=(2, 8, 8, 64)), jnp.float32)
-    plain, pls = SubpixelDeconv(3), SubpixelDeconv(3, pallas=True)
-    v = plain.init(jax.random.key(0), xm)
-    assert (jax.tree_util.tree_structure(v)
-            == jax.tree_util.tree_structure(pls.init(jax.random.key(1), xm)))
-    np.testing.assert_allclose(
-        np.asarray(pls.apply(v, xm)), np.asarray(plain.apply(v, xm)),
-        rtol=1e-5, atol=1e-5)
-
-
-def test_convlayer_thin_head_kn2row_equals_plain():
-    """ConvLayer's thin-head kn2row dispatch (stride 1, features·16 ≤ C_in
-    — e.g. the ResNet/Expand generators' k9→3 image head) matches the
-    plain VALID-conv path on the same params, fwd and grads."""
-    import jax
-
-    from p2p_tpu.ops.conv import ThinHeadConv, reflect_pad_2d
-    from flax import linen as nn
-
-    rng = np.random.default_rng(7)
-    x = jnp.asarray(rng.normal(size=(2, 12, 10, 64)), jnp.float32)
-
-    class Thin(nn.Module):
-        # the module ConvLayer dispatches to at >=300k-pixel extents
-        # (the spatial gate keeps test shapes on the plain path, so the
-        # dispatch target is exercised directly here)
-        @nn.compact
-        def __call__(self, x):
-            x = reflect_pad_2d(x, 4)
-            return ThinHeadConv(3, kernel_size=9, name="Conv_0")(x)
-
-    thin = Thin()
-    v = thin.init(jax.random.key(0), x)
-
-    class Plain(nn.Module):
-        @nn.compact
-        def __call__(self, x):
-            x = reflect_pad_2d(x, 4)
-            return nn.Conv(3, kernel_size=(9, 9), padding="VALID",
-                           name="Conv_0")(x)
-
-    np.testing.assert_allclose(
-        np.asarray(thin.apply(v, x)), np.asarray(Plain().apply(v, x)),
-        rtol=2e-5, atol=2e-5)
-
-    g1 = jax.grad(lambda xx: jnp.sum(jnp.sin(thin.apply(v, xx))))(x)
-    g2 = jax.grad(lambda xx: jnp.sum(jnp.sin(Plain().apply(v, xx))))(x)
-    np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
-                               rtol=2e-4, atol=2e-4)
-
-    # the hand-written VJP's dw (flip + reorder through patches of dz)
-    # must match the autodiff conv weight-grad exactly
-    gw1 = jax.grad(lambda vv: jnp.sum(jnp.sin(thin.apply(vv, x))))(v)
-    gw2 = jax.grad(lambda vv: jnp.sum(jnp.sin(Plain().apply(vv, x))))(v)
-    for a, b in zip(jax.tree_util.tree_leaves(gw1),
-                    jax.tree_util.tree_leaves(gw2)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-4)
-
-
-def test_convlayer_thin_input_patches_equals_plain():
-    """ConvLayer's thin-INPUT stem dispatch (stride 1, C_in ≤ 8,
-    features ≥ 16 — e.g. the pix2pixHD enhancer's RGB k7 stem) matches the
-    plain VALID-conv path on the same params, fwd and weight-grad."""
-    import jax
-
-    from flax import linen as nn
-
-    from p2p_tpu.ops.conv import PatchesConv, reflect_pad_2d
-
-    rng = np.random.default_rng(8)
-    x = jnp.asarray(rng.normal(size=(2, 14, 12, 3)), jnp.float32)
-
-    class Stem(nn.Module):
-        # the module ConvLayer dispatches to at >=300k-pixel extents
-        @nn.compact
-        def __call__(self, x):
-            x = reflect_pad_2d(x, 3)
-            return PatchesConv(16, kernel_size=7, name="Conv_0")(x)
-
-    stem = Stem()
-    v = stem.init(jax.random.key(0), x)
-
-    class Plain(nn.Module):
-        @nn.compact
-        def __call__(self, x):
-            x = reflect_pad_2d(x, 3)
-            return nn.Conv(16, kernel_size=(7, 7), padding="VALID",
-                           name="Conv_0")(x)
-
-    np.testing.assert_allclose(
-        np.asarray(stem.apply(v, x)), np.asarray(Plain().apply(v, x)),
-        rtol=2e-5, atol=2e-5)
-
-    g1 = jax.grad(lambda vv: jnp.sum(jnp.sin(stem.apply(vv, x))))(v)
-    g2 = jax.grad(lambda vv: jnp.sum(jnp.sin(Plain().apply(vv, x))))(v)
-    for a, b in zip(jax.tree_util.tree_leaves(g1),
-                    jax.tree_util.tree_leaves(g2)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-4)
-
-
-def test_nearest_up2_conv_matches_upsample_conv(monkeypatch):
+def test_nearest_up2_conv_matches_upsample_conv():
     """The subpixel decomposition of UpsampleConvLayer (×2 nearest →
     reflect-pad → 3×3 conv ≡ one low-res 3×3 conv ci→4co + depth-to-space,
-    edge-padded) is exact: fwd + dx + dw match the plain path with the
-    SAME params, boundary rows included."""
+    edge-padded) is exact: fwd + dx + dw match the plain chain, written
+    out here, with the SAME params, boundary rows included."""
     import jax
+    from flax import linen as nn
 
     from p2p_tpu.ops.conv import UpsampleConvLayer
 
-    # post-upsample extent 600·512 = 307k > the dispatch gate
+    # post-upsample extent 600·512 = 307k > the extent gate
     x = jnp.asarray(rng(1, 300, 256, 8), jnp.float32)
     layer = UpsampleConvLayer(6, kernel_size=3, upsample=2)
-
-    monkeypatch.setenv("P2P_UP2SP", "0")
     params = layer.init(jax.random.key(0), x)
-    ref, ref_vjp = jax.vjp(lambda p, xx: layer.apply(p, xx), params, x)
+    conv = nn.Conv(6, (3, 3), padding="VALID")
 
-    monkeypatch.setenv("P2P_UP2SP", "1")
+    def plain(p, xx):
+        up = reflect_pad_2d(upsample_nearest(xx, 2), 1)
+        return conv.apply({"params": p["params"]["Conv_0"]}, up)
+
+    ref, ref_vjp = jax.vjp(plain, params, x)
     got, got_vjp = jax.vjp(lambda p, xx: layer.apply(p, xx), params, x)
-    # routing really changed: the subpixel path pads the LOW-RES input
+    # the layer really took the subpixel path: it pads the LOW-RES input
     # (300→302 rows) and never materializes a padded upsampled tensor
-    # (600→602 rows, the plain path's reflect pad)
+    # (600→602 rows, the plain chain's reflect pad)
     jaxpr = str(jax.make_jaxpr(lambda p, xx: layer.apply(p, xx))(params, x))
     assert "302" in jaxpr and "602" not in jaxpr
+    assert "602" in str(jax.make_jaxpr(plain)(params, x))
 
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -733,36 +561,43 @@ def test_blocked_conv_equals_conv(case, dtype, tol):
         assert np.abs(a - b).max() <= tol * np.abs(b).max()
 
 
-def _traced_convs(layer, shape):
-    """(kernel shape, under the ``blocked_conv`` scope) of every
-    ``conv_general_dilated`` a layer traces to on an input of ``shape``,
-    and the ``conv_form_sites_total`` ticks the trace made. Abstract
-    evaluation only, no compute."""
-    from p2p_tpu.ops.conv import conv_form_sites
+def _walk_convs(jaxpr):
+    """(kernel shape, under the ``blocked_conv`` scope, shape of the
+    conv's input, window strides) of every ``conv_general_dilated`` in
+    ``jaxpr``, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "conv_general_dilated":
+            yield (eqn.invars[1].aval.shape,
+                   "blocked_conv" in str(eqn.source_info.name_stack),
+                   eqn.invars[0].aval.shape,
+                   tuple(eqn.params["window_strides"]))
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (list, tuple))
+                        else [param]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _walk_convs(inner)
 
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "conv_general_dilated":
-                yield (eqn.invars[1].aval.shape,
-                       "blocked_conv" in str(eqn.source_info.name_stack))
-            for param in eqn.params.values():
-                for sub in (param if isinstance(param, (list, tuple))
-                            else [param]):
-                    inner = getattr(sub, "jaxpr", sub)
-                    if hasattr(inner, "eqns"):
-                        yield from walk(inner)
+
+def _traced_convs(layer, shape):
+    """(kernel shape, under the ``blocked_conv`` scope, rows of the conv's
+    input) of every ``conv_general_dilated`` a layer traces to on an input
+    of ``shape``, and the ``conv_form_sites_total`` ticks the trace made.
+    Abstract evaluation only, no compute."""
+    from p2p_tpu.ops.conv import conv_form_sites
 
     x = jax.ShapeDtypeStruct(shape, jnp.float32)
     variables = jax.eval_shape(layer.init, jax.random.key(0), x)
     before = conv_form_sites()
-    convs = list(walk(jax.make_jaxpr(layer.apply)(variables, x).jaxpr))
+    convs = [(kernel, blocked, lhs[1]) for kernel, blocked, lhs, _ in
+             _walk_convs(jax.make_jaxpr(layer.apply)(variables, x).jaxpr)]
     after = conv_form_sites()
     return convs, {f: after[f] - before[f] for f in after if
                    after[f] != before[f]}
 
 
-# layer, input shape -> (form counted, the one conv's kernel or None where
-# the form is matmuls). The shapes of the two benchmark cells, routed as
+# layer, input shape -> (form counted, the one conv's kernel[, the rows of
+# that conv's input]). The shapes of the three benchmark cells, routed as
 # they read on the chip (PERF.md section 6, PR 24), and toy / odd shapes.
 ROUTING_CASES = {
     # reference_256.train: ExpandNetwork's stem and head, C's k5 stem
@@ -779,11 +614,23 @@ ROUTING_CASES = {
                          "blocked", (7, 2, 256, 24)),
     "g1_stem_k7_3to64": (ConvLayer(64, kernel_size=7), (2, 256, 512, 3),
                          "blocked", (7, 2, 24, 512)),
-    # a width no block of 8 divides keeps the hand-made forms
+    # pix2pixhd_2048x1024.train_spatial4: the enhancer's stem and head on
+    # one image of the paper's extent
+    "hd2048_stem_k7_3to32": (ConvLayer(32, kernel_size=7),
+                             (1, 1024, 2048, 3), "blocked", (7, 2, 24, 256)),
+    "hd2048_head_k7_32to3": (ConvLayer(3, kernel_size=7),
+                             (1, 1024, 2048, 32), "blocked",
+                             (7, 2, 256, 24)),
+    # a width no block of 8 divides: the plain conv (the hand-made kn2row
+    # and im2col forms that caught these lost on the chip and went, PR 27)
     "odd_head_k7_64to3": (ConvLayer(3, kernel_size=7), (1, 600, 516, 64),
-                          "thin_head", None),
+                          None, (7, 7, 64, 3)),
     "odd_stem_k7_3to32": (ConvLayer(32, kernel_size=7), (1, 600, 516, 3),
-                          "patches", None),
+                          None, (7, 7, 3, 32)),
+    # k < 7 on a large extent: plain too (was im2col patches; 4.60 ms
+    # plain against 7.15 on the chip, PR 24)
+    "big_stem_k5_3to64": (ConvLayer(64, kernel_size=5), (1, 600, 512, 3),
+                          None, (5, 5, 3, 64)),
     # below the smallest extent measured: the plain conv
     "toy_head_k7_64to3": (ConvLayer(3, kernel_size=7), (1, 64, 64, 64),
                           None, (7, 7, 64, 3)),
@@ -794,6 +641,15 @@ ROUTING_CASES = {
     # a wide trunk conv never leaves the plain path
     "trunk_k3_128to128": (ConvLayer(128, kernel_size=3), (2, 512, 512, 128),
                           None, (3, 3, 128, 128)),
+    # UpsampleConvLayer(k3, upsample=2) either side of its extent gate
+    # (4*272*272 = 295,936 < 300,000 <= 300,288 = 4*276*272): below, the
+    # conv reads the reflect-padded UPSAMPLED tensor (2*272+2 rows); from
+    # the gate up, one conv to 4*C_out channels reads the edge-padded
+    # LOW-RES input (276+2 rows) -- _NearestUp2Conv
+    "up2_k3_under_gate": (UpsampleConvLayer(6, kernel_size=3, upsample=2),
+                          (1, 272, 272, 8), None, (3, 3, 8, 6), 546),
+    "up2_k3_over_gate": (UpsampleConvLayer(6, kernel_size=3, upsample=2),
+                         (1, 276, 272, 8), None, (3, 3, 8, 24), 278),
 }
 
 
@@ -803,55 +659,83 @@ def test_thin_conv_dispatch_routing(case):
     the shapes they see: the blocked form is a ``conv_general_dilated``
     too, so the forms are told apart by the ``blocked_conv`` scope, the
     kernel's shape (s times the channels, k' taps along W) and the
-    ``conv_form_sites_total`` counter, which ticks once a traced site."""
-    layer, shape, form, kernel = ROUTING_CASES[case]
+    ``conv_form_sites_total`` counter, which ticks once a traced site;
+    the subpixel form of an upsample by the rows its conv reads."""
+    layer, shape, form, kernel, *rows = ROUTING_CASES[case]
     convs, ticks = _traced_convs(layer, shape)
     assert ticks == ({form: 1} if form else {})
-    if kernel is None:          # patches / kn2row: matmuls, no conv
-        assert convs == []
-    else:
-        assert convs == [(kernel, form == "blocked")]
+    assert [c[:2] for c in convs] == [(kernel, form == "blocked")]
+    if rows:
+        assert [c[2] for c in convs] == rows
 
 
-def test_patches_conv_strided_stem_equals_conv():
-    """Strided PatchesConv (stride=2, zero_pad=1 — the U-Net down0 form
-    behind ModelConfig.thin_stem) == nn.Conv k4 s2 pad1, forward and both
-    param grads, same param tree."""
-    from flax import linen as nn
+@pytest.mark.parametrize("preset", list_presets())
+def test_every_preset_sends_its_thin_convs_to_the_blocked_form(preset):
+    """G (and C where the preset has one), traced abstractly at the
+    preset's own extent: every stride-1 k >= 7 conv with a thin side of
+    <= 16 channels on >= 65k padded pixels runs in the blocked form. A
+    width the block does not divide would send such a layer to the plain
+    conv, which since PR 27 is the only way to lose PR 24's gain
+    silently."""
+    from p2p_tpu.core.config import get_preset
+    from p2p_tpu.ops.conv import _BLOCKED_MIN_PIXELS, conv_form_sites
+    from p2p_tpu.train.state import build_models
 
-    from p2p_tpu.ops.conv import PatchesConv, normal_init
+    cfg = get_preset(preset)
+    g, _, c = build_models(cfg, jnp.bfloat16)
+    h = cfg.data.image_size
+    x = jax.ShapeDtypeStruct(
+        (1, h, cfg.data.image_width or h, cfg.model.input_nc), jnp.bfloat16)
+    before = conv_form_sites()["blocked"]
+    convs = []
+    for net in (g, c):
+        if net is not None:
+            convs += _walk_convs(jax.make_jaxpr(
+                lambda x, net=net: net.init(jax.random.key(0), x, False)
+            )(x).jaxpr)
+    blocked = [c for c in convs if c[1]]
+    assert conv_form_sites()["blocked"] - before == len(blocked)
+    lost = [
+        (kernel, lhs) for kernel, in_blocked, lhs, strides in convs
+        if not in_blocked and strides == (1, 1) and kernel[0] >= 7
+        and min(kernel[2:]) <= 16 and lhs[1] * lhs[2] >= _BLOCKED_MIN_PIXELS]
+    assert lost == []
+    # the presets with such layers (U-Nets have none)
+    want = {"reference": 2, "pix2pixhd": 3, "cityscapes_spatial": 2}
+    assert len(blocked) == want.get(preset, 0)
 
-    rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.normal(size=(2, 32, 32, 3)), jnp.float32)
-    ref = nn.Conv(16, kernel_size=(4, 4), strides=(2, 2), padding=1,
-                  use_bias=True, kernel_init=normal_init())
-    pc = PatchesConv(16, kernel_size=4, stride=2, zero_pad=1, use_bias=True,
-                     kernel_init=normal_init())
-    v = ref.init(jax.random.key(0), x)
-    yr, yp = ref.apply(v, x), pc.apply(v, x)
-    assert yp.shape == yr.shape
-    np.testing.assert_allclose(np.asarray(yp), np.asarray(yr),
-                               rtol=1e-5, atol=1e-5)
 
-    gr = jax.grad(lambda p: jnp.sum(jnp.square(ref.apply(p, x))))(v)
-    gp = jax.grad(lambda p: jnp.sum(jnp.square(pc.apply(p, x))))(v)
-    for a, b in zip(jax.tree.leaves(gr), jax.tree.leaves(gp)):
-        scale = max(float(np.abs(np.asarray(a)).max()), 1.0)
-        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
-                                   rtol=2e-5, atol=2e-5 * scale)
+def test_no_layer_reads_the_environment_to_pick_an_implementation():
+    """Which form a conv, an upsample or a norm's moments take follows
+    from the shapes the layer sees and nothing else (PR 27): no source of
+    ops/conv.py, ops/norm.py, ops/pallas/ or models/ reads the
+    environment, but for two reads that choose no implementation."""
+    import glob
+    import os
+    import re
 
+    import p2p_tpu
 
-def test_unet_thin_stem_matches_default():
-    """thin_stem U-Net == default U-Net on the same params (the dispatch
-    only reroutes down0's compute; param tree unchanged)."""
-    from p2p_tpu.models.unet import UNetGenerator
-
-    rng = np.random.default_rng(1)
-    x = jnp.asarray(rng.uniform(-1, 1, (2, 32, 32, 3)), jnp.float32)
-    base = UNetGenerator(ngf=8)
-    thin = UNetGenerator(ngf=8, thin_stem=True)
-    v = base.init(jax.random.key(1), x, False)
-    yb = base.apply(v, x, False)
-    yt = thin.apply(v, x, False)
-    np.testing.assert_allclose(np.asarray(yt), np.asarray(yb),
-                               rtol=1e-5, atol=1e-5)
+    allowed = {
+        # interpret-mode switch for CPU runs of the kernels (cli/lint)
+        os.path.join("ops", "pallas", "__init__.py"): "P2P_TPU_FORCE_PALLAS",
+        # a path to the pretrained weights
+        os.path.join("models", "vgg.py"): "P2P_TPU_VGG19_NPZ",
+    }
+    pkg = os.path.dirname(p2p_tpu.__file__)
+    files = ([os.path.join(pkg, "ops", "conv.py"),
+              os.path.join(pkg, "ops", "norm.py")]
+             + sorted(glob.glob(os.path.join(pkg, "ops", "pallas", "*.py")))
+             + sorted(glob.glob(os.path.join(pkg, "models", "*.py"))))
+    assert len(files) > 10
+    reads = {}
+    for path in files:
+        with open(path) as f:
+            src = f.read()
+        for m in re.finditer(r"os\.(?:environ|getenv)\b.*", src):
+            reads.setdefault(os.path.relpath(path, pkg), []).append(
+                m.group(0))
+    for rel, lines in reads.items():
+        assert rel in allowed, (rel, lines)
+        assert all(allowed[rel] in line for line in lines), (rel, lines)
+    assert set(reads) == set(allowed)
