@@ -64,13 +64,38 @@ def save_conv_out(y: jax.Array) -> jax.Array:
     return checkpoint_name(y, "conv_out")
 
 
-# UpsampleConvLayer(k3, upsample=2) takes the subpixel form
-# (_NearestUp2Conv) from this POST-upsample area up ("bigger than
-# 512x512": the pix2pixHD enhancer's 64->32 at 1024x512). Below it the
-# layer keeps the plain upsample -> pad -> conv chain: ExpandNetwork's
-# 256x256 site was never read on the chip in the subpixel form (PERF.md
-# section 7).
-_NEAREST_UP2_MIN_PIXELS = 300_000
+# The smallest batch of post-upsample pixels (N*4*H*W) an
+# UpsampleConvLayer(k3, upsample=2) site was read at on the chip: one image
+# of ExpandNetwork's first upsample, [1,64,64,128] -> 64
+# (nearest_up2_engages). Below it the layer keeps the plain chain: what was
+# not measured is not rerouted (toy extents; no preset holds less).
+_NEAREST_UP2_MIN_PIXELS = 16_384
+
+
+def nearest_up2_engages(x, features: int) -> bool:
+    """Whether an UpsampleConvLayer(k3, stride 1, upsample=2) site with
+    the LOW-RES input ``x`` (N,H,W,C) takes the subpixel form
+    (:class:`_NearestUp2Conv`): below 128 output channels.
+
+    There the plain conv writes a part of the 128 lanes onto the
+    4x-materialised upsampled tensor, and the four phases of the subpixel
+    form fill them. Set from readings on one v5e, one layer alone, forward
+    + both gradients in bf16, plain -> subpixel milliseconds (PERF.md
+    section 6, PR 28): ExpandNetwork's [32,128,128,64] -> 32 21.25 ->
+    11.16 and [32,64,64,128] -> 64 5.19 -> 4.04; the pix2pixhd enhancer's
+    [2,256,512,64] -> 32 21.17 -> 6.87 and G1's last [2,128,256,128] -> 64
+    4.84 -> 1.81; and in their steps' traces. One image of ExpandNetwork,
+    the smallest read: [1,128,128,64] -> 32 1.24 -> 0.52 and [1,64,64,128]
+    -> 64 0.51 -> 0.50 (the host's launch floor; device busy 0.33 ->
+    0.18): the form loses at no extent read, so training, one-image
+    inference and serving of a preset take one form (a gate on ONE
+    image's 300k pixels, a pre-round bs1 reading, kept ExpandNetwork's
+    sites out). At 128 channels ([2,64,128,256] -> 128) the form read 2.05
+    -> 1.32 alone, but was read in no step, and on a ``spatial`` > 1 mesh
+    every engaged site adds a whole-shard collective-permute in the
+    backward of its edge pad (PERF.md section 4 (3)): it stays plain."""
+    n, h, w, _ = x.shape
+    return features < 128 and n * 4 * h * w >= _NEAREST_UP2_MIN_PIXELS
 
 
 # The blocked form (BlockedConv) below this padded area was never measured
@@ -105,7 +130,7 @@ def blocked_conv_block(x, features: int, kernel_size: int,
 
 
 #: the non-plain forms a ConvLayer / UpsampleConvLayer call site can take
-CONV_FORMS = ("blocked",)
+CONV_FORMS = ("blocked", "nearest_up2")
 
 
 def _count_form(form: str) -> None:
@@ -487,6 +512,37 @@ def depth_to_space_2x(out: jax.Array, features: int) -> jax.Array:
     return out.reshape(n, 2 * h, 2 * w, features)
 
 
+def nearest_up2_kernel(w: jax.Array) -> jax.Array:
+    """The (3,3,ci,co) kernel of a (nearest x2 -> ReflectionPad(1) -> k3
+    conv) chain folded onto the LOW-RES grid: (3,3,ci,4*co) float32 with
+    the output channels in the order (u, v, o), phase (u,v) of the x2
+    output at channel block u*2+v. ``Wc[r,c,i,(u,v,o)] = sum_{a,b}
+    M[u,r,a] M[v,c,b] W[a,b,i,o]`` with the constant 0/1 folding matrix
+    ``M[u, o+1, a+1] = 1 where floor((u+a)/2) == o`` (folded into the
+    weights at trace time; autodiff carries the gradient back to ``w``)."""
+    m = np.zeros((2, 3, 3), np.float32)
+    for u in (0, 1):
+        for ia, a in enumerate((-1, 0, 1)):
+            m[u, (u + a) // 2 + 1, ia] = 1.0
+    m = jnp.asarray(m)
+    wc = jnp.einsum("ura,vcb,abio->rciuvo", m, m, w.astype(jnp.float32))
+    return wc.reshape(3, 3, w.shape[2], 4 * w.shape[3])
+
+
+def nearest_up2_conv(x: jax.Array, w: jax.Array, dtype) -> jax.Array:
+    """(nearest x2 -> ReflectionPad(1) -> k3 conv) of ``x`` (N,H,W,ci) with
+    the HWIO kernel ``w`` (3,3,ci,co), no bias, in the subpixel form: one k3
+    conv ``ci -> 4*co`` of the edge-padded LOW-RES input with the folded
+    kernel, computed in ``dtype``, then :func:`depth_to_space_2x`."""
+    wc = nearest_up2_kernel(w)
+    xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)), mode="edge")
+    y = jax.lax.conv_general_dilated(
+        xp.astype(dtype), wc.astype(dtype), window_strides=(1, 1),
+        padding="VALID", dimension_numbers=("NHWC", "HWIO", "NHWC"),
+    )
+    return depth_to_space_2x(save_conv_out(y), w.shape[-1])
+
+
 class _NearestUp2Conv(nn.Module):
     """EXACT subpixel decomposition of UpsampleConvLayer's
     (nearest ×2 upsample → ReflectionPad(1) → 3×3 conv) chain.
@@ -522,29 +578,12 @@ class _NearestUp2Conv(nn.Module):
                             jnp.float32)
         bias = (self.param("bias", nn.initializers.zeros, (co,), jnp.float32)
                 if self.use_bias else None)
-        # M[u, o+1, a+1] = 1 where floor((u+a)/2) == o — the tap→offset
-        # folding matrix (constant, folded into the weights at trace time)
-        m = np.zeros((2, 3, 3), np.float32)
-        for u in (0, 1):
-            for ia, a in enumerate((-1, 0, 1)):
-                m[u, (u + a) // 2 + 1, ia] = 1.0
-        m = jnp.asarray(m)
-        # Wc[r,c,i,(u,v,o)] = Σ_{a,b} M[u,r,a]·M[v,c,b]·W[a,b,i,o]
-        wc = jnp.einsum("ura,vcb,abio->rciuvo", m, m, kernel)
-        wc = wc.reshape(3, 3, ci, 4 * co)
         # house convention for dispatch targets (cf. _SplitStemConv):
         # dtype=None computes in f32, as the plain nn.Conv path does
-        dt = self.dtype or jnp.float32
-        xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)), mode="edge")
-        y = jax.lax.conv_general_dilated(
-            xp.astype(dt), wc.astype(dt), window_strides=(1, 1),
-            padding="VALID",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        )
-        y = save_conv_out(y)
-        y = depth_to_space_2x(y, co)
-        if bias is not None:
-            y = y + bias.astype(y.dtype)
+        with jax.named_scope("nearest_up2"):
+            y = nearest_up2_conv(x, kernel, self.dtype or jnp.float32)
+            if bias is not None:
+                y = y + bias.astype(y.dtype)
         return y
 
 
@@ -563,10 +602,11 @@ class UpsampleConvLayer(nn.Module):
     @nn.compact
     def __call__(self, x):
         if (self.upsample == 2 and self.kernel_size == 3 and self.stride == 1
-                and 4 * x.shape[1] * x.shape[2] >= _NEAREST_UP2_MIN_PIXELS):
-            # subpixel decomposition of upsample→conv at big extents (the
-            # pix2pixHD enhancer's 64→32 at 1024×512 — see _NearestUp2Conv;
-            # gated on the POST-upsample extent)
+                and nearest_up2_engages(x, self.features)):
+            # subpixel decomposition of upsample→conv (ExpandNetwork's two
+            # upsamples, the pix2pixHD enhancer's and G1's last — see
+            # _NearestUp2Conv)
+            _count_form("nearest_up2")
             return _NearestUp2Conv(
                 self.features, use_bias=self.use_bias, dtype=self.dtype,
                 kernel_init=self.kernel_init, name="Conv_0",

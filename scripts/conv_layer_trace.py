@@ -12,8 +12,9 @@ so for every layer whose path matches ``--layers`` this prints, after
 (net scope, layer, forward | backward) the milliseconds a step and the
 costliest ops with their result shapes (an input gradient and a weight
 gradient are both "backward"; their shapes tell them apart), and
-``blocked_conv_ms``, the device milliseconds a step under the
-``blocked_conv`` scope of ``ops/conv.py`` (0 on a program without it).
+``blocked_conv_ms`` and ``nearest_up2_ms``, the device milliseconds a
+step under the ``blocked_conv`` and ``nearest_up2`` scopes of
+``ops/conv.py`` (0 on a program without the scope).
 A fusion is counted where its root instruction's ``op_name`` points, so a
 norm's backward fused into a convolution's gradient counts as that layer.
 """
@@ -27,12 +28,15 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+#: the named scopes of ``ops/conv.py``'s non-plain forms
+FORM_SCOPES = ("blocked_conv", "nearest_up2")
+
 
 def layer_keys(hlo_text, scopes, layers):
-    """Instruction name -> (net scope, layer path, "fwd" | "bwd", under
-    ``blocked_conv``) for the instructions whose ``op_name`` holds a
-    component matching ``layers``; the layer path runs from the component
-    after the scope to the matching one."""
+    """Instruction name -> (net scope, layer path, "fwd" | "bwd", the
+    form scope it is under or None) for the instructions whose ``op_name``
+    holds a component matching ``layers``; the layer path runs from the
+    component after the scope to the matching one."""
     # the join's own two patterns: one text, one way to read it
     from benchmark.scope_time import _INSTRUCTION, _OP_NAME, first_scope
 
@@ -52,7 +56,7 @@ def layer_keys(hlo_text, scopes, layers):
         start = words.index(net) + 1 if net in words else 1
         out[m.group(1)] = (net or "unscoped", "/".join(words[start:hit + 1]),
                            "bwd" if "transpose(" in op.group(1) else "fwd",
-                           "blocked_conv" in words)
+                           next((f for f in FORM_SCOPES if f in words), None))
     return out
 
 
@@ -65,7 +69,8 @@ def by_layer(xplane_path, hlo_text, layers, top=4):
 
     keys = layer_keys(hlo_text, scope_time.program_scopes(), layers)
     module = scope_time.module_name(hlo_text)
-    total, ops_s, blocked, executions, chips = {}, {}, 0.0, 0, 0
+    total, ops_s, executions, chips = {}, {}, 0, 0
+    form_ns = dict.fromkeys(FORM_SCOPES, 0.0)
     for plane in ProfileData.from_file(xplane_path).planes:
         if not trace_reduce.DEVICE_PLANE.match(plane.name):
             continue
@@ -90,20 +95,21 @@ def by_layer(xplane_path, hlo_text, layers, top=4):
             name, _, opcode = trace_reduce.parse_op(ev.name)
             if opcode in trace_reduce.CONTAINER_OPCODES or name not in keys:
                 continue
-            net, layer, direction, in_blocked = keys[name]
+            net, layer, direction, form = keys[name]
             key = f"{net}|{layer}|{direction}"
             total[key] = total.get(key, 0.0) + ev.duration_ns
             per_op = ops_s.setdefault(key, {})
             label = trace_reduce.op_label(ev.name)
             per_op[label] = per_op.get(label, 0.0) + ev.duration_ns
-            if in_blocked:
-                blocked += ev.duration_ns
+            if form:
+                form_ns[form] += ev.duration_ns
     if not executions:
         raise ValueError(f"{xplane_path}: no execution of {module}")
     per_step = 1e-6 / executions    # ns over all chips -> ms a step a chip
     return {
         "steps": executions // chips,
-        "blocked_conv_ms": blocked * per_step,
+        "blocked_conv_ms": form_ns["blocked_conv"] * per_step,
+        "nearest_up2_ms": form_ns["nearest_up2"] * per_step,
         "layer_ms": {
             key: {"ms": ns * per_step,
                   "ops": [[label, v * per_step] for label, v in sorted(

@@ -360,6 +360,13 @@ def test_trainer_obs_wiring(tmp_path, monkeypatch):
         jax.eval_shape(
             lambda p, a: vgg_loss(p, a, a), jax.eval_shape(load_vgg19_params),
             jax.ShapeDtypeStruct((1, 16, 16, 3), jnp.bfloat16))
+        # ... and a x2-upsample k3 convolution in the subpixel form
+        from p2p_tpu.ops.conv import UpsampleConvLayer
+
+        jax.eval_shape(
+            lambda a: UpsampleConvLayer(4, kernel_size=3, upsample=2).init(
+                jax.random.key(0), a),
+            jax.ShapeDtypeStruct((1, 256, 256, 4), jnp.float32))
         tr.fit()
 
         manifest = json.load(open(tmp_path / "manifest_obswire.json"))
@@ -374,7 +381,11 @@ def test_trainer_obs_wiring(tmp_path, monkeypatch):
         assert "train" in kinds and "epoch" in kinds
         epoch = next(r for r in recs if r["kind"] == "epoch")
         assert epoch["epoch"] == 1 and math.isfinite(epoch["loss_g"])
-        # ... and the run's stream says which dtype VGG19 stored
+        # ... and the run's stream says which forms its convolutions took
+        forms = [r for r in recs if r["kind"] == "conv_forms"][-1]
+        assert forms["conv_form_sites_total.nearest_up2"] >= 1
+        assert set(forms) >= {"conv_form_sites_total.blocked"}
+        # ... and which dtype VGG19 stored
         (vgg,) = [r for r in recs if r["kind"] == "vgg_loss"]
         assert vgg["vgg_loss_traces_total.bfloat16"] >= 1
         assert set(vgg) >= {"vgg_loss_traces_total.float32"}
@@ -603,13 +614,21 @@ def test_conv_layer_trace_reads_device_time_by_layer_and_direction():
         text = f.read()
 
     keys = tool.layer_keys(text, ("G",), "net_a|net_b")
-    assert keys["fusion.3"] == ("unscoped", "net_a", "fwd", False)
-    assert keys["copy.9"] == ("unscoped", "net_b", "fwd", False)
+    assert keys["fusion.3"] == ("unscoped", "net_a", "fwd", None)
+    assert keys["copy.9"] == ("unscoped", "net_b", "fwd", None)
+    # an instruction under a form's scope of ops/conv.py is marked with it
+    scoped = text.replace("net_a/", "net_a/nearest_up2/")
+    assert tool.layer_keys(scoped, ("G",), "net_a|net_b")["fusion.3"] == (
+        "unscoped", "net_a", "fwd", "nearest_up2")
     assert "copy.6" not in keys       # named after a parameter: no layer
     got = tool.by_layer(trace, text, "net_a|net_b", top=2)
     want = scope_time.by_scope(trace, text, ("net_a", "net_b"))
     assert got["steps"] == want["executions"] == 3
-    assert got["blocked_conv_ms"] == 0.0
+    assert got["blocked_conv_ms"] == got["nearest_up2_ms"] == 0.0
+    up2 = tool.by_layer(trace, scoped, "net_a|net_b", top=2)
+    assert up2["blocked_conv_ms"] == 0.0
+    assert up2["nearest_up2_ms"] == pytest.approx(
+        up2["layer_ms"]["unscoped|net_a|fwd"]["ms"], rel=1e-9)
     for net in ("net_a", "net_b"):
         row = got["layer_ms"][f"unscoped|{net}|fwd"]
         assert row["ms"] == pytest.approx(

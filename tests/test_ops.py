@@ -476,7 +476,6 @@ def test_nearest_up2_conv_matches_upsample_conv():
 
     from p2p_tpu.ops.conv import UpsampleConvLayer
 
-    # post-upsample extent 600·512 = 307k > the extent gate
     x = jnp.asarray(rng(1, 300, 256, 8), jnp.float32)
     layer = UpsampleConvLayer(6, kernel_size=3, upsample=2)
     params = layer.init(jax.random.key(0), x)
@@ -507,13 +506,73 @@ def test_nearest_up2_conv_matches_upsample_conv():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
 
-    # small extents stay on the plain path (gate): the padded UPSAMPLED
-    # tensor (64+2 = 66 rows) is materialized there
+    # under the smallest extent read on the chip (one image at 64x64) the
+    # plain chain stays: the padded UPSAMPLED tensor (64+2 = 66 rows) is
+    # materialized there
     small = jnp.zeros((1, 32, 32, 8), jnp.float32)
     jaxpr_small = str(jax.make_jaxpr(
         lambda p, xx: layer.apply(p, xx))(
             layer.init(jax.random.key(0), small), small))
     assert "66" in jaxpr_small
+
+
+@pytest.mark.parametrize("batch,f", [(1, 3), (2, 1), (3, 8), (32, 3)])
+def test_depth_to_space_2x_is_the_phase_map(batch, f):
+    """``depth_to_space_2x`` puts phase (u,v) at channel block u*2+v:
+    ``y[2i+u, 2j+v] = out[i, j, (u*2+v)*F:]``, forward and backward (a
+    permutation: the cotangent comes back in place), at the batches the
+    cells hold."""
+    from p2p_tpu.ops.conv import depth_to_space_2x
+
+    out = np.arange(batch * 2 * 5 * 4 * f, dtype=np.float32).reshape(
+        batch, 2, 5, 4 * f)
+    want = np.zeros((batch, 4, 10, f), np.float32)
+    for u in (0, 1):
+        for v in (0, 1):
+            want[:, u::2, v::2] = out[..., (u * 2 + v) * f:(u * 2 + v + 1) * f]
+    got, vjp = jax.vjp(lambda o: depth_to_space_2x(o, f), jnp.asarray(out))
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(np.asarray(vjp(jnp.asarray(want))[0]), out)
+
+
+@pytest.mark.parametrize("shape,co", [((2, 12, 16, 64), 32),
+                                      ((32, 4, 6, 64), 32),
+                                      ((2, 8, 8, 128), 64),
+                                      ((1, 6, 10, 8), 6)],
+                         ids=["expand_up1_64to32", "expand_up1_64to32_bs32",
+                              "expand_up0_128to64", "odd_8to6"])
+def test_nearest_up2_conv_bf16_matches_plain_chain(shape, co):
+    """The subpixel form in bf16, as the presets compute, at
+    ExpandNetwork's widths: forward, input gradient and weight gradient
+    against the plain chain (upsample -> reflect pad -> ``nn.Conv``) on
+    the same float32 parameters, relative to each tensor's largest entry.
+    The folded kernel is rounded to bf16 once, after the float32 sums."""
+    from flax import linen as nn
+
+    from p2p_tpu.ops.conv import _NearestUp2Conv
+
+    r = np.random.default_rng(co)
+    x = jnp.asarray(r.normal(size=shape), jnp.bfloat16)
+    n, h, w, _ = shape
+    ct = jnp.asarray(r.normal(size=(n, 2 * h, 2 * w, co)), jnp.bfloat16)
+    form = _NearestUp2Conv(co, dtype=jnp.bfloat16)
+    conv = nn.Conv(co, (3, 3), padding="VALID", dtype=jnp.bfloat16)
+    params = form.init(jax.random.key(1), x)
+    params = jax.tree_util.tree_map(
+        lambda p: jnp.asarray(0.1 * r.normal(size=p.shape), p.dtype), params)
+
+    def plain(p, xx):
+        return conv.apply(p, reflect_pad_2d(upsample_nearest(xx, 2), 1))
+
+    want, want_vjp = jax.vjp(plain, params, x)
+    got, got_vjp = jax.vjp(form.apply, params, x)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    pairs = zip(jax.tree_util.tree_leaves((got, got_vjp(ct))),
+                jax.tree_util.tree_leaves((want, want_vjp(ct))))
+    for a, b in pairs:
+        assert a.shape == b.shape and a.dtype == b.dtype
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= 2e-2 * np.abs(b).max()
 
 
 # (k, C_in, C_out, block, H, W): the layers the blocked form is for, at toy
@@ -641,15 +700,49 @@ ROUTING_CASES = {
     # a wide trunk conv never leaves the plain path
     "trunk_k3_128to128": (ConvLayer(128, kernel_size=3), (2, 512, 512, 128),
                           None, (3, 3, 128, 128)),
-    # UpsampleConvLayer(k3, upsample=2) either side of its extent gate
-    # (4*272*272 = 295,936 < 300,000 <= 300,288 = 4*276*272): below, the
-    # conv reads the reflect-padded UPSAMPLED tensor (2*272+2 rows); from
-    # the gate up, one conv to 4*C_out channels reads the edge-padded
-    # LOW-RES input (276+2 rows) -- _NearestUp2Conv
-    "up2_k3_under_gate": (UpsampleConvLayer(6, kernel_size=3, upsample=2),
-                          (1, 272, 272, 8), None, (3, 3, 8, 6), 546),
-    "up2_k3_over_gate": (UpsampleConvLayer(6, kernel_size=3, upsample=2),
-                         (1, 276, 272, 8), None, (3, 3, 8, 24), 278),
+    # UpsampleConvLayer(k3, upsample=2) either side of both bounds of its
+    # rule (fewer than 128 output channels, and a batch of at least 16,384
+    # post-upsample pixels = 4*64*64, the smallest read on the chip): in
+    # the plain chain the conv reads the reflect-padded UPSAMPLED tensor
+    # (2*H+2 rows); in the subpixel form one conv to 4*C_out channels
+    # reads the edge-padded LOW-RES input (H+2 rows) -- _NearestUp2Conv
+    "up2_k3_under_floor": (UpsampleConvLayer(6, kernel_size=3, upsample=2),
+                           (1, 64, 63, 8), None, (3, 3, 8, 6), 130),
+    "up2_k3_at_floor": (UpsampleConvLayer(6, kernel_size=3, upsample=2),
+                        (1, 64, 64, 8), "nearest_up2", (3, 3, 8, 24), 66),
+    # the floor counts the batch, not one image
+    "up2_k3_batch_counts": (UpsampleConvLayer(6, kernel_size=3, upsample=2),
+                            (4, 32, 32, 8), "nearest_up2", (3, 3, 8, 24),
+                            34),
+    "up2_k3_127_channels": (UpsampleConvLayer(127, kernel_size=3, upsample=2),
+                            (1, 256, 256, 8), "nearest_up2", (3, 3, 8, 508),
+                            258),
+    "up2_k3_128_channels": (UpsampleConvLayer(128, kernel_size=3, upsample=2),
+                            (1, 256, 256, 8), None, (3, 3, 8, 128), 514),
+    # the cells' own sites: ExpandNetwork's two at bs32, the pix2pixhd
+    # enhancer's and G1's last and third at bs2 and on one image of the
+    # paper's extent (PERF.md section 6, PR 28)
+    "ref_up1_64to32": (UpsampleConvLayer(32, kernel_size=3, upsample=2),
+                       (32, 128, 128, 64), "nearest_up2", (3, 3, 64, 128),
+                       130),
+    "ref_up0_128to64": (UpsampleConvLayer(64, kernel_size=3, upsample=2),
+                        (32, 64, 64, 128), "nearest_up2", (3, 3, 128, 256),
+                        66),
+    "hd_enh_64to32": (UpsampleConvLayer(32, kernel_size=3, upsample=2),
+                      (2, 256, 512, 64), "nearest_up2", (3, 3, 64, 128), 258),
+    "hd_g1_last_128to64": (UpsampleConvLayer(64, kernel_size=3, upsample=2),
+                           (2, 128, 256, 128), "nearest_up2",
+                           (3, 3, 128, 256), 130),
+    "hd_g1_third_256to128": (UpsampleConvLayer(128, kernel_size=3,
+                                               upsample=2),
+                             (2, 64, 128, 256), None, (3, 3, 256, 128), 130),
+    "hd2048_g1_third_256to128": (UpsampleConvLayer(128, kernel_size=3,
+                                                   upsample=2),
+                                 (2, 128, 256, 256), None,
+                                 (3, 3, 256, 128), 258),
+    # k5 after the upsample: the edge-pad identity holds for one ring only
+    "up2_k5_stays_plain": (UpsampleConvLayer(6, kernel_size=5, upsample=2),
+                           (1, 256, 256, 8), None, (5, 5, 8, 6), 516),
 }
 
 
@@ -660,7 +753,8 @@ def test_thin_conv_dispatch_routing(case):
     too, so the forms are told apart by the ``blocked_conv`` scope, the
     kernel's shape (s times the channels, k' taps along W) and the
     ``conv_form_sites_total`` counter, which ticks once a traced site;
-    the subpixel form of an upsample by the rows its conv reads."""
+    the subpixel form of an upsample by the rows its conv reads, its
+    four-phase kernel and its own label of the counter."""
     layer, shape, form, kernel, *rows = ROUTING_CASES[case]
     convs, ticks = _traced_convs(layer, shape)
     assert ticks == ({form: 1} if form else {})
@@ -703,6 +797,68 @@ def test_every_preset_sends_its_thin_convs_to_the_blocked_form(preset):
     # the presets with such layers (U-Nets have none)
     want = {"reference": 2, "pix2pixhd": 3, "cityscapes_spatial": 2}
     assert len(blocked) == want.get(preset, 0)
+
+
+# preset[@extent] -> (batch, (H, W) or None for the preset's own, the
+# (C_out, takes the subpixel form) of G's k3-up2 sites in call order). The
+# batch is the benchmark cell's where the preset has one (reference 32,
+# pix2pixhd 2), else the preset's own.
+UP2_SITE_CASES = {
+    # reference_256.train: both of ExpandNetwork's upsamples (PR 28)
+    "reference": (32, None, ((64, True), (32, True))),
+    # one image of it, as cli.infer feeds it: the same form as in training
+    "reference@bs1": (1, None, ((64, True), (32, True))),
+    # pix2pixhd_1024x512.train: G1's last (new in PR 28) and the enhancer's
+    "pix2pixhd": (2, None, ((512, False), (256, False), (128, False),
+                            (64, True), (32, True))),
+    # pix2pixhd_2048x1024.train_spatial4 (the global batch the step sees):
+    # the same two sites as before PR 28
+    "pix2pixhd@1024x2048": (2, (1024, 2048), (
+        (512, False), (256, False), (128, False), (64, True), (32, True))),
+    # no cell: follows the rule unmeasured
+    "cityscapes_spatial": (4, None, ((128, False), (64, True))),
+}
+
+
+@pytest.mark.parametrize("case", list(UP2_SITE_CASES) + [
+    p for p in list_presets() if p not in UP2_SITE_CASES])
+def test_every_preset_sends_its_up2_convs_where_the_rule_says(case):
+    """G traced abstractly at the preset's own extent and its cell's
+    batch: which ``UpsampleConvLayer(k3, upsample=2)`` sites take the
+    subpixel form (``conv_form_sites_total{form=nearest_up2}`` ticks
+    inside the site's call) is pinned site by site, so PR 28's gain
+    cannot be lost silently and no site changes form unnoticed. The
+    U-Net presets have no such site."""
+    from flax import linen as nn
+
+    from p2p_tpu.core.config import get_preset
+    from p2p_tpu.ops.conv import conv_form_sites
+    from p2p_tpu.train.state import build_models
+
+    batch, extent, want = UP2_SITE_CASES.get(case, (None, None, ()))
+    cfg = get_preset(case.split("@")[0])
+    g, _, _ = build_models(cfg, jnp.bfloat16)
+    h, w = extent or (cfg.data.image_size,
+                      cfg.data.image_width or cfg.data.image_size)
+    x = jax.ShapeDtypeStruct(
+        (batch or cfg.data.batch_size, h, w, cfg.model.input_nc),
+        jnp.bfloat16)
+    sites = []
+
+    def watch(next_fun, args, kwargs, context):
+        m = context.module
+        if not (isinstance(m, UpsampleConvLayer) and m.upsample == 2
+                and m.kernel_size == 3 and context.method_name == "__call__"):
+            return next_fun(*args, **kwargs)
+        before = conv_form_sites()["nearest_up2"]
+        out = next_fun(*args, **kwargs)
+        sites.append((m.features,
+                      conv_form_sites()["nearest_up2"] - before == 1))
+        return out
+
+    with nn.intercept_methods(watch):
+        jax.eval_shape(lambda x: g.init(jax.random.key(0), x, False), x)
+    assert tuple(sites) == want
 
 
 def test_no_layer_reads_the_environment_to_pick_an_implementation():
