@@ -232,14 +232,9 @@ def activation_peak_bytes(cfg, local_batch: int, train_dtype=None) -> int:
                                  train_dtype=train_dtype)
     sds = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), state)
-    h, w = cfg.image_hw
-    dt = np.uint8 if cfg.data.uint8_pipeline else np.float32
-    batch = {
-        "input": jax.ShapeDtypeStruct(
-            (cfg.data.batch_size, h, w, cfg.model.input_nc), dt),
-        "target": jax.ShapeDtypeStruct(
-            (cfg.data.batch_size, h, w, cfg.model.output_nc), dt),
-    }
+    from p2p_tpu.utils.images import dummy_batch
+
+    batch = dummy_batch(cfg, (cfg.data.batch_size,), abstract=True)
     step = build_train_step(cfg, train_dtype=train_dtype, jit=False)
     jx = jax.make_jaxpr(step)(sds, batch)
     state_bytes = sum(leaf_nbytes(l) for l in jax.tree_util.tree_leaves(sds))
@@ -530,11 +525,9 @@ def dead_restore_findings(presets: Sequence[str] = ("facades",),
         cfg = get_preset(preset)
         # the EMA variant is where the dead restore can creep in
         cfg = dc.replace(cfg, health=dc.replace(cfg.health, ema_decay=0.999))
-        h, w = cfg.image_hw
-        sample = {
-            "input": np.zeros((1, h, w, cfg.model.input_nc), np.uint8),
-            "target": np.zeros((1, h, w, cfg.model.output_nc), np.uint8),
-        }
+        from p2p_tpu.utils.images import dummy_batch
+
+        sample = dummy_batch(cfg, dtype=np.uint8)
         template = jax.eval_shape(
             lambda c=cfg, s=sample: serving_restore_template(c, s))
         out.extend(template_dead_restore_findings(

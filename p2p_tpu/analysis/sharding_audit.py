@@ -319,12 +319,9 @@ def abstract_train_state(cfg, batch_size: Optional[int] = None,
 
     from p2p_tpu.train.state import create_train_state
 
-    h, w = cfg.image_hw
-    bs = batch_size or cfg.data.batch_size
-    dt = np.uint8 if cfg.data.uint8_pipeline else np.float32
-    nc_in, nc_out = cfg.model.input_nc, cfg.model.output_nc
-    sample = {"input": np.zeros((bs, h, w, nc_in), dt),
-              "target": np.zeros((bs, h, w, nc_out), dt)}
+    from p2p_tpu.utils.images import dummy_batch
+
+    sample = dummy_batch(cfg, (batch_size or cfg.data.batch_size,))
     return jax.eval_shape(
         lambda: create_train_state(cfg, jax.random.key(0), sample,
                                    train_dtype=train_dtype))

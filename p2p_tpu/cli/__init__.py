@@ -17,6 +17,17 @@
 import dataclasses
 
 
+def with_label_classes(model, classes):
+    """``--label_classes`` on a label-map preset's ``ModelConfig`` (None =
+    the flag was not given): the conditioning map's channel count follows
+    (classes + the edge channel), as ``input_nc`` states it."""
+    if classes is None:
+        return model
+    return dataclasses.replace(
+        model, label_classes=classes,
+        input_nc=classes + int(model.label_edge))
+
+
 def apply_overrides(obj, **kw):
     """dataclasses.replace with None-valued (unset flag) entries dropped —
     the shared preset-override rule for every CLI."""

@@ -48,6 +48,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "presets still restore the FULL state and need "
                         "the trained value")
     p.add_argument("--n_blocks", type=int, default=None)
+    p.add_argument("--image_width", type=int, default=None)
+    p.add_argument("--label_classes", type=int, default=None,
+                   help="label-map presets: the number of class ids the "
+                        "checkpoint was trained with")
     p.add_argument("--metrics", action="store_true",
                    help="also print mean/max PSNR+SSIM vs the targets")
     p.add_argument("--ema_decay", type=float, default=None,
@@ -122,8 +126,13 @@ def main(argv=None) -> int:
 
     cfg = get_preset(args.preset)
     data = over(cfg.data, dataset=args.dataset, direction=args.direction,
-                test_batch_size=args.batch_size, image_size=args.image_size)
-    model = over(cfg.model, ngf=args.ngf, n_blocks=args.n_blocks)
+                test_batch_size=args.batch_size, image_size=args.image_size,
+                image_width=args.image_width)
+    from p2p_tpu.cli import with_label_classes
+
+    model = with_label_classes(
+        over(cfg.model, ngf=args.ngf, n_blocks=args.n_blocks),
+        args.label_classes)
     health = over(cfg.health, ema_decay=args.ema_decay)
     cfg = dataclasses.replace(cfg, data=data, model=model, health=health,
                               name=args.name or cfg.name)
@@ -146,6 +155,7 @@ def main(argv=None) -> int:
         ds = PairedImageDataset(
             root, "test", cfg.data.direction, cfg.data.image_size,
             cfg.data.image_width, dtype=ds_dtype,
+            label_input=cfg.model.label_classes > 0,
         )
     except (RuntimeError, FileNotFoundError) as e:
         print(f"no test images under {root}: {e}", file=sys.stderr)

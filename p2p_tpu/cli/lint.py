@@ -144,17 +144,13 @@ def _sds_tree(tree):
 
 
 def _tiny_batch(cfg, frames: int = 0):
-    import jax
     import numpy as np
 
-    bs, (h, w) = cfg.data.batch_size, cfg.image_hw
-    lead = (bs, frames) if frames else (bs,)
-    return {
-        "input": jax.ShapeDtypeStruct(
-            lead + (h, w, cfg.model.input_nc), np.uint8),
-        "target": jax.ShapeDtypeStruct(
-            lead + (h, w, cfg.model.output_nc), np.uint8),
-    }
+    from p2p_tpu.utils.images import dummy_batch
+
+    bs = cfg.data.batch_size
+    return dummy_batch(cfg, (bs, frames) if frames else (bs,), np.uint8,
+                       abstract=True)
 
 
 #: the sharding-audit preset set: every family audits (and diffs)
@@ -294,11 +290,9 @@ def _pp_program(overlap: bool = False):
     cfg = dataclasses.replace(
         cfg, parallel=dataclasses.replace(cfg.parallel,
                                           pp_overlap=overlap))
-    bs, (h, w) = cfg.data.batch_size, cfg.image_hw
-    sample = {
-        "input": np.zeros((bs, h, w, cfg.model.input_nc), np.uint8),
-        "target": np.zeros((bs, h, w, cfg.model.output_nc), np.uint8),
-    }
+    from p2p_tpu.utils.images import dummy_batch
+
+    sample = dummy_batch(cfg, (cfg.data.batch_size,), np.uint8)
     mesh = Mesh(np.array(jax.devices()[:2]).reshape(1, 2),
                 ("data", "pipe"))
     # pp_split_state stacks + places the trunk: a (tiny) concrete state

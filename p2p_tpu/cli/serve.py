@@ -320,6 +320,10 @@ def main(argv=None) -> int:
         from p2p_tpu.resilience.chaos import chaos_point
 
         chaos_point("decode")
+        if cfg.model.label_classes:
+            from p2p_tpu.data.pipeline import load_label_map
+
+            return load_label_map(path, h, w)
         return load_image(path, h, w, as_uint8=as_uint8)
 
     try:

@@ -203,6 +203,10 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--direction", type=str, default=None, help="a2b or b2a")
     p.add_argument("--input_nc", type=int, default=None)
     p.add_argument("--output_nc", type=int, default=None)
+    p.add_argument("--label_classes", type=int, default=None,
+                   help="label-map presets: the number of class ids of "
+                        "the conditioning map (sets input_nc = classes + "
+                        "the edge channel)")
     p.add_argument("--ngf", type=int, default=None)
     p.add_argument("--ndf", type=int, default=None)
     p.add_argument("--lr", type=float, default=None)
@@ -279,6 +283,7 @@ def config_from_flags(args: argparse.Namespace) -> Config:
         cfg.model, cfg.loss, cfg.optim, cfg.data, cfg.train, cfg.parallel
     )
     from p2p_tpu.cli import apply_overrides as over
+    from p2p_tpu.cli import with_label_classes
 
     model = over(model, input_nc=args.input_nc, output_nc=args.output_nc,
                  ngf=args.ngf, ndf=args.ndf, n_blocks=args.n_blocks,
@@ -289,6 +294,7 @@ def config_from_flags(args: argparse.Namespace) -> Config:
                  int8_compression=args.int8_compression,
                  int8_fused_epilogue=args.int8_fused_epilogue,
                  legacy_layout=args.legacy_layout, norm_d=args.norm_d)
+    model = with_label_classes(model, args.label_classes)
     loss = over(loss, lambda_l1=args.lamb, lambda_vgg=args.lambda_vgg,
                 lambda_feat=args.lambda_feat, lambda_tv=args.lambda_tv,
                 lambda_sobel=args.lambda_sobel,

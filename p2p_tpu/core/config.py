@@ -24,8 +24,18 @@ class ModelConfig:
     # networks.py:447), "unet" (classic pix2pix U-Net), "pix2pixhd"
     # (coarse-to-fine global+local), "resnet" (9-block ResnetGenerator,
     # the commented alternative at networks.py:168).
+    # "spade" is the SPADE / GauGAN generator (models/spade.py): no
+    # encoder, driven by a label map at every block.
     generator: str = "expand"
     input_nc: int = 3
+    # Label-map conditioning (0 = the input is an image). With
+    # label_classes > 0 the loader ships uint8 (H, W, 2) maps — class id,
+    # instance-edge bit — and the steps one-hot them ON DEVICE
+    # (utils/images.ingest_input) into label_classes (+1 with label_edge)
+    # channels, which is what G and D's conditioning half then see;
+    # input_nc states that channel count.
+    label_classes: int = 0
+    label_edge: bool = False
     output_nc: int = 3
     ngf: int = 32            # reference ExpandNetwork base width (networks.py:460)
     ndf: int = 64            # discriminator base width (networks.py:708)
@@ -145,6 +155,9 @@ class ModelConfig:
 @dataclasses.dataclass(frozen=True)
 class LossConfig:
     gan_mode: str = "lsgan"          # lsgan | vanilla | hinge
+    # Reduce the per-scale GAN losses of a multiscale D by their MEAN (the
+    # SPADE lineage) instead of the reference's SUM (networks.py:808-850).
+    gan_scale_mean: bool = False
     lambda_feat: float = 10.0        # train.py:351
     lambda_vgg: float = 10.0         # train.py:377
     lambda_tv: float = 1.0           # train.py:378
@@ -173,6 +186,9 @@ class LossConfig:
 @dataclasses.dataclass(frozen=True)
 class OptimConfig:
     lr: float = 2e-4                 # train.py:241-243
+    # D's own learning rate (TTUR); None = ``lr``, the reference's one
+    # rate for every net. The schedule's shape is shared.
+    lr_d: Optional[float] = None
     beta1: float = 0.5
     beta2: float = 0.999
     lr_policy: str = "lambda"        # lambda | step | plateau | cosine (networks.py:104)
@@ -505,6 +521,53 @@ _register(
         data=DataConfig(dataset="vid2vid", image_size=256, batch_size=1,
                         n_frames=8),
         parallel=ParallelConfig(mesh=MeshSpec(data=-1, time=4)),
+    )
+)
+
+
+# 6. SPADE / GauGAN label->photo at Cityscapes' 512x256 (Park et al. 2019,
+#    arXiv:1903.07291 sec. 3 + app. A; sizes of github.com/NVlabs/SPADE
+#    SPADEGenerator 'normal', cityscapes options). m = one-hot of 35
+#    classes + 1 instance-edge channel, 36 channels at 256x512; nf = 64.
+#      SPADE_C(x, m) = BN0(x) * (1 + gamma) + beta, BN0 affine-free batch
+#        norm (eps 1e-5; batch statistics in training, running in eval),
+#        a = relu(conv3x3(resize_nearest(m, size(x)), 36 -> 128)),
+#        gamma, beta = conv3x3(a, 128 -> C) each; bias, zero pad 1, no SN.
+#      ResBlk(fin, fout), fmid = min(fin, fout):
+#        dx = conv3x3_sn(lrelu(SPADE_fin(x, m)), fin -> fmid)
+#        dx = conv3x3_sn(lrelu(SPADE_fmid(dx, m)), fmid -> fout)
+#        xs = x, or conv1x1_sn_nobias(SPADE_fin(x, m)) where fin != fout
+#        out = xs + dx      (LeakyReLU 0.2 as the authors' code runs it;
+#        the paper's figure draws ReLU)
+#      G: conv3x3(resize_nearest(m, 8x16), 36 -> 1024); ResBlk(1024,1024);
+#        up; 2 x ResBlk(1024,1024); up; ResBlk(1024,512); up;
+#        ResBlk(512,256); up; ResBlk(256,128); up; ResBlk(128,64);
+#        tanh(conv3x3(lrelu(x), 64 -> 3)); every up is nearest x2.
+#      D: 2 scales of C64(s2)-C128(s2)-C256(s2)-C512(s1)-1, k4 pad 2,
+#        LeakyReLU 0.2, spectral norm + affine-free instance norm on the
+#        three inner convs, on concat(m, image) = 39 channels.
+#      Losses: hinge, averaged over the scales; feature matching 10 / num_D;
+#        VGG19 relu1_1..5_1 L1 x 10. Adam(0, 0.9): G 1e-4, D 4e-4 (TTUR).
+#    Spectral norm (one power iteration a forward, u in the state) sits on
+#    the three convs of every ResBlk and D's inner convs, nowhere else;
+#    init xavier-normal with gain 0.02.
+#    Departures: this Trainer's step (D and G losses from ONE generator
+#    forward, G seeing the D of the step's start; the authors update G, then
+#    run G again for D) and its 0.5 on D's loss (train/step.py).
+_register(
+    Config(
+        name="spade_cityscapes",
+        model=ModelConfig(generator="spade", ngf=64, input_nc=36,
+                          label_classes=35, label_edge=True, norm="batch",
+                          num_D=2, n_layers_D=3, norm_d="instance",
+                          use_compression_net=False, init_type="xavier",
+                          split_d_pairs=True),
+        loss=LossConfig(gan_mode="hinge", gan_scale_mean=True,
+                        lambda_feat=10.0, lambda_vgg=10.0, lambda_tv=0.0),
+        optim=OptimConfig(lr=1e-4, lr_d=4e-4, beta1=0.0, beta2=0.9),
+        data=DataConfig(dataset="cityscapes", image_size=256,
+                        image_width=512, batch_size=1),
+        parallel=ParallelConfig(mesh=MeshSpec(data=-1)),
     )
 )
 

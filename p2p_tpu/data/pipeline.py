@@ -87,11 +87,55 @@ def load_image_bytes(data: bytes, h: int, w: int,
             * np.float32(1.0 / 127.5))
 
 
+def _label_planes(img: Image.Image, h: int, w: int) -> np.ndarray:
+    """uint8 ``(h, w, 2)`` — class id, instance-edge bit — from a decoded
+    label image: its first channel holds the ids, its second (where it
+    has one: ``LA``, ``RGB``) the edge bit. Ids are never blended: another
+    size is reached by NEAREST resampling, palette indices are taken as
+    they are."""
+    if img.size != (w, h):
+        img = img.resize((w, h), Image.NEAREST)
+    arr = np.asarray(img)
+    if arr.dtype != np.uint8:
+        raise ValueError(f"label image of dtype {arr.dtype}: expected "
+                         "8-bit class ids")
+    if arr.ndim == 2:
+        arr = np.stack([arr, np.zeros_like(arr)], axis=-1)
+    return np.ascontiguousarray(arr[..., :2])
+
+
+def load_label_map(path: str, h: int, w: int) -> np.ndarray:
+    """Decode a label map (:func:`_label_planes`) under the span
+    ``label_decode``; each one counts on ``label_maps_decoded_total`` of
+    the process registry (a dataset is built before its run's)."""
+    from p2p_tpu.obs.registry import get_registry
+
+    with timed_annotation(
+            "label_decode",
+            get_registry().histogram("label_decode_secs")):
+        out = _label_planes(Image.open(path), h, w)
+    get_registry().counter("label_maps_decoded_total").inc()
+    return out
+
+
+def load_label_bytes(data: bytes, h: int, w: int) -> np.ndarray:
+    """:func:`load_label_map` over an in-memory encoded label image (the
+    HTTP request body of a label-map tenant)."""
+    import io
+
+    return _label_planes(Image.open(io.BytesIO(data)), h, w)
+
+
 class PairedImageDataset:
     """Random-access paired dataset; items are dicts of HWC images —
     float32 [-1,1] by default, raw uint8 [0,255] with ``dtype='uint8'``
     (the uint8 input pipeline: smaller memo/PCIe, device-side normalize
-    via utils/images.ingest — numerically identical)."""
+    via utils/images.ingest — numerically identical).
+
+    ``label_input``: the side that becomes ``"input"`` holds label maps
+    (class ids, an instance-edge bit), decoded by :func:`load_label_map`
+    to uint8 ``(H, W, 2)`` whatever ``dtype`` says, and ``augment`` is the
+    flip alone: a resize-and-crop would resample ids."""
 
     def __init__(
         self,
@@ -104,7 +148,9 @@ class PairedImageDataset:
         aug_seed: int = 0,
         cache: Union[bool, str] = "auto",
         dtype: str = "float32",
+        label_input: bool = False,
     ):
+        self.label_input = label_input
         self.a_dir = os.path.join(root, split, "a")
         self.b_dir = os.path.join(root, split, "b")
         self.direction = direction
@@ -131,8 +177,9 @@ class PairedImageDataset:
             raise ValueError(f"dtype must be float32|uint8, got {dtype!r}")
         self.as_uint8 = dtype == "uint8"
         if cache == "auto":
-            lh = (self.h * 286 // 256) if augment else self.h
-            lw = (self.w * 286 // 256) if augment else self.w
+            scaled = augment and not label_input
+            lh = (self.h * 286 // 256) if scaled else self.h
+            lw = (self.w * 286 // 256) if scaled else self.w
             bpp = 1 if self.as_uint8 else 4  # the uint8 memo is 4× smaller
             cache = len(self.names) * lh * lw * 3 * bpp * 2 <= 4 << 30
         self.cache_enabled = bool(cache)
@@ -141,16 +188,27 @@ class PairedImageDataset:
     def __len__(self) -> int:
         return len(self.names)
 
+    @property
+    def memo_full(self) -> bool:
+        """Every item is a memo hit: both sides of every pair are held."""
+        return self.cache_enabled and len(self._memo) >= 2 * len(self.names)
+
     def _load(self, path: str, h: Optional[int] = None,
-              w: Optional[int] = None) -> np.ndarray:
+              w: Optional[int] = None, labels: bool = False) -> np.ndarray:
         h = h or self.h
         w = w or self.w
-        if not self.cache_enabled:
+
+        def decode():
+            if labels:
+                return load_label_map(path, h, w)
             return load_image(path, h, w, self.as_uint8)
+
+        if not self.cache_enabled:
+            return decode()
         key = (path, h, w)
         hit = self._memo.get(key)
         if hit is None:
-            hit = load_image(path, h, w, self.as_uint8)
+            hit = decode()
             hit.setflags(write=False)
             self._memo[key] = hit
         return hit
@@ -159,6 +217,17 @@ class PairedImageDataset:
         if hasattr(idx, "__index__"):
             idx = idx.__index__()
         name = self.names[idx]
+        if self.label_input:
+            in_dir, tgt_dir = ((self.a_dir, self.b_dir)
+                               if self.direction == "a2b"
+                               else (self.b_dir, self.a_dir))
+            m = self._load(os.path.join(in_dir, name), labels=True)
+            t = self._load(os.path.join(tgt_dir, name))
+            if self.augment and np.random.default_rng(
+                    (0x9E3779B9, self.aug_seed, idx)).random() < 0.5:
+                m = np.ascontiguousarray(m[:, ::-1])
+                t = np.ascontiguousarray(t[:, ::-1])
+            return {"input": m, "target": t}
         if self.augment:
             # the reference's commented-out aug (dataset.py:28-46): load at
             # 286/256-scaled size, take the SAME random crop from a and b,
@@ -201,6 +270,18 @@ class _Stacked:
             yield {
                 k: np.stack([it[k] for it in items]) for k in items[0]
             }
+
+
+class _InSamplerOrder:
+    """A dataset read in a Grain sampler's order: item ``i`` is the record
+    the sampler puts at position ``i``."""
+
+    def __init__(self, ds, sampler):
+        self.ds = ds
+        self.sampler = sampler
+
+    def __getitem__(self, i: int):
+        return self.ds[self.sampler[i].record_key]
 
 
 _WORKERS_WARNED = False
@@ -422,13 +503,6 @@ def make_loader(
         num_epochs=num_epochs,
         seed=seed,
     )
-    loader = pg.DataLoader(
-        data_source=dataset,
-        sampler=sampler,
-        operations=[pg.Batch(batch_size=batch_size, drop_remainder=drop_remainder)],
-        worker_count=num_workers,
-    )
-    it = iter(loader)
     skip = max(0, int(skip_batches))
     if skip_samples > 0:
         # Grain consumes whole local batches; a sample-granular prefix
@@ -442,6 +516,26 @@ def make_loader(
                 "skip a partial batch; run with P2P_TPU_NO_GRAIN=1 for "
                 "sample-granular elastic accounting")
         skip += skip_samples // global_b
+    if (num_workers == 0 and num_epochs is not None
+            and jax.process_count() == 1
+            and getattr(dataset, "memo_full", False)):
+        # Every record is a memo hit, so there is nothing to decode in
+        # parallel, and starting the DataLoader's reader threads costs an
+        # epoch's first batches 20-50 ms of host time that varies by as
+        # much from epoch to epoch while the device waits (PERF.md section
+        # 6, PR 29). The same batches in the sampler's own order, stacked
+        # in this thread as they are asked for; the skip is index
+        # arithmetic.
+        return iter(_Stacked(
+            _InSamplerOrder(dataset, sampler), batch_size,
+            range(skip * batch_size, len(sampler)), drop_remainder))
+    loader = pg.DataLoader(
+        data_source=dataset,
+        sampler=sampler,
+        operations=[pg.Batch(batch_size=batch_size, drop_remainder=drop_remainder)],
+        worker_count=num_workers,
+    )
+    it = iter(loader)
     if skip > 0:
         def skipping():
             for i, b in enumerate(it):

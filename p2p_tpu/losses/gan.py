@@ -49,10 +49,14 @@ def _elementwise(pred: jax.Array, target_is_real: bool, mode: str,
 
 
 def gan_loss(preds: Preds, target_is_real: bool, mode: str = "lsgan",
-             for_discriminator: bool = True) -> jax.Array:
-    """Sum of per-scale losses on the final prediction map of each scale."""
+             for_discriminator: bool = True,
+             scale_mean: bool = False) -> jax.Array:
+    """Sum of per-scale losses on the final prediction map of each scale;
+    their mean with ``scale_mean`` (``LossConfig.gan_scale_mean``, the
+    SPADE lineage)."""
     losses = [
         _elementwise(p, target_is_real, mode, for_discriminator)
         for p in _final_preds(preds)
     ]
-    return jnp.sum(jnp.stack(losses))
+    total = jnp.sum(jnp.stack(losses))
+    return total / len(losses) if scale_mean else total

@@ -89,7 +89,23 @@ def define_G(cfg: ModelConfig, dtype=None, remat=False) -> nn.Module:
             norm=cfg.norm, remat=remat, int8=int8_g, int8_delayed=delayed,
             legacy_layout=cfg.legacy_layout, dtype=dtype,
         )
+    if cfg.generator == "spade":
+        from p2p_tpu.models.spade import SPADEGenerator
+
+        return SPADEGenerator(nf=cfg.ngf, out_channels=cfg.output_nc,
+                              dtype=dtype)
     raise ValueError(f"unknown generator {cfg.generator!r}")
+
+
+def generator_gauges(cfg: ModelConfig, h: int, w: int) -> Dict[str, float]:
+    """What the configured generator does for one ``h`` x ``w`` image, from
+    its shapes, as gauges the Trainer sets when it builds the step; empty
+    for a generator that states none."""
+    if cfg.generator == "spade":
+        from p2p_tpu.models.spade import spade_arithmetic
+
+        return spade_arithmetic(cfg.ngf, cfg.input_nc, h, w)
+    return {}
 
 
 def define_D(cfg: ModelConfig, dtype=None) -> nn.Module:
@@ -115,7 +131,10 @@ def _kernel_initializer(init_type: str, gain: float):
     if init_type == "normal":
         return nn.initializers.normal(stddev=gain)
     if init_type == "xavier":
-        return nn.initializers.xavier_normal()
+        # init.xavier_normal_(w, gain): std = gain * sqrt(2 / (fan_in +
+        # fan_out)) (networks.py:133)
+        return nn.initializers.variance_scaling(gain ** 2, "fan_avg",
+                                                "normal")
     if init_type == "kaiming":
         return nn.initializers.kaiming_normal()
     if init_type == "orthogonal":

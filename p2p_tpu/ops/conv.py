@@ -259,11 +259,17 @@ class ConvLayer(nn.Module):
     int8_delayed: bool = False
     dtype: Optional[jnp.dtype] = None
     kernel_init: Callable = normal_init()
+    # "zero": Conv2d(padding=k//2) in the layer's place (the SPADE
+    # lineage pads with zeros); the conv's form is chosen as for reflect
+    pad_mode: str = "reflect"
 
     @nn.compact
     def __call__(self, x):
         pad = self.kernel_size // 2
-        x = reflect_pad_2d(x, pad)
+        if self.pad_mode == "zero":
+            x = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
+        else:
+            x = reflect_pad_2d(x, pad)
         if self.int8:
             from p2p_tpu.ops.int8 import QuantConv
 

@@ -94,13 +94,19 @@ class _FastBatchNorm(nn.Module):
     epsilon: float = 1e-5
     axis_name: Optional[str] = None
     dtype: Optional[jnp.dtype] = None
+    # False: BatchNorm2d(affine=False), no params (what SPADE modulates)
+    affine: bool = True
 
     @nn.compact
     def __call__(self, x):
         c = x.shape[-1]
         reduce_axes = tuple(range(x.ndim - 1))
-        scale = self.param("scale", _gamma_init, (c,), jnp.float32)
-        bias = self.param("bias", nn.initializers.zeros, (c,), jnp.float32)
+        if self.affine:
+            scale = self.param("scale", _gamma_init, (c,), jnp.float32)
+            bias = self.param("bias", nn.initializers.zeros, (c,),
+                              jnp.float32)
+        else:
+            scale, bias = 1.0, 0.0
         init = self.is_initializing()
         ra_mean = self.variable(
             "batch_stats", "mean", lambda: jnp.zeros((c,), jnp.float32)
@@ -161,6 +167,7 @@ class BatchNorm(nn.Module):
     epsilon: float = 1e-5
     axis_name: Optional[str] = None
     dtype: Optional[jnp.dtype] = None
+    affine: bool = True
 
     @nn.compact
     def __call__(self, x, use_running_average: Optional[bool] = None):
@@ -175,6 +182,7 @@ class BatchNorm(nn.Module):
             epsilon=self.epsilon,
             axis_name=self.axis_name,
             dtype=self.dtype,
+            affine=self.affine,
             name="BatchNorm_0",
         )(x)
 
@@ -292,3 +300,53 @@ def make_norm(kind: str, *, train: bool = True, axis_name: Optional[str] = None,
     if kind == "none":
         return lambda: (lambda x: x)
     raise ValueError(f"unknown norm kind {kind!r}")
+
+
+class SPADE(nn.Module):
+    """Spatially-adaptive normalisation (Park et al. 2019, section 3):
+
+        SPADE_C(x, m) = BN0(x) * (1 + gamma) + beta
+        a     = relu(conv3x3(resize_nearest(m, size(x)), M -> hidden))
+        gamma = conv3x3(a, hidden -> C),  beta = conv3x3(a, hidden -> C)
+
+    ``BN0`` is batch normalisation with no affine: the moments come from
+    :class:`BatchNorm`'s own path (one pass, float32 accumulation, global
+    under a ``data`` mesh, running statistics in eval), so ``gamma`` and
+    ``beta`` are full ``[N,H,W,C]`` tensors where every other norm here
+    has a per-channel pair or none. The three convolutions carry a bias,
+    pad with zeros and run through ``ops/conv.ConvLayer`` like every
+    other convolution (the form is ``_routed_conv``'s choice). ``m`` is
+    the conditioning map at the generator's full extent or at ``x``'s own:
+    nearest resize by a whole factor is a strided slice.
+
+    The whole site runs under ``jax.named_scope("spade")`` with
+    ``shared_conv``, ``gamma_beta`` and ``modulate`` inside it."""
+
+    hidden: int = 128
+    train: bool = True
+    dtype: Optional[jnp.dtype] = None
+
+    @nn.compact
+    def __call__(self, x, m):
+        from p2p_tpu.ops.activations import relu_y
+        from p2p_tpu.ops.conv import ConvLayer
+
+        if m.shape[1] % x.shape[1] or m.shape[2] % x.shape[2]:
+            raise ValueError(f"SPADE: the map {m.shape} is no whole "
+                             f"multiple of the activation {x.shape}")
+        fh, fw = m.shape[1] // x.shape[1], m.shape[2] // x.shape[2]
+        conv = lambda features, name: ConvLayer(  # noqa: E731
+            features, kernel_size=3, pad_mode="zero", dtype=self.dtype,
+            name=name)
+        with jax.named_scope("spade"):
+            with jax.named_scope("shared_conv"):
+                a = relu_y(conv(self.hidden, "shared")(m[:, ::fh, ::fw]))
+            with jax.named_scope("gamma_beta"):
+                gamma = conv(x.shape[-1], "gamma")(a)
+                beta = conv(x.shape[-1], "beta")(a)
+            with jax.named_scope("modulate"):
+                xn = BatchNorm(use_running_average=not self.train,
+                               dtype=self.dtype, affine=False,
+                               name="norm")(x)
+                one = jnp.ones((), gamma.dtype)
+                return xn * (one + gamma) + beta

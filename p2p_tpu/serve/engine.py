@@ -146,16 +146,13 @@ class InferenceEngine:
 
         # host batch spec the buckets are compiled for: uint8 transport
         # when the pipeline ships raw bytes (DataConfig.uint8_pipeline)
-        self._batch_dtype = (np.uint8 if cfg.data.uint8_pipeline
-                             else np.float32)
-        h, w = cfg.image_hw
+        # (a label-map input is uint8 (H, W, 2): utils/images.wire_spec)
+        from p2p_tpu.utils.images import wire_spec
+
         keys = ["input"]
         if cfg.model.use_compression_net or with_metrics:
             keys.append("target")
-        nc = {"input": cfg.model.input_nc, "target": cfg.model.output_nc}
-        self._batch_spec = {
-            k: (h, w, nc[k]) for k in keys
-        }
+        self._batch_spec = {k: wire_spec(cfg, k) for k in keys}
 
     @property
     def batch_keys(self):
@@ -165,8 +162,8 @@ class InferenceEngine:
     # ------------------------------------------------------------- warmup
     def _abstract_batch(self, bucket_bs: int) -> Dict[str, jax.ShapeDtypeStruct]:
         return {
-            k: jax.ShapeDtypeStruct((bucket_bs,) + hwc, self._batch_dtype)
-            for k, hwc in self._batch_spec.items()
+            k: jax.ShapeDtypeStruct((bucket_bs,) + hwc, dt)
+            for k, (hwc, dt) in self._batch_spec.items()
         }
 
     def _compile_bucket(self, bucket_bs: int):

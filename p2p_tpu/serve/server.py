@@ -157,10 +157,15 @@ class _TenantRuntime:
         def decode(req: Request) -> np.ndarray:
             # same chaos seam as the directory frontend: chaos drills at
             # `decode` rehearse the retry/poison ladder over HTTP too
-            from p2p_tpu.data.pipeline import load_image_bytes
+            from p2p_tpu.data.pipeline import (
+                load_image_bytes,
+                load_label_bytes,
+            )
             from p2p_tpu.resilience.chaos import chaos_point
 
             chaos_point("decode")
+            if tenant.cfg.model.label_classes:
+                return load_label_bytes(req.payload, h, w)
             return load_image_bytes(req.payload, h, w, as_uint8=as_uint8)
 
         alias = tenant.alias

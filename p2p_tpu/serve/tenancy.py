@@ -65,11 +65,9 @@ def checkpoint_dir(cfg: Config, workdir: str) -> str:
 def serving_sample_batch(cfg: Config) -> Dict[str, np.ndarray]:
     """The 1-image host batch a serving restore template is built from
     (shape/dtype only — values never matter)."""
-    h, w = cfg.image_hw
-    sample = np.zeros(
-        (1, h, w, cfg.model.input_nc),
-        np.uint8 if cfg.data.uint8_pipeline else np.float32)
-    return {"input": sample, "target": sample}
+    from p2p_tpu.utils.images import dummy_batch
+
+    return dummy_batch(cfg)
 
 
 class Tenant:

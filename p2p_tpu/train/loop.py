@@ -49,6 +49,7 @@ import numpy as np
 from p2p_tpu.core.config import Config
 from p2p_tpu.core.mesh import local_batch_size, batch_sharding, make_mesh
 from p2p_tpu.data.pipeline import PairedImageDataset, device_prefetch, make_loader
+from p2p_tpu.models.registry import generator_gauges
 from p2p_tpu.models.vgg import load_vgg19_params
 from p2p_tpu.obs import (
     MemoryWatchdog,
@@ -917,14 +918,15 @@ class Trainer:
         # normalize on device — bit-exact with the f32 pipeline, 4× less
         # memo RAM and PCIe traffic (DataConfig.uint8_pipeline)
         ds_dtype = "uint8" if cfg.data.uint8_pipeline else "float32"
+        labels = cfg.model.label_classes > 0
         self.train_ds = PairedImageDataset(
             root, "train", cfg.data.direction, cfg.data.image_size,
             cfg.data.image_width, augment=cfg.data.augment,
-            dtype=ds_dtype,
+            dtype=ds_dtype, label_input=labels,
         )
         self.test_ds = PairedImageDataset(
             root, "test", cfg.data.direction, cfg.data.image_size,
-            cfg.data.image_width, dtype=ds_dtype,
+            cfg.data.image_width, dtype=ds_dtype, label_input=labels,
         )
         self.steps_per_epoch = max(1, len(self.train_ds) // cfg.data.batch_size)
         self.mesh = mesh if mesh is not None else (
@@ -1063,6 +1065,9 @@ class Trainer:
         # THIS run's registry, not the process default
         self.ckpt = CheckpointManager(ckpt_dir, registry=self.obs)
         self._init_obs()
+        # what the step's generator does, from its shapes
+        for name, value in generator_gauges(cfg.model, *cfg.image_hw).items():
+            self.obs.gauge(name).set(value)
         self.plateau = (
             PlateauController() if cfg.optim.lr_policy == "plateau" else None
         )
@@ -1632,12 +1637,21 @@ class Trainer:
                     return np.asarray(
                         ingest(np.asarray(arr)[0]), np.float32)
 
+                def input_img(arr):
+                    if not cfg.model.label_classes:
+                        return first_img(arr)
+                    from p2p_tpu.utils.images import label_preview
+
+                    if n_proc > 1:
+                        arr = arr.addressable_shards[0].data
+                    return label_preview(np.asarray(arr)[0])
+
                 if jax.process_index() == 0:
                     out_dir = os.path.join(
                         self.workdir, cfg.train.result_dir, cfg.data.dataset
                     )
                     os.makedirs(out_dir, exist_ok=True)
-                    save_img(first_img(batch["input"]),
+                    save_img(input_img(batch["input"]),
                              os.path.join(out_dir, f"e{self.epoch}_input.png"))
                     save_img(first_img(batch["target"]),
                              os.path.join(out_dir, f"e{self.epoch}_target.png"))
@@ -1653,7 +1667,7 @@ class Trainer:
 
                         mask = np.bitwise_and(
                             to_uint8_img(first_img(pred)),
-                            to_uint8_img(first_img(batch["input"])),
+                            to_uint8_img(input_img(batch["input"])),
                         )
                         save_img(mask, os.path.join(
                             out_dir, f"e{self.epoch}_mask.png"))

@@ -9,6 +9,7 @@ step function is pure state-in/state-out — Q4/Q5 are unrepresentable.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Dict, Optional
 
@@ -60,6 +61,11 @@ class TrainState(struct.PyTreeNode):
     # present. None when EMA is off — None flattens to an empty subtree,
     # so pre-round-6 checkpoints keep restoring bit-for-bit.
     ema_g: Any = None
+    # power-iteration vectors of a generator that carries spectral norm
+    # (models/spade.py), threaded like spectral_d: one iteration a
+    # training forward. None for every other generator (an empty subtree:
+    # their checkpoints and their step are what they were).
+    spectral_g: Any = None
 
 
 class InferState(struct.PyTreeNode):
@@ -91,6 +97,9 @@ class InferState(struct.PyTreeNode):
     # (HealthConfig.ema_decay) — the serving engine swaps them in for
     # params_g (ProGAN-lineage: serve the smoothed generator)
     ema_g: Any = None
+    # a spectrally normalised generator's vectors (models/spade.py): in
+    # eval the collection is read-only, the weight served is W / sigma(u)
+    spectral_g: Any = None
 
 
 # State init runs as ONE jitted program per (config, dtype). Run eagerly,
@@ -134,9 +143,9 @@ def _init_infer_state(cfg, rng, sample_batch, train_dtype=None):
     c = (define_C(cfg.model, dtype=train_dtype)
          if cfg.model.use_compression_net else None)
     kg, _, kc = jax.random.split(rng, 3)
-    from p2p_tpu.utils.images import ingest
+    from p2p_tpu.utils.images import ingest_input
 
-    x = ingest(jnp.asarray(sample_batch["input"]))
+    x = ingest_input(jnp.asarray(sample_batch["input"]), cfg.model)
     vg = init_variables(g, kg, x, cfg.model.init_type, cfg.model.init_gain,
                         train=False)
     params_c = batch_stats_c = quant_c = None
@@ -160,6 +169,7 @@ def _init_infer_state(cfg, rng, sample_batch, train_dtype=None):
         # the smoothed weights from disk too (same tree as params_g)
         ema_g=(jax.tree_util.tree_map(jnp.copy, vg["params"])
                if cfg.health.ema_decay is not None else None),
+        spectral_g=vg.get("spectral"),
     )
 
 
@@ -175,6 +185,7 @@ def infer_state_from_train(state: "TrainState") -> InferState:
         quant_g=state.quant_g,
         quant_c=state.quant_c,
         ema_g=state.ema_g,
+        spectral_g=state.spectral_g,
     )
 
 
@@ -310,8 +321,9 @@ def scale_by_adam_lp(b1: float, b2: float, eps: float,
 
 
 def make_optimizers(cfg: Config, steps_per_epoch: int):
-    """Three Adam optimizers with the reference hyperparameters
-    (lr=2e-4, β=(0.5, 0.999) — train.py:241-243) on the configured schedule.
+    """Three Adam optimizers (G, D, C) with the reference hyperparameters
+    (lr=2e-4, β=(0.5, 0.999) — train.py:241-243) on the configured
+    schedule; D's runs at ``OptimConfig.lr_d`` where that is set (TTUR).
 
     ``OptimConfig.grad_clip > 0`` prepends global-norm clipping — off by
     default (the reference has none), but the practical guard against
@@ -322,8 +334,10 @@ def make_optimizers(cfg: Config, steps_per_epoch: int):
     """
     from p2p_tpu.train.schedules import make_schedule
 
-    def make_one():
-        sched = make_schedule(cfg.optim, steps_per_epoch, cfg.train.epoch_count)
+    def make_one(lr=None):
+        optim = (cfg.optim if lr is None
+                 else dataclasses.replace(cfg.optim, lr=lr))
+        sched = make_schedule(optim, steps_per_epoch, cfg.train.epoch_count)
         clip = cfg.optim.grad_clip
 
         def inner(learning_rate):
@@ -356,7 +370,7 @@ def make_optimizers(cfg: Config, steps_per_epoch: int):
 
         return optax.inject_hyperparams(inner)(learning_rate=sched)
 
-    return make_one(), make_one(), make_one()
+    return make_one(), make_one(cfg.optim.lr_d), make_one()
 
 
 def build_models(cfg: Config, train_dtype=None):
@@ -383,11 +397,12 @@ def _init_train_state(cfg, rng, sample_batch, steps_per_epoch=1,
     opt_g, opt_d, opt_c = make_optimizers(cfg, steps_per_epoch)
 
     kg, kd, kc = jax.random.split(rng, 3)
-    from p2p_tpu.utils.images import ingest
+    from p2p_tpu.utils.images import ingest, ingest_input
 
     # uint8 samples (DataConfig.uint8_pipeline) normalize to f32 here so
     # shape/dtype inference at init matches what the step's ingest feeds
-    x = ingest(jnp.asarray(sample_batch["input"]))
+    # (a label map one-hots: D's stem is conditioning + image channels)
+    x = ingest_input(jnp.asarray(sample_batch["input"]), cfg.model)
     pair = jnp.concatenate(
         [x, ingest(jnp.asarray(sample_batch["target"]))], axis=-1)
 
@@ -439,4 +454,5 @@ def _init_train_state(cfg, rng, sample_batch, steps_per_epoch=1,
         quant_d=vd.get("quant", {}) if delayed else None,
         quant_c=quant_c,
         ema_g=ema_g,
+        spectral_g=vg.get("spectral"),
     )

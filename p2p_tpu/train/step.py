@@ -69,7 +69,7 @@ from p2p_tpu.losses import (
 from p2p_tpu.ops.quantize import quantize, quantize_ste
 from p2p_tpu.ops.tv import total_variation_loss
 from p2p_tpu.train.state import TrainState, build_models, make_optimizers
-from p2p_tpu.utils.images import ingest
+from p2p_tpu.utils.images import ingest, ingest_input
 
 
 #: Whose work an op of the step program is: every net, loss and optimizer
@@ -93,7 +93,8 @@ def _concat_pair(a: jax.Array, b: jax.Array) -> jax.Array:
 
 
 def single_forward_d_losses(d_apply, dvars0, params_d, fake_pair,
-                            real_pair, gan_mode: str):
+                            real_pair, gan_mode: str,
+                            scale_mean: bool = False):
     """ONE D(fake) forward whose vjp serves both the D loss and (later) the
     G loss — the "single-forward structure" of the module docstring, shared
     by the image step (spatial D) and the video step (spatial + temporal D).
@@ -115,7 +116,8 @@ def single_forward_d_losses(d_apply, dvars0, params_d, fake_pair,
 
     def d_gan_loss(pred, is_real):
         with jax.named_scope("loss_gan"):
-            return 0.5 * gan_loss(pred, is_real, gan_mode)
+            return 0.5 * gan_loss(pred, is_real, gan_mode,
+                                  scale_mean=scale_mean)
 
     pred_fake, d_vjp, dvars1 = jax.vjp(
         fake_primal, params_d, fake_pair, has_aux=True
@@ -156,7 +158,8 @@ def make_g_loss_fn(cfg: Config, vgg_params: Optional[Any] = None,
     def g_losses(fake_b, pred_fake_g, pred_real, real_a, real_b, step):
         with jax.named_scope("loss_gan"):
             l_gan = gan_loss(pred_fake_g, True, L.gan_mode,
-                             for_discriminator=False)
+                             for_discriminator=False,
+                             scale_mean=L.gan_scale_mean)
         parts = {"g_gan": l_gan}
         total = l_gan
         if L.lambda_feat > 0:
@@ -289,17 +292,22 @@ def build_train_step(
     health_guard = cfg.health.enabled
     ema_decay = cfg.health.ema_decay
 
-    def g_fwd(params, bstats, quant, x, rng=None):
+    def g_fwd(params, bstats, quant, x, rng=None, spectral=None):
         rngs = {"dropout": rng} if (use_dropout and rng is not None) else None
         variables = {"params": params, "batch_stats": bstats}
         mut = ["batch_stats"]
         if use_quant:
             variables["quant"] = quant
             mut.append("quant")
+        if spectral is not None:
+            # a spectrally normalised generator (models/spade.py): one
+            # power iteration a training forward, like D's
+            variables["spectral"] = spectral
+            mut.append("spectral")
         with jax.named_scope("G"):
             out, v = g.apply(variables, x, True, mutable=mut, rngs=rngs)
         return out, v["batch_stats"], (v.get("quant", {}) if use_quant
-                                       else None)
+                                       else None), v.get("spectral")
 
     def d_fwd(params, dvars, x):
         out, mut = d.apply(
@@ -310,7 +318,8 @@ def build_train_step(
     def step(state: TrainState, batch: Dict[str, jax.Array]):
         # uint8 batches (DataConfig.uint8_pipeline) normalize here — fused
         # into the first conv's input read; bit-exact with host f32 input
-        real_a = ingest(batch["input"], train_dtype)
+        # (a label-map input one-hots here instead: ingest_input)
+        real_a = ingest_input(batch["input"], cfg.model, train_dtype)
         real_b = ingest(batch["target"], train_dtype)
 
         # ---- 1. compression pre-filter + quantizer ----------------------
@@ -353,11 +362,12 @@ def build_train_step(
         # var/mean primal diverges after the first norm), silently doubling
         # the cityscapes/pix2pixHD generator cost.
         def g_primal(params_g):
-            out, bs, qg = g_fwd(params_g, state.batch_stats_g, state.quant_g,
-                                g_input, drop_rng)
-            return out, (bs, qg)
+            out, bs, qg, sg = g_fwd(params_g, state.batch_stats_g,
+                                    state.quant_g, g_input, drop_rng,
+                                    state.spectral_g)
+            return out, (bs, qg, sg)
 
-        fake_b_primal, g_vjp, (bs_g1, quant_g1) = jax.vjp(
+        fake_b_primal, g_vjp, (bs_g1, quant_g1, spectral_g1) = jax.vjp(
             g_primal, state.params_g, has_aux=True
         )
 
@@ -408,7 +418,7 @@ def build_train_step(
             loss_d, grads_d, pred_fake, pred_real, dvars2, pull = (
                 single_forward_d_losses(
                     d_fwd, dvars0, state.params_d,
-                    fake_pair, real_pair, L.gan_mode,
+                    fake_pair, real_pair, L.gan_mode, L.gan_scale_mean,
                 )
             )
 
@@ -447,8 +457,10 @@ def build_train_step(
                     pred_real, v2 = d_fwd(params_d, v1, real_pair)
                 with jax.named_scope("loss_gan"):
                     loss = 0.5 * (
-                        gan_loss(pred_fake, False, L.gan_mode)
-                        + gan_loss(pred_real, True, L.gan_mode)
+                        gan_loss(pred_fake, False, L.gan_mode,
+                                 scale_mean=L.gan_scale_mean)
+                        + gan_loss(pred_real, True, L.gan_mode,
+                                   scale_mean=L.gan_scale_mean)
                     )
                 return loss, (v2, pred_real)
 
@@ -520,6 +532,10 @@ def build_train_step(
             if use_quant:
                 quant_g1 = health_select(ok, quant_g1, state.quant_g)
                 quant_d1 = health_select(ok, quant_d1, state.quant_d)
+            if spectral_g1 is not None:
+                with jax.named_scope("opt_g"):
+                    spectral_g1 = health_select(ok, spectral_g1,
+                                                state.spectral_g)
             if use_pool:
                 pool1 = health_select(ok, pool1, state.pool)
                 pool_n1 = health_select(ok, pool_n1, state.pool_n)
@@ -546,7 +562,8 @@ def build_train_step(
                 cq, _, _ = compressed_fn(params_c)
                 c_rng = (jax.random.fold_in(drop_rng, 1)
                          if drop_rng is not None else None)
-                fake_ac, bs2, _ = g_fwd(params_g1, bs_g1, quant_g1, cq, c_rng)
+                fake_ac, bs2, _, _ = g_fwd(params_g1, bs_g1, quant_g1, cq,
+                                           c_rng, spectral_g1)
                 loss = jnp.mean(
                     (fake_ac.astype(jnp.float32) - real_b.astype(jnp.float32)) ** 2
                 )
@@ -601,6 +618,7 @@ def build_train_step(
             quant_d=quant_d1,
             quant_c=quant_c1,
             ema_g=ema_g1,
+            spectral_g=spectral_g1,
         )
         metrics = {
             "loss_d": loss_d.astype(jnp.float32),
@@ -1008,7 +1026,7 @@ def make_infer_forward(cfg: Config, train_dtype=None,
     bits = cfg.model.quant_bits
 
     def fwd(state, batch: Dict[str, jax.Array]):
-        real_a = ingest(batch["input"], train_dtype)
+        real_a = ingest_input(batch["input"], cfg.model, train_dtype)
         if cfg.model.use_compression_net:
             real_b = ingest(batch["target"], train_dtype)
             c_vars = {"params": state.params_c,
@@ -1025,6 +1043,9 @@ def make_infer_forward(cfg: Config, train_dtype=None,
                   "batch_stats": state.batch_stats_g}
         if cfg.model.int8_delayed:
             g_vars["quant"] = state.quant_g
+        if getattr(state, "spectral_g", None) is not None:
+            # read-only here: the weight served is W / sigma(u)
+            g_vars["spectral"] = state.spectral_g
         pred = g.apply(g_vars, g_in, False)
         metrics = {}
         if with_metrics:

@@ -36,6 +36,79 @@ def ingest(x, train_dtype=None):
     return x
 
 
+def one_hot_labels(x, classes: int, edge: bool, dtype=None):
+    """A label map as the loader ships it — integer ``(..., H, W, 2)``:
+    class id, instance-edge bit — to the conditioning map the nets see:
+    ``classes`` one-hot channels (+ the edge bit as one more), exact 0/1
+    in ``dtype`` (float32 when None). An id outside ``[0, classes)`` gives
+    an all-zero pixel. Works on jax and numpy arrays (returns jnp)."""
+    import jax.numpy as jnp
+
+    dtype = dtype or jnp.float32
+    ids = jnp.asarray(x[..., :1])
+    m = (ids == jnp.arange(classes, dtype=ids.dtype)).astype(dtype)
+    if edge:
+        m = jnp.concatenate([m, (x[..., 1:2] != 0).astype(dtype)], axis=-1)
+    return m
+
+
+def ingest_input(x, model, train_dtype=None):
+    """``batch["input"]`` as the configuration's nets take it: a label-map
+    configuration (``ModelConfig.label_classes`` > 0) one-hots its integer
+    map on the device — class ids are never centred at 127.5 —, every
+    other goes through :func:`ingest`."""
+    if model.label_classes:
+        if not np.issubdtype(np.dtype(x.dtype), np.integer):
+            raise TypeError("a label-map input is an integer map of class "
+                            f"ids, got {x.dtype}")
+        return one_hot_labels(x, model.label_classes, model.label_edge,
+                              train_dtype)
+    return ingest(x, train_dtype)
+
+
+def wire_spec(cfg, key: str = "input"):
+    """``((H, W, C), dtype)`` of one item of ``batch[key]`` as the loader
+    of ``cfg`` ships it: what every dummy batch (lint, the audits, the
+    serving templates and buckets) has to be built from. A label-map
+    input is uint8 ``(H, W, 2)`` whatever ``uint8_pipeline`` says."""
+    h, w = cfg.image_hw
+    if key == "input" and cfg.model.label_classes:
+        return (h, w, 2), np.dtype(np.uint8)
+    nc = cfg.model.input_nc if key == "input" else cfg.model.output_nc
+    return (h, w, nc), np.dtype(
+        np.uint8 if cfg.data.uint8_pipeline else np.float32)
+
+
+def dummy_batch(cfg, lead=(1,), dtype=None, abstract: bool = False):
+    """``{"input", "target"}`` in the shapes and dtypes the loader of
+    ``cfg`` ships (:func:`wire_spec`) behind the leading axes ``lead``:
+    zeros, or ``jax.ShapeDtypeStruct``s with ``abstract``. ``dtype``
+    overrides the wire dtype (the sites that always trace uint8). The
+    one place lint, the audits and the serving templates get a stand-in
+    batch from, so a label-map configuration is never handed an image."""
+    out = {}
+    for key in ("input", "target"):
+        hwc, wire = wire_spec(cfg, key)
+        shape, dt = tuple(lead) + hwc, np.dtype(dtype or wire)
+        if abstract:
+            import jax
+
+            out[key] = jax.ShapeDtypeStruct(shape, dt)
+        else:
+            out[key] = np.zeros(shape, dt)
+    return out
+
+
+def label_preview(x) -> np.ndarray:
+    """A label map ``(H, W, 2)`` as an RGB uint8 picture (a fixed colour a
+    class, edges white) for the sample dumps."""
+    ids = np.asarray(x[..., 0], np.uint32)
+    rgb = np.stack([(ids * 67 + 29) % 256, (ids * 131 + 71) % 256,
+                    (ids * 197 + 113) % 256], axis=-1).astype(np.uint8)
+    rgb[np.asarray(x[..., 1]) != 0] = 255
+    return rgb
+
+
 def to_uint8_img(x) -> np.ndarray:
     """[-1,1] float HWC → uint8 HWC. uint8 input passes through unscaled
     (already-converted images, e.g. the masking experiment's AND output)."""

@@ -87,6 +87,40 @@ def test_loader_deterministic_under_seed(tmp_path):
     np.testing.assert_allclose(b1, b2)
 
 
+@pytest.mark.parametrize("bs, drop, skip, epochs", [
+    (2, True, 0, 1), (3, True, 0, 1), (3, False, 0, 1), (2, True, 2, 1),
+    (3, False, 1, 2)])
+def test_full_memo_loader_gives_the_dataloaders_batches(tmp_path, bs, drop,
+                                                        skip, epochs):
+    """Once every item is a memo hit ``make_loader`` stacks the batches in
+    its own thread, in the sampler's order: the same batches, in the same
+    order, as Grain's DataLoader gives from an empty memo (so a resumed
+    process, whose memo is empty, continues the epoch it left)."""
+    pytest.importorskip("grain.python")
+    make_synthetic_dataset(str(tmp_path), n_train=11, n_test=0, size=16)
+    kw = dict(shuffle=True, seed=5, num_epochs=epochs, drop_remainder=drop,
+              skip_batches=skip)
+    cold = PairedImageDataset(str(tmp_path), image_size=16, cache=True)
+    assert not cold.memo_full
+    through_grain = list(make_loader(cold, bs, **kw))
+    warm = PairedImageDataset(str(tmp_path), image_size=16, cache=True)
+    [warm[i] for i in range(len(warm))]
+    assert warm.memo_full
+    in_thread = make_loader(warm, bs, **kw)
+    assert type(in_thread).__name__ == "generator"
+    in_thread = list(in_thread)
+    assert len(in_thread) == len(through_grain) > 0
+    for a, b in zip(in_thread, through_grain):
+        assert a.keys() == b.keys()
+        for k in a:
+            assert a[k].dtype == b[k].dtype
+            np.testing.assert_array_equal(a[k], b[k])
+    # a split that is not memoised stays on the DataLoader
+    plain = PairedImageDataset(str(tmp_path), image_size=16, cache=False)
+    list(make_loader(plain, bs))
+    assert not plain.memo_full
+
+
 def test_synthetic_batch_shapes():
     b = synthetic_batch(batch_size=2, size=64)
     assert b["input"].shape == (2, 64, 64, 3)

@@ -41,6 +41,11 @@ def test_preset_trains_two_steps(preset):
         k: jnp.asarray(rng.uniform(-1, 1, (2, 32, 32, 3)), jnp.float32)
         for k in ("input", "target")
     }
+    if cfg.model.label_classes:
+        # a label-map preset reads class ids + an edge bit, not an image
+        batch["input"] = jnp.asarray(np.stack(
+            [rng.integers(0, cfg.model.label_classes, (2, 32, 32)),
+             rng.integers(0, 2, (2, 32, 32))], -1), jnp.uint8)
     state = create_train_state(cfg, jax.random.key(0), batch)
     step = build_train_step(cfg)
     losses = []
