@@ -12,6 +12,7 @@ same choice the reference made to avoid checkerboard artifacts.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -187,23 +188,120 @@ def _reflect_pad_h_sharded(x: jax.Array, pad: int, mesh) -> jax.Array:
                          out_specs=spec)(x)
 
 
+#: how a ``reflect_pad_2d`` call site's backward is built
+REFLECT_PAD_BACKWARDS = ("one_pass", "one_pass_w", "autodiff")
+
+
+def reflect_pad_sites() -> dict:
+    """backward -> ``reflect_pad_2d`` call sites traced with it so far in
+    this process (``reflect_pad_sites_total{backward=...}``, counted like
+    :func:`conv_form_sites`)."""
+    from p2p_tpu.obs.registry import get_registry
+
+    reg = get_registry()
+    return {b: int(reg.counter("reflect_pad_sites_total", backward=b).value)
+            for b in REFLECT_PAD_BACKWARDS}
+
+
+def _reflect_fold(g: jax.Array, pad: int, axes: tuple) -> jax.Array:
+    """The transpose of a reflect pad of ``axes`` by ``pad``, in float32:
+    along the first axis the centre band plus the two border strips,
+    reversed and zero-filled onto the rows 1..pad and -pad-1..-2 they
+    were copied from, every band folded along the remaining axes FIRST.
+    So the corners reach the strips (``pad`` rows: small) before the
+    strips are placed, and the terms the size of the tensor (the centre
+    and, per axis, two placed strips) are independent of each other: one
+    sum, where autodiff's fold of each axis reads the fold before it."""
+    if not axes:
+        return g.astype(jnp.float32)
+    axis, rest = axes[0], axes[1:]
+    n = g.shape[axis] - 2 * pad
+
+    def band(lo, hi):
+        return _reflect_fold(jax.lax.slice_in_dim(g, lo, hi, axis=axis),
+                             pad, rest)
+
+    def placed(strip, low):
+        config = [(0, 0, 0)] * g.ndim
+        config[axis] = (low, n - pad - low, 0)
+        return jax.lax.pad(jax.lax.rev(strip, (axis,)),
+                           jnp.zeros((), strip.dtype), config)
+
+    return (band(pad, pad + n) + placed(band(0, pad), 1)
+            + placed(band(pad + n, n + 2 * pad), n - pad - 1))
+
+
+def _jnp_reflect_pad(x: jax.Array, pad: int, axes: tuple) -> jax.Array:
+    widths = [(pad, pad) if a in axes else (0, 0) for a in range(x.ndim)]
+    return jnp.pad(x, widths, mode="reflect")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _reflect_pad(x: jax.Array, pad: int, axes: tuple) -> jax.Array:
+    """``jnp.pad(mode="reflect")`` of ``axes`` by ``pad`` (less than the
+    extent), with a backward that reads the padded cotangent once
+    (:func:`_reflect_fold`: up to four contributions of an element summed
+    in float32 and rounded once) where autodiff chains two slice-add
+    passes over the whole tensor an axis."""
+    return _jnp_reflect_pad(x, pad, axes)
+
+
+def _reflect_pad_fwd(x, pad, axes):
+    return _reflect_pad(x, pad, axes), None
+
+
+def _reflect_pad_bwd(pad, axes, _, g):
+    # traced under the forward's name stack: the scope ``reflect_pad``
+    return (_reflect_fold(g, pad, axes).astype(g.dtype),)
+
+
+_reflect_pad.defvjp(_reflect_pad_fwd, _reflect_pad_bwd)
+
+
 def reflect_pad_2d(x: jax.Array, pad: int) -> jax.Array:
-    """Reflection-pad H and W of an NHWC tensor. Inside a step whose mesh
-    shards H (``core.mesh.current_mesh`` with ``spatial`` > 1) a pad of two
-    rows or more is built shard by shard (:func:`_reflect_pad_h_sharded`)
-    where the padded rows split evenly; one row needs no reverse and
-    stays GSPMD's."""
+    """Reflection-pad H and W of an NHWC tensor, under the named scope
+    ``reflect_pad``; the values are ``jnp.pad(mode="reflect")``'s.
+
+    The backward is one pass over the padded cotangent
+    (:func:`_reflect_pad`) along the axes no mesh shards: H and W, or W
+    alone inside a step whose mesh shards H (``core.mesh.current_mesh``
+    with ``spatial`` > 1), because the fold REVERSES strips, and a reverse
+    along a sharded dimension is what GSPMD answers by re-sharding the
+    whole tensor. There H keeps what it had: a pad of two rows or more is
+    built shard by shard (:func:`_reflect_pad_h_sharded`) where the padded
+    rows split evenly; one row needs no reverse and stays GSPMD's. A pad
+    as large as the extent (``jnp.pad`` reflects it again and again) keeps
+    autodiff's backward. Which of the three a traced call site took is
+    counted in ``reflect_pad_sites_total{backward=...}``."""
     if pad == 0:
         return x
-    from p2p_tpu.core.mesh import SPATIAL_AXIS, spatial_shard_mesh
+    from p2p_tpu.core.mesh import (
+        SPATIAL_AXIS,
+        current_mesh,
+        spatial_shard_mesh,
+    )
+    from p2p_tpu.obs.registry import get_registry
 
-    mesh = spatial_shard_mesh(x) if pad >= 2 else None
-    s = mesh.shape[SPATIAL_AXIS] if mesh is not None else 1
-    if s > 1 and (2 * pad) % s == 0 and x.shape[1] // s > pad:
-        x = _reflect_pad_h_sharded(x, pad, mesh)
-        return jnp.pad(x, ((0, 0), (0, 0), (pad, pad), (0, 0)),
-                       mode="reflect")
-    return jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)), mode="reflect")
+    mesh = current_mesh()
+    s = mesh.shape.get(SPATIAL_AXIS, 1) if mesh is not None else 1
+    if pad >= min(x.shape[1], x.shape[2]):
+        backward, axes = "autodiff", ()
+    elif s > 1:
+        backward, axes = "one_pass_w", (2,)
+    else:
+        backward, axes = "one_pass", (1, 2)
+    get_registry().counter("reflect_pad_sites_total", backward=backward).inc()
+    with jax.named_scope("reflect_pad"):
+        if 1 not in axes:
+            if (s > 1 and pad >= 2 and (2 * pad) % s == 0
+                    and x.shape[1] // s > pad
+                    and spatial_shard_mesh(x) is not None):
+                x = _reflect_pad_h_sharded(x, pad, mesh)
+            else:
+                x = _jnp_reflect_pad(x, pad, (1,))
+        if 2 not in axes:
+            x = _jnp_reflect_pad(x, pad, (2,))
+        return _reflect_pad(x, pad, axes) if axes else x
 
 
 def normal_init(stddev: float = 0.02):

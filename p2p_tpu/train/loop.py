@@ -62,7 +62,7 @@ from p2p_tpu.obs import (
     write_manifest,
 )
 from p2p_tpu.losses.perceptual import vgg_loss_traces
-from p2p_tpu.ops.conv import conv_form_sites
+from p2p_tpu.ops.conv import conv_form_sites, reflect_pad_sites
 from p2p_tpu.resilience import Preempted, PreemptionGuard
 from p2p_tpu.resilience.chaos import FaultInjected, chaos_point
 from p2p_tpu.resilience.health import DivergenceError
@@ -1542,13 +1542,15 @@ class Trainer:
             record[f"{phase}_s"] = round(secs, 6)
             record[f"slowest_{phase}_s"] = round(slowest[phase][0], 6)
             record[f"slowest_{phase}_step"] = slowest[phase][1]
-        # which form the thin convolutions took and which dtype VGG19's
-        # activations were stored in (ops/conv.py and losses/perceptual.py
-        # count as they are traced): one record each whenever a trace
-        # added some
+        # which form the thin convolutions took, how the reflect pads'
+        # backward was built and which dtype VGG19's activations were
+        # stored in (ops/conv.py and losses/perceptual.py count as they are
+        # traced): one record each whenever a trace added some
         logged = self._trace_counts_logged
         for kind, name, counts in (
                 ("conv_forms", "conv_form_sites_total", conv_form_sites()),
+                ("reflect_pad", "reflect_pad_sites_total",
+                 reflect_pad_sites()),
                 ("vgg_loss", "vgg_loss_traces_total", vgg_loss_traces())):
             if counts != logged.get(kind) and any(counts.values()):
                 logged[kind] = counts

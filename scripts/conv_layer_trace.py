@@ -14,7 +14,10 @@ costliest ops with their result shapes (an input gradient and a weight
 gradient are both "backward"; their shapes tell them apart), and
 ``blocked_conv_ms`` and ``nearest_up2_ms``, the device milliseconds a
 step under the ``blocked_conv`` and ``nearest_up2`` scopes of
-``ops/conv.py`` (0 on a program without the scope).
+``ops/conv.py`` (0 on a program without the scope), and
+``reflect_pad_ms``: the same under the scope ``reflect_pad``
+(``reflect_pad_2d``, the pad and its backward), in all and by net and
+direction, whichever layers ``--layers`` names.
 A fusion is counted where its root instruction's ``op_name`` points, so a
 norm's backward fused into a convolution's gradient counts as that layer.
 """
@@ -67,9 +70,12 @@ def by_layer(xplane_path, hlo_text, layers, top=4):
 
     from benchmark import scope_time, trace_reduce
 
-    keys = layer_keys(hlo_text, scope_time.program_scopes(), layers)
+    scopes = scope_time.program_scopes()
+    keys = layer_keys(hlo_text, scopes, layers)
+    pads = {name: f"{net}|{direction}" for name, (net, _, direction, _)
+            in layer_keys(hlo_text, scopes, "reflect_pad").items()}
     module = scope_time.module_name(hlo_text)
-    total, ops_s, executions, chips = {}, {}, 0, 0
+    total, ops_s, pad_ns, executions, chips = {}, {}, {}, 0, 0
     form_ns = dict.fromkeys(FORM_SCOPES, 0.0)
     for plane in ProfileData.from_file(xplane_path).planes:
         if not trace_reduce.DEVICE_PLANE.match(plane.name):
@@ -93,7 +99,12 @@ def by_layer(xplane_path, hlo_text, layers, top=4):
             if i < 0 or ev.start_ns >= runs[i][1]:
                 continue
             name, _, opcode = trace_reduce.parse_op(ev.name)
-            if opcode in trace_reduce.CONTAINER_OPCODES or name not in keys:
+            if opcode in trace_reduce.CONTAINER_OPCODES:
+                continue
+            if name in pads:
+                pad_ns[pads[name]] = pad_ns.get(pads[name], 0.0) \
+                    + ev.duration_ns
+            if name not in keys:
                 continue
             net, layer, direction, form = keys[name]
             key = f"{net}|{layer}|{direction}"
@@ -110,6 +121,9 @@ def by_layer(xplane_path, hlo_text, layers, top=4):
         "steps": executions // chips,
         "blocked_conv_ms": form_ns["blocked_conv"] * per_step,
         "nearest_up2_ms": form_ns["nearest_up2"] * per_step,
+        "reflect_pad_ms": {
+            "all": sum(pad_ns.values()) * per_step,
+            **{key: ns * per_step for key, ns in sorted(pad_ns.items())}},
         "layer_ms": {
             key: {"ms": ns * per_step,
                   "ops": [[label, v * per_step] for label, v in sorted(

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from p2p_tpu import obs
+from p2p_tpu.core.config import list_presets
 from p2p_tpu.obs.registry import combine_host_snapshots
 
 
@@ -367,6 +368,12 @@ def test_trainer_obs_wiring(tmp_path, monkeypatch):
             lambda a: UpsampleConvLayer(4, kernel_size=3, upsample=2).init(
                 jax.random.key(0), a),
             jax.ShapeDtypeStruct((1, 256, 256, 4), jnp.float32))
+        # ... and a reflect-padded k3 layer
+        from p2p_tpu.ops.conv import ConvLayer
+
+        jax.eval_shape(
+            lambda a: ConvLayer(4, kernel_size=3).init(jax.random.key(0), a),
+            jax.ShapeDtypeStruct((1, 16, 16, 4), jnp.float32))
         tr.fit()
 
         manifest = json.load(open(tmp_path / "manifest_obswire.json"))
@@ -385,6 +392,11 @@ def test_trainer_obs_wiring(tmp_path, monkeypatch):
         forms = [r for r in recs if r["kind"] == "conv_forms"][-1]
         assert forms["conv_form_sites_total.nearest_up2"] >= 1
         assert set(forms) >= {"conv_form_sites_total.blocked"}
+        # ... how its reflect pads' backward was built
+        pads = [r for r in recs if r["kind"] == "reflect_pad"][-1]
+        assert pads["reflect_pad_sites_total.one_pass"] >= 1
+        assert set(pads) >= {"reflect_pad_sites_total.one_pass_w",
+                             "reflect_pad_sites_total.autodiff"}
         # ... and which dtype VGG19 stored
         (vgg,) = [r for r in recs if r["kind"] == "vgg_loss"]
         assert vgg["vgg_loss_traces_total.bfloat16"] >= 1
@@ -625,6 +637,15 @@ def test_conv_layer_trace_reads_device_time_by_layer_and_direction():
     want = scope_time.by_scope(trace, text, ("net_a", "net_b"))
     assert got["steps"] == want["executions"] == 3
     assert got["blocked_conv_ms"] == got["nearest_up2_ms"] == 0.0
+    assert got["reflect_pad_ms"] == {"all": 0.0}
+    # the reflect pad's scope is read whichever layers were asked for
+    padded = tool.by_layer(trace, text.replace("net_b/", "net_b/reflect_pad/"),
+                           "net_a", top=2)
+    assert list(padded["layer_ms"]) == ["unscoped|net_a|fwd"]
+    assert padded["reflect_pad_ms"]["all"] == pytest.approx(
+        got["layer_ms"]["unscoped|net_b|fwd"]["ms"], rel=1e-9)
+    assert padded["reflect_pad_ms"]["unscoped|fwd"] == \
+        padded["reflect_pad_ms"]["all"]
     up2 = tool.by_layer(trace, scoped, "net_a|net_b", top=2)
     assert up2["blocked_conv_ms"] == 0.0
     assert up2["nearest_up2_ms"] == pytest.approx(
@@ -634,3 +655,52 @@ def test_conv_layer_trace_reads_device_time_by_layer_and_direction():
         assert row["ms"] == pytest.approx(
             1000.0 * want["scope_s"][net] / 3, rel=1e-9)
         assert len(row["ops"]) == 2 and row["ops"][0][1] >= row["ops"][1][1]
+
+
+# preset -> the reflect-padded sites of G (and C where the preset has one),
+# traced at the preset's own extent: ExpandNetwork 1 stem + 2 stride-2 + 18
+# in the residual blocks + 1 head and the compression net's 3; pix2pixHD's
+# enhancer and G1; the ResNet generator of cityscapes_spatial; the U-Nets
+# pad with zeros (facades_int8_full's 3 are its compression net's), and so
+# does SPADE.
+REFLECT_PAD_SITES = {"reference": 25, "pix2pixhd": 35,
+                     "cityscapes_spatial": 23, "facades_int8_full": 3}
+
+
+@pytest.mark.parametrize("spatial", [1, 2], ids=["one_device", "spatial2"])
+@pytest.mark.parametrize("preset", list_presets())
+def test_every_preset_builds_its_reflect_pads_backward_in_one_pass(
+        preset, spatial, devices8):
+    """G and C traced abstractly at the preset's own extent: every
+    ``reflect_pad_2d`` call site ticks
+    ``reflect_pad_sites_total{backward=...}`` once, ``one_pass`` on one
+    device and ``one_pass_w`` inside a mesh that shards H, none keeps
+    autodiff's four chained passes, and a preset that pads with zeros
+    ticks nothing (PR 33: the gain cannot be lost silently)."""
+    import contextlib
+
+    from p2p_tpu.core.config import get_preset
+    from p2p_tpu.core.mesh import MeshSpec, make_mesh, mesh_context
+    from p2p_tpu.ops.conv import reflect_pad_sites
+    from p2p_tpu.train.state import build_models
+
+    cfg = get_preset(preset)
+    g, _, c = build_models(cfg, jnp.bfloat16)
+    h = cfg.data.image_size
+    x = jax.ShapeDtypeStruct(
+        (2, h, cfg.data.image_width or h, cfg.model.input_nc), jnp.bfloat16)
+    inside = contextlib.nullcontext() if spatial == 1 else mesh_context(
+        make_mesh(MeshSpec(data=1, spatial=spatial),
+                  devices=devices8[:spatial]))
+    before = reflect_pad_sites()
+    with inside:
+        for net in (g, c):
+            if net is not None:
+                jax.eval_shape(
+                    lambda x, net=net: net.init(jax.random.key(0), x, False),
+                    x)
+    ticks = {k: v - before[k] for k, v in reflect_pad_sites().items()}
+    want = dict.fromkeys(ticks, 0)
+    want["one_pass" if spatial == 1 else "one_pass_w"] = \
+        REFLECT_PAD_SITES.get(preset, 0)
+    assert ticks == want

@@ -76,6 +76,92 @@ def test_reflect_pad_matches_torch():
     np.testing.assert_allclose(ours, ref, rtol=1e-6)
 
 
+def _plain_reflect_pad(x, pad):
+    return jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)),
+                   mode="reflect")
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("extent", ["square", "wide", "smallest"])
+@pytest.mark.parametrize("pad", [1, 2, 3, 4])
+def test_reflect_pad_backward_is_autodiffs(pad, extent, dtype):
+    """``reflect_pad_2d``'s one-pass backward (PR 33) against autodiff of
+    ``jnp.pad(mode="reflect")``: the forward to the last bit; the
+    gradient to 1e-6 in float32, and in bf16 within one rounding of the
+    float32 truth and no farther from it than autodiff's chained bf16
+    adds; the same under ``jit`` + ``jax.checkpoint``; and a second-order
+    gradient through it. ``smallest`` is the least extent a reflect pad
+    is defined for, ``pad + 1``."""
+    from p2p_tpu.ops.conv import reflect_pad_sites
+
+    h, w = {"square": (12, 12), "wide": (9, 14),
+            "smallest": (pad + 1, pad + 1)}[extent]
+    x32 = jnp.asarray(rng(2, h, w, 3, seed=pad))
+    g32 = jnp.asarray(rng(2, h + 2 * pad, w + 2 * pad, 3, seed=pad + 10))
+    x, g = x32.astype(dtype), g32.astype(dtype)
+    before = reflect_pad_sites()
+    ours, pull = jax.vjp(lambda a: reflect_pad_2d(a, pad), x)
+    assert {k: v - before[k] for k, v in reflect_pad_sites().items()} == {
+        "one_pass": 1, "one_pass_w": 0, "autodiff": 0}
+    plain, pull_plain = jax.vjp(lambda a: _plain_reflect_pad(a, pad), x)
+    assert ours.dtype == plain.dtype and bool(jnp.all(ours == plain))
+    dx, = pull(g)
+    assert dx.dtype == x.dtype
+    chain = np.asarray(pull_plain(g)[0], np.float32)
+    truth = np.asarray(jax.vjp(lambda a: _plain_reflect_pad(a, pad), x32)[1](
+        g.astype(jnp.float32))[0])
+    got = np.asarray(dx, np.float32)
+    if dtype == "float32":
+        np.testing.assert_allclose(got, truth, rtol=1e-6, atol=1e-6)
+    else:
+        assert np.all(np.abs(got - truth) <= 2.0 ** -8 * np.abs(truth) + 1e-30)
+        assert np.abs(got - truth).max() <= np.abs(chain - truth).max()
+
+    def loss(f):
+        return lambda a: jnp.sum(jnp.sin(f(a, pad).astype(jnp.float32))
+                                 * g32)
+
+    tol = dict(rtol=1e-5, atol=1e-5) if dtype == "float32" else dict(
+        rtol=0.05, atol=0.05)
+    remat = jax.jit(jax.grad(jax.checkpoint(loss(reflect_pad_2d))))(x)
+    np.testing.assert_allclose(
+        np.asarray(remat, np.float32),
+        np.asarray(jax.grad(loss(_plain_reflect_pad))(x), np.float32), **tol)
+
+    def second(f):
+        return jax.grad(lambda a: jnp.sum(
+            jax.grad(loss(f))(a).astype(jnp.float32) ** 2))
+
+    np.testing.assert_allclose(
+        np.asarray(second(reflect_pad_2d)(x), np.float32),
+        np.asarray(second(_plain_reflect_pad)(x), np.float32), **tol)
+
+
+def test_reflect_pad_scope_holds_the_backward_too():
+    """The custom backward is traced under the forward's name stack, so
+    the scope ``reflect_pad`` names both directions in the lowered text
+    (``scripts/conv_layer_trace.py``'s ``reflect_pad_ms`` reads it)."""
+    text = jax.jit(jax.grad(lambda a: jnp.sum(reflect_pad_2d(a, 2) ** 2))
+                   ).lower(jnp.ones((1, 6, 6, 2))).as_text(debug_info=True)
+    assert "jvp(reflect_pad)/jit(_pad)" in text
+    assert "transpose(jvp(reflect_pad))/add" in text
+
+
+def test_reflect_pad_as_large_as_the_extent_keeps_autodiff():
+    """``jnp.pad`` reflects a pad of the extent or more again and again;
+    the one-pass fold is written for one reflection, so such a site
+    keeps autodiff's backward and says so in the counter."""
+    from p2p_tpu.ops.conv import reflect_pad_sites
+
+    x = jnp.asarray(rng(1, 3, 6, 2))
+    before = reflect_pad_sites()["autodiff"]
+    ours, pull = jax.vjp(lambda a: reflect_pad_2d(a, 4), x)
+    assert reflect_pad_sites()["autodiff"] == before + 1
+    plain, pull_plain = jax.vjp(lambda a: _plain_reflect_pad(a, 4), x)
+    np.testing.assert_array_equal(ours, plain)
+    np.testing.assert_array_equal(pull(plain)[0], pull_plain(plain)[0])
+
+
 @pytest.mark.slow
 def test_conv_layer_shapes():
     x = jnp.asarray(rng(2, 16, 16, 3))

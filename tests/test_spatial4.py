@@ -98,6 +98,63 @@ def test_reflect_pad_of_a_sharded_height(devices8, axes, pad, sharded_path):
     # of a reverse depends on the backend's simplifier
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("axes, pad, sharded_path", [
+    (dict(data=2, spatial=2), 3, True),     # the k7 layers
+    (dict(data=2, spatial=2), 4, True),     # a k9 layer
+    (dict(data=1, spatial=2), 3, True),
+    (dict(data=2, spatial=4), 2, True),
+    (dict(data=2, spatial=4), 3, False),    # 6 rows do not split in 4
+    (dict(data=2, spatial=2), 1, False),    # one row: every k3 layer
+], ids=lambda v: str(v).replace(" ", "") if not isinstance(v, bool) else
+    ("shard_map" if v else "gspmd"))
+def test_reflect_pad_gradient_of_a_sharded_height(devices8, axes, pad,
+                                                  sharded_path, dtype):
+    """The backward of ``reflect_pad_2d`` under a mesh that shards H
+    (PR 33): the site counts as ``one_pass_w`` (the one-pass fold along W
+    alone: a fold reverses strips, and H is sharded), value and gradient
+    equal the unsharded ones (the one-pass fold of both axes on one
+    device, and autodiff of ``jnp.pad``), and with the shard-by-shard H
+    half the compiled value-and-grad still moves halo rows only."""
+    from p2p_tpu.ops.conv import reflect_pad_2d, reflect_pad_sites
+
+    mesh = _mesh(devices8, **axes)
+    x = jax.random.normal(jax.random.key(0), (2, 64, 48, 8)).astype(dtype)
+    weight = jax.random.normal(
+        jax.random.key(1), (2, 64 + 2 * pad, 48 + 2 * pad, 8))
+
+    def loss(f):
+        return lambda a: jnp.sum(jnp.sin(f(a).astype(jnp.float32)) * weight)
+
+    def plain(a):
+        return jnp.pad(a, ((0, 0), (pad, pad), (pad, pad), (0, 0)),
+                       mode="reflect")
+
+    def in_mesh(a):
+        with mesh_context(mesh):
+            return reflect_pad_2d(a, pad)
+
+    jitted = jax.jit(jax.value_and_grad(loss(in_mesh)),
+                     in_shardings=batch_sharding(mesh))
+    before = reflect_pad_sites()
+    value, grad = jitted(x)
+    assert {k: v - before[k] for k, v in reflect_pad_sites().items()} == {
+        "one_pass": 0, "one_pass_w": 1, "autodiff": 0}
+    tol = dict(rtol=1e-6, atol=1e-6) if dtype == "float32" else dict(
+        rtol=2.0 ** -6, atol=2.0 ** -6)    # H keeps chained bf16 adds
+    for unsharded in (lambda a: reflect_pad_2d(a, pad), plain):
+        want_value, want = jax.value_and_grad(loss(unsharded))(x)
+        # a float32 sum of 70k terms in another order
+        np.testing.assert_allclose(float(value), float(want_value),
+                                   rtol=1e-4, atol=1e-3)
+        np.testing.assert_allclose(np.asarray(grad, np.float32),
+                                   np.asarray(want, np.float32), **tol)
+    if sharded_path:
+        census = collect_collectives(jitted.lower(x).compile().as_text())
+        assert census["collective-permute"] and not census["all-to-all"] \
+            and not census["all-gather"], dict(census)
+
+
 # --------------------------------------------- the generator's layer forms
 
 
@@ -279,6 +336,13 @@ def test_sharded_trainer_step_against_the_plain_reference(devices8, capsys,
     # the check's own jit of the generator sees no mesh and takes the XLA
     # norm, as on the chips
     monkeypatch.setenv("P2P_TPU_FORCE_PALLAS", "1")
+    # the driver points this process's environment at the cell's compile
+    # cache (harness.prepare_jax_env): given back at teardown, or a later
+    # test of this worker that names its own cache directory is refused
+    # (tests/test_serve.py, core/cache.resolve_cache_dir)
+    for name in ("JAX_COMPILATION_CACHE_DIR",
+                 "JAX_COMPILATION_CACHE_MAX_SIZE"):
+        monkeypatch.setenv(name, os.environ.get(name, ""))
     bench = os.path.join(harness.BENCH_DIR, "tests", "cells",
                          "SPATIAL4.json")
     cell = harness.load_cell(
