@@ -28,6 +28,29 @@ def with_label_classes(model, classes):
         input_nc=classes + int(model.label_edge))
 
 
+def add_vq_flags(parser) -> None:
+    """The sizes of the ``vqgan`` generator a run may shrink (train and
+    infer name the same ones; the preset's are the published model's)."""
+    parser.add_argument("--vq_ch_mult", type=str, default=None,
+                        help="vqgan presets: channel multipliers a level, "
+                             "e.g. 1,1,2,2,4")
+    parser.add_argument("--vq_res_blocks", type=int, default=None)
+    parser.add_argument("--vq_codes", type=int, default=None)
+    parser.add_argument("--vq_embed_dim", type=int, default=None,
+                        help="vqgan presets: the codebook's width, and "
+                             "the latent's channels with it")
+
+
+def with_vq_sizes(model, args):
+    """The ``add_vq_flags`` values on a ``ModelConfig`` (flags not given
+    leave the preset's)."""
+    mult = (tuple(int(m) for m in args.vq_ch_mult.split(","))
+            if args.vq_ch_mult else None)
+    return apply_overrides(
+        model, vq_ch_mult=mult, vq_res_blocks=args.vq_res_blocks,
+        vq_codes=args.vq_codes, vq_embed_dim=args.vq_embed_dim)
+
+
 def apply_overrides(obj, **kw):
     """dataclasses.replace with None-valued (unset flag) entries dropped —
     the shared preset-override rule for every CLI."""

@@ -52,6 +52,9 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--label_classes", type=int, default=None,
                    help="label-map presets: the number of class ids the "
                         "checkpoint was trained with")
+    from p2p_tpu.cli import add_vq_flags
+
+    add_vq_flags(p)
     p.add_argument("--metrics", action="store_true",
                    help="also print mean/max PSNR+SSIM vs the targets")
     p.add_argument("--ema_decay", type=float, default=None,
@@ -128,11 +131,11 @@ def main(argv=None) -> int:
     data = over(cfg.data, dataset=args.dataset, direction=args.direction,
                 test_batch_size=args.batch_size, image_size=args.image_size,
                 image_width=args.image_width)
-    from p2p_tpu.cli import with_label_classes
+    from p2p_tpu.cli import with_label_classes, with_vq_sizes
 
-    model = with_label_classes(
+    model = with_vq_sizes(with_label_classes(
         over(cfg.model, ngf=args.ngf, n_blocks=args.n_blocks),
-        args.label_classes)
+        args.label_classes), args)
     health = over(cfg.health, ema_decay=args.ema_decay)
     cfg = dataclasses.replace(cfg, data=data, model=model, health=health,
                               name=args.name or cfg.name)

@@ -207,6 +207,9 @@ def build_parser() -> argparse.ArgumentParser:
                    help="label-map presets: the number of class ids of "
                         "the conditioning map (sets input_nc = classes + "
                         "the edge channel)")
+    from p2p_tpu.cli import add_vq_flags
+
+    add_vq_flags(p)
     p.add_argument("--ngf", type=int, default=None)
     p.add_argument("--ndf", type=int, default=None)
     p.add_argument("--lr", type=float, default=None)
@@ -294,7 +297,10 @@ def config_from_flags(args: argparse.Namespace) -> Config:
                  int8_compression=args.int8_compression,
                  int8_fused_epilogue=args.int8_fused_epilogue,
                  legacy_layout=args.legacy_layout, norm_d=args.norm_d)
-    model = with_label_classes(model, args.label_classes)
+    from p2p_tpu.cli import with_vq_sizes
+
+    model = with_vq_sizes(with_label_classes(model, args.label_classes),
+                          args)
     loss = over(loss, lambda_l1=args.lamb, lambda_vgg=args.lambda_vgg,
                 lambda_feat=args.lambda_feat, lambda_tv=args.lambda_tv,
                 lambda_sobel=args.lambda_sobel,

@@ -26,6 +26,9 @@ class ModelConfig:
     # the commented alternative at networks.py:168).
     # "spade" is the SPADE / GauGAN generator (models/spade.py): no
     # encoder, driven by a label map at every block.
+    # "vqgan" is the whole VQGAN autoencoder (models/vqgan.py): encoder,
+    # learned codebook, decoder, trained under ONE loss in the G slot;
+    # ngf is its base width ``ch``, the vq_* fields below its sizes.
     generator: str = "expand"
     input_nc: int = 3
     # Label-map conditioning (0 = the input is an image). With
@@ -150,6 +153,21 @@ class ModelConfig:
     # but the pair tensors at 1024×512 run at 26 GB/s in the round-4
     # profile, so the HD preset flips it on (round-5 ledger).
     split_d_pairs: bool = False
+    # False: D sees the image ALONE (an unconditional PatchGAN, the VQGAN
+    # lineage's ``disc_conditional: False``); True pairs it with the
+    # conditioning input, as every pix2pix-family preset does.
+    d_conditional: bool = True
+    # zero padding of D's five k4 convolutions: 2 is the reference's
+    # ceil(3/2) (networks.py:716), 1 the pix2pix / VQGAN PatchGAN's.
+    d_padding: int = 2
+    # generator="vqgan" (models/vqgan.py; the authors' ddconfig names):
+    # channel multipliers a level (one stride-2 downsampling between two
+    # levels), residual blocks a level (the decoder has one more), the
+    # codebook's size and its width, which is the latent's too.
+    vq_ch_mult: Tuple[int, ...] = (1, 1, 2, 2, 4)
+    vq_res_blocks: int = 2
+    vq_codes: int = 16384
+    vq_embed_dim: int = 256
 
 
 @dataclasses.dataclass(frozen=True)
@@ -181,6 +199,16 @@ class LossConfig:
     # vectors — the reference's commented-out experiment
     # (train.py:355-360; angular_loss at networks.py:870). 0 = off.
     lambda_angular: float = 0.0
+    # LPIPS (losses/lpips.py: VGG16 taps relu1_2 .. relu5_3, unit-
+    # normalised over channels, squared difference, a learned non-negative
+    # 1x1 head a tap, spatial mean): the VQGAN lineage's perceptual term.
+    lambda_lpips: float = 0.0
+    # > 0: the VQGAN lineage's adaptive adversarial weight. The GAN term
+    # of G's loss is scaled by this times lambda = |grad_W nll| /
+    # (|grad_W g| + 1e-4), clipped to [0, 1e4] and held constant, W the
+    # generator's last kernel, nll the terms that reach the image
+    # directly and g the term that reaches it through D (train/step.py).
+    adaptive_gan_weight: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -191,7 +219,7 @@ class OptimConfig:
     lr_d: Optional[float] = None
     beta1: float = 0.5
     beta2: float = 0.999
-    lr_policy: str = "lambda"        # lambda | step | plateau | cosine (networks.py:104)
+    lr_policy: str = "lambda"        # lambda | step | plateau | cosine (networks.py:104) | constant
     niter: int = 100                 # epochs at constant lr
     niter_decay: int = 100           # epochs of linear decay to 0
     lr_decay_iters: int = 50         # step policy period
@@ -567,6 +595,57 @@ _register(
         optim=OptimConfig(lr=1e-4, lr_d=4e-4, beta1=0.0, beta2=0.9),
         data=DataConfig(dataset="cityscapes", image_size=256,
                         image_width=512, batch_size=1),
+        parallel=ParallelConfig(mesh=MeshSpec(data=-1)),
+    )
+)
+
+
+# 7. VQGAN, the released ImageNet f16 model with 16384 codes (Esser,
+#    Rombach, Ommer 2021, arXiv:2012.09841 sec. 3.1; sizes of
+#    github.com/CompVis/taming-transformers vqgan_imagenet_f16_16384
+#    model.yaml). GN = GroupNorm(32, eps 1e-6, affine), sw(x) = x *
+#    sigmoid(x), every k3 convolution pads 1 with zeros and has a bias.
+#      Res(cin, cout)(x) = s(x) + conv3(sw(GN(conv3(sw(GN(x)))))), s the
+#        identity or conv1x1 where cin != cout.
+#      Attn(c)(x) = x + proj(softmax(q k^T c^-0.5) v), q, k, v, proj 1x1
+#        convolutions of GN(x), one head over the H*W positions.
+#      Down = conv3 stride 2 on a zero pad below and right; Up =
+#        conv3(nearest x2).
+#      Encoder, ch 128, ch_mult (1,1,2,2,4), 2 Res a level (+ Attn at
+#        extent 16), Down between levels; mid Res Attn Res;
+#        conv3(sw(GN), 512 -> 256). Decoder the mirror with 3 Res a level.
+#      Quantizer: z = conv1x1(enc); k = argmin_j |z - e_j|^2 over 16384
+#        codes of width 256; forward z + sg(e_k - z); L_q = mean((sg(e_k)
+#        - z)^2) + 0.25 mean((e_k - sg(z))^2) (the code's legacy form:
+#        beta sits on the CODEBOOK term, not on the commitment term as
+#        the paper writes it); conv1x1 after it.
+#      D: C64(s2) - C128(s2, BN) - C256(s1, BN) - 1, k4 pad 1, LeakyReLU
+#        0.2, on the image alone. Losses: nll = L1 + LPIPS; G: nll + 0.75
+#        * lambda * (-mean D(r)) + L_q, lambda the adaptive weight; D: 0.5
+#        * (hinge real + hinge fake). Adam(0.5, 0.9), lr 4.5e-6 x batch,
+#        constant.
+#    The whole autoencoder is the G slot (one loss, one optimizer); input
+#    = target. Departures: this Trainer's step (D's fake comes from the
+#    same generator forward as G's loss; the authors run the autoencoder
+#    again after its update), convolution biases start at zero (torch
+#    draws them uniform), VGG16 and the LPIPS heads are seeded.
+_register(
+    Config(
+        name="vqgan_imagenet_f16",
+        model=ModelConfig(generator="vqgan", ngf=128, norm="group_swish",
+                          ndf=64, num_D=1, n_layers_D=2, norm_d="batch",
+                          use_spectral_norm=False, get_interm_feat=False,
+                          use_compression_net=False, d_conditional=False,
+                          d_padding=1),
+        loss=LossConfig(gan_mode="hinge", lambda_feat=0.0, lambda_vgg=0.0,
+                        lambda_tv=0.0, lambda_l1=1.0, lambda_lpips=1.0,
+                        adaptive_gan_weight=0.75),
+        # 4.5e-6 x 12: the authors' base rate times configs/
+        # imagenet_vqgan.yaml's batch on one device (cli.train scales
+        # nothing: give --lr with another batch)
+        optim=OptimConfig(lr=5.4e-5, beta1=0.5, beta2=0.9,
+                          lr_policy="constant"),
+        data=DataConfig(dataset="imagenet", image_size=256, batch_size=12),
         parallel=ParallelConfig(mesh=MeshSpec(data=-1)),
     )
 )

@@ -108,6 +108,9 @@ class _PlainConv(nn.Module):
     # quantize-fused input epilogue threading (ops/int8.py QuantConv)
     epilogue: Optional[Callable] = None
     epilogue_tap: bool = False
+    # False in front of a BatchNorm (the VQGAN lineage's D: the norm's
+    # own shift stands in); the plain bf16 convolution only
+    use_bias: bool = True
     dtype: Optional[jnp.dtype] = None
 
     @nn.compact
@@ -148,6 +151,7 @@ class _PlainConv(nn.Module):
             kernel_size=(4, 4),
             strides=(self.stride, self.stride),
             padding=self.padding,
+            use_bias=self.use_bias,
             dtype=self.dtype,
             kernel_init=normal_init(),
         )(x))
@@ -182,17 +186,26 @@ class NLayerDiscriminator(nn.Module):
     # "pallas_instance" the whole conv epilogue (norm + LeakyReLU) runs as
     # ONE fused Pallas pass (ops/pallas/norm_act.py) — the D-side leaky
     # variant of the generator's fused chains.
+    # "batch" (the VQGAN lineage's D): BatchNorm with affine after the
+    # inner convolutions, which then carry no bias; always the batch's own
+    # moments (D only ever runs inside the train step), its running
+    # statistics threaded by the step as ``TrainState.batch_stats_d``.
     norm: str = "none"
+    # zero padding of the five k4 convolutions (ModelConfig.d_padding)
+    padding: int = 2
     dtype: Optional[jnp.dtype] = None
 
     @nn.compact
     def __call__(self, x) -> List[jax.Array]:
-        if self.norm not in ("none", "instance", "pallas_instance"):
-            # the train step threads no batch_stats for D — stat-free
-            # (per-forward) norms only
+        if self.norm not in ("none", "instance", "pallas_instance", "batch"):
             raise ValueError(
-                f"discriminator norm must be none/instance/pallas_instance "
-                f"(stateless), got {self.norm!r}")
+                f"discriminator norm must be none, instance, "
+                f"pallas_instance (stateless) or batch (its statistics "
+                f"threaded as TrainState.batch_stats_d), got {self.norm!r}")
+        if self.norm == "batch" and (self.use_spectral_norm or self.int8):
+            raise ValueError(
+                "discriminator norm 'batch' goes with plain convolutions: "
+                "no spectral norm, no int8")
         fused_q = (self.int8 and self.int8_delayed
                    and self.int8_fused_epilogue)
         if fused_q and self.norm not in ("instance", "pallas_instance"):
@@ -203,7 +216,7 @@ class NLayerDiscriminator(nn.Module):
         nf = self.ndf
         na = (make_norm_act(self.norm, dtype=self.dtype)
               if self.norm != "none" else None)
-        y = _PlainConv(nf, stride=2,
+        y = _PlainConv(nf, stride=2, padding=self.padding,
                        int8=self.int8 and self.int8_stem,
                        int8_delayed=self.int8_delayed,
                        dtype=self.dtype)(x)
@@ -213,13 +226,16 @@ class NLayerDiscriminator(nn.Module):
         def inner_conv(y, features, stride, ep=None, tap=False):
             if self.use_spectral_norm:
                 return SpectralConv(
-                    features, kernel_size=4, stride=stride, padding=2,
+                    features, kernel_size=4, stride=stride,
+                    padding=self.padding,
                     int8=self.int8, int8_delayed=self.int8_delayed,
                     epilogue=ep, epilogue_tap=tap, dtype=self.dtype
                 )(y)
-            return _PlainConv(features, stride=stride, int8=self.int8,
+            return _PlainConv(features, stride=stride, padding=self.padding,
+                              int8=self.int8,
                               int8_delayed=self.int8_delayed,
                               epilogue=ep, epilogue_tap=tap,
+                              use_bias=self.norm != "batch",
                               dtype=self.dtype)(y)
 
         def inner(y, features, stride):
@@ -264,7 +280,7 @@ class NLayerDiscriminator(nn.Module):
             y = na(raw, act="leaky", slope=0.2)
             feats.append(y)
 
-        y = _PlainConv(1, stride=1,
+        y = _PlainConv(1, stride=1, padding=self.padding,
                        int8=self.int8 and self.int8_head,
                        int8_delayed=self.int8_delayed,
                        dtype=self.dtype)(y)
@@ -290,6 +306,7 @@ class MultiscaleDiscriminator(nn.Module):
     int8_head: bool = False
     int8_fused_epilogue: bool = False
     norm: str = "none"
+    padding: int = 2
     dtype: Optional[jnp.dtype] = None
 
     @nn.compact
@@ -311,6 +328,7 @@ class MultiscaleDiscriminator(nn.Module):
                 int8_head=self.int8_head,
                 int8_fused_epilogue=self.int8_fused_epilogue,
                 norm=self.norm,
+                padding=self.padding,
                 dtype=self.dtype,
                 name=f"scale{self.num_D - 1 - i}",
             )

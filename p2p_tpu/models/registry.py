@@ -9,7 +9,8 @@ conv/linear kernels re-initialized, BatchNorm γ~N(1,0.02), biases zero).
 
 from __future__ import annotations
 
-from typing import Any, Dict
+import functools
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -94,7 +95,43 @@ def define_G(cfg: ModelConfig, dtype=None, remat=False) -> nn.Module:
 
         return SPADEGenerator(nf=cfg.ngf, out_channels=cfg.output_nc,
                               dtype=dtype)
+    if cfg.generator == "vqgan":
+        from p2p_tpu.models.vqgan import VQGAN
+
+        return VQGAN(ch=cfg.ngf, ch_mult=tuple(cfg.vq_ch_mult),
+                     res_blocks=cfg.vq_res_blocks, codes=cfg.vq_codes,
+                     embed_dim=cfg.vq_embed_dim,
+                     out_channels=cfg.output_nc, dtype=dtype)
     raise ValueError(f"unknown generator {cfg.generator!r}")
+
+
+class GeneratorSide(NamedTuple):
+    """What a generator with a learned quantizer hands the train step
+    beside its image (``train/step.py`` reads these and names no model):
+    ``collection`` is the variable collection its training forward fills,
+    ``read`` turns that collection into ``codebook_loss`` (a scalar the
+    step adds to G's loss and pulls back through), ``indices`` and
+    ``last_input`` (the input of the last convolution), ``last_kernel``
+    is that convolution's kernel's path in ``params_g`` (a k3 convolution
+    on a zero pad of 1: ``train/step.adaptive_gan_weight``) and ``usage``
+    maps the indices to (distinct codes, perplexity)."""
+
+    collection: str
+    read: Callable[[Any], Dict[str, jax.Array]]
+    last_kernel: Tuple[str, ...]
+    usage: Callable[[jax.Array], Tuple[jax.Array, jax.Array]]
+
+
+def generator_side(cfg: ModelConfig) -> Optional[GeneratorSide]:
+    """The configured generator's :class:`GeneratorSide`, or None for one
+    that maps a tensor to a tensor and nothing else."""
+    if cfg.generator == "vqgan":
+        from p2p_tpu.models import vqgan
+
+        return GeneratorSide("vq", vqgan.side_outputs, vqgan.LAST_KERNEL,
+                             functools.partial(vqgan.code_usage,
+                                               codes=cfg.vq_codes))
+    return None
 
 
 def generator_gauges(cfg: ModelConfig, h: int, w: int) -> Dict[str, float]:
@@ -105,6 +142,17 @@ def generator_gauges(cfg: ModelConfig, h: int, w: int) -> Dict[str, float]:
         from p2p_tpu.models.spade import spade_arithmetic
 
         return spade_arithmetic(cfg.ngf, cfg.input_nc, h, w)
+    if cfg.generator == "vqgan":
+        from p2p_tpu.models.vgg import vgg_gflop_per_image
+        from p2p_tpu.models.vqgan import vqgan_arithmetic
+
+        out = vqgan_arithmetic(cfg.ngf, tuple(cfg.vq_ch_mult),
+                               cfg.vq_res_blocks, cfg.vq_codes,
+                               cfg.vq_embed_dim, h, w)
+        # LPIPS: one VGG16 forward (the step runs two and one backward)
+        out["vqgan_lpips_gflop_per_image"] = vgg_gflop_per_image(
+            "vgg16", h, w)
+        return out
     return {}
 
 
@@ -121,6 +169,7 @@ def define_D(cfg: ModelConfig, dtype=None) -> nn.Module:
         int8_head=cfg.int8_head,
         int8_fused_epilogue=cfg.int8_fused_epilogue,
         norm=cfg.norm_d,
+        padding=cfg.d_padding,
         dtype=dtype,
     )
 

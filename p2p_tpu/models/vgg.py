@@ -41,6 +41,20 @@ _CFG = [
 # Taps after these convs' relus == torchvision indices 2/7/12/21/30.
 _TAPS = ("conv1_1", "conv2_1", "conv3_1", "conv4_1", "conv5_1")
 
+# VGG16 through conv5_3, tapped after relu1_2, relu2_2, relu3_3, relu4_3,
+# relu5_3 (torchvision indices 3/8/15/22/29): what LPIPS reads
+# (losses/lpips.py).
+_CFG16 = [
+    ("conv1_1", 64), ("conv1_2", 64), ("M", 0),
+    ("conv2_1", 128), ("conv2_2", 128), ("M", 0),
+    ("conv3_1", 256), ("conv3_2", 256), ("conv3_3", 256), ("M", 0),
+    ("conv4_1", 512), ("conv4_2", 512), ("conv4_3", 512), ("M", 0),
+    ("conv5_1", 512), ("conv5_2", 512), ("conv5_3", 512),
+]
+_TAPS16 = ("conv1_2", "conv2_2", "conv3_3", "conv4_3", "conv5_3")
+#: arch -> (layer table, tapped layers)
+ARCHS = {"vgg19": (_CFG, _TAPS), "vgg16": (_CFG16, _TAPS16)}
+
 _IMAGENET_MEAN = np.array([0.485, 0.456, 0.406], np.float32)
 _IMAGENET_STD = np.array([0.229, 0.224, 0.225], np.float32)
 
@@ -115,16 +129,19 @@ class VGG19Features(nn.Module):
 
     imagenet_norm: bool = False
     store_dtype: Optional[jnp.dtype] = None
+    # "vgg16": the same trunk under VGG16's layer table and LPIPS's taps
+    arch: str = "vgg19"
 
     @nn.compact
     def __call__(self, x) -> List[jax.Array]:
+        cfg, taps = ARCHS[self.arch]
         if self.imagenet_norm:
             # incoming images are [-1,1]; map to [0,1] then standardize
             x = (x + 1.0) * 0.5
             x = (x - _IMAGENET_MEAN) / _IMAGENET_STD
         outs = []
         y = x if self.store_dtype is None else x.astype(self.store_dtype)
-        for name, ch in _CFG:
+        for name, ch in cfg:
             if name == "M":
                 y = nn.max_pool(y, (2, 2), strides=(2, 2))
                 continue
@@ -134,7 +151,7 @@ class VGG19Features(nn.Module):
                 y = relu_y(y)
             else:
                 y = _StoredConvRelu(ch, name=name)(y)
-            if name in _TAPS:
+            if name in taps:
                 outs.append(y)
         return outs
 
@@ -177,3 +194,25 @@ def load_vgg19_params(dtype=jnp.float32, seed: int = 190):
         assert kernel.shape[-1] == ch, (name, kernel.shape)
         params[name] = {"kernel": kernel, "bias": bias}
     return params
+
+
+def load_vgg16_params(dtype=jnp.float32, seed: int = 160):
+    """The frozen VGG16 tree LPIPS reads: fixed-seed random, as VGG19's
+    is where no asset exists (this repo ships no VGG16 asset; speed does
+    not depend on the values)."""
+    dummy = jnp.zeros((1, 64, 64, 3), dtype)
+    return VGG19Features(arch="vgg16").init(jax.random.key(seed),
+                                            dummy)["params"]
+
+
+def vgg_gflop_per_image(arch: str, h: int, w: int) -> float:
+    """One forward of the trunk on one ``h`` x ``w`` image, 2 x
+    multiply-adds, in GFLOP."""
+    total, c = 0.0, 3
+    for name, ch in ARCHS[arch][0]:
+        if name == "M":
+            h, w = h // 2, w // 2
+            continue
+        total += 2.0 * h * w * 9 * c * ch
+        c = ch
+    return total / 1e9

@@ -358,7 +358,9 @@ class ConvLayer(nn.Module):
     dtype: Optional[jnp.dtype] = None
     kernel_init: Callable = normal_init()
     # "zero": Conv2d(padding=k//2) in the layer's place (the SPADE
-    # lineage pads with zeros); the conv's form is chosen as for reflect
+    # lineage pads with zeros); the conv's form is chosen as for reflect.
+    # "zero_after": zeros below and to the right only (the stride-2
+    # downsampling of models/vqgan.py: F.pad(x, (0, 1, 0, 1)), padding 0)
     pad_mode: str = "reflect"
 
     @nn.compact
@@ -366,6 +368,8 @@ class ConvLayer(nn.Module):
         pad = self.kernel_size // 2
         if self.pad_mode == "zero":
             x = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
+        elif self.pad_mode == "zero_after":
+            x = jnp.pad(x, ((0, 0), (0, pad), (0, pad), (0, 0)))
         else:
             x = reflect_pad_2d(x, pad)
         if self.int8:
@@ -702,10 +706,16 @@ class UpsampleConvLayer(nn.Module):
     use_bias: bool = True
     dtype: Optional[jnp.dtype] = None
     kernel_init: Callable = normal_init()
+    # "zero": Conv2d(padding=k//2) after the upsample (models/vqgan.py).
+    # Such a site keeps the plain chain: the subpixel form's edge ring is
+    # the reflect pad's, and the one zero-padded user has no site under
+    # the 128 output channels it engages at
+    pad_mode: str = "reflect"
 
     @nn.compact
     def __call__(self, x):
         if (self.upsample == 2 and self.kernel_size == 3 and self.stride == 1
+                and self.pad_mode == "reflect"
                 and nearest_up2_engages(x, self.features)):
             # subpixel decomposition of upsample→conv (ExpandNetwork's two
             # upsamples, the pix2pixHD enhancer's and G1's last — see
@@ -718,7 +728,10 @@ class UpsampleConvLayer(nn.Module):
         if self.upsample:
             x = upsample_nearest(x, self.upsample)
         pad = self.kernel_size // 2
-        x = reflect_pad_2d(x, pad)
+        if self.pad_mode == "zero":
+            x = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
+        else:
+            x = reflect_pad_2d(x, pad)
         # ExpandNetwork's k9 head 32→3 lives HERE, not in ConvLayer
         # (networks.py:518-520)
         return _routed_conv(self, x)

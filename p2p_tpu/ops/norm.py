@@ -19,6 +19,7 @@ regions pass ``axis_name='data'`` to opt in explicitly.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -217,6 +218,86 @@ class InstanceNorm(nn.Module):
         return y.astype(self.dtype or orig_dtype)
 
 
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def affine_act(x, a, b, swish: bool):
+    """``act(x * a + b)`` with a per-(image, channel) pair ``a``, ``b``
+    (float32 ``[N, C]``) and ``act`` swish (``z * sigmoid(z)``) or the
+    identity: the normalise-and-activate pass of :class:`GroupNorm`.
+    The chain runs in float32 and is stored once in ``x.dtype``; the
+    backward keeps ``x`` alone and recomputes ``z``, so no float32 copy
+    of the activation outlives the pass."""
+    z = x.astype(jnp.float32) * a[:, None, None, :] + b[:, None, None, :]
+    if swish:
+        z = z * jax.nn.sigmoid(z)
+    return z.astype(x.dtype)
+
+
+def _affine_act_fwd(x, a, b, swish):
+    return affine_act(x, a, b, swish), (x, a, b)
+
+
+def _affine_act_bwd(swish, res, ct):
+    x, a, b = res
+    xf = x.astype(jnp.float32)
+    dz = ct.astype(jnp.float32)
+    if swish:
+        z = xf * a[:, None, None, :] + b[:, None, None, :]
+        s = jax.nn.sigmoid(z)
+        dz = dz * (s * (1.0 + z * (1.0 - s)))
+    dx = (dz * a[:, None, None, :]).astype(x.dtype)
+    return dx, jnp.sum(dz * xf, (1, 2)), jnp.sum(dz, (1, 2))
+
+
+affine_act.defvjp(_affine_act_fwd, _affine_act_bwd)
+
+
+class GroupNorm(nn.Module):
+    """``GroupNorm(groups, eps, affine)`` over NHWC, with its swish:
+
+        y = act((x - mean_g) * rsqrt(var_g + eps) * scale + bias)
+
+    ``mean_g`` / ``var_g`` (biased) are taken per (image, group) over H,
+    W and the group's ``C / groups`` channels, in float32 whatever
+    ``x.dtype`` is: one pass over ``x`` for the per-channel sums
+    (:func:`dual_moments` an image), the groups combined on ``[N, C]``
+    numbers; the normalisation folds into a per-(image, channel) affine
+    that :func:`affine_act` applies with the activation in one pass.
+    ``swish``: ``z * sigmoid(z)`` after it (the residual blocks' two
+    sites and each net's last norm); without it the norm alone (the
+    attention blocks'). Scale 1, bias 0 at init, as torch's.
+
+    A swish site runs under ``jax.named_scope("gn_swish")``, a plain one
+    under ``"gn"``."""
+
+    groups: int = 32
+    epsilon: float = 1e-6
+    swish: bool = True
+
+    @nn.compact
+    def __call__(self, x):
+        n, h, w, c = x.shape
+        if c % self.groups:
+            raise ValueError(f"GroupNorm: {c} channels do not divide "
+                             f"into {self.groups} groups")
+        scale = self.param("scale", nn.initializers.ones, (c,), jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros, (c,), jnp.float32)
+        with jax.named_scope("gn_swish" if self.swish else "gn"):
+            s, ss = jax.vmap(dual_moments)(x)
+            cg = c // self.groups
+            count = h * w * cg
+            by_group = lambda t: jnp.sum(  # noqa: E731
+                t.reshape(n, self.groups, cg), -1, keepdims=True) / count
+            mean = by_group(s)
+            var = jnp.maximum(by_group(ss) - jnp.square(mean), 0.0)
+            a = scale.reshape(self.groups, cg) * jax.lax.rsqrt(
+                var + self.epsilon)
+            b = bias.reshape(self.groups, cg) - mean * a
+            a = checkpoint_name(a.reshape(n, c), "norm_stats")
+            b = checkpoint_name(b.reshape(n, c), "norm_stats")
+            return affine_act(x, a, b, self.swish)
+
+
 def make_norm_act(kind: str, *, train: bool = True,
                   axis_name: Optional[str] = None, dtype=None):
     """Factory for the post-conv epilogue ``act(norm(y) [+ residual])`` —
@@ -297,6 +378,10 @@ def make_norm(kind: str, *, train: bool = True, axis_name: Optional[str] = None,
         from p2p_tpu.ops.pallas.instance_norm import PallasInstanceNorm
 
         return lambda: PallasInstanceNorm(dtype=dtype)
+    if kind == "group":
+        return lambda: GroupNorm(swish=False)
+    if kind == "group_swish":
+        return lambda: GroupNorm(swish=True)
     if kind == "none":
         return lambda: (lambda x: x)
     raise ValueError(f"unknown norm kind {kind!r}")

@@ -1001,6 +1001,15 @@ class Trainer:
             if (cfg.loss.lambda_vgg > 0 or cfg.loss.lambda_style > 0
                 or cfg.train.eval_fid) else None
         )
+        if cfg.loss.lambda_lpips > 0:
+            if self.vgg_params is not None:
+                raise ValueError("lambda_lpips goes with no VGG19 term "
+                                 "(lambda_vgg, lambda_style, eval_fid): the "
+                                 "step is handed ONE frozen tree")
+            from p2p_tpu.losses.lpips import load_lpips_params
+
+            # VGG16 + the five heads, in VGG19's place
+            self.vgg_params = load_lpips_params()
         self.fid_feature_fn = None
         self.vgg_source = None
         self._trace_counts_logged = {}  # kind -> counts last written

@@ -64,8 +64,9 @@ def make_schedule(cfg: OptimConfig, steps_per_epoch: int,
             mult = 0.1 ** (epoch // cfg.lr_decay_iters)
         elif cfg.lr_policy == "cosine":
             mult = 0.5 * (1.0 + jnp.cos(jnp.pi * epoch / cfg.niter))
-        elif cfg.lr_policy == "plateau":
-            mult = 1.0  # host-controlled via PlateauController
+        elif cfg.lr_policy in ("plateau", "constant"):
+            # plateau: host-controlled via PlateauController
+            mult = 1.0
         else:
             raise ValueError(f"unknown lr policy {cfg.lr_policy!r}")
         return base * mult

@@ -66,6 +66,10 @@ class TrainState(struct.PyTreeNode):
     # training forward. None for every other generator (an empty subtree:
     # their checkpoints and their step are what they were).
     spectral_g: Any = None
+    # running statistics of a BatchNorm discriminator (ModelConfig.norm_d
+    # "batch"), threaded like spectral_d: two updates a step (fake call,
+    # real call). None for every other discriminator (an empty subtree).
+    batch_stats_d: Any = None
 
 
 class InferState(struct.PyTreeNode):
@@ -403,8 +407,9 @@ def _init_train_state(cfg, rng, sample_batch, steps_per_epoch=1,
     # shape/dtype inference at init matches what the step's ingest feeds
     # (a label map one-hots: D's stem is conditioning + image channels)
     x = ingest_input(jnp.asarray(sample_batch["input"]), cfg.model)
-    pair = jnp.concatenate(
-        [x, ingest(jnp.asarray(sample_batch["target"]))], axis=-1)
+    pair = ingest(jnp.asarray(sample_batch["target"]))
+    if cfg.model.d_conditional:
+        pair = jnp.concatenate([x, pair], axis=-1)
 
     vg = init_variables(g, kg, x, cfg.model.init_type, cfg.model.init_gain,
                         train=False)
@@ -455,4 +460,6 @@ def _init_train_state(cfg, rng, sample_batch, steps_per_epoch=1,
         quant_c=quant_c,
         ema_g=ema_g,
         spectral_g=vg.get("spectral"),
+        batch_stats_d=(vd.get("batch_stats", {})
+                       if cfg.model.norm_d == "batch" else None),
     )

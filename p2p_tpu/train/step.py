@@ -66,6 +66,7 @@ from p2p_tpu.losses import (
     ssim,
     vgg_loss,
 )
+from p2p_tpu.models.registry import generator_side
 from p2p_tpu.ops.quantize import quantize, quantize_ste
 from p2p_tpu.ops.tv import total_variation_loss
 from p2p_tpu.train.state import TrainState, build_models, make_optimizers
@@ -83,9 +84,20 @@ from p2p_tpu.utils.images import ingest, ingest_input
 #: ``loss_pix`` the pixel-space terms (L1, angular, sobel); ``C_branch`` is
 #: the compression branch's whole pass against the updated G; the ``opt_*``
 #: hold the skip guard's selects on what they update, ``opt_g`` G's EMA.
+#: ``loss_lpips`` is the LPIPS term (VGG16), ``loss_adaptive`` the
+#: adaptive adversarial weight (one weight-gradient convolution of the
+#: generator's last layer for two cotangents, and their norms),
+#: ``loss_codebook`` the code-usage numbers of a learned quantizer (its
+#: loss is computed inside ``G``, under ``vq``).
 STEP_SCOPES = ("compress", "G", "D_fake", "D_real", "loss_gan", "loss_fm",
                "loss_vgg", "loss_tv", "loss_pix", "C_branch", "opt_g",
-               "opt_d", "opt_c")
+               "opt_d", "opt_c", "loss_lpips", "loss_adaptive",
+               "loss_codebook")
+
+
+#: weight of the codebook loss a generator with a learned quantizer hands
+#: the step (the VQGAN lineage's ``codebook_weight``)
+_CODEBOOK_WEIGHT = 1.0
 
 
 def _concat_pair(a: jax.Array, b: jax.Array) -> jax.Array:
@@ -143,6 +155,29 @@ def single_forward_d_losses(d_apply, dvars0, params_d, fake_pair,
     )
 
 
+def adaptive_gan_weight(last_input, last_kernel, ct_nll, ct_gan):
+    """The VQGAN lineage's ``lambda = |grad_W nll| / (|grad_W g| + 1e-4)``,
+    clipped to [0, 1e4] and held constant, ``W`` the generator's last
+    kernel (a k3 convolution on a zero pad of 1, ``last_input`` its
+    input) and ``ct_nll`` / ``ct_gan`` the cotangents of the two terms
+    with respect to the generated image. A weight gradient is linear in
+    the cotangent and does not depend on ``W``'s value, so both come from
+    ONE pull of the two cotangents side by side through a convolution of
+    twice the output channels: one pass over ``last_input``, and no
+    second backward through the decoder."""
+    c = ct_nll.shape[-1]
+    both = jnp.concatenate([ct_nll, ct_gan], axis=-1).astype(last_input.dtype)
+    conv = lambda w: jax.lax.conv_general_dilated(  # noqa: E731
+        last_input, w.astype(last_input.dtype), (1, 1), ((1, 1), (1, 1)),
+        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+    w2 = jnp.zeros(last_kernel.shape[:3] + (2 * c,), jnp.float32)
+    (gw,) = jax.vjp(conv, w2)[1](both)
+    norm = lambda g: jnp.sqrt(jnp.sum(jnp.square(  # noqa: E731
+        g.astype(jnp.float32))))
+    lam = norm(gw[..., :c]) / (norm(gw[..., c:]) + 1e-4)
+    return jax.lax.stop_gradient(jnp.clip(lam, 0.0, 1e4))
+
+
 def make_g_loss_fn(cfg: Config, vgg_params: Optional[Any] = None,
                    steps_per_epoch: int = 1):
     """The generator-side loss surface (GAN + feature-matching + VGG +
@@ -154,6 +189,7 @@ def make_g_loss_fn(cfg: Config, vgg_params: Optional[Any] = None,
     through D."""
     L = cfg.loss
     need_vgg = (L.lambda_vgg > 0) and vgg_params is not None
+    need_lpips = (L.lambda_lpips > 0) and vgg_params is not None
 
     def g_losses(fake_b, pred_fake_g, pred_real, real_a, real_b, step):
         with jax.named_scope("loss_gan"):
@@ -177,6 +213,14 @@ def make_g_loss_fn(cfg: Config, vgg_params: Optional[Any] = None,
                 ) * L.lambda_vgg
             parts["g_vgg"] = l_vgg
             total = total + l_vgg
+        if need_lpips:
+            from p2p_tpu.losses.lpips import lpips_loss
+
+            with jax.named_scope("loss_lpips"):
+                l_lpips = lpips_loss(vgg_params, fake_b,
+                                     real_b) * L.lambda_lpips
+            parts["g_lpips"] = l_lpips
+            total = total + l_lpips
         if L.lambda_style > 0 and vgg_params is not None:
             from p2p_tpu.losses.style import style_loss
 
@@ -282,6 +326,26 @@ def build_train_step(
     # amax, ops/int8.py) through G and D exactly like batch_stats/spectral
     use_quant = cfg.model.int8_delayed
     d_colls = ("spectral", "quant") if use_quant else ("spectral",)
+    # a BatchNorm discriminator threads its running statistics the same
+    # way (TrainState.batch_stats_d)
+    d_bn = cfg.model.norm_d == "batch"
+    if d_bn:
+        d_colls = d_colls + ("batch_stats",)
+    # a generator with a learned quantizer leaves its codebook loss, its
+    # indices and its last convolution's input in a collection of its own
+    # (models/registry.GeneratorSide); None for every other generator
+    side = generator_side(cfg.model)
+    adaptive = L.adaptive_gan_weight > 0
+    if adaptive and (side is None or L.lambda_feat > 0):
+        raise ValueError(
+            "adaptive_gan_weight needs a generator that exposes its last "
+            "convolution's input (models/registry.generator_side) and no "
+            "feature matching (the GAN term alone may reach the image "
+            "through D)")
+    if (side or not cfg.model.d_conditional) and cfg.train.pool_size > 0:
+        raise ValueError("the historical-fake pool holds conditional "
+                         "pairs of a generator with one output: set "
+                         "pool_size 0")
     g_loss_fn = make_g_loss_fn(cfg, vgg_params, steps_per_epoch)
     # Self-healing (resilience/health.py, rung 1 of the recovery ladder):
     # a non-finite step SKIPS — gradients are zeroed before they can
@@ -304,10 +368,15 @@ def build_train_step(
             # power iteration a training forward, like D's
             variables["spectral"] = spectral
             mut.append("spectral")
+        if side:
+            mut.append(side.collection)
         with jax.named_scope("G"):
             out, v = g.apply(variables, x, True, mutable=mut, rngs=rngs)
-        return out, v["batch_stats"], (v.get("quant", {}) if use_quant
-                                       else None), v.get("spectral")
+        if side:
+            # the codebook loss is a second differentiable output
+            out = (out, side.read(v[side.collection]))
+        return out, v.get("batch_stats", {}), (
+            v.get("quant", {}) if use_quant else None), v.get("spectral")
 
     def d_fwd(params, dvars, x):
         out, mut = d.apply(
@@ -365,11 +434,22 @@ def build_train_step(
             out, bs, qg, sg = g_fwd(params_g, state.batch_stats_g,
                                     state.quant_g, g_input, drop_rng,
                                     state.spectral_g)
+            if side:
+                # (image, codebook loss) are both pulled back through;
+                # the indices and the last convolution's input ride along
+                image, beside = out
+                return (image, beside["codebook_loss"]), (
+                    bs, qg, sg, (beside["indices"], beside["last_input"]))
             return out, (bs, qg, sg)
 
-        fake_b_primal, g_vjp, (bs_g1, quant_g1, spectral_g1) = jax.vjp(
-            g_primal, state.params_g, has_aux=True
-        )
+        if side:
+            ((fake_b_primal, loss_codebook), g_vjp,
+             (bs_g1, quant_g1, spectral_g1, (vq_indices, vq_last_input))
+             ) = jax.vjp(g_primal, state.params_g, has_aux=True)
+        else:
+            fake_b_primal, g_vjp, (bs_g1, quant_g1, spectral_g1) = jax.vjp(
+                g_primal, state.params_g, has_aux=True
+            )
 
         # historical-fake pool (reference train.py:307: the CONCAT pair is
         # pooled into D's fake branch; size 0 = passthrough). Device-side
@@ -395,6 +475,8 @@ def build_train_step(
             dvars0 = {"spectral": state.spectral_d}
             if use_quant:
                 dvars0["quant"] = state.quant_d
+            if d_bn:
+                dvars0["batch_stats"] = state.batch_stats_d
             # Pair form is MEASURED shape-dependent (ModelConfig.
             # split_d_pairs): concat wins at 256²/bs128 (1661 vs 1701 —
             # two 3-ch stem convs tile the MXU's contraction dim worse,
@@ -409,7 +491,11 @@ def build_train_step(
             # pair is a pytree either way).
             split = cfg.model.split_d_pairs
             in_c = real_a.shape[-1]
-            if split:
+            if not cfg.model.d_conditional:
+                # an unconditional D sees the image alone
+                split, in_c = False, 0
+                fake_pair, real_pair = fake_b_primal, real_b
+            elif split:
                 fake_pair = (real_a, fake_b_primal)
                 real_pair = (real_a, real_b)
             else:
@@ -427,8 +513,25 @@ def build_train_step(
             )(fake_b_primal, pred_fake)
             # params cotangent dead (reference zero_grad) → DCE; on the
             # split path the pair cotangent is already the (a, b) tuple
-            grad_fake = ct_fake_direct + (
-                pull(ct_pred)[1] if split else pull(ct_pred)[..., in_c:])
+            ct_through_d = (pull(ct_pred)[1] if split
+                            else pull(ct_pred)[..., in_c:])
+            if adaptive:
+                # ct_fake_direct is the cotangent of nll (what reaches
+                # the image directly), ct_through_d the GAN term's: each
+                # is pulled through the last convolution ALONE for the
+                # weight, then the weighted sum goes through g_vjp once
+                last_kernel = state.params_g
+                for key in side.last_kernel:
+                    last_kernel = last_kernel[key]
+                with jax.named_scope("loss_adaptive"):
+                    d_weight = adaptive_gan_weight(
+                        vq_last_input, last_kernel, ct_fake_direct,
+                        ct_through_d)
+                    gan_w = L.adaptive_gan_weight * d_weight
+                    ct_through_d = gan_w.astype(
+                        ct_through_d.dtype) * ct_through_d
+                loss_g = loss_g + (gan_w - 1.0) * g_parts["g_gan"]
+            grad_fake = ct_fake_direct + ct_through_d
         else:
             # Pool active: D's fake pair is the pooled history, not the live
             # fake — the forwards genuinely differ, keep the reference's
@@ -485,9 +588,18 @@ def build_train_step(
                 loss_g_fn, has_aux=True
             )(fake_b_primal)
 
-        (grads_g,) = g_vjp(grad_fake)
+        if side:
+            # the codebook loss enters G's loss with the weight
+            # _CODEBOOK_WEIGHT, and its cotangent is that weight
+            (grads_g,) = g_vjp((grad_fake, jnp.asarray(
+                _CODEBOOK_WEIGHT, loss_codebook.dtype)))
+            loss_g = loss_g + _CODEBOOK_WEIGHT * loss_codebook
+            g_parts["g_codebook"] = loss_codebook
+        else:
+            (grads_g,) = g_vjp(grad_fake)
         spectral2 = dvars2["spectral"]
         quant_d1 = dvars2.get("quant") if use_quant else None
+        bs_d1 = dvars2["batch_stats"] if d_bn else state.batch_stats_d
 
         # ---- skip guard (health ladder rung 1) --------------------------
         ok = None
@@ -529,6 +641,8 @@ def build_train_step(
             with jax.named_scope("opt_d"):
                 opt_d1 = health_select(ok, opt_d1, state.opt_d)
                 spectral2 = health_select(ok, spectral2, state.spectral_d)
+                if d_bn:
+                    bs_d1 = health_select(ok, bs_d1, state.batch_stats_d)
             if use_quant:
                 quant_g1 = health_select(ok, quant_g1, state.quant_g)
                 quant_d1 = health_select(ok, quant_d1, state.quant_d)
@@ -619,6 +733,7 @@ def build_train_step(
             quant_c=quant_c1,
             ema_g=ema_g1,
             spectral_g=spectral_g1,
+            batch_stats_d=bs_d1,
         )
         metrics = {
             "loss_d": loss_d.astype(jnp.float32),
@@ -626,6 +741,13 @@ def build_train_step(
             "loss_c": loss_c,
             **{k: v.astype(jnp.float32) for k, v in g_parts.items()},
         }
+        if adaptive:
+            metrics["d_weight"] = d_weight.astype(jnp.float32)
+        if side:
+            with jax.named_scope("loss_codebook"):
+                used, perplexity = side.usage(vq_indices)
+            metrics["vq_codes_used"] = used
+            metrics["vq_perplexity"] = perplexity
         if ok_all is not None:
             # 1.0 = updates applied, 0.0 = the skip guard dropped this
             # step; the host sentinel counts the skips off this flag

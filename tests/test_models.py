@@ -410,7 +410,8 @@ def test_discriminator_norm_d_variants():
     pallas_instance norms on the inner convs are affine-free, so the
     param/spectral trees are IDENTICAL to norm='none' (checkpoints
     interchange); the two instance kinds agree numerically (the fused
-    Pallas epilogue == module chain); stateful norms are rejected."""
+    Pallas epilogue == module chain); a norm the step cannot thread is
+    rejected."""
     x = jnp.asarray(
         np.random.default_rng(5).uniform(-1, 1, (2, 32, 32, 6)), jnp.float32)
     plain = MultiscaleDiscriminator(ndf=8, n_layers=3, num_D=2)
@@ -434,8 +435,10 @@ def test_discriminator_norm_d_variants():
     assert not np.allclose(np.asarray(out_p[0][-1]),
                            np.asarray(out_i[0][-1]))
 
+    # (since PR 34 "batch" is taken too, its statistics threaded by the
+    # step: tests/test_vqgan.py)
     with pytest.raises(ValueError, match="stateless"):
-        NLayerDiscriminator(ndf=8, norm="batch").init(jax.random.key(0), x)
+        NLayerDiscriminator(ndf=8, norm="layer").init(jax.random.key(0), x)
 
 
 def test_discriminator_norm_d_composes_with_int8():

@@ -903,6 +903,11 @@ UP2_SITE_CASES = {
         (512, False), (256, False), (128, False), (64, True), (32, True))),
     # no cell: follows the rule unmeasured
     "cityscapes_spatial": (4, None, ((128, False), (64, True))),
+    # vqgan_imagenet_f16_16384.train: the decoder's four sites pad with
+    # ZEROS, and the subpixel form's edge ring is the reflect pad's: all
+    # plain, the last (128 wide at 256x256) too
+    "vqgan_imagenet_f16": (12, None, ((512, False), (256, False),
+                                      (256, False), (128, False))),
 }
 
 

@@ -414,8 +414,14 @@ def test_cli_infer_equals_the_eval_generator_of_the_train_state(
 def _tiny(preset):
     cfg = get_preset(preset)
     size = 64 if cfg.model.generator in ("pix2pixhd", "unet") else 32
+    model = dataclasses.replace(cfg.model, ngf=4, ndf=4, n_blocks=1)
+    if cfg.model.generator == "vqgan":
+        # GroupNorm's 32 groups need a base width of 32
+        model = dataclasses.replace(
+            model, ngf=32, vq_ch_mult=(1, 2), vq_res_blocks=1, vq_codes=64,
+            vq_embed_dim=32)
     return cfg.replace(
-        model=dataclasses.replace(cfg.model, ngf=4, ndf=4, n_blocks=1),
+        model=model,
         data=dataclasses.replace(cfg.data, image_size=size, image_width=size,
                                  batch_size=1),
         loss=dataclasses.replace(cfg.loss, lambda_vgg=0.0),
