@@ -131,7 +131,9 @@ def blocked_conv_block(x, features: int, kernel_size: int,
 
 
 #: the non-plain forms a ConvLayer / UpsampleConvLayer call site can take
-CONV_FORMS = ("blocked", "nearest_up2")
+#: (a thin k7 / k9 site under a mesh that shards H takes two: ``halo``
+#: around ``blocked``)
+CONV_FORMS = ("blocked", "nearest_up2", "halo")
 
 
 def _count_form(form: str) -> None:
@@ -151,6 +153,39 @@ def conv_form_sites() -> dict:
     reg = get_registry()
     return {f: int(reg.counter("conv_form_sites_total", form=f).value)
             for f in CONV_FORMS}
+
+
+def halo_conv_mesh(x, kernel_size: int, stride: int):
+    """The mesh over which a reflect-padded convolution site with the
+    UNPADDED input ``x`` (N,H,W,C) runs as one ``shard_map``
+    (``parallel.spatial.halo_conv``: halo exchange, local W pad, local
+    VALID conv), or None where the site keeps the pad-then-conv chain.
+
+    It engages on what the site can observe: a mesh made visible by
+    ``core.mesh.mesh_context`` on which ``x`` lays out ``P((data, fsdp),
+    spatial)`` and whose ``spatial`` axis is above 1, an odd kernel above
+    1, a shard's rows more than the pad and a multiple of the stride
+    (k3 stride 2 wants an even number of local rows), W wider than the
+    pad. There GSPMD's own partition of the padded tensor, whose
+    H + 2p rows do not split like the activation's H, re-windowed every
+    layer (PERF.md section 6, PR 35). A mesh with ``model``, ``pipe`` or
+    ``time`` above 1 keeps GSPMD's path: the ``shard_map`` takes its
+    kernel replicated and would gather a tensor-parallel one at every
+    site."""
+    from p2p_tpu.core.mesh import BATCH_AXES, SPATIAL_AXIS, spatial_shard_mesh
+
+    mesh = spatial_shard_mesh(x)
+    if mesh is None:
+        return None
+    s = mesh.shape.get(SPATIAL_AXIS, 1)
+    pad = kernel_size // 2
+    rows = x.shape[1] // s
+    if (s > 1 and pad and kernel_size % 2 and rows > pad
+            and rows % stride == 0 and x.shape[2] > pad
+            and all(n == 1 for a, n in mesh.shape.items()
+                    if a not in (*BATCH_AXES, SPATIAL_AXIS))):
+        return mesh
+    return None
 
 
 def _reflect_pad_h_sharded(x: jax.Array, pad: int, mesh) -> jax.Array:
@@ -304,6 +339,19 @@ def reflect_pad_2d(x: jax.Array, pad: int) -> jax.Array:
         return _reflect_pad(x, pad, axes) if axes else x
 
 
+def reflect_pad_w(x: jax.Array, pad: int) -> jax.Array:
+    """Reflection-pad W alone, inside a ``shard_map`` whose shards hold
+    their H halo already (``parallel.spatial.halo_conv``): the one-pass
+    backward along W, under the scope ``reflect_pad`` and counted as a
+    ``one_pass_w`` site like :func:`reflect_pad_2d`'s under such a mesh."""
+    from p2p_tpu.obs.registry import get_registry
+
+    get_registry().counter("reflect_pad_sites_total",
+                           backward="one_pass_w").inc()
+    with jax.named_scope("reflect_pad"):
+        return _reflect_pad(x, pad, (2,))
+
+
 def normal_init(stddev: float = 0.02):
     """Reference default weight init: N(0, 0.02) (networks.py:131)."""
     return nn.initializers.normal(stddev=stddev)
@@ -341,6 +389,28 @@ def _routed_conv(layer, x):
         strides=(stride, stride), padding="VALID", **kw)(x))
 
 
+def _reflect_padded_conv(layer, x):
+    """ReflectionPad(k // 2) + the layer's conv on the unpadded ``x``:
+    one ``shard_map`` where :func:`halo_conv_mesh` gives a mesh
+    (:class:`HaloConv`; the blocked local conv where the padded shape
+    asks for it, as without a mesh), else :func:`reflect_pad_2d` and
+    :func:`_routed_conv`."""
+    k, stride = layer.kernel_size, layer.stride
+    mesh = halo_conv_mesh(x, k, stride)
+    if mesh is None:
+        return _routed_conv(layer, reflect_pad_2d(x, k // 2))
+    n, h, w, c = x.shape
+    block = blocked_conv_block(
+        jax.ShapeDtypeStruct((n, h + k - 1, w + k - 1, c), x.dtype),
+        layer.features, k, stride)
+    _count_form("halo")
+    if block:
+        _count_form("blocked")
+    return HaloConv(layer.features, kernel_size=k, stride=stride,
+                    block=block, use_bias=layer.use_bias, dtype=layer.dtype,
+                    kernel_init=layer.kernel_init, name="Conv_0")(x, mesh)
+
+
 class ConvLayer(nn.Module):
     """ReflectionPad(k//2) + conv. Ref: networks.py:395-405.
 
@@ -366,6 +436,8 @@ class ConvLayer(nn.Module):
     @nn.compact
     def __call__(self, x):
         pad = self.kernel_size // 2
+        if self.pad_mode == "reflect" and not self.int8:
+            return _reflect_padded_conv(self, x)
         if self.pad_mode == "zero":
             x = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
         elif self.pad_mode == "zero_after":
@@ -525,6 +597,45 @@ class BlockedConv(nn.Module):
             y = blocked_conv(x.astype(dt), kernel, self.block)
             if bias is not None:
                 y = y + bias.astype(y.dtype)
+        return save_conv_out(y)
+
+
+class HaloConv(nn.Module):
+    """ReflectionPad(k // 2) + conv of an H-sharded input as ONE
+    ``shard_map`` over the mesh it is called with
+    (``parallel.spatial.halo_conv``: halo rows from the neighbours, W
+    padded locally, a local VALID conv, on pixel blocks where ``block``
+    says so). Takes the UNPADDED input. Param tree ("kernel"
+    (k,k,C_in,C_out) float32 + "bias") matches ``nn.Conv`` and callers
+    name it ``Conv_0``, as :class:`BlockedConv`.
+
+    The kernel is cast to the compute dtype out here, so the ``psum`` the
+    ``shard_map``'s transpose gives its cotangent (one all-reduce over
+    ``data`` and ``spatial`` together) moves bf16 in a bf16 step; the
+    bias is added out here too, on the global output, GSPMD's."""
+
+    features: int
+    kernel_size: int
+    stride: int = 1
+    block: int = 0
+    use_bias: bool = True
+    dtype: Optional[jnp.dtype] = None
+    kernel_init: Callable = normal_init()
+
+    @nn.compact
+    def __call__(self, x, mesh):
+        from p2p_tpu.parallel.spatial import halo_conv
+
+        k = self.kernel_size
+        kernel = self.param("kernel", self.kernel_init,
+                            (k, k, x.shape[-1], self.features), jnp.float32)
+        bias = (self.param("bias", nn.initializers.zeros, (self.features,),
+                           jnp.float32) if self.use_bias else None)
+        dt = self.dtype or jnp.float32
+        y = halo_conv(x.astype(dt), kernel.astype(dt), mesh,
+                      stride=self.stride, block=self.block)
+        if bias is not None:
+            y = y + bias.astype(y.dtype)
         return save_conv_out(y)
 
 
@@ -728,10 +839,9 @@ class UpsampleConvLayer(nn.Module):
         if self.upsample:
             x = upsample_nearest(x, self.upsample)
         pad = self.kernel_size // 2
-        if self.pad_mode == "zero":
-            x = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
-        else:
-            x = reflect_pad_2d(x, pad)
         # ExpandNetwork's k9 head 32→3 lives HERE, not in ConvLayer
         # (networks.py:518-520)
-        return _routed_conv(self, x)
+        if self.pad_mode == "zero":
+            x = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
+            return _routed_conv(self, x)
+        return _reflect_padded_conv(self, x)

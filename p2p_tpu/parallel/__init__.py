@@ -10,8 +10,9 @@
 - ``tp``       tensor parallelism: Megatron-style channel shards on the
                ResNet trunk's conv pairs over the ``model`` mesh axis
                (the tree builder is a shim over ``rules``).
-- ``spatial``  GSPMD spatial sharding of H with explicit shard_map halo
-               exchange for the stride-1 conv trunk.
+- ``spatial``  sharding of H: GSPMD for the ops whose rows split alike,
+               one shard_map (halo exchange + local VALID conv) for
+               every reflect-padded convolution.
 - ``temporal`` sequence parallelism over video frames for the vid2vid
                temporal discriminator.
 - ``pp``       pipeline parallelism: GPipe fill/drain over the generator's
@@ -51,9 +52,7 @@ from p2p_tpu.parallel.rules import (
 from p2p_tpu.parallel.tp import place_state_tp, tp_sharding_tree
 from p2p_tpu.parallel.spatial import (
     check_spatial_divisible,
-    conv2d_local,
-    make_sharded_conv,
-    sharded_conv2d,
+    halo_conv,
     spatial_activation_sharding,
 )
 from p2p_tpu.parallel.temporal import (
@@ -86,9 +85,7 @@ __all__ = [
     "tp_sharding_tree",
     "ring_shift",
     "check_spatial_divisible",
-    "conv2d_local",
-    "make_sharded_conv",
-    "sharded_conv2d",
+    "halo_conv",
     "spatial_activation_sharding",
     "gather_frames",
     "make_sharded_temporal_conv",

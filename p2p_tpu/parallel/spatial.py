@@ -1,112 +1,96 @@
-"""GSPMD spatial sharding — large images split along H over the ``spatial``
+"""Spatial sharding — large images split along H over the ``spatial``
 mesh axis (BASELINE configs[2] Cityscapes 512×256, configs[3] pix2pixHD
 1024×512; pix2pixHD at the paper's 2048×1024 on data=2 × spatial=2 is the
 one layout that has run on chips, PERF.md section 4).
 
-Two complementary paths, per the scaling-book recipe ("annotate shardings,
-let XLA insert collectives, profile, hand-optimize what's left"):
+The batch is laid out ``P(('data', 'fsdp'), 'spatial', None, None)`` and
+the whole train step is one ``jit`` (``parallel.dp.make_parallel_train_step``).
+Who partitions what inside it, per the scaling-book recipe ("annotate
+shardings, let XLA insert collectives, profile, hand-optimize what's left"):
 
-1. **GSPMD path (default).** Shard the batch ``P('data', 'spatial', None,
-   None)`` and ``jit`` the whole train step. XLA's spatial partitioner
-   inserts the conv halo exchanges itself — including for the stride-2
-   encoder convs where manual index bookkeeping is error-prone. This is the
-   production path; ``p2p_tpu.parallel.dp.make_parallel_train_step`` uses it
-   for every preset.
+1. **GSPMD** partitions every op whose input and output rows split alike:
+   the zero-padded (``SAME``) convolutions of the discriminators and of
+   VGG19 with the halo exchanges XLA inserts itself, the pools, every
+   elementwise pass, the losses and the optimizers.
 
-2. **shard_map path (hand-optimized).** For the stride-1 ResidualBlock trunk
-   (9 × k3 convs at 128ch — the FLOPs bulk of ExpandNetwork/ResnetGenerator,
-   ref networks.py:472-480), :func:`sharded_conv2d` does one explicit
-   nearest-neighbor ``ppermute`` halo exchange per conv and computes purely
-   locally, guaranteeing no accidental resharding. Verified bitwise against
-   the unsharded conv in tests/test_parallel.py.
+2. **One ``shard_map`` a reflect-padded convolution** (:func:`halo_conv`;
+   every ``ops/conv.ConvLayer`` / ``UpsampleConvLayer`` site of a generator
+   under a mesh whose ``spatial`` axis is above 1, chosen by
+   ``ops/conv.halo_conv_mesh`` from the input's shape and the visible
+   mesh). The reflect-padded tensor has H + 2p rows, which do not split
+   like the activation's H, so GSPMD re-windowed every such layer (pads to
+   an even extent, dynamic slices, whole-tensor layout copies, several
+   collective-permutes a layer and a two-stage gradient all-reduce;
+   PERF.md section 6, PR 35). Here each shard exchanges ``k // 2`` rows
+   with its neighbours (``parallel.halo.halo_exchange``: one ``ppermute``
+   pair, reflected rows at the image's two edges), pads W itself and runs
+   a local ``VALID`` convolution: the shard's output rows are exactly its
+   slice of the unsharded layer's, stride 1 or 2, and GSPMD never sees
+   the padded tensor. tests/test_spatial4.py holds values, gradients and
+   the compiled text's collectives against the unsharded layer.
 
-Halo sizing: a stack of stride-1 convs with kernels k_i needs Σ (k_i // 2)
-halo rows if exchanged once up front, or k//2 per conv if exchanged per-conv;
-:func:`residual_block_sharded` exchanges once per conv (2 rows/block) which
-keeps each message at ~W×128×4 bytes — latency-bound but overlappable.
+The Pallas norm kernels wrap themselves in a ``shard_map`` of the same
+layout (``ops/pallas/instance_norm``), and a bare ``reflect_pad_2d`` of two
+rows or more builds its halo the same way (``ops/conv``).
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
-import jax.numpy as jnp
-from jax import lax, shard_map
+from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from p2p_tpu.core.mesh import SPATIAL_AXIS
+
+from p2p_tpu.core.mesh import BATCH_AXES, SPATIAL_AXIS
 from p2p_tpu.parallel.halo import halo_exchange
 
 _DIMNUMS = ("NHWC", "HWIO", "NHWC")
 
 
-def conv2d_local(
+def halo_conv(
     x: jax.Array,
     kernel: jax.Array,
-    *,
-    stride: int = 1,
-    w_pad_mode: str = "reflect",
-) -> jax.Array:
-    """Plain local conv, H already halo-padded; W padded locally (unsharded)."""
-    pw = kernel.shape[1] // 2
-    if pw:
-        if w_pad_mode == "reflect":
-            x = jnp.pad(x, ((0, 0), (0, 0), (pw, pw), (0, 0)), mode="reflect")
-        elif w_pad_mode == "zero":
-            x = jnp.pad(x, ((0, 0), (0, 0), (pw, pw), (0, 0)))
-        elif w_pad_mode == "wrap":
-            x = jnp.pad(x, ((0, 0), (0, 0), (pw, pw), (0, 0)), mode="wrap")
-        else:
-            raise ValueError(f"unknown w_pad_mode {w_pad_mode!r}")
-    dn = lax.conv_dimension_numbers(x.shape, kernel.shape, _DIMNUMS)
-    return lax.conv_general_dilated(
-        x, kernel, (stride, stride), "VALID", dimension_numbers=dn
-    )
-
-
-def sharded_conv2d(
-    x: jax.Array,
-    kernel: jax.Array,
-    *,
-    axis_name: str = SPATIAL_AXIS,
-    edge_mode: str = "reflect",
-) -> jax.Array:
-    """Stride-1 'same' conv on an H-sharded NHWC shard (inside shard_map).
-
-    One bidirectional ppermute of k//2 boundary rows, then a fully local
-    VALID conv — the per-shard output rows exactly equal the corresponding
-    slice of the unsharded conv output.
-    """
-    kh = kernel.shape[0]
-    halo = kh // 2
-    x = halo_exchange(x, dim=1, halo=halo, axis_name=axis_name,
-                      edge_mode=edge_mode)
-    return conv2d_local(x, kernel, stride=1, w_pad_mode=edge_mode)
-
-
-def make_sharded_conv(
     mesh: Mesh,
     *,
-    axis_name: str = SPATIAL_AXIS,
-    edge_mode: str = "reflect",
-):
-    """Wrap :func:`sharded_conv2d` in shard_map over ``mesh`` for global
-    NHWC arrays sharded along H. Returns ``fn(x_global, kernel) -> y_global``.
-    """
-    spec_x = P(None, axis_name, None, None)
+    stride: int = 1,
+    block: int = 0,
+) -> jax.Array:
+    """ReflectionPad(k // 2) + ``VALID`` conv of the global NHWC ``x``,
+    laid out ``P((data, fsdp), spatial, None, None)`` on ``mesh``, with
+    the replicated HWIO ``kernel`` (k odd): one ``shard_map``. Returns the
+    layer's global output in the same layout.
 
-    @functools.partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=(spec_x, P()),
-        out_specs=spec_x,
-    )
-    def _fn(x, kernel):
-        return sharded_conv2d(
-            x, kernel, axis_name=axis_name, edge_mode=edge_mode
-        )
+    A shard's local rows have to exceed ``k // 2`` and divide by
+    ``stride``: with a symmetric halo of ``k // 2`` rows the local
+    ``VALID`` conv then gives ``rows / stride`` output rows, the shard's
+    own slice of the unsharded output (for k3 stride 2 an even number of
+    local rows). W is not sharded and is padded locally, with the reflect
+    pad's one-pass backward (``ops/conv.reflect_pad_w``); H's backward is
+    the transpose of the ``ppermute`` pair and the concatenate. ``block``
+    > 0 runs the local conv on blocks of pixels (``ops/conv.blocked_conv``:
+    the thin k7 / k9 stems and heads), stride 1.
 
-    return _fn
+    The kernel enters replicated (``P()``) and in the dtype it is given
+    in: the transpose sums its cotangent with ONE ``psum`` over every
+    mesh axis the input varies over, in that dtype. Cast it to the
+    compute dtype OUTSIDE (``ops/conv.HaloConv`` does), so a bf16 step
+    all-reduces bf16 gradients as it does elsewhere."""
+    from p2p_tpu.ops.conv import blocked_conv, reflect_pad_w
+
+    pad = kernel.shape[0] // 2
+
+    def local(xl, w):
+        xl = halo_exchange(xl, dim=1, halo=pad, axis_name=SPATIAL_AXIS,
+                           edge_mode="reflect")
+        xl = reflect_pad_w(xl, pad)
+        if block:
+            with jax.named_scope("blocked_conv"):
+                return blocked_conv(xl, w, block)
+        return lax.conv_general_dilated(
+            xl, w, (stride, stride), "VALID", dimension_numbers=_DIMNUMS)
+
+    spec = P(BATCH_AXES, SPATIAL_AXIS, None, None)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, P()),
+                         out_specs=spec)(x, kernel)
 
 
 def spatial_activation_sharding(mesh: Mesh) -> NamedSharding:

@@ -24,6 +24,19 @@ whose debug locations name files and LINES of the call stack (moving
 pix2pixhd step and nothing else, PR 27). ``sha256`` is therefore taken
 with every payload replaced by the hash of its location-free text;
 ``sha256_raw`` is the text as lowered.
+
+``--compile`` also COMPILES the lowered step for the described chips
+(~5 min for the four-chip cell) and prints what the compiled text
+says of it: the compiler's own ``estimated_cycles`` summed over the
+module and by opcode (a ranking of whole steps, not a time: PERF.md
+section 6), the count and the bytes of every kind of collective
+(``analysis/jaxpr_lint``), the all-reduced bytes by dtype, and the
+temporaries. A PR on ``pix2pixhd_2048x1024.train_spatial4`` reads
+whether a form engaged, and whether a shard was undone (an all-gather or
+an all-to-all), off these before it asks for four chips:
+
+    python scripts/step_program_hash.py --compile \
+        --cell pix2pixhd_2048x1024.train_spatial4
 """
 
 from __future__ import annotations
@@ -60,6 +73,56 @@ def without_kernel_locations(text: str) -> str:
     return _BODY.sub(digest, text)
 
 
+_CYCLES = re.compile(r'"estimated_cycles":"(\d+)"')
+_OPCODE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
+_FUSION_KIND = re.compile(r"\bkind=(k[A-Za-z]+)")
+
+
+def compiled_census(compiled) -> dict:
+    """What the compiled step's text says of itself (module docstring)."""
+    # its private patterns too: this also runs on a parent's tree (--root)
+    from p2p_tpu.analysis.jaxpr_lint import (
+        _HLO_ARRAY_RE,
+        _HLO_DTYPE_BYTES,
+        _HLO_OP_RE,
+        collect_collectives,
+        hlo_collective_bytes,
+    )
+
+    text = compiled.as_text()
+    cycles, ops, reduced = {}, {}, {}
+    for ln in text.splitlines():
+        body = ln.partition(" = ")[2]
+        m = _CYCLES.search(body)
+        if m:
+            op = _OPCODE.search(" " + body)
+            name = op.group(1) if op else "?"
+            if name == "fusion":
+                kind = _FUSION_KIND.search(body)
+                name = f"fusion:{kind.group(1)}" if kind else name
+            cycles[name] = cycles.get(name, 0) + int(m.group(1))
+            ops[name] = ops.get(name, 0) + 1
+        m = _HLO_OP_RE.search(ln)
+        if m and m.group(1) == "all-reduce":
+            for dtype, dims in _HLO_ARRAY_RE.findall(ln[:m.start(1)]):
+                n = _HLO_DTYPE_BYTES.get(dtype, 0)
+                for d in dims.split(","):
+                    n *= int(d) if d else 1
+                reduced[dtype] = reduced.get(dtype, 0) + n
+    top = sorted(cycles, key=cycles.get, reverse=True)[:8]
+    mem = compiled.memory_analysis()
+    return {
+        "estimated_cycles": sum(cycles.values()),
+        "ops_with_cycles": sum(ops.values()),
+        "cycles_by_opcode": {k: [cycles[k], ops[k]] for k in top},
+        "collectives": dict(collect_collectives(text)),
+        "collective_bytes": dict(hlo_collective_bytes(text)),
+        "all_reduce_bytes_by_dtype": reduced,
+        "temp_gib": round(mem.temp_size_in_bytes / 2 ** 30, 3),
+        "argument_gib": round(mem.argument_size_in_bytes / 2 ** 30, 3),
+    }
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--cell", required=True,
@@ -69,6 +132,11 @@ def main() -> None:
         os.path.abspath(__file__))), help="the tree to import from")
     ap.add_argument("--text", default=None,
                     help="also write the lowered text to this file")
+    ap.add_argument("--compile", action="store_true",
+                    help="also compile the step for the described chips "
+                         "and print estimated cycles, collectives, memory")
+    ap.add_argument("--compiled_text", default=None,
+                    help="with --compile: write the compiled text here")
     args = ap.parse_args()
     root = os.path.abspath(args.root)
     sys.path.insert(0, root)
@@ -140,18 +208,27 @@ def main() -> None:
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                            sharding=sharding), tree)
 
-    text = step.lower(on(state, rep),
-                      on({"input": image, "target": image}, bsh)).as_text()
+    lowered = step.lower(on(state, rep),
+                         on({"input": image, "target": image}, bsh))
+    text = lowered.as_text()
     plain = without_kernel_locations(text)
     if args.text:
         with open(args.text, "w") as f:
             f.write(plain)
-    print(json.dumps({
+    line = {
         "cell": args.cell, "root": root, "mesh": dict(mesh.shape),
         "batch": bs, "extent": [h, w], "text_bytes": len(text),
         "tpu_custom_calls": text.count("@tpu_custom_call"),
         "sha256": hashlib.sha256(plain.encode()).hexdigest(),
-        "sha256_raw": hashlib.sha256(text.encode()).hexdigest()}))
+        "sha256_raw": hashlib.sha256(text.encode()).hexdigest()}
+    print(json.dumps(line), flush=True)
+    if args.compile:
+        compiled = lowered.compile()
+        if args.compiled_text:
+            with open(args.compiled_text, "w") as f:
+                f.write(compiled.as_text())
+        print(json.dumps({"cell": args.cell, "sha256": line["sha256"][:8],
+                          **compiled_census(compiled)}))
 
 
 if __name__ == "__main__":

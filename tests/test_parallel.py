@@ -1,5 +1,5 @@
 """Parallelism tests on the 8-fake-CPU-device mesh (SURVEY.md §4.3):
-halo exchange vs jnp.pad oracles, sharded convs vs unsharded bitwise,
+halo exchange vs jnp.pad oracles, the halo convolution vs unsharded,
 GSPMD stride-2 conv equivalence, and DP train-step == single-device step.
 """
 
@@ -20,8 +20,8 @@ from p2p_tpu.core.mesh import (
 )
 from p2p_tpu.parallel import (
     halo_exchange,
+    halo_conv,
     make_parallel_train_step,
-    make_sharded_conv,
     make_sharded_temporal_conv,
     replicate_state,
     ring_shift,
@@ -93,16 +93,17 @@ def _conv_oracle(x, kernel, stride=1, mode="reflect"):
 
 
 @pytest.mark.parametrize("k", [3, 5])
-@pytest.mark.parametrize("edge_mode", ["reflect", "zero"])
-@pytest.mark.slow
-def test_sharded_conv2d_matches_unsharded(devices8, k, edge_mode):
-    mesh = _axis_mesh(devices8, 4, "spatial")
+@pytest.mark.parametrize("stride", [1, 2])
+def test_halo_conv_matches_unsharded(devices8, k, stride):
+    """``parallel.spatial.halo_conv`` (the one halo convolution in the
+    tree; ``ops/conv.HaloConv`` is its caller) on spatial=4, called as a
+    function on global arrays: the reflect-padded unsharded conv."""
+    mesh = make_mesh(MeshSpec(data=1, spatial=4), devices=devices8[:4])
     x = jax.random.normal(jax.random.key(1), (2, 32, 16, 4))
     kernel = jax.random.normal(jax.random.key(2), (k, k, 4, 8)) * 0.1
 
-    fn = make_sharded_conv(mesh, edge_mode=edge_mode)
-    got = fn(x, kernel)
-    want = _conv_oracle(x, kernel, mode=edge_mode)
+    got = halo_conv(x, kernel, mesh, stride=stride)
+    want = _conv_oracle(x, kernel, stride=stride)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 

@@ -155,6 +155,186 @@ def test_reflect_pad_gradient_of_a_sharded_height(devices8, axes, pad,
             and not census["all-gather"], dict(census)
 
 
+# ------------------- reflect-padded convolutions as one shard_map (PR 35)
+
+
+def _value_and_grads(layer):
+    def fn(v, a):
+        return jax.value_and_grad(
+            lambda vv, aa: jnp.sum(jnp.sin(
+                layer.apply(vv, aa).astype(jnp.float32))),
+            argnums=(0, 1))(v, a)
+    return fn
+
+
+def _in_mesh(fn, mesh):
+    def wrapped(*args):
+        with mesh_context(mesh):
+            return fn(*args)
+    return jax.jit(wrapped, in_shardings=(replicated(mesh),
+                                          batch_sharding(mesh)))
+
+
+def _halo_sites():
+    from p2p_tpu.ops.conv import ConvLayer, UpsampleConvLayer
+
+    # name -> (layer, input shape, mesh axes, engages, blocked inside)
+    return {
+        "k3_stride1": (ConvLayer(16, 3), (2, 32, 24, 8),
+                       dict(data=2, spatial=2), True, False),
+        "k3_stride2": (ConvLayer(16, 3, stride=2), (2, 32, 24, 8),
+                       dict(data=2, spatial=2), True, False),
+        "k5_stride1_spatial4": (ConvLayer(8, 5), (2, 32, 16, 4),
+                                dict(data=1, spatial=4), True, False),
+        "k5_stride2_spatial4": (ConvLayer(8, 5, stride=2), (2, 32, 16, 4),
+                                dict(data=1, spatial=4), True, False),
+        "k7_stem_blocked": (ConvLayer(32, 7), (2, 256, 256, 3),
+                            dict(data=2, spatial=2), True, True),
+        "up2_plain_chain": (UpsampleConvLayer(128, 3, upsample=2),
+                            (2, 16, 16, 8), dict(data=2, spatial=2),
+                            True, False),
+        "no_bias_fsdp": (ConvLayer(16, 3, use_bias=False), (2, 32, 24, 8),
+                         dict(fsdp=2, spatial=2), True, False),
+        # what keeps GSPMD's path
+        "spatial1": (ConvLayer(16, 3), (2, 32, 24, 8),
+                     dict(data=2), False, False),
+        "odd_local_rows_stride2": (ConvLayer(16, 3, stride=2),
+                                   (2, 30, 24, 8),
+                                   dict(data=2, spatial=2), False, False),
+        "zero_pad": (ConvLayer(16, 3, pad_mode="zero"), (2, 32, 24, 8),
+                     dict(data=2, spatial=2), False, False),
+    }
+
+
+@pytest.mark.parametrize("site", list(_halo_sites()))
+def test_reflect_padded_conv_as_one_shard_map(devices8, site):
+    """A reflect-padded ``ConvLayer`` / ``UpsampleConvLayer`` site under
+    ``mesh_context`` (PR 35): where ``ops/conv.halo_conv_mesh`` engages
+    (``spatial`` > 1, an odd kernel, local rows above the pad and a
+    multiple of the stride, no axis beyond data / fsdp / spatial) the
+    site is ONE ``shard_map`` (``parallel.spatial.halo_conv``), counted
+    in ``conv_form_sites()["halo"]``; forward, input gradient and kernel
+    / bias gradient equal the unsharded layer's, the parameter tree is
+    ``Conv_0/{kernel,bias}``, and the compiled text moves halo rows only
+    (collective-permutes; no all-gather, no all-to-all). Everything else
+    keeps the pad-then-conv chain, counts nothing and is still right."""
+    from p2p_tpu.ops.conv import conv_form_sites
+
+    layer, shape, axes, engages, blocked = _halo_sites()[site]
+    mesh = _mesh(devices8, **axes)
+    x = jax.random.normal(jax.random.key(1), shape)
+    before = conv_form_sites()
+    variables = layer.init(jax.random.key(2), x)
+    assert conv_form_sites()["halo"] == before["halo"]      # no mesh: 0
+    assert set(variables["params"]["Conv_0"]) == (
+        {"kernel", "bias"} if layer.use_bias else {"kernel"})
+    fn = _value_and_grads(layer)
+    want = jax.jit(fn)(variables, x)
+    before = conv_form_sites()
+    sharded = _in_mesh(fn, mesh)
+    got = sharded(variables, x)
+    took = {k: v - before[k] for k, v in conv_form_sites().items()}
+    assert took == {"halo": int(engages), "blocked": int(blocked),
+                    "nearest_up2": 0}
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=2e-4,
+            atol=2e-4 * float(jnp.max(jnp.abs(b))) + 1e-6)
+    if engages:
+        census = collect_collectives(
+            sharded.lower(variables, x).compile().as_text())
+        assert census["collective-permute"] and not census["all-gather"] \
+            and not census["all-to-all"], dict(census)
+
+
+@pytest.mark.parametrize("axes, kwargs", [
+    (dict(spatial=2, model=2), {}),     # a tensor-parallel kernel: P() would
+    (dict(spatial=2, pipe=2), {}),      # gather it at every site
+    (dict(spatial=2, time=2), {}),
+    (dict(data=2, spatial=2), dict(int8=True)),
+    (dict(data=2, spatial=2), dict(pad_mode="zero_after", stride=2)),
+], ids=lambda v: "-".join(f"{k}{n}" for k, n in v.items()) or "reflect")
+def test_halo_form_declines(devices8, axes, kwargs):
+    """Traced only (GSPMD's partition of such programs is not this
+    test's, and the CPU backend refuses an int8 pad along sharded rows):
+    a mesh with an axis beyond data / fsdp / spatial above 1, the int8
+    path and a zero pad trace no ``shard_map`` and count no ``halo``
+    site; the same layer on data=2 x spatial=2 traces one."""
+    from p2p_tpu.ops.conv import ConvLayer, conv_form_sites
+
+    layer = ConvLayer(16, 3, **kwargs)
+    x = jnp.ones((2, 32, 24, 8))
+    variables = layer.init(jax.random.key(0), x)
+
+    def traced(mesh):
+        with mesh_context(mesh):
+            return str(jax.make_jaxpr(
+                lambda a: layer.apply(variables, a))(x))
+
+    before = conv_form_sites()["halo"]
+    assert "shard_map" not in traced(_mesh(devices8, **axes))
+    assert conv_form_sites()["halo"] == before
+    if not kwargs:
+        assert "shard_map" in traced(_mesh(devices8, data=2, spatial=2))
+        assert conv_form_sites()["halo"] == before + 1
+
+
+class _TwoLayers(nn.Module):
+    dtype: object = None
+
+    @nn.compact
+    def __call__(self, x):
+        from p2p_tpu.ops.conv import ConvLayer
+
+        x = ConvLayer(16, 3, stride=2, dtype=self.dtype)(x)
+        return ConvLayer(16, 3, dtype=self.dtype)(nn.relu(x))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_two_halo_layers_move_halo_rows_and_one_gradient_sum(devices8,
+                                                             dtype):
+    """A stride-2 and a stride-1 reflect-padded layer with a ReLU
+    between, forward and both gradients on data=2 x spatial=2: the
+    compiled text holds no all-gather and no all-to-all, and no more
+    collective-permutes than one pair a layer and direction (GSPMD's
+    partition of the padded tensor made 18 of two such layers on the
+    described chips, PERF.md section 6, PR 35). In the traced program
+    every kernel's cotangent is summed by ONE psum over data, fsdp and
+    spatial together, in the compute dtype (the kernel is cast outside
+    the shard_map): a bf16 step all-reduces bf16."""
+    from p2p_tpu.ops.conv import conv_form_sites
+
+    mesh = _mesh(devices8, data=2, spatial=2)
+    block = _TwoLayers(dtype=jnp.dtype(dtype))
+    x = jax.random.normal(jax.random.key(1), (2, 32, 24, 8)).astype(dtype)
+    variables = block.init(jax.random.key(2), x)
+    fn = _value_and_grads(block)
+    before = conv_form_sites()["halo"]
+    sharded = _in_mesh(fn, mesh)
+    census = collect_collectives(
+        sharded.lower(variables, x).compile().as_text())
+    assert conv_form_sites()["halo"] == before + 2
+    assert not census["all-gather"] and not census["all-to-all"], dict(census)
+    assert 0 < census["collective-permute"] <= 8, dict(census)
+
+    from p2p_tpu.analysis.jaxpr_lint import iter_eqns
+
+    psums = [e for e in iter_eqns(jax.make_jaxpr(sharded)(variables, x).jaxpr)
+             if e.primitive.name.startswith("psum")
+             and e.invars[0].aval.ndim == 4]
+    assert len(psums) == 2, psums
+    for e in psums:
+        assert set(e.params["axes"]) == {"data", "fsdp", "spatial"}
+        assert e.invars[0].aval.dtype == jnp.dtype(dtype)
+    if dtype == "float32":
+        for a, b in zip(jax.tree.leaves(sharded(variables, x)),
+                        jax.tree.leaves(jax.jit(fn)(variables, x))):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=2e-4,
+                atol=2e-4 * float(jnp.max(jnp.abs(b))) + 1e-6)
+
+
 # --------------------------------------------- the generator's layer forms
 
 
@@ -177,18 +357,19 @@ class _Block(nn.Module):
 def _layer_forms():
     from p2p_tpu.ops.conv import ConvLayer, UpsampleConvLayer
 
-    # name -> (layer, input shape, may GSPMD's own k3 reflect pad show);
-    # the extents are the smallest at which each form is the one the
-    # preset takes at 2048x1024
+    # name -> (layer, input shape, may a reflect pad of GSPMD's own show:
+    # none does since the reflect-padded convolutions run as one
+    # shard_map, PR 35); the extents are the smallest at which each form
+    # is the one the preset takes at 2048x1024
     return {
         "enhancer_stem_k7_blocked": (
             ConvLayer(32, kernel_size=7), (2, 256, 256, 3), False),
         "enhancer_head_k7_blocked": (
             ConvLayer(3, kernel_size=7), (2, 256, 256, 32), False),
         "down_k3_stride2": (
-            ConvLayer(16, kernel_size=3, stride=2), (2, 128, 128, 8), True),
+            ConvLayer(16, kernel_size=3, stride=2), (2, 128, 128, 8), False),
         "resblock_k3_fused_norm_act_residual": (
-            _Block(), (2, 64, 64, 16), True),
+            _Block(), (2, 64, 64, 16), False),
         "up2_conv_subpixel": (
             UpsampleConvLayer(8, kernel_size=3, upsample=2),
             (2, 256, 320, 16), False),
@@ -203,11 +384,9 @@ def test_generator_layer_form_keeps_its_h_shard(devices8, monkeypatch, form):
     single-device one, rows cross the shard boundary as halo
     ``collective-permute``s, and no all-gather reaches a quarter of the
     layer's input (the smoke's bound is the step's smallest normed
-    activation; a gathered activation is the whole of one). The k7
-    layers, the subpixel upsample and the pooling also hold no
-    all-to-all; a k3 reflect pad is GSPMD's own and on the CPU backend
-    its one-row reverse survives as one (not on the chip: PERF.md
-    section 4), so it is not pinned there."""
+    activation; a gathered activation is the whole of one), and no
+    all-to-all (a reflect pad left to GSPMD shows as one on the CPU
+    backend: its reverse along the sharded rows)."""
     from p2p_tpu.ops.conv import conv_form_sites
 
     # the Pallas norm kernels (interpreted) inside their shard_map, as on
@@ -216,21 +395,14 @@ def test_generator_layer_form_keeps_its_h_shard(devices8, monkeypatch, form):
     layer, shape, gspmd_pad = _layer_forms()[form]
     mesh = _mesh(devices8, data=2, spatial=2)
     x = jax.random.normal(jax.random.key(1), shape)
-    blocked = conv_form_sites()["blocked"]
+    before = conv_form_sites()
     variables = layer.init(jax.random.key(2), x)
-    assert (conv_form_sites()["blocked"] > blocked) == ("blocked" in form)
+    assert (conv_form_sites()["blocked"] > before["blocked"]) == (
+        "blocked" in form)
+    assert conv_form_sites()["halo"] == before["halo"]      # no mesh
 
-    def value_and_grads(v, a):
-        return jax.value_and_grad(
-            lambda vv, aa: jnp.sum(jnp.sin(layer.apply(vv, aa))),
-            argnums=(0, 1))(v, a)
-
-    def in_mesh(v, a):
-        with mesh_context(mesh):
-            return value_and_grads(v, a)
-
-    sharded = jax.jit(in_mesh, in_shardings=(replicated(mesh),
-                                             batch_sharding(mesh)))
+    value_and_grads = _value_and_grads(layer)
+    sharded = _in_mesh(value_and_grads, mesh)
     got = jax.tree.leaves(sharded(variables, x))
     want = jax.tree.leaves(jax.jit(value_and_grads)(variables, x))
     for a, b in zip(got, want):
