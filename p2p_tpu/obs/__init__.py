@@ -40,7 +40,9 @@ from p2p_tpu.obs.sinks import (
     prometheus_exposition,
 )
 from p2p_tpu.obs.spans import (
+    GcPauseMeter,
     SpanRecorder,
+    StepClock,
     get_recorder,
     span,
     timed_annotation,
@@ -64,6 +66,7 @@ __all__ = [
     "Counter",
     "EWMARate",
     "Gauge",
+    "GcPauseMeter",
     "Histogram",
     "JSONLSink",
     "MemoryWatchdog",
@@ -76,6 +79,7 @@ __all__ = [
     "Sink",
     "SpanRecorder",
     "StdoutSink",
+    "StepClock",
     "StepTimer",
     "TensorBoardSink",
     "add_sentinel_handler",

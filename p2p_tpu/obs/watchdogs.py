@@ -63,7 +63,6 @@ class RetraceWatchdog:
         self.compiles += 1
         reg = self.registry
         if reg is not None:
-            reg.counter("xla_compiles").inc()
             reg.histogram("xla_compile_secs").observe(duration)
         if self.armed:
             self.unexpected += 1
@@ -173,8 +172,7 @@ def crosscheck_hbm_budget(cfg, mesh, registry=None, logger=None,
     (the trainer passes its VGG feature tree — loaded before this check
     runs, so it is part of the honest baseline, not drift).
 
-    Publishes ``hbm_budget_state_bytes`` / ``hbm_budget_live_bytes``
-    gauges and a ``kind="hbm_budget"`` record; WARNS (and counts
+    Writes a ``kind="hbm_budget"`` record with both; WARNS (and counts
     ``hbm_budget_drift_total``) past :data:`HBM_BUDGET_DRIFT`. Returns
     the record, or None on backends that report no memory stats (CPU
     CI)."""
@@ -195,11 +193,8 @@ def crosscheck_hbm_budget(cfg, mesh, registry=None, logger=None,
            "extra_bytes": int(extra_bytes),
            "live_bytes_in_use": live, "drift": round(drift, 4),
            "out_of_band": out_of_band, "mesh": sizes}
-    if registry is not None:
-        registry.gauge("hbm_budget_state_bytes").set(expected)
-        registry.gauge("hbm_budget_live_bytes").set(live)
-        if out_of_band:
-            registry.counter("hbm_budget_drift_total").inc()
+    if registry is not None and out_of_band:
+        registry.counter("hbm_budget_drift_total").inc()
     if logger is not None:
         logger.log(rec, force=True)
     if out_of_band:

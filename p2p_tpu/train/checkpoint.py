@@ -502,7 +502,6 @@ class CheckpointManager:
                 restored = restored.replace(**updates)
                 self.last_restore_initialized_quant = [
                     "/".join(p) for p in missing]
-                self._reg().counter("quant_init_total").inc(len(missing))
             if shardings is not None:
                 # counted only on SUCCESS — the audit counter must name
                 # resharded restores that happened, not ones attempted

@@ -52,10 +52,12 @@ from p2p_tpu.data.pipeline import PairedImageDataset, device_prefetch, make_load
 from p2p_tpu.models.registry import generator_gauges
 from p2p_tpu.models.vgg import load_vgg19_params
 from p2p_tpu.obs import (
+    GcPauseMeter,
     MemoryWatchdog,
     MetricsLogger,
     RetraceWatchdog,
     SpanRecorder,
+    StepClock,
     add_sentinel_handler,
     crosscheck_hbm_budget,
     timed_annotation,
@@ -76,8 +78,9 @@ from p2p_tpu.utils.images import ingest, save_img
 def init_trainer_obs(tr) -> None:
     """Shared telemetry wiring for both trainers (p2p_tpu.obs): run manifest
     + provenance record, span recorder + trace path, recompile/HBM
-    watchdogs, smoothed dispatch-rate EWMA, and sentinel-event routing into
-    the run's metrics stream. ``tr`` needs cfg/workdir/mesh/logger/obs."""
+    watchdogs, the step clock (when each dispatch finished) with the
+    collector's pauses, and sentinel-event routing into the run's metrics
+    stream. ``tr`` needs cfg/workdir/mesh/logger/obs."""
     cfg = tr.cfg
     tr.spans = SpanRecorder()
     tr._trace_path = os.path.join(tr.workdir, f"trace_{cfg.name}.json")
@@ -95,7 +98,9 @@ def init_trainer_obs(tr) -> None:
         )
     tr.retrace = RetraceWatchdog(registry=tr.obs, logger=tr.logger)
     tr.memwatch = MemoryWatchdog(registry=tr.obs)
-    tr._img_rate = tr.obs.ewma("img_dispatch_rate")
+    tr.step_clock = StepClock(tr.obs)
+    tr.gc_pauses = GcPauseMeter()
+    tr.gc_pauses.install()
     tr._sentinel_handler = None
     if cfg.debug.nan_sentinel:
         # route in-jit sentinel events (obs/taps.py) into this run's
@@ -131,13 +136,15 @@ def init_trainer_obs(tr) -> None:
 
 def close_trainer_obs(tr) -> None:
     """Tear down the process-global hooks ``init_trainer_obs`` installed —
-    the compile-event listener and the sentinel handler. Without this a
-    SECOND trainer in the same process (sweeps, phase global→full, tests)
-    would keep routing its compiles and NaN events into the FIRST run's
-    metrics stream. Idempotent; the CLI calls it after fit()."""
+    the compile-event listener, the collector's callback and the sentinel
+    handler. Without this a SECOND trainer in the same process (sweeps,
+    phase global→full, tests) would keep routing its compiles, collections
+    and NaN events into the FIRST run's metrics stream. Idempotent; the
+    CLI calls it after fit()."""
     from p2p_tpu.obs import remove_sentinel_handler
 
     tr.retrace.close()
+    tr.gc_pauses.remove()
     if getattr(tr, "_sentinel_handler", None) is not None:
         remove_sentinel_handler(tr._sentinel_handler)
         tr._sentinel_handler = None
@@ -670,24 +677,29 @@ def queue_health_observation(tr, metrics_dev, k: int) -> None:
 
 def flush_health_observations(tr) -> None:
     """Drain the delayed slot (end of epoch / before eval or checkpoint:
-    the last dispatch must not escape the sentinel)."""
+    the last dispatch must not escape the sentinel). The step clock's
+    epoch ends with it: no interval spans two epochs."""
     if tr.health is None:
         return
     pend, tr._pending_health = tr._pending_health, None
     if pend is not None:
         consume_health_observation(tr, pend)
+    tr.step_clock.close_epoch()
 
 
 def consume_health_observation(tr, pend) -> None:
     """Fetch one queued dispatch's metrics and walk them through the
     sentinel + ladder, one step at a time. The ``nan`` chaos seam poisons
     the OBSERVED losses here — the ladder rehearsal hook
-    (``P2P_CHAOS=nan@50x3`` fails steps 50..52)."""
+    (``P2P_CHAOS=nan@50x3`` fails steps 50..52). The fetch is the one
+    place the host learns that a dispatch has FINISHED: the step clock
+    times the wait (``device_wait``) and stamps its return."""
     from p2p_tpu.resilience.health import poison_nan_observation
 
     first_step, dev, k = pend
-    # p2p-lint: disable=ast-host-sync-hot-loop -- this IS the designed delayed read: the fetch lands ONE DISPATCH LATE (queue_health_observation), so the device is already past it
-    host = jax.device_get(dev)
+    with tr.step_clock.device_wait(k):
+        # p2p-lint: disable=ast-host-sync-hot-loop -- this IS the designed delayed read: the fetch lands ONE DISPATCH LATE (queue_health_observation), so the device is already past it
+        host = jax.device_get(dev)
     for i in range(k):
         step = first_step + i
         m = {key: float(v[i]) if k > 1 else float(v)
@@ -1304,7 +1316,11 @@ class Trainer:
         call leaves ONE ``train_epoch`` record in the span ring and the
         metrics stream: the sum of each phase, the time to the first
         dispatch and the slowest single wait, dispatch and bookkeeping
-        with the step each fell on."""
+        with the step each fell on; with the health queue on also the
+        step clock's fields (``obs.StepClock``): how long the host waited
+        for the device, the host's own work, the interval from one
+        step's completion to the next, and which phase starved the
+        device where an interval ran long."""
         with self.spans.span(
                 "train_epoch", registry=self.obs, force=True,
                 histogram=self.obs.histogram("train_epoch_secs"),
@@ -1317,6 +1333,8 @@ class Trainer:
                      ) -> Dict[str, float]:
         cfg = self.cfg
         hist = self.obs.histogram
+        before = (time.process_time(), self.gc_pauses.seconds,
+                  self.retrace.compiles)
         with timed_annotation("epoch_setup", hist("epoch_setup_secs")) as setup:
             # Per-epoch entropy (shuffle order + augmentation crops),
             # reproducible across same-seed runs. Defaults to the current
@@ -1363,6 +1381,10 @@ class Trainer:
         phase_s = {"feed_next": 0.0, "train_dispatch": 0.0,
                    "step_bookkeeping": 0.0}
         slowest = dict.fromkeys(phase_s, (0.0, 0))
+        # for the step clock: (steps done, start, seconds) of each
+        # dispatch and the seconds of each feed_next
+        dispatches: List = []
+        feeds: List[float] = []
 
         def note(phase, span, at):
             phase_s[phase] += span.secs
@@ -1392,11 +1414,11 @@ class Trainer:
             if at == 0:
                 record["epoch_start_s"] = round(disp.t0 - setup.t0, 6)
             note("train_dispatch", disp, at)
+            dispatches.append((at, disp.t0, disp.secs))
             stop = False
             with timed_annotation("step_bookkeeping", book_hist) as book:
                 if k == 1 and self._collectives_of is not None:
                     note_step_collectives(self, batch_or_stack)
-                self._img_rate.mark(k * cfg.data.batch_size)
                 # divergence sentinel: queue THIS dispatch, read the
                 # previous one (a wait on the device when the host runs
                 # ahead, not idle time); scanned dispatches feed their
@@ -1520,6 +1542,7 @@ class Trainer:
             # step's wait: the histogram counts one wait a dispatch
             with timed_annotation("feed_next") as feed:
                 item = next(batches, None)
+            feeds.append(feed.secs)
             if item is None:
                 phase_s["feed_next"] += feed.secs
                 break
@@ -1551,6 +1574,17 @@ class Trainer:
             record[f"{phase}_s"] = round(secs, 6)
             record[f"slowest_{phase}_s"] = round(slowest[phase][0], 6)
             record[f"slowest_{phase}_step"] = slowest[phase][1]
+        clock = self.step_clock.epoch_fields(dispatches, feeds, drain.t0)
+        if clock:
+            # the host's own work: the per-step phases less what the
+            # bookkeeping spent waiting for the device
+            record.update(
+                clock,
+                host_s=round(sum(phase_s.values()) - first_feed_s
+                             - clock["device_wait_s"], 6),
+                cpu_s=round(time.process_time() - before[0], 6),
+                gc_pause_s=round(self.gc_pauses.seconds - before[1], 6),
+                compiles=self.retrace.compiles - before[2])
         # which form the thin convolutions took, how the reflect pads'
         # backward was built and which dtype VGG19's activations were
         # stored in (ops/conv.py and losses/perceptual.py count as they are

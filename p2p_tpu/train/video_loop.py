@@ -290,7 +290,6 @@ class VideoTrainer:
                 else:
                     self.state, last = self.train_step(self.state, batch)
                     step_metrics = last
-            self._img_rate.mark(k * cfg.data.batch_size * cfg.data.n_frames)
             # divergence sentinel: delayed read, per-step rows on the
             # scan path (cf. Trainer.train_epoch)
             queue_health_observation(self, metrics if k > 1 else last, k)

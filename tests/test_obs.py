@@ -414,10 +414,15 @@ def test_trainer_obs_wiring(tmp_path, monkeypatch):
         assert 0 < span["epoch_start_s"] <= span["sec"]
         assert tr.obs.histogram("feed_next_secs").count == 2
         assert tr.obs.histogram("step_bookkeeping_secs").count == 2
-        # the dispatch-rate EWMA saw the epoch's dispatches (2 marks: the
-        # first pins the clock epoch, the second produces a rate), and
+        # the step clock saw the epoch's dispatches FINISH (one delayed
+        # read each; the two stamps give one interval, and the record the
+        # rate steps complete at, not the rate the host enqueues them), and
         # every dispatch fed the duration histogram
-        assert tr.obs.ewma("img_dispatch_rate").rate > 0
+        assert tr.obs.histogram("device_wait_secs").count == 2
+        assert tr.obs.histogram("step_interval_secs").count == 1
+        assert span["step_interval_median_s"] == pytest.approx(
+            tr.obs.histogram("step_interval_secs").sum, abs=1e-5)
+        assert span["step_interval_median_s"] > 0
         assert tr.obs.histogram("dispatch_secs").count == 2
         assert tr.retrace.armed
     finally:
@@ -592,7 +597,7 @@ def test_crosscheck_hbm_budget_record_and_warn(capsys):
     assert rec is not None and not rec["out_of_band"]
     assert rec["static_state_bytes"] == static
     assert log.recs and log.recs[0]["kind"] == "hbm_budget"
-    assert reg.gauge("hbm_budget_state_bytes").value == static
+    assert log.recs[0]["static_state_bytes"] == static
     assert "WARNING" not in capsys.readouterr().out
 
     rec = crosscheck_hbm_budget(
