@@ -38,6 +38,7 @@ preemptible fleet can resume on whatever capacity the scheduler grants.
 
 from __future__ import annotations
 
+import functools
 import os
 import signal
 import time
@@ -804,13 +805,23 @@ def log_health_summary(tr) -> None:
                       force=True)
 
 
+def scan_axis_sum(metrics, k: int):
+    """A dispatch's metrics summed over its scan axis (a single step's
+    as they are)."""
+    if k > 1:
+        metrics = jax.tree_util.tree_map(
+            lambda v: jax.numpy.sum(v, axis=0), metrics)
+    return metrics
+
+
 def mask_skipped_metrics(metrics, k: int):
     """The epoch accumulator's view of one dispatch: every metric of a
     SKIPPED step (``health_ok == 0`` — the in-jit guard dropped its
     update) zeroed, then summed over the scan axis. A single NaN step
     would otherwise poison the whole epoch's averages and feed NaN to the
     plateau controller. Without ``health_ok`` (guard off) this is the
-    plain scan-axis sum the loop always used."""
+    plain scan-axis sum the loop always used. The loops run it traced,
+    inside :func:`accumulate_metrics`."""
     import jax.numpy as jnp
 
     ok = metrics.get("health_ok")
@@ -822,10 +833,34 @@ def mask_skipped_metrics(metrics, k: int):
                   else jnp.where(okb, v, jnp.zeros_like(v)))
             for key, v in metrics.items()
         }
-    if k > 1:
-        metrics = jax.tree_util.tree_map(
-            lambda v: jnp.sum(v, axis=0), metrics)
-    return metrics
+    return scan_axis_sum(metrics, k)
+
+
+@functools.partial(jax.jit, static_argnums=2, donate_argnums=0)
+def _accumulate(sums, metrics, k: int):
+    step_metrics = mask_skipped_metrics(metrics, k)
+    if sums is not None:
+        step_metrics = jax.tree_util.tree_map(
+            jax.numpy.add, sums, step_metrics)
+    last = jax.tree_util.tree_map(lambda v: v[-1], metrics) if k > 1 else None
+    return step_metrics, last
+
+
+def accumulate_metrics(sums, metrics, k: int):
+    """One dispatch's metrics into the epoch's running sums, as ONE
+    compiled call: mask the skipped steps, sum over the scan axis, add to
+    ``sums`` (None on the epoch's first dispatch; donated otherwise).
+    Returns ``(sums, last)``, ``last`` the dispatch's last step's metrics.
+
+    One call, because a runtime holds a bounded number of computations
+    in flight a device (32 here) and blocks the dispatch that exceeds it:
+    issued eagerly this is ~3 computations a metric key, all queued
+    behind the running step, and with 11 keys the 33rd call held the host
+    until the step finished, the device then idle for the next feed +
+    dispatch (PERF.md section 6, PR 37). Compiled once a ``(k, keys)``,
+    twice with the epoch's first dispatch."""
+    sums, last = _accumulate(sums, metrics, k)
+    return sums, (metrics if k == 1 else last)
 
 
 def epoch_metric_means(host_sums, count: int):
@@ -1399,18 +1434,8 @@ class Trainer:
             # every dispatch takes the one light path: annotation +
             # histogram; the ring holds the epoch's record, not its steps
             with timed_annotation("train_dispatch", disp_hist) as disp:
-                if k > 1:
-                    self.state, metrics = self.multi_step(
-                        self.state, batch_or_stack
-                    )
-                    step_metrics = jax.tree_util.tree_map(
-                        lambda v: jax.numpy.sum(v, axis=0), metrics
-                    )
-                    last = jax.tree_util.tree_map(lambda v: v[-1], metrics)
-                else:
-                    self.state, last = self.train_step(
-                        self.state, batch_or_stack)
-                    step_metrics = last
+                step_fn = self.multi_step if k > 1 else self.train_step
+                self.state, metrics = step_fn(self.state, batch_or_stack)
             if at == 0:
                 record["epoch_start_s"] = round(disp.t0 - setup.t0, 6)
             note("train_dispatch", disp, at)
@@ -1423,7 +1448,7 @@ class Trainer:
                 # previous one (a wait on the device when the host runs
                 # ahead, not idle time); scanned dispatches feed their
                 # per-step stacked metrics so no step escapes
-                queue_health_observation(self, metrics if k > 1 else last, k)
+                queue_health_observation(self, metrics, k)
                 if self._quant_freeze_remaining:
                     # --recalibrate_steps warmup after a TP amax migration:
                     # re-pin the migrated scales (resilience/reshape.py)
@@ -1439,14 +1464,15 @@ class Trainer:
                     # dispatch can't slip past
                     from p2p_tpu.core.debug import check_finite
 
-                    check_finite(step_metrics, "step_metrics",
+                    check_finite(scan_axis_sum(metrics, k), "step_metrics",
                                  registry=self.obs)
                 # a skipped step's NaN losses must not poison the epoch-sum
                 # averages (or the plateau controller fed from them): mask
                 # skipped steps out of the ACCUMULATOR only — the raw values
-                # still reach the sentinel/check_finite/log paths above
-                step_metrics = mask_skipped_metrics(
-                    metrics if k > 1 else last, k)
+                # still reach the sentinel/check_finite/log paths above.
+                # The one call on device arrays between this dispatch and
+                # the next feed_next, whatever the number of metric keys
+                sums, last = accumulate_metrics(sums, metrics, k)
                 if count > 0 and k not in seen_kinds:
                     # first use of this dispatch shape mid-epoch (e.g. the
                     # single-step remainder after scanned dispatches): the
@@ -1454,8 +1480,6 @@ class Trainer:
                     # img_per_sec
                     compile_skew += time.perf_counter() - t_call
                 seen_kinds.add(k)
-                sums = step_metrics if sums is None else \
-                    jax.tree_util.tree_map(jax.numpy.add, sums, step_metrics)
                 first = count == 0
                 count += k
                 if first:

@@ -27,6 +27,7 @@ from p2p_tpu.obs import MetricsLogger
 from p2p_tpu.resilience import PreemptionGuard
 from p2p_tpu.train.checkpoint import CheckpointManager
 from p2p_tpu.train.loop import (
+    accumulate_metrics,
     acquire_preempt_guard,
     apply_health_lr,
     build_trainer_mesh,
@@ -38,7 +39,6 @@ from p2p_tpu.train.loop import (
     flush_health_observations,
     init_trainer_obs,
     log_health_summary,
-    mask_skipped_metrics,
     metrics_path,
     perform_rollback,
     plan_elastic_restore,
@@ -46,6 +46,7 @@ from p2p_tpu.train.loop import (
     queue_health_observation,
     release_preempt_guard,
     save_trainer_ckpt,
+    scan_axis_sum,
 )
 from p2p_tpu.utils.images import ingest
 from p2p_tpu.train.video_step import (
@@ -281,30 +282,21 @@ class VideoTrainer:
                 cm = timed_annotation("train_dispatch", disp_hist)
             n_disp += 1
             with cm:
-                if k > 1:
-                    self.state, metrics = self.multi_step(self.state, batch)
-                    step_metrics = jax.tree_util.tree_map(
-                        lambda v: jnp.sum(v, axis=0), metrics
-                    )
-                    last = jax.tree_util.tree_map(lambda v: v[-1], metrics)
-                else:
-                    self.state, last = self.train_step(self.state, batch)
-                    step_metrics = last
+                step_fn = self.multi_step if k > 1 else self.train_step
+                self.state, metrics = step_fn(self.state, batch)
             # divergence sentinel: delayed read, per-step rows on the
             # scan path (cf. Trainer.train_epoch)
-            queue_health_observation(self, metrics if k > 1 else last, k)
+            queue_health_observation(self, metrics, k)
             if cfg.debug.check_finite:
                 # scan-axis sum: catches an intermediate scanned step's
                 # NaN/Inf, not just the last slice (cf. Trainer)
                 from p2p_tpu.core.debug import check_finite
 
-                check_finite(step_metrics, "step_metrics", registry=self.obs)
-            # skipped steps out of the epoch accumulator (cf. Trainer)
-            step_metrics = mask_skipped_metrics(
-                metrics if k > 1 else last, k)
-            sums = step_metrics if sums is None else jax.tree_util.tree_map(
-                jnp.add, sums, step_metrics
-            )
+                check_finite(scan_axis_sum(metrics, k), "step_metrics",
+                             registry=self.obs)
+            # skipped steps out of the epoch accumulator, one compiled
+            # call a dispatch (cf. Trainer)
+            sums, last = accumulate_metrics(sums, metrics, k)
             first = count == 0
             count += k
             if first:
