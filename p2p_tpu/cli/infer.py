@@ -152,6 +152,9 @@ def main(argv=None) -> int:
                   "params-only restore needs no checkpoint template "
                   "beyond the generator", file=sys.stderr)
 
+    if cfg.model.scale > 1:
+        return _upscale_main(args, cfg)
+
     root = args.data_root or os.path.join(cfg.data.root, cfg.data.dataset)
     ds_dtype = "uint8" if cfg.data.uint8_pipeline else "float32"
     try:
@@ -211,6 +214,76 @@ def main(argv=None) -> int:
               f"ssim_mean={np.mean(ssims):.4f} ssim_max={np.max(ssims):.4f}")
     if args.stats:
         print(json.dumps({"kind": "serve_stats", **stats.as_dict()}))
+    return 0
+
+
+def _upscale_main(args, cfg) -> int:
+    """A preset whose output is ``model.scale`` times its input
+    (super-resolution): every image of the test split's input side, AT ITS
+    OWN EXTENT, to its upscaled image. An input is padded below and to the
+    right by mirroring (the SwinIR authors' test script) up to the multiple
+    its generator needs (``models/registry.input_extent_multiple``), run
+    through the serving engine of that padded extent (one engine, one
+    restore and one compile an extent), and the result cropped back."""
+    import dataclasses
+
+    from PIL import Image
+
+    from p2p_tpu.data.generate import is_image_file
+    from p2p_tpu.models.registry import input_extent_multiple
+    from p2p_tpu.serve import engine_from_checkpoint
+    from p2p_tpu.utils.images import save_img
+
+    root = args.data_root or os.path.join(cfg.data.root, cfg.data.dataset)
+    in_dir = os.path.join(
+        root, "test", "a" if cfg.data.direction == "a2b" else "b")
+    try:
+        names = sorted(f for f in os.listdir(in_dir) if is_image_file(f))
+    except FileNotFoundError:
+        names = []
+    if not names:
+        print(f"no test images under {in_dir}", file=sys.stderr)
+        return 1
+    if args.metrics:
+        print("note: --metrics needs targets of one extent; ignored for an "
+              "upscaling preset", file=sys.stderr)
+    ckpt_dir = os.path.join(
+        args.workdir, cfg.train.checkpoint_dir, cfg.data.dataset, cfg.name)
+    out_dir = args.out or os.path.join(
+        args.workdir, cfg.train.result_dir, cfg.data.dataset)
+    os.makedirs(out_dir, exist_ok=True)
+    s, m = cfg.model.scale, input_extent_multiple(cfg.model)
+    engines, step = {}, None
+    for name in names:
+        img = np.asarray(Image.open(os.path.join(in_dir, name))
+                         .convert("RGB"), np.uint8)
+        h, w = img.shape[:2]
+        ph, pw = -(-h // m) * m, -(-w // m) * m
+        lq = np.pad(img, ((0, ph - h), (0, pw - w), (0, 0)),
+                    mode="symmetric")
+        if not cfg.data.uint8_pipeline:
+            lq = ((lq.astype(np.float32) - np.float32(127.5))
+                  * np.float32(1.0 / 127.5))
+        if (ph, pw) not in engines:
+            ext = dataclasses.replace(
+                cfg, data=dataclasses.replace(
+                    cfg.data, image_size=ph * s, image_width=pw * s))
+            try:
+                engines[ph, pw], step = engine_from_checkpoint(
+                    ext, ckpt_dir, {"input": lq[None]}, step=args.step,
+                    buckets=(1,), dtype=args.dtype,
+                    mesh=_parse_mesh(args.mesh), tp_min_ch=args.tp_min_ch,
+                    with_metrics=False,
+                    compilation_cache_dir=args.compilation_cache,
+                    io_workers=args.io_threads)
+            except FileNotFoundError as e:
+                print(str(e), file=sys.stderr)
+                return 1
+        pred, _, _ = engines[ph, pw].infer_batch({"input": lq[None]})
+        save_img(np.asarray(pred[0], np.float32)[:h * s, :w * s],
+                 os.path.join(out_dir, name))
+    print(f"wrote {len(names)} x{s} predictions (checkpoint step {step}, "
+          f"{len(engines)} extents) to {out_dir}")
     return 0
 
 

@@ -307,7 +307,9 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2
 
-    h, w = cfg.image_hw
+    # the request image drives the input slot wherever the input's extent
+    # differs from the target's (model.scale > 1)
+    h, w = cfg.input_hw
     as_uint8 = cfg.data.uint8_pipeline
 
     def decode_path(path):

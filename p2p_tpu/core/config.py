@@ -29,6 +29,9 @@ class ModelConfig:
     # "vqgan" is the whole VQGAN autoencoder (models/vqgan.py): encoder,
     # learned codebook, decoder, trained under ONE loss in the G slot;
     # ngf is its base width ``ch``, the vq_* fields below its sizes.
+    # "swinir" is the SwinIR super-resolution transformer (models/swinir.py):
+    # ngf is its embedding width, n_blocks its groups (RSTB), ``scale`` below
+    # its upsampler's factor; what SwinIR fixes are constants of the module.
     generator: str = "expand"
     input_nc: int = 3
     # Label-map conditioning (0 = the input is an image). With
@@ -40,6 +43,12 @@ class ModelConfig:
     label_classes: int = 0
     label_edge: bool = False
     output_nc: int = 3
+    # Target extent over input extent (super-resolution: 4 = a 64x64 input
+    # for a 256x256 target). ``DataConfig.image_size`` / ``image_width`` state
+    # the TARGET's extent; the loader, the dummy batches (utils/images.
+    # wire_spec), cli.infer, the serving buckets and the evaluation read the
+    # input's from ``Config.input_hw``. 1 for every image-to-image preset.
+    scale: int = 1
     ngf: int = 32            # reference ExpandNetwork base width (networks.py:460)
     ndf: int = 64            # discriminator base width (networks.py:708)
     n_blocks: int = 9        # residual blocks in expand/resnet G (networks.py:472)
@@ -68,8 +77,9 @@ class ModelConfig:
     # the conv epilogue (norm + LeakyReLU) is ONE fused Pallas pass
     # (ops/pallas/norm_act.py).
     norm_d: str = "none"
-    # U-Net decoder dropout (the pix2pix noise source). The train step
-    # threads a per-step dropout rng when this is on.
+    # U-Net decoder dropout (the pix2pix noise source); for "swinir" its
+    # stochastic depth. The train step threads a per-step ``dropout`` rng
+    # when this is on.
     use_dropout: bool = False
     init_type: str = "normal"   # normal | xavier | kaiming | orthogonal
     init_gain: float = 0.02
@@ -157,6 +167,11 @@ class ModelConfig:
     # lineage's ``disc_conditional: False``); True pairs it with the
     # conditioning input, as every pix2pix-family preset does.
     d_conditional: bool = True
+    # "patch" (the multiscale PatchGAN, models/patchgan.py) | "unet" (the
+    # Real-ESRGAN lineage's U-Net with per-pixel logits at the image's
+    # extent, models/unet_d.py; ndf its width; spectrally normalised, one
+    # scale, unconditional: use_spectral_norm, num_D 1, d_conditional False).
+    discriminator: str = "patch"
     # zero padding of D's five k4 convolutions: 2 is the reference's
     # ceil(3/2) (networks.py:716), 1 the pix2pix / VQGAN PatchGAN's.
     d_padding: int = 2
@@ -176,6 +191,9 @@ class LossConfig:
     # Reduce the per-scale GAN losses of a multiscale D by their MEAN (the
     # SPADE lineage) instead of the reference's SUM (networks.py:808-850).
     gan_scale_mean: bool = False
+    # weight of the GAN term in G's loss (1 everywhere but the ESRGAN
+    # lineage, whose option files give 0.1)
+    gan_weight: float = 1.0
     lambda_feat: float = 10.0        # train.py:351
     lambda_vgg: float = 10.0         # train.py:377
     lambda_tv: float = 1.0           # train.py:378
@@ -187,6 +205,10 @@ class LossConfig:
     # (networks.py:26 — no ImageNet mean/std). Changes loss scale; keep
     # faithful by default.
     vgg_imagenet_norm: bool = False
+    # "relu" (the reference's five post-ReLU taps relu1_1 .. relu5_1 with
+    # weights 1/32 .. 1) | "preact" (the ESRGAN lineage's: conv1_2, conv2_2,
+    # conv3_4, conv4_4, conv5_4 BEFORE the ReLU, weights 0.1, 0.1, 1, 1, 1).
+    vgg_taps: str = "relu"
     # Sobel edge L1 between fake and real — the reference's commented-out
     # edge experiment (train.py:307,313,362-363; sobelLayer at
     # networks.py:852). Dead there (0 here) but live behind this weight.
@@ -424,6 +446,16 @@ class Config:
         w = self.data.image_width or h
         return h, w
 
+    @property
+    def input_hw(self) -> Tuple[int, int]:
+        """The input's extent: the target's over ``model.scale``."""
+        h, w = self.image_hw
+        s = self.model.scale
+        if h % s or w % s:
+            raise ValueError(f"image extent {h}x{w} does not divide by "
+                             f"model.scale {s}")
+        return h // s, w // s
+
 
 # ----------------------------------------------------------------------------
 # The five BASELINE.json target configs, checked in as presets.
@@ -647,6 +679,43 @@ _register(
                           lr_policy="constant"),
         data=DataConfig(dataset="imagenet", image_size=256, batch_size=12),
         parallel=ParallelConfig(mesh=MeshSpec(data=-1)),
+    )
+)
+
+
+# 8. SwinIR-M, real-world x4 super-resolution with its GAN objective (Liang
+#    et al. 2021, arXiv:2108.10257 sec. 3, 4.1; sizes of github.com/
+#    JingyunLiang/SwinIR 003_realSR_BSRGAN_DFO_s64w8_SwinIR-M_x4_GAN and
+#    cszn/KAIR train_swinir_sr_realworld_x4_gan.json). The equations are
+#    models/swinir.py's docstring (G: 6 groups of 6 Swin layers, width 180,
+#    6 heads of 30 over 8x8 windows, 'nearest+conv' x4) and models/
+#    unet_d.py's (D: UNetDiscriminatorSN, 64 features, per-pixel logits).
+#      L_G = 1 * mean|y - r| + 1 * sum_l w_l mean|phi_l(y) - phi_l(r)| + 0.1
+#        * BCE(D(y), 1) on images in [0, 1] (lambda_l1 0.5 on this system's
+#        [-1, 1]); phi_l VGG19's conv1_2 .. conv5_4 before the ReLU on
+#        ImageNet-normalised inputs, w = (0.1, 0.1, 1, 1, 1).
+#      L_D = BCE(D(r), 1) + BCE(D(sg(y)), 0). Adam(0.9, 0.999) at 1e-4,
+#        constant; EMA of G at 0.999.
+#    Departures: this Trainer's step (D's fake comes from the same generator
+#    forward as G's loss) and its 0.5 on D's loss (train/step.py); no
+#    PSNR-pretrained start; the LQ side is whatever the dataset holds (the
+#    authors degrade on the host with BSRGAN's random pipeline).
+_register(
+    Config(
+        name="swinir_realsr_x4",
+        model=ModelConfig(generator="swinir", ngf=180, n_blocks=6, scale=4,
+                          norm="layer", ndf=64, discriminator="unet",
+                          num_D=1, use_spectral_norm=True,
+                          get_interm_feat=False, use_compression_net=False,
+                          d_conditional=False, use_dropout=True),
+        loss=LossConfig(gan_mode="vanilla", gan_weight=0.1, lambda_feat=0.0,
+                        lambda_vgg=1.0, lambda_tv=0.0, lambda_l1=0.5,
+                        vgg_imagenet_norm=True, vgg_taps="preact"),
+        optim=OptimConfig(lr=1e-4, beta1=0.9, beta2=0.999,
+                          lr_policy="constant"),
+        data=DataConfig(dataset="realsr", image_size=256, batch_size=4),
+        parallel=ParallelConfig(mesh=MeshSpec(data=-1)),
+        health=HealthConfig(ema_decay=0.999),
     )
 )
 

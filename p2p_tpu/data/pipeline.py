@@ -135,7 +135,13 @@ class PairedImageDataset:
     ``label_input``: the side that becomes ``"input"`` holds label maps
     (class ids, an instance-edge bit), decoded by :func:`load_label_map`
     to uint8 ``(H, W, 2)`` whatever ``dtype`` says, and ``augment`` is the
-    flip alone: a resize-and-crop would resample ids."""
+    flip alone: a resize-and-crop would resample ids.
+
+    ``scale`` (``ModelConfig.scale``): ``image_size`` / ``image_width`` are
+    the TARGET's extent and the input side is loaded at that over
+    ``scale`` (super-resolution: ``a/`` holds the HQ images, ``b/`` the LQ
+    ones, direction ``b2a``). ``augment`` takes the SAME crop, in input
+    pixels, and the same flip from both."""
 
     def __init__(
         self,
@@ -149,8 +155,12 @@ class PairedImageDataset:
         cache: Union[bool, str] = "auto",
         dtype: str = "float32",
         label_input: bool = False,
+        scale: int = 1,
     ):
         self.label_input = label_input
+        if scale > 1 and label_input:
+            raise ValueError("a label-map input has the target's extent")
+        self.scale = scale
         self.a_dir = os.path.join(root, split, "a")
         self.b_dir = os.path.join(root, split, "b")
         self.direction = direction
@@ -217,10 +227,10 @@ class PairedImageDataset:
         if hasattr(idx, "__index__"):
             idx = idx.__index__()
         name = self.names[idx]
+        in_dir, tgt_dir = ((self.a_dir, self.b_dir)
+                           if self.direction == "a2b"
+                           else (self.b_dir, self.a_dir))
         if self.label_input:
-            in_dir, tgt_dir = ((self.a_dir, self.b_dir)
-                               if self.direction == "a2b"
-                               else (self.b_dir, self.a_dir))
             m = self._load(os.path.join(in_dir, name), labels=True)
             t = self._load(os.path.join(tgt_dir, name))
             if self.augment and np.random.default_rng(
@@ -228,28 +238,29 @@ class PairedImageDataset:
                 m = np.ascontiguousarray(m[:, ::-1])
                 t = np.ascontiguousarray(t[:, ::-1])
             return {"input": m, "target": t}
-        if self.augment:
-            # the reference's commented-out aug (dataset.py:28-46): load at
-            # 286/256-scaled size, take the SAME random crop from a and b,
-            # flip both. Deterministic per (aug_seed, idx) — see __init__.
-            lh = self.h * 286 // 256
-            lw = self.w * 286 // 256
-            a = self._load(os.path.join(self.a_dir, name), lh, lw)
-            b = self._load(os.path.join(self.b_dir, name), lh, lw)
-            rng = np.random.default_rng((0x9E3779B9, self.aug_seed, idx))
-            oy = int(rng.integers(0, lh - self.h + 1))
-            ox = int(rng.integers(0, lw - self.w + 1))
-            a = a[oy : oy + self.h, ox : ox + self.w]
-            b = b[oy : oy + self.h, ox : ox + self.w]
-            if rng.random() < 0.5:
-                a, b = a[:, ::-1], b[:, ::-1]
-            a, b = np.ascontiguousarray(a), np.ascontiguousarray(b)
-        else:
-            a = self._load(os.path.join(self.a_dir, name))
-            b = self._load(os.path.join(self.b_dir, name))
-        if self.direction == "a2b":
-            return {"input": a, "target": b}
-        return {"input": b, "target": a}
+        # the reference's commented-out aug (dataset.py:28-46) behind
+        # ``augment``: load 286/256 larger, take the SAME random crop from
+        # both sides, flip both; deterministic per (aug_seed, idx), see
+        # __init__. With ``scale`` > 1 the input side has the target's
+        # extent over it: the crop is drawn in INPUT pixels and taken at
+        # ``scale`` times its offset from the target.
+        s = self.scale
+        ih, iw = self.h // s, self.w // s
+        if not self.augment:
+            return {"input": self._load(os.path.join(in_dir, name), ih, iw),
+                    "target": self._load(os.path.join(tgt_dir, name))}
+        lh, lw = ih * 286 // 256, iw * 286 // 256
+        x = self._load(os.path.join(in_dir, name), lh, lw)
+        t = self._load(os.path.join(tgt_dir, name), lh * s, lw * s)
+        rng = np.random.default_rng((0x9E3779B9, self.aug_seed, idx))
+        oy = int(rng.integers(0, lh - ih + 1))
+        ox = int(rng.integers(0, lw - iw + 1))
+        x = x[oy:oy + ih, ox:ox + iw]
+        t = t[oy * s:oy * s + self.h, ox * s:ox * s + self.w]
+        if rng.random() < 0.5:
+            x, t = x[:, ::-1], t[:, ::-1]
+        return {"input": np.ascontiguousarray(x),
+                "target": np.ascontiguousarray(t)}
 
 
 class _Stacked:

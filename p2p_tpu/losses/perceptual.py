@@ -18,6 +18,11 @@ from p2p_tpu.models.vgg import VGG19Features
 from p2p_tpu.obs.registry import get_registry
 
 VGG_SLICE_WEIGHTS = (1.0 / 32, 1.0 / 16, 1.0 / 8, 1.0 / 4, 1.0)
+#: ``LossConfig.vgg_taps`` -> (the trunk's table in models/vgg.ARCHS, the
+#: five taps' weights). "preact" is the ESRGAN lineage's: conv1_2, conv2_2,
+#: conv3_4, conv4_4, conv5_4 before the ReLU.
+VGG_TAPS = {"relu": ("vgg19", VGG_SLICE_WEIGHTS),
+            "preact": ("vgg19_preact", (0.1, 0.1, 1.0, 1.0, 1.0))}
 
 
 #: the dtypes VGG19's activations can be stored in between its layers
@@ -39,8 +44,10 @@ def vgg_loss(
     x: jax.Array,
     y: jax.Array,
     imagenet_norm: bool = False,
+    taps: str = "relu",
 ) -> jax.Array:
-    """Perceptual distance between x and y (target y stop-gradiented).
+    """Perceptual distance between x and y (target y stop-gradiented);
+    ``taps`` picks the trunk's table and the weights (:data:`VGG_TAPS`).
 
     bf16 images (mixed precision) keep VGG19's activations in bf16, which
     is what its convolutions read of them on the MXU anyway; any other
@@ -51,17 +58,20 @@ def vgg_loss(
     get_registry().counter(
         "vgg_loss_traces_total",
         act_dtype="float32" if store is None else "bfloat16").inc()
-    model = VGG19Features(imagenet_norm=imagenet_norm, store_dtype=store)
+    arch, weights = VGG_TAPS[taps]
+    model = VGG19Features(imagenet_norm=imagenet_norm, store_dtype=store,
+                          arch=arch)
     return tap_distance(
         model.apply({"params": vgg_params}, x),
-        model.apply({"params": vgg_params}, jax.lax.stop_gradient(y)))
+        model.apply({"params": vgg_params}, jax.lax.stop_gradient(y)),
+        weights)
 
 
-def tap_distance(feats_x, feats_y) -> jax.Array:
+def tap_distance(feats_x, feats_y, weights=VGG_SLICE_WEIGHTS) -> jax.Array:
     """The weighted L1 between two sets of VGG19 taps, in float32 (the
     second set stop-gradiented)."""
     total = jnp.zeros((), jnp.float32)
-    for w, fx, fy in zip(VGG_SLICE_WEIGHTS, feats_x, feats_y):
+    for w, fx, fy in zip(weights, feats_x, feats_y):
         fy = jax.lax.stop_gradient(fy)
         total = total + w * jnp.mean(
             jnp.abs(fx.astype(jnp.float32) - fy.astype(jnp.float32))

@@ -102,6 +102,12 @@ def define_G(cfg: ModelConfig, dtype=None, remat=False) -> nn.Module:
                      res_blocks=cfg.vq_res_blocks, codes=cfg.vq_codes,
                      embed_dim=cfg.vq_embed_dim,
                      out_channels=cfg.output_nc, dtype=dtype)
+    if cfg.generator == "swinir":
+        from p2p_tpu.models.swinir import SwinIR
+
+        return SwinIR(embed=cfg.ngf, groups=cfg.n_blocks,
+                      out_channels=cfg.output_nc, scale=cfg.scale,
+                      dtype=dtype)
     raise ValueError(f"unknown generator {cfg.generator!r}")
 
 
@@ -134,6 +140,18 @@ def generator_side(cfg: ModelConfig) -> Optional[GeneratorSide]:
     return None
 
 
+def input_extent_multiple(cfg: ModelConfig) -> int:
+    """What the configured generator needs its INPUT's height and width to
+    be multiples of beyond what its preset's own extent shows (a window
+    transformer: its window); 1 where an input of another extent is not
+    served (``cli.infer`` pads up to it)."""
+    if cfg.generator == "swinir":
+        from p2p_tpu.models.swinir import WINDOW
+
+        return WINDOW
+    return 1
+
+
 def generator_gauges(cfg: ModelConfig, h: int, w: int) -> Dict[str, float]:
     """What the configured generator does for one ``h`` x ``w`` image, from
     its shapes, as gauges the Trainer sets when it builds the step; empty
@@ -153,10 +171,33 @@ def generator_gauges(cfg: ModelConfig, h: int, w: int) -> Dict[str, float]:
         out["vqgan_lpips_gflop_per_image"] = vgg_gflop_per_image(
             "vgg16", h, w)
         return out
+    if cfg.generator == "swinir":
+        from p2p_tpu.models.swinir import swinir_arithmetic
+        from p2p_tpu.models.vgg import vgg_gflop_per_image
+
+        # h x w is the TARGET's extent; the body runs on the input's
+        out = swinir_arithmetic(cfg.ngf, cfg.n_blocks, h // cfg.scale,
+                                w // cfg.scale)
+        # one VGG19 forward through conv5_4 (the step runs two and one
+        # backward)
+        out["swinir_vgg_gflop_per_image"] = vgg_gflop_per_image(
+            "vgg19_preact", h, w)
+        return out
     return {}
 
 
 def define_D(cfg: ModelConfig, dtype=None) -> nn.Module:
+    if cfg.discriminator == "unet":
+        from p2p_tpu.models.unet_d import UNetDiscriminatorSN
+
+        if not cfg.use_spectral_norm or cfg.num_D != 1 or cfg.d_conditional:
+            raise ValueError(
+                "discriminator 'unet' is spectrally normalised, has one "
+                "scale and sees the image alone: set use_spectral_norm, "
+                "num_D 1 and d_conditional False")
+        return UNetDiscriminatorSN(ndf=cfg.ndf, dtype=dtype)
+    if cfg.discriminator != "patch":
+        raise ValueError(f"unknown discriminator {cfg.discriminator!r}")
     return MultiscaleDiscriminator(
         ndf=cfg.ndf,
         n_layers=cfg.n_layers_D,

@@ -52,8 +52,17 @@ _CFG16 = [
     ("conv5_1", 512), ("conv5_2", 512), ("conv5_3", 512),
 ]
 _TAPS16 = ("conv1_2", "conv2_2", "conv3_3", "conv4_3", "conv5_3")
+
+# VGG19 through conv5_4, tapped at conv1_2, conv2_2, conv3_4, conv4_4,
+# conv5_4 BEFORE the ReLU (torchvision indices 2/7/16/25/34): what the
+# ESRGAN lineage's perceptual loss reads (losses/perceptual.py "preact").
+_CFG19_FULL = _CFG + [("conv5_2", 512), ("conv5_3", 512), ("conv5_4", 512)]
+_TAPS19_PREACT = ("conv1_2", "conv2_2", "conv3_4", "conv4_4", "conv5_4")
 #: arch -> (layer table, tapped layers)
-ARCHS = {"vgg19": (_CFG, _TAPS), "vgg16": (_CFG16, _TAPS16)}
+ARCHS = {"vgg19": (_CFG, _TAPS), "vgg16": (_CFG16, _TAPS16),
+         "vgg19_preact": (_CFG19_FULL, _TAPS19_PREACT)}
+#: the archs whose taps are the convolution's output before its ReLU
+PREACT_ARCHS = frozenset({"vgg19_preact"})
 
 _IMAGENET_MEAN = np.array([0.485, 0.456, 0.406], np.float32)
 _IMAGENET_STD = np.array([0.229, 0.224, 0.225], np.float32)
@@ -103,11 +112,38 @@ def _stored_bwd(res, ct):
 conv3x3_relu_stored.defvjp(_stored_fwd, _stored_bwd)
 
 
+@jax.custom_vjp
+def conv3x3_bias_stored(x, w, b):
+    """``conv3x3(x, w) + b`` stored in ``x.dtype`` with the float32 sum and
+    ONE rounding of :func:`conv3x3_relu_stored`, and no ReLU: a tapped
+    layer of a pre-activation table keeps this tensor and hands its ReLU
+    (``relu_y``, masked from its own output) to the next layer."""
+    z = _conv3x3(x, w.astype(x.dtype), jnp.float32)
+    return (z + b.astype(jnp.float32)).astype(x.dtype)
+
+
+def _bias_stored_fwd(x, w, b):
+    return conv3x3_bias_stored(x, w, b), (x, w, b)
+
+
+def _bias_stored_bwd(res, ct):
+    x, w, b = res
+    dx, dw = jax.vjp(_conv3x3, x, w.astype(x.dtype))[1](ct)
+    db = jnp.sum(ct, (0, 1, 2), dtype=jnp.float32)
+    return dx, dw.astype(w.dtype), db.astype(b.dtype)
+
+
+conv3x3_bias_stored.defvjp(_bias_stored_fwd, _bias_stored_bwd)
+
+
 class _StoredConvRelu(nn.Module):
     """``nn.Conv`` 3x3 + ReLU with ``nn.Conv``'s parameter tree, through
     :func:`conv3x3_relu_stored`."""
 
     features: int
+    # True: the convolution's output before its ReLU (a tapped layer of a
+    # pre-activation table)
+    preact: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -115,6 +151,8 @@ class _StoredConvRelu(nn.Module):
                             (3, 3, x.shape[-1], self.features), jnp.float32)
         bias = self.param("bias", nn.initializers.zeros,
                           (self.features,), jnp.float32)
+        if self.preact:
+            return conv3x3_bias_stored(x, kernel, bias)
         return conv3x3_relu_stored(x, kernel, bias)
 
 
@@ -135,6 +173,7 @@ class VGG19Features(nn.Module):
     @nn.compact
     def __call__(self, x) -> List[jax.Array]:
         cfg, taps = ARCHS[self.arch]
+        preact = self.arch in PREACT_ARCHS
         if self.imagenet_norm:
             # incoming images are [-1,1]; map to [0,1] then standardize
             x = (x + 1.0) * 0.5
@@ -145,14 +184,20 @@ class VGG19Features(nn.Module):
             if name == "M":
                 y = nn.max_pool(y, (2, 2), strides=(2, 2))
                 continue
+            # a pre-activation table taps the convolution's own output and
+            # hands its ReLU to the next layer
+            pre = preact and name in taps
             if self.store_dtype is None:
                 y = save_conv_out(nn.Conv(
                     ch, kernel_size=(3, 3), padding=1, name=name)(y))
-                y = relu_y(y)
+                if not pre:
+                    y = relu_y(y)
             else:
-                y = _StoredConvRelu(ch, name=name)(y)
+                y = _StoredConvRelu(ch, preact=pre, name=name)(y)
             if name in taps:
                 outs.append(y)
+            if pre:
+                y = relu_y(y)
         return outs
 
 
@@ -169,9 +214,11 @@ def vgg19_params_source() -> str:
     return "pretrained" if vgg19_npz_path() else "random"
 
 
-def load_vgg19_params(dtype=jnp.float32, seed: int = 190):
+def load_vgg19_params(dtype=jnp.float32, seed: int = 190,
+                      arch: str = "vgg19"):
     """Build the frozen VGG19 param tree (pretrained npz or fixed-seed
-    random).
+    random). ``arch`` "vgg19_preact" is the trunk through conv5_4 (the
+    asset, where there is one, has to hold its three further layers).
 
     ``seed`` selects the random-feature draw when no pretrained asset
     exists — the multi-seed VFID robustness protocol
@@ -180,13 +227,13 @@ def load_vgg19_params(dtype=jnp.float32, seed: int = 190):
     asset is present.
     """
     path = vgg19_npz_path()
-    model = VGG19Features()
+    model = VGG19Features(arch=arch)
     if path is None:
         dummy = jnp.zeros((1, 64, 64, 3), dtype)
         return model.init(jax.random.key(seed), dummy)["params"]
     data = np.load(path)
     params = {}
-    for name, ch in _CFG:
+    for name, ch in ARCHS[arch][0]:
         if name == "M":
             continue
         kernel = jnp.asarray(data[f"{name}_kernel"], dtype)  # HWIO

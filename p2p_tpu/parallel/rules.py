@@ -251,6 +251,21 @@ def make_resnet_tp_rules(axis_size: int = 2, min_ch: int = 512) -> Tuple:
     )
 
 
+def make_window_transformer_rules() -> Tuple:
+    """The leaves of a window-attention generator (models/swinir.py),
+    REPLICATED over the ``model`` axis by name: LayerNorm's affine, the
+    qkv / proj / fc1 / fc2 kernels and biases, the relative-position bias
+    table. No Megatron pair is declared for them (a q k v split by heads
+    with proj in-sharded, fc1 out / fc2 in, is not in the tree), so a
+    ``model`` axis leaves these whole, by these rows and not by the
+    catch-all."""
+    return (
+        (r"attn/relative_position_bias_table$", P()),
+        (r"(?:attn/qkv|attn/proj|fc1|fc2)/(?:kernel|bias)$", P()),
+        (r"(?:norm1|norm2|norm|patch_norm)/(?:scale|bias)$", P()),
+    )
+
+
 def tp_equivalence_rules(cfg, axis_size: int = 2,
                          min_ch: int = 512) -> Optional[Rules]:
     """The declarative table reproducing ``tp_leaf_spec`` for ``cfg``'s
@@ -295,7 +310,8 @@ def make_tp_rules(axis_size: int = 2, min_ch: int = 512) -> Tuple:
     inside :func:`trainstate_rules`."""
     return (make_unet_tp_rules(axis_size, min_ch)
             + make_resnet_tp_rules(axis_size, min_ch)
-            + make_patchgan_tp_rules(axis_size, min_ch))
+            + make_patchgan_tp_rules(axis_size, min_ch)
+            + make_window_transformer_rules())
 
 
 #: the TrainState fields the FSDP table shards (ZeRO-1: pure per-device

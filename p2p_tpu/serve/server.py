@@ -151,7 +151,9 @@ class _TenantRuntime:
         self.batcher = ContinuousBatcher(
             self.queue, tenant.engine.buckets,
             group_cap=group_cap, linger_s=linger_s)
-        h, w = tenant.cfg.image_hw
+        # the request image drives the input slot wherever the input's
+        # extent differs from the target's (model.scale > 1)
+        h, w = tenant.cfg.input_hw
         as_uint8 = tenant.cfg.data.uint8_pipeline
 
         def decode(req: Request) -> np.ndarray:

@@ -39,6 +39,7 @@ preemptible fleet can resume on whatever capacity the scheduler grants.
 from __future__ import annotations
 
 import functools
+import gc
 import os
 import signal
 import time
@@ -135,6 +136,27 @@ def init_trainer_obs(tr) -> None:
     init_trainer_health(tr)
 
 
+def settle_collector(tr) -> None:
+    """Once a run, after the epoch that compiled the step: collect what
+    set-up left behind and FREEZE what it built (the step's jaxprs, the
+    loaded executables, the datasets: millions of objects that live as long
+    as the run), so that no later full collection walks them. Left alone,
+    the one full collection set-up leaves due lands wherever the allocation
+    count puts it: 0.23 - 0.50 s inside an epoch with the device starved
+    11 - 40 ms (``gc_pause_s`` of the epoch records, my chip run 3, PR 38;
+    PERF.md section 6). Outside the epoch's span, whose phases tile it; its
+    seconds reach the JSONL as one ``kind="gc_settle"`` line;
+    ``close_trainer_obs`` thaws."""
+    if getattr(tr, "_gc_settled", False):
+        return
+    tr._gc_settled = True
+    t0 = time.perf_counter()
+    gc.collect()
+    gc.freeze()
+    tr.logger.log({"kind": "gc_settle",
+                   "sec": round(time.perf_counter() - t0, 6)}, force=True)
+
+
 def close_trainer_obs(tr) -> None:
     """Tear down the process-global hooks ``init_trainer_obs`` installed —
     the compile-event listener, the collector's callback and the sentinel
@@ -146,6 +168,9 @@ def close_trainer_obs(tr) -> None:
 
     tr.retrace.close()
     tr.gc_pauses.remove()
+    if getattr(tr, "_gc_settled", False):
+        tr._gc_settled = False
+        gc.unfreeze()   # the run is over: its objects may be collected
     if getattr(tr, "_sentinel_handler", None) is not None:
         remove_sentinel_handler(tr._sentinel_handler)
         tr._sentinel_handler = None
@@ -969,11 +994,12 @@ class Trainer:
         self.train_ds = PairedImageDataset(
             root, "train", cfg.data.direction, cfg.data.image_size,
             cfg.data.image_width, augment=cfg.data.augment,
-            dtype=ds_dtype, label_input=labels,
+            dtype=ds_dtype, label_input=labels, scale=cfg.model.scale,
         )
         self.test_ds = PairedImageDataset(
             root, "test", cfg.data.direction, cfg.data.image_size,
             cfg.data.image_width, dtype=ds_dtype, label_input=labels,
+            scale=cfg.model.scale,
         )
         self.steps_per_epoch = max(1, len(self.train_ds) // cfg.data.batch_size)
         self.mesh = mesh if mesh is not None else (
@@ -1043,8 +1069,10 @@ class Trainer:
             cfg = dataclasses.replace(
                 cfg, train=dataclasses.replace(cfg.train, eval_fid=False))
             self.cfg = cfg
+        from p2p_tpu.losses.perceptual import VGG_TAPS
+
         self.vgg_params = (
-            load_vgg19_params()
+            load_vgg19_params(arch=VGG_TAPS[cfg.loss.vgg_taps][0])
             if (cfg.loss.lambda_vgg > 0 or cfg.loss.lambda_style > 0
                 or cfg.train.eval_fid) else None
         )
@@ -1360,8 +1388,10 @@ class Trainer:
                 "train_epoch", registry=self.obs, force=True,
                 histogram=self.obs.histogram("train_epoch_secs"),
                 epoch=self.epoch) as record:
-            return self._train_epoch(record, seed, skip_batches,
-                                     skip_samples)
+            means = self._train_epoch(record, seed, skip_batches,
+                                      skip_samples)
+        settle_collector(self)
+        return means
 
     def _train_epoch(self, record: Dict, seed: Optional[int],
                      skip_batches: int, skip_samples: int

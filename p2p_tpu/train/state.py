@@ -19,7 +19,12 @@ import optax
 from flax import struct
 
 from p2p_tpu.core.config import Config
-from p2p_tpu.models.registry import define_C, define_D, define_G, init_variables
+from p2p_tpu.models.registry import (
+    define_C,
+    define_D,
+    define_G,
+    init_variables,
+)
 
 
 class TrainState(struct.PyTreeNode):
@@ -70,6 +75,14 @@ class TrainState(struct.PyTreeNode):
     # "batch"), threaded like spectral_d: two updates a step (fake call,
     # real call). None for every other discriminator (an empty subtree).
     batch_stats_d: Any = None
+    # a seed as DATA (a uint32 scalar drawn from the init's rng, so it
+    # follows ``--seed``), for a step that draws noise
+    # (``ModelConfig.use_dropout``: U-Net dropout, stochastic depth): the
+    # step folds it with the step counter, so neither the compiled step nor
+    # the compiled init holds ``--seed`` as a constant and one cache entry
+    # of each serves every seed. None where the step draws none (an empty
+    # subtree: those checkpoints and steps are what they were).
+    noise_seed: Any = None
 
 
 class InferState(struct.PyTreeNode):
@@ -462,4 +475,9 @@ def _init_train_state(cfg, rng, sample_batch, steps_per_epoch=1,
         spectral_g=vg.get("spectral"),
         batch_stats_d=(vd.get("batch_stats", {})
                        if cfg.model.norm_d == "batch" else None),
+        # drawn from the init's rng ARGUMENT (not from cfg.train.seed, which
+        # would be a constant of this jitted init and compile it anew a seed)
+        noise_seed=(jax.random.bits(jax.random.fold_in(rng, 0x5EED), (),
+                                    jnp.uint32)
+                    if cfg.model.use_dropout else None),
     )

@@ -196,6 +196,8 @@ def make_g_loss_fn(cfg: Config, vgg_params: Optional[Any] = None,
             l_gan = gan_loss(pred_fake_g, True, L.gan_mode,
                              for_discriminator=False,
                              scale_mean=L.gan_scale_mean)
+        if L.gan_weight != 1.0:
+            l_gan = l_gan * L.gan_weight
         parts = {"g_gan": l_gan}
         total = l_gan
         if L.lambda_feat > 0:
@@ -209,8 +211,8 @@ def make_g_loss_fn(cfg: Config, vgg_params: Optional[Any] = None,
         if need_vgg:
             with jax.named_scope("loss_vgg"):
                 l_vgg = vgg_loss(
-                    vgg_params, fake_b, real_b, L.vgg_imagenet_norm
-                ) * L.lambda_vgg
+                    vgg_params, fake_b, real_b, L.vgg_imagenet_norm,
+                    L.vgg_taps) * L.lambda_vgg
             parts["g_vgg"] = l_vgg
             total = total + l_vgg
         if need_lpips:
@@ -416,9 +418,10 @@ def build_train_step(
 
         g_input = jax.lax.stop_gradient(compressed)
 
-        # per-step dropout noise (pix2pix's noise source)
+        # per-step dropout noise (pix2pix's noise source); the seed is the
+        # state's own (TrainState.noise_seed: the program holds no --seed)
         drop_rng = (
-            jax.random.fold_in(jax.random.key(cfg.train.seed), state.step)
+            jax.random.fold_in(jax.random.key(state.noise_seed), state.step)
             if use_dropout else None
         )
 

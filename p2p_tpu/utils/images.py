@@ -70,8 +70,9 @@ def wire_spec(cfg, key: str = "input"):
     """``((H, W, C), dtype)`` of one item of ``batch[key]`` as the loader
     of ``cfg`` ships it: what every dummy batch (lint, the audits, the
     serving templates and buckets) has to be built from. A label-map
-    input is uint8 ``(H, W, 2)`` whatever ``uint8_pipeline`` says."""
-    h, w = cfg.image_hw
+    input is uint8 ``(H, W, 2)`` whatever ``uint8_pipeline`` says; the
+    input's extent is the target's over ``cfg.model.scale``."""
+    h, w = cfg.input_hw if key == "input" else cfg.image_hw
     if key == "input" and cfg.model.label_classes:
         return (h, w, 2), np.dtype(np.uint8)
     nc = cfg.model.input_nc if key == "input" else cfg.model.output_nc

@@ -12,7 +12,8 @@ change, and compare the two lines.
 The configuration is built as ``benchmark/drivers/train.py`` builds it
 (the CLI's parser and ``config_from_flags`` on the cell's flags); the state
 is abstract (``jax.eval_shape``), the batch two uint8 images per example
-at the cell's extent, the mesh the cell's own over the described chips
+at the cell's extent (the input at that over the configuration's ``scale``
+where it states one), the mesh the cell's own over the described chips
 with the Pallas branch taken as on the chip. ``steps_per_epoch`` is the
 cell's ``dataset_pairs // batch_size``. VGG19's seeded weights are
 closed-over constants of the step: they are part of the text.
@@ -193,13 +194,25 @@ def main() -> None:
     steps_per_epoch = max(1, int(cfgf["dataset_pairs"]) // bs)
     dtype = jnp.bfloat16 if cfg.train.mixed_precision else None
     image = jax.ShapeDtypeStruct((bs, h, w, 3), jnp.uint8)
+    # a super-resolution cell's input has the target's extent over its scale
+    scale = int(cfgf.get("scale", 1))
+    lq = jax.ShapeDtypeStruct((bs, h // scale, w // scale, 3), jnp.uint8)
     state = jax.eval_shape(
         lambda: create_train_state(
             cfg, jax.random.key(0),
-            {"input": jnp.zeros(image.shape, image.dtype),
+            {"input": jnp.zeros(lq.shape, lq.dtype),
              "target": jnp.zeros(image.shape, image.dtype)},
             steps_per_epoch, dtype))
-    vgg = load_vgg19_params() if cfg.loss.lambda_vgg > 0 else None
+    vgg = None
+    if cfg.loss.lambda_vgg > 0:
+        # (a parent's tree may lack the field: its taps are the first table)
+        taps = getattr(cfg.loss, "vgg_taps", "relu")
+        if taps == "relu":
+            vgg = load_vgg19_params()
+        else:
+            from p2p_tpu.losses.perceptual import VGG_TAPS
+
+            vgg = load_vgg19_params(arch=VGG_TAPS[taps][0])
     step = make_parallel_train_step(cfg, mesh, vgg, steps_per_epoch, dtype)
     rep, bsh = replicated(mesh), batch_sharding(mesh)
 
@@ -209,7 +222,7 @@ def main() -> None:
                                            sharding=sharding), tree)
 
     lowered = step.lower(on(state, rep),
-                         on({"input": image, "target": image}, bsh))
+                         on({"input": lq, "target": image}, bsh))
     text = lowered.as_text()
     plain = without_kernel_locations(text)
     if args.text:

@@ -908,6 +908,11 @@ UP2_SITE_CASES = {
     # plain, the last (128 wide at 256x256) too
     "vqgan_imagenet_f16": (12, None, ((512, False), (256, False),
                                       (256, False), (128, False))),
+    # swinir_m_realsr_x4_gan.train (G reads the 64x64 LQ image): the
+    # 'nearest+conv' upsampler's two sites pad with ZEROS too, 64 wide at
+    # 128x128 and 256x256: plain, the second customer of a zero-ring
+    # subpixel form (PERF.md section 7)
+    "swinir_realsr_x4": (4, (64, 64), ((64, False), (64, False))),
 }
 
 

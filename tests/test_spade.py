@@ -420,6 +420,9 @@ def _tiny(preset):
         model = dataclasses.replace(
             model, ngf=32, vq_ch_mult=(1, 2), vq_res_blocks=1, vq_codes=64,
             vq_embed_dim=32)
+    if cfg.model.generator == "swinir":
+        # the embedding is a whole number of 30-wide heads
+        model = dataclasses.replace(model, ngf=30)
     return cfg.replace(
         model=model,
         data=dataclasses.replace(cfg.data, image_size=size, image_width=size,

@@ -423,6 +423,34 @@ def test_close_removes_the_collectors_hook(tmp_path):
     tr.close()  # idempotent
 
 
+@pytest.mark.parametrize("epochs", [1, 3])
+def test_the_first_epoch_settles_the_collector(tmp_path, epochs):
+    """(g) once a run, after its first epoch: one full collection, what
+    set-up built frozen out of every later one, one ``kind="gc_settle"``
+    line; the collection lies outside every epoch's ``gc_pause_s``; a
+    closed Trainer thaws."""
+    frozen_before = gc.get_freeze_count()
+    tr = toy_trainer(tmp_path, ToySteps(work=0))
+    try:
+        assert gc.get_freeze_count() == frozen_before
+        for _ in range(epochs):
+            tr.train_epoch()
+            tr.epoch += 1
+        assert gc.get_freeze_count() > frozen_before + 10_000
+        lines = [json.loads(x)
+                 for x in open(tmp_path / "metrics_clock.jsonl")]
+        settles = [r for r in lines if r.get("kind") == "gc_settle"]
+        assert len(settles) == 1 and settles[0]["sec"] > 0
+        spans = [r for r in lines if r.get("kind") == "span"]
+        assert len(spans) == epochs
+        # a frozen heap: a full collection now walks the young objects only
+        assert all(r["gc_pause_s"] < settles[0]["sec"] for r in spans[1:])
+    finally:
+        tr.close()
+    assert gc.get_freeze_count() == 0
+    tr.close()  # idempotent: thaws once
+
+
 def test_the_clock_adds_no_fence_and_reads_one_dispatch_late(
         tmp_path, monkeypatch):
     """(g) the read of dispatch ``j`` comes after dispatch ``j + 1`` was
