@@ -186,6 +186,22 @@ def generator_gauges(cfg: ModelConfig, h: int, w: int) -> Dict[str, float]:
     return {}
 
 
+def generator_trace_gauges(cfg: ModelConfig) -> Dict[str, float]:
+    """What the configured generator chose by itself the LAST time this
+    process traced it (a kernel in XLA's place), as gauges the Trainer
+    sets right after a dispatch of its own step traced; empty for a
+    generator that chooses nothing."""
+    if cfg.generator == "swinir":
+        from p2p_tpu.ops.pallas.window_attention import kernel_sites
+
+        sites = kernel_sites()
+        return {
+            "swinir_attn_kernel_layers": float(sites["layers"]),
+            "swinir_attn_kernel_windows_per_block": float(
+                sites["windows_per_block"])}
+    return {}
+
+
 def define_D(cfg: ModelConfig, dtype=None) -> nn.Module:
     if cfg.discriminator == "unet":
         from p2p_tpu.models.unet_d import UNetDiscriminatorSN

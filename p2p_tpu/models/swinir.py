@@ -39,7 +39,8 @@ program of that precision would store is rounded to it, :func:`_stored`).
 
 Scopes: ``swin_ln`` (every LayerNorm), ``swin_window`` (roll, partition,
 reverse, roll back: layout only), ``swin_attn`` (qkv, logits + bias + mask,
-softmax, A v, proj), ``swin_mlp``.
+softmax, A v, proj: on the chip the part between qkv and proj is one Pallas
+call a direction, ``ops/pallas/window_attention.py``), ``swin_mlp``.
 """
 
 from __future__ import annotations
@@ -54,6 +55,8 @@ from flax import linen as nn
 
 from p2p_tpu.ops.activations import leaky_relu_y
 from p2p_tpu.ops.conv import ConvLayer, UpsampleConvLayer
+from p2p_tpu.ops.pallas import window_attention as attention
+from p2p_tpu.ops.pallas.window_attention import stored as _stored
 
 #: what SwinIR-M fixes (the authors' ``window_size``, ``mlp_ratio``,
 #: ``drop_path_rate``, layers a group, head width, upsampler width, the
@@ -154,33 +157,6 @@ def window_reverse(x: jax.Array, window: int, h: int, w: int) -> jax.Array:
 # --------------------------------------------------------------- modules
 
 
-def _stored(x: jax.Array, dtype) -> jax.Array:
-    """``x`` as a program that keeps this tensor in ``dtype`` reads it back:
-    float32 rounded to ``dtype``'s exponent and mantissa bits. By
-    ``lax.reduce_precision``, which no compiler pass removes: a convert to
-    bfloat16 and back inside a fusion is dropped on the TPU
-    (``xla_allow_excess_precision``), so ``astype`` there rounds nothing
-    (my chip run 2, PR 38: the bf16 softmax read as the float32 one to
-    three digits, on the CPU 4.8x off)."""
-    if dtype == jnp.float32:
-        return x.astype(jnp.float32)
-    info = jnp.finfo(dtype)
-    return jax.lax.reduce_precision(x.astype(jnp.float32), info.nexp,
-                                    info.nmant)
-
-
-def _softmax(logits: jax.Array, dtype) -> jax.Array:
-    """Softmax over the last axis in float32, or (a control) with every
-    intermediate rounded to ``dtype``."""
-    if dtype == jnp.float32:
-        return jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    z = _stored(logits, dtype)
-    z = _stored(z - jnp.max(z, axis=-1, keepdims=True), dtype)
-    e = _stored(jnp.exp(z), dtype)
-    return _stored(e / _stored(jnp.sum(e, axis=-1, keepdims=True), dtype),
-                   dtype)
-
-
 class LayerNorm(nn.Module):
     """LayerNorm over the last axis, moments in ``norm_dtype`` (float32;
     narrower, every intermediate is rounded to it), the result in the
@@ -205,17 +181,32 @@ class LayerNorm(nn.Module):
 
 class Dense(nn.Module):
     """``x @ kernel + bias`` over the last axis: operands in ``dtype``,
-    the sum in float32, the result in ``dtype``."""
+    the sum in float32, the result in ``dtype``. For the attention
+    kernel's layout (lane-aligned groups of channels) zero columns go into
+    the kernel at apply time, so the parameters keep their shapes:
+    ``out_heads`` = (groups, heads) lays the output's columns out as
+    ``ops/pallas/window_attention.pad_heads`` does; ``in_heads`` =
+    (heads, width) says the input is ``width`` channels laid out so (the
+    zeros meet zero rows; the kernel parameter has ``width`` rows)."""
 
     features: int
     dtype: Optional[jnp.dtype] = None
+    out_heads: Tuple[int, int] = ()
+    in_heads: Tuple[int, int] = ()
 
     @nn.compact
     def __call__(self, x):
-        kernel = self.param("kernel", _DENSE_INIT,
-                            (x.shape[-1], self.features), jnp.float32)
+        rows = self.in_heads[1] if self.in_heads else x.shape[-1]
+        kernel = self.param("kernel", _DENSE_INIT, (rows, self.features),
+                            jnp.float32)
         bias = self.param("bias", nn.initializers.zeros, (self.features,),
                           jnp.float32)
+        if self.out_heads:
+            kernel = attention.pad_heads(kernel, *self.out_heads)
+            bias = attention.pad_heads(bias, *self.out_heads)
+        if self.in_heads:
+            kernel = attention.pad_heads(kernel.T, 1, self.in_heads[0]).T
+            assert kernel.shape[0] == x.shape[-1], (kernel.shape, x.shape)
         dt = self.dtype or x.dtype
         y = jax.lax.dot_general(
             x.astype(dt), kernel.astype(dt),
@@ -226,7 +217,10 @@ class Dense(nn.Module):
 
 class WindowAttention(nn.Module):
     """``WMSA_s`` on windows already split: ``[B, T, C]`` -> ``[B, T, C]``,
-    ``mask`` ``[nW, T, T]`` or None."""
+    ``mask`` ``[nW, T, T]`` or None. Between the two linear layers the
+    function is ``ops/pallas/window_attention``'s, as its kernel where that
+    module's ``kernel_plan`` says so (the TPU, a float32 softmax, one
+    device, a shape it takes) and as XLA's chain everywhere else."""
 
     heads: int
     window: int = WINDOW
@@ -235,36 +229,28 @@ class WindowAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, mask=None):
-        b, t, c = x.shape
-        d = c // self.heads
+        c = x.shape[-1]
         table = self.param(
             "relative_position_bias_table", _DENSE_INIT,
             ((2 * self.window - 1) ** 2, self.heads), jnp.float32)
-        qkv = Dense(3 * c, self.dtype, name="qkv")(x)
-        q, k, v = (qkv[..., i * c:(i + 1) * c].reshape(b, t, self.heads, d)
-                   for i in range(3))
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                            preferred_element_type=jnp.float32)
-        logits = logits * (float(d) ** -0.5) + self.bias(table, t)[None]
-        if mask is not None:
-            nw = mask.shape[0]
-            logits = (logits.reshape(b // nw, nw, self.heads, t, t)
-                      + mask[None, :, None]).reshape(b, self.heads, t, t)
-        attn = _softmax(logits, self.softmax_dtype)
-        out = jnp.einsum("bhqk,bkhd->bqhd", attn.astype(v.dtype), v,
-                         preferred_element_type=jnp.float32)
-        return Dense(c, self.dtype, name="proj")(
-            out.astype(v.dtype).reshape(b, t, c))
-
-    def bias(self, table, t: int):
-        """``B[idx]`` as ``[heads, T, T]``: the rows of the table picked by
-        a one-hot product (exact at HIGHEST precision; its transpose is a
-        product too, where a gather's is a scatter-add)."""
-        idx = jnp.asarray(relative_position_index(self.window)).reshape(-1)
-        onehot = (idx[:, None] == jnp.arange(table.shape[0])[None, :])
-        picked = jnp.dot(onehot.astype(jnp.float32), table,
-                         precision=jax.lax.Precision.HIGHEST)
-        return picked.reshape(t, t, self.heads).transpose(2, 0, 1)
+        index = relative_position_index(self.window)
+        # the kernel or XLA's chain: from what this site can observe
+        wb, interpret = attention.kernel_plan(
+            x.shape, self.heads, self.dtype or x.dtype, mask,
+            self.softmax_dtype)
+        attention.note_site(self.path, wb)
+        if not wb:
+            qkv = Dense(3 * c, self.dtype, name="qkv")(x)
+            out = attention.window_attention(
+                qkv, table, index, mask, self.heads, self.softmax_dtype)
+            return Dense(c, self.dtype, name="proj")(out)
+        qkv = Dense(3 * c, self.dtype, out_heads=(3, self.heads),
+                    name="qkv")(x)
+        out = attention.window_attention_fused(
+            qkv, table, index, mask, self.heads, c // self.heads, wb,
+            interpret)
+        return Dense(c, self.dtype, in_heads=(self.heads, c),
+                     name="proj")(out)
 
 
 class SwinLayer(nn.Module):

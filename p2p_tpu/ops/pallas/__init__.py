@@ -15,6 +15,10 @@ of probing the backend itself, so "which program ran" has one answer:
 
 A backend that fails to initialise raises out of ``default_backend()``;
 nothing here turns that into "use the XLA path".
+
+``spans_devices`` is the second question every such seam asks: a Mosaic
+call is not GSPMD's to partition, so in a program over several devices the
+compiled kernel runs inside a ``shard_map`` or not at all.
 """
 
 from __future__ import annotations
@@ -42,3 +46,20 @@ def kernel_dispatch(force: bool = False,
             f"Pallas kernel forced on backend {backend!r}: the kernels "
             "compile for TPU only, and interpret mode is for CPU tests")
     return True, True
+
+
+def spans_devices(interpret: bool = False) -> bool:
+    """Whether the program being traced may span several devices: the
+    visible mesh (``core.mesh.mesh_context``) has more than one. With NO
+    mesh visible in a process that has several devices nothing at trace
+    time says what the program spans (a jit on a mesh Trainer's replicated
+    state from outside its ``mesh_context``, the benchmark's generator
+    check, is a multi-device program), so the answer is yes there too; the
+    step, the evaluation and the server all trace inside ``mesh_context``.
+    (The interpreted kernel is plain XLA ops and needs no such care.)"""
+    from p2p_tpu.core.mesh import current_mesh
+
+    mesh = current_mesh()
+    if mesh is None:
+        return not interpret and jax.device_count() > 1
+    return mesh.size > 1

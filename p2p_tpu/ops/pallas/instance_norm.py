@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from p2p_tpu.ops.pallas import kernel_dispatch
+from p2p_tpu.ops.pallas import kernel_dispatch, spans_devices
 
 
 def _xla_instance_norm(x, scale, bias, eps):
@@ -115,22 +115,14 @@ def _sharding_mesh_for(x: jax.Array, interpret: bool = False):
     """``(mesh, multi)``: the visible mesh when it spans several devices
     and x can be laid out over (data×fsdp, spatial) on it, else None
     (core/mesh.spatial_shard_mesh); and whether the program being traced
-    may span several devices. In such a program the compiled kernel runs
-    inside a ``shard_map`` or not at all (Mosaic refuses to partition it):
-    an x that cannot be laid out takes the XLA norm, which GSPMD
-    partitions. With NO mesh visible in a process that has several
-    devices nothing at trace time says what the program spans — a jit on
-    a mesh Trainer's replicated state from outside its ``mesh_context``
-    (the benchmark's generator check) is a multi-device program — so the
-    compiled kernel is not taken there either; the step, the evaluation
-    and the server all trace inside ``mesh_context`` and are not touched.
-    (The interpreted kernel is plain XLA ops and needs no such care.)"""
+    may span several devices (``ops/pallas.spans_devices``). In such a
+    program the compiled kernel runs inside a ``shard_map`` or not at all
+    (Mosaic refuses to partition it): an x that cannot be laid out takes
+    the XLA norm, which GSPMD partitions."""
     from p2p_tpu.core.mesh import current_mesh, spatial_shard_mesh
 
-    mesh = current_mesh()
-    if mesh is None:
-        return None, not interpret and jax.device_count() > 1
-    return spatial_shard_mesh(x), mesh.size > 1
+    mesh = None if current_mesh() is None else spatial_shard_mesh(x)
+    return mesh, spans_devices(interpret)
 
 
 def pallas_instance_norm(

@@ -51,7 +51,7 @@ import numpy as np
 from p2p_tpu.core.config import Config
 from p2p_tpu.core.mesh import local_batch_size, batch_sharding, make_mesh
 from p2p_tpu.data.pipeline import PairedImageDataset, device_prefetch, make_loader
-from p2p_tpu.models.registry import generator_gauges
+from p2p_tpu.models.registry import generator_gauges, generator_trace_gauges
 from p2p_tpu.models.vgg import load_vgg19_params
 from p2p_tpu.obs import (
     GcPauseMeter,
@@ -1152,6 +1152,9 @@ class Trainer:
         # what the step's generator does, from its shapes
         for name, value in generator_gauges(cfg.model, *cfg.image_hw).items():
             self.obs.gauge(name).set(value)
+        # and what it chooses by itself where it is traced: nothing yet
+        for name in generator_trace_gauges(cfg.model):
+            self.obs.gauge(name).set(0.0)
         self.plateau = (
             PlateauController() if cfg.optim.lr_policy == "plateau" else None
         )
@@ -1165,6 +1168,23 @@ class Trainer:
 
     def _init_obs(self) -> None:
         init_trainer_obs(self)
+
+    def _set_trace_gauges(self) -> None:
+        """What the generator chose by itself in the trace just made
+        (``generator_trace_gauges``), as gauges: called right after a
+        dispatch of this Trainer's own step compiled, and at no other
+        time, since the modules keep one process-wide note a call site and
+        any other trace of them (the benchmark's float32 check, a control)
+        overwrites it while the compiled step runs on as it was traced.
+        One ``kind="generator_trace"`` record whenever a trace moved the
+        gauges (off zero, the first time)."""
+        gauges = generator_trace_gauges(self.cfg.model)
+        for name, value in gauges.items():
+            self.obs.gauge(name).set(value)
+        logged = self._trace_counts_logged
+        if gauges != logged.get("generator_trace", dict.fromkeys(gauges, 0.0)):
+            logged["generator_trace"] = gauges
+            self.logger.log({"kind": "generator_trace", **gauges}, force=True)
 
     def close(self) -> None:
         """Release process-global telemetry hooks (safe to call twice)."""
@@ -1463,9 +1483,13 @@ class Trainer:
             at = count
             # every dispatch takes the one light path: annotation +
             # histogram; the ring holds the epoch's record, not its steps
+            compiled = self.retrace.compiles
             with timed_annotation("train_dispatch", disp_hist) as disp:
                 step_fn = self.multi_step if k > 1 else self.train_step
                 self.state, metrics = step_fn(self.state, batch_or_stack)
+            if self.retrace.compiles != compiled:
+                # this dispatch traced the step
+                self._set_trace_gauges()
             if at == 0:
                 record["epoch_start_s"] = round(disp.t0 - setup.t0, 6)
             note("train_dispatch", disp, at)

@@ -9,6 +9,10 @@ change, and compare the two lines.
     python scripts/step_program_hash.py --cell reference_256.train \
         --root /root/scratch/parent --text /root/scratch/parent_ref.txt
 
+``--xla_path`` hashes the program the CPU backend traces instead (no
+kernel taken anywhere): what a PR that adds a kernel shows to say that
+the path beside it is still the parent's, letter for letter.
+
 The configuration is built as ``benchmark/drivers/train.py`` builds it
 (the CLI's parser and ``config_from_flags`` on the cell's flags); the state
 is abstract (``jax.eval_shape``), the batch two uint8 images per example
@@ -138,6 +142,10 @@ def main() -> None:
                          "and print estimated cycles, collectives, memory")
     ap.add_argument("--compiled_text", default=None,
                     help="with --compile: write the compiled text here")
+    ap.add_argument("--xla_path", action="store_true",
+                    help="leave the kernel dispatcher alone: the program "
+                         "a CPU backend traces (every Pallas site's XLA "
+                         "form), lowered for the described chips")
     args = ap.parse_args()
     root = os.path.abspath(args.root)
     sys.path.insert(0, root)
@@ -183,7 +191,7 @@ def main() -> None:
     chips = int(workload["chips"])
     mesh = make_mesh(spec, devices=topo.devices[:chips])
     # the backend is the CPU here: take the chip's branch of the dispatcher
-    for mod in (pallas, instance_norm):
+    for mod in () if args.xla_path else (pallas, instance_norm):
         mod.kernel_dispatch = lambda force=False, interpret=False: (True, False)
     # a program traced outside a mesh context asks this whether it may span
     # devices (.claude/skills/verify/SKILL.md): answer for the topology

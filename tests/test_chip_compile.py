@@ -134,6 +134,43 @@ def test_sharded_instance_norm_keeps_the_shard(mesh2x2):
     assert_no_collective_as_large_as(text, n * h * w * c // 4)
 
 
+# a layer's attention of the SwinIR cell: 256 windows (4 images x 64) of 64
+# tokens, six heads of 30, in the kernel's layout (heads 32 apart, groups
+# of 256 columns)
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_window_attention_kernel_compiles(one_chip, grad, dtype):
+    """Forward and backward at the cell's shape, shifted (the mask path),
+    at the block size the rule picks; float32 operands too (the check's
+    float32 program, products at HIGHEST). No ``[.., T, T]`` tensor is an
+    operand or a result of anything in the compiled program: the logits
+    and the probabilities live in the kernel."""
+    from p2p_tpu.models.swinir import relative_position_index, shift_mask
+    from p2p_tpu.ops.pallas import window_attention as wa
+
+    heads, d, images, extent = 6, 30, 4, 64
+    wb = wa.block_windows(256, 64, heads, d, dtype, 64)
+    assert wb == (16 if dtype == jnp.bfloat16 else 8)
+
+    def fn(qkv, table):
+        return wa.window_attention_fused(
+            qkv, table, relative_position_index(8),
+            shift_mask(extent, extent, 8), heads, d, wb)
+
+    qkv = jax.ShapeDtypeStruct(
+        (images * 64, 64, 3 * wa.group_width(heads, d)), dtype,
+        sharding=one_chip)
+    table = jax.ShapeDtypeStruct((225, heads), jnp.float32,
+                                 sharding=one_chip)
+    text = _compiled_text(_sum_grad(fn, argnums=(0, 1)) if grad else fn,
+                          qkv, table)
+    _assert_kernel(text)
+    assert text.count("window_attention_bwd" if grad
+                      else "window_attention_fwd") >= 1
+    assert not re.search(r"\[256,6,64,64\]|\[256,64,6,64\]", text)
+
+
 # the thin image-side layers of the two benchmark cells, at their own
 # extents and batch: ExpandNetwork's k9 head 32->3 (bs32 256x256) and the
 # pix2pixHD enhancer's k7 stem 3->32 (bs2 1024x512)

@@ -184,8 +184,8 @@ def test_shift_mask_against_region_labels(ref):
 
 
 def test_relative_position_index_and_bias(ref):
-    from p2p_tpu.models.swinir import (WindowAttention,
-                                       relative_position_index)
+    from p2p_tpu.models.swinir import relative_position_index
+    from p2p_tpu.ops.pallas.window_attention import relative_bias
 
     for win in (4, 8):
         assert np.array_equal(relative_position_index(win),
@@ -194,14 +194,14 @@ def test_relative_position_index_and_bias(ref):
     assert idx.min() == 0 and idx.max() == 224 and idx[0, 0] == 7 * 15 + 7
     table = jnp.asarray(np.random.default_rng(0).standard_normal(
         (49, 2)).astype(np.float32))
-    got = WindowAttention(heads=2, window=4).bias(table, 16)
+    got = relative_bias(table, relative_position_index(4), 2)
     want = np.asarray(table)[ref.relative_index(4)].transpose(2, 0, 1)
     assert np.array_equal(np.asarray(got), want)   # a one-hot pick is exact
     # the table's gradient is the scatter-add of the picked cotangents
     ct = np.random.default_rng(1).standard_normal((2, 16, 16)).astype(
         np.float32)
     grad = jax.grad(lambda t: jnp.vdot(
-        WindowAttention(heads=2, window=4).bias(t, 16), ct))(table)
+        relative_bias(t, relative_position_index(4), 2), ct))(table)
     want_grad = np.zeros((49, 2), np.float32)
     np.add.at(want_grad, ref.relative_index(4).reshape(-1),
               ct.transpose(1, 2, 0).reshape(-1, 2))
@@ -557,7 +557,8 @@ def test_bf16_softmax_fails_the_comparison(ref, toy):
     LayerNorm, nor kernels rounded to 8-bit integers. The rounding is
     ``lax.reduce_precision``'s, which survives compilation."""
     from benchmark.drivers import train_sr
-    from p2p_tpu.models.swinir import _softmax, _stored
+    from p2p_tpu.models.swinir import _stored
+    from p2p_tpu.ops.pallas.window_attention import softmax as _softmax
 
     cfg, state, _ = toy
     wide = train_sr.widened(state)
@@ -741,10 +742,45 @@ def test_cli_train_runs_the_preset_through_the_trainer(trained, sr_root):
         assert trainer.maybe_resume() and int(trainer.state.step) == 2
         gauges = {k: v["value"] for k, v in trainer.obs.snapshot().items()
                   if k.startswith(("swinir_", "generator_gflop"))}
+        # a trace of the Trainer's OWN step that took the kernel moves the
+        # gauges and leaves a record; no other trace of the modules does
+        # (the benchmark's float32 check, a control), before or after
+        from p2p_tpu.ops.pallas import window_attention
+
+        sites = dict(window_attention._SITES)
+        try:
+            window_attention.note_site(("some_layer", "attn"), 8)
+
+            @jax.jit
+            def step(state, batch):
+                window_attention._SITES.clear()
+                window_attention.note_site(("some_layer", "attn"), 16)
+                loss = jnp.mean(batch["input"].astype(jnp.float32))
+                return (state.replace(step=state.step + 1),
+                        {"loss_g": loss, "loss_d": loss * 2.0})
+
+            trainer.train_step = step
+            trainer.train_epoch()
+            window_attention.note_site(("some_layer", "attn"), 0)
+            trainer.train_epoch()
+            moved = trainer.obs.snapshot()
+        finally:
+            window_attention._SITES.clear()
+            window_attention._SITES.update(sites)
     finally:
         trainer.close()
+    assert moved["swinir_attn_kernel_layers"]["value"] == 1
+    assert moved["swinir_attn_kernel_windows_per_block"]["value"] == 16
+    said = [json.loads(x) for x in open(
+        os.path.join(work, "metrics_toy.jsonl"))]
+    assert [r["swinir_attn_kernel_windows_per_block"] for r in said
+            if r.get("kind") == "generator_trace"] == [16.0]
     assert gauges["swinir_layers"] == 6
     assert gauges["swinir_windows_per_image"] == (LQ // 8) ** 2
+    # no layer's attention ran as the kernel on the CPU, and no record says so
+    assert gauges["swinir_attn_kernel_layers"] == 0
+    assert gauges["swinir_attn_kernel_windows_per_block"] == 0
+    assert not [r for r in stream if r.get("kind") == "generator_trace"]
     parts = ("attn_products", "qkv_proj", "mlp", "group_convs", "upsampler")
     assert abs(sum(gauges[f"swinir_{p}_gflop_per_image"] for p in parts)
                - gauges["generator_gflop_per_image"]) < 1e-9
