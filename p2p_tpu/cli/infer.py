@@ -55,6 +55,10 @@ def build_parser() -> argparse.ArgumentParser:
     from p2p_tpu.cli import add_vq_flags
 
     add_vq_flags(p)
+    p.add_argument("--mask_dir", type=str, default=None,
+                   help="inpainting presets: where the masks lie, one a "
+                        "test image under the image's file name, nonzero = "
+                        "a pixel to fill (default <data_root>/test/mask)")
     p.add_argument("--metrics", action="store_true",
                    help="also print mean/max PSNR+SSIM vs the targets")
     p.add_argument("--ema_decay", type=float, default=None,
@@ -154,6 +158,10 @@ def main(argv=None) -> int:
 
     if cfg.model.scale > 1:
         return _upscale_main(args, cfg)
+    from p2p_tpu.models.registry import input_mask_channel
+
+    if input_mask_channel(cfg.model) is not None:
+        return _inpaint_main(args, cfg)
 
     root = args.data_root or os.path.join(cfg.data.root, cfg.data.dataset)
     ds_dtype = "uint8" if cfg.data.uint8_pipeline else "float32"
@@ -283,6 +291,96 @@ def _upscale_main(args, cfg) -> int:
         save_img(np.asarray(pred[0], np.float32)[:h * s, :w * s],
                  os.path.join(out_dir, name))
     print(f"wrote {len(names)} x{s} predictions (checkpoint step {step}, "
+          f"{len(engines)} extents) to {out_dir}")
+    return 0
+
+
+def _inpaint_main(args, cfg) -> int:
+    """A preset whose input carries a mask (inpainting): every image of
+    the test split's target side with the mask of the same file name
+    (``--mask_dir``, nonzero = a pixel to fill), AT ITS OWN EXTENT, to the
+    composite: the generator's prediction where the mask says so, the
+    image's own pixels elsewhere. Image and mask are padded below and to
+    the right by mirroring (the LaMa authors' ``pad_img_to_modulo``) up to
+    the multiple the generator needs (``models/registry.
+    input_extent_multiple``), run through the serving engine of that
+    padded extent (one engine, one restore and one compile an extent), and
+    the result cropped back. The model is fully convolutional: the extent
+    it was trained at binds nothing."""
+    import dataclasses
+
+    from PIL import Image
+
+    from p2p_tpu.data.generate import is_image_file
+    from p2p_tpu.data.masks import masked_input
+    from p2p_tpu.models.registry import input_extent_multiple
+    from p2p_tpu.serve import engine_from_checkpoint
+    from p2p_tpu.utils.images import save_img
+
+    root = args.data_root or os.path.join(cfg.data.root, cfg.data.dataset)
+    img_dir = os.path.join(
+        root, "test", "b" if cfg.data.direction == "a2b" else "a")
+    mask_dir = args.mask_dir or os.path.join(root, "test", "mask")
+    try:
+        names = sorted(f for f in os.listdir(img_dir) if is_image_file(f))
+    except FileNotFoundError:
+        names = []
+    if not names:
+        print(f"no test images under {img_dir}", file=sys.stderr)
+        return 1
+    if args.metrics:
+        print("note: --metrics needs targets of one extent; ignored for an "
+              "inpainting preset", file=sys.stderr)
+    ckpt_dir = os.path.join(
+        args.workdir, cfg.train.checkpoint_dir, cfg.data.dataset, cfg.name)
+    out_dir = args.out or os.path.join(
+        args.workdir, cfg.train.result_dir, cfg.data.dataset)
+    os.makedirs(out_dir, exist_ok=True)
+    m = input_extent_multiple(cfg.model)
+    engines, step = {}, None
+    for name in names:
+        img = np.asarray(Image.open(os.path.join(img_dir, name))
+                         .convert("RGB"), np.uint8)
+        try:
+            mask = np.asarray(Image.open(os.path.join(mask_dir, name))
+                              .convert("L"), np.uint8) > 0
+        except FileNotFoundError:
+            print(f"no mask {os.path.join(mask_dir, name)} for {name}",
+                  file=sys.stderr)
+            return 1
+        h, w = img.shape[:2]
+        if mask.shape != (h, w):
+            print(f"mask of {name} is {mask.shape[1]}x{mask.shape[0]}, the "
+                  f"image {w}x{h}", file=sys.stderr)
+            return 1
+        ph, pw = -(-h // m) * m, -(-w // m) * m
+        grow = lambda a: np.pad(  # noqa: E731
+            a, ((0, ph - h), (0, pw - w)) + ((0, 0),) * (a.ndim - 2),
+            mode="symmetric")
+        img_p = grow(img)
+        if not cfg.data.uint8_pipeline:
+            img_p = ((img_p.astype(np.float32) - np.float32(127.5))
+                     * np.float32(1.0 / 127.5))
+        inp = masked_input(img_p, grow(mask))
+        if (ph, pw) not in engines:
+            ext = dataclasses.replace(
+                cfg, data=dataclasses.replace(
+                    cfg.data, image_size=ph, image_width=pw))
+            try:
+                engines[ph, pw], step = engine_from_checkpoint(
+                    ext, ckpt_dir, {"input": inp[None]}, step=args.step,
+                    buckets=(1,), dtype=args.dtype,
+                    mesh=_parse_mesh(args.mesh), tp_min_ch=args.tp_min_ch,
+                    with_metrics=False,
+                    compilation_cache_dir=args.compilation_cache,
+                    io_workers=args.io_threads)
+            except FileNotFoundError as e:
+                print(str(e), file=sys.stderr)
+                return 1
+        pred, _, _ = engines[ph, pw].infer_batch({"input": inp[None]})
+        save_img(np.asarray(pred[0], np.float32)[:h, :w],
+                 os.path.join(out_dir, name))
+    print(f"wrote {len(names)} inpainted images (checkpoint step {step}, "
           f"{len(engines)} extents) to {out_dir}")
     return 0
 

@@ -32,6 +32,12 @@ class ModelConfig:
     # "swinir" is the SwinIR super-resolution transformer (models/swinir.py):
     # ngf is its embedding width, n_blocks its groups (RSTB), ``scale`` below
     # its upsampler's factor; what SwinIR fixes are constants of the module.
+    # "lama" is the LaMa inpainting generator of fast Fourier convolutions
+    # (models/ffc.py): ngf its stem width, n_blocks its residual FFC blocks,
+    # ffc_ratio below the global share of their channels; its input is the
+    # masked image and the mask as a fourth channel (input_nc 4), which is
+    # what tells the loader, the step and cli.infer that inputs carry masks
+    # (models/registry.input_mask_channel).
     generator: str = "expand"
     input_nc: int = 3
     # Label-map conditioning (0 = the input is an image). With
@@ -183,11 +189,17 @@ class ModelConfig:
     vq_res_blocks: int = 2
     vq_codes: int = 16384
     vq_embed_dim: int = 256
+    # generator="lama": the share of a residual block's channels on the
+    # GLOBAL branch, the one a spectral transform (rfft2, 1x1 convolution,
+    # irfft2) mixes over the whole extent; the released models use 0.75.
+    ffc_ratio: float = 0.75
 
 
 @dataclasses.dataclass(frozen=True)
 class LossConfig:
-    gan_mode: str = "lsgan"          # lsgan | vanilla | hinge
+    # lsgan | vanilla | hinge | nonsaturating (softplus of the logits: D
+    # minimises softplus(-D(x)) + softplus(D(G)); G softplus(-D(G)))
+    gan_mode: str = "lsgan"
     # Reduce the per-scale GAN losses of a multiscale D by their MEAN (the
     # SPADE lineage) instead of the reference's SUM (networks.py:808-850).
     gan_scale_mean: bool = False
@@ -195,6 +207,15 @@ class LossConfig:
     # lineage, whose option files give 0.1)
     gan_weight: float = 1.0
     lambda_feat: float = 10.0        # train.py:351
+    # "l1": the reference's weighted L1 sum over D's taps (train.py:344-351)
+    # | "mse": the LaMa lineage's MEAN over the taps of the mean squared
+    # difference, times lambda_feat.
+    feat_mode: str = "l1"
+    # > 0: the R1 gradient penalty on D's real call (Mescheder et al. 2018,
+    # as the LaMa lineage's NonSaturatingWithR1 has it): D's loss gains
+    # gp_coef x the batch mean of |grad_x sum D(x)|^2, x the real image in
+    # [0, 1] units; the first second-order term of the step.
+    gp_coef: float = 0.0
     lambda_vgg: float = 10.0         # train.py:377
     lambda_tv: float = 1.0           # train.py:378
     lambda_l1: float = 0.0           # reference --lamb=10 but L1 is dead (Q3)
@@ -225,6 +246,11 @@ class LossConfig:
     # normalised over channels, squared difference, a learned non-negative
     # 1x1 head a tap, spatial mean): the VQGAN lineage's perceptual term.
     lambda_lpips: float = 0.0
+    # The LaMa lineage's high-receptive-field perceptual term (losses/
+    # perceptual.hrf_loss): the sum over the four stages of a frozen DILATED
+    # ResNet50 (models/resnet_dilated.py) of the mean squared difference of
+    # its features, ImageNet-normalised inputs.
+    lambda_hrf: float = 0.0
     # > 0: the VQGAN lineage's adaptive adversarial weight. The GAN term
     # of G's loss is scaled by this times lambda = |grad_W nll| /
     # (|grad_W g| + 1e-4), clipped to [0, 1e4] and held constant, W the
@@ -716,6 +742,49 @@ _register(
         data=DataConfig(dataset="realsr", image_size=256, batch_size=4),
         parallel=ParallelConfig(mesh=MeshSpec(data=-1)),
         health=HealthConfig(ema_decay=0.999),
+    )
+)
+
+
+# 9. Big LaMa, large-mask inpainting on fast Fourier convolutions (Suvorov et
+#    al., WACV 2022, arXiv:2109.07161 sec. 2.1-2.4, 3; sizes of github.com/
+#    advimman/lama configs/training/big-lama.yaml + generator/
+#    ffc_resnet_075.yaml, as recalled). The layer equations are models/
+#    ffc.py's docstring: a k7 stem 4 -> 64, three stride-2 k3 convolutions to
+#    512, 18 residual blocks of two FFCs at 128 local / 384 global channels
+#    (the Fourier unit on 192), three transposed convolutions back, k7 head,
+#    sigmoid. x = image, m = mask (1 = missing), input = [x * (1 - m), m].
+#      D: C64(s2) - C128(s2,BN) - C256(s2,BN) - C512(s2,BN) - C512(s1,BN) -
+#        1, k4 pad 2, LeakyReLU 0.2, on the image alone; every layer's
+#        output a feature for the matching term.
+#      L_D = softplus(-D(x)) + gp_coef * mean_n |grad_x sum D(x)|^2 +
+#        softplus(D(y)) * m' + softplus(-D(y)) * (1 - m'), m' the mask
+#        resized (nearest) to the logits: the known pixels of a generated
+#        image count as real (mask_as_fake_target).
+#      L_G = 10 * mean softplus(-D(y)) + 10 * mean(|y - x| * (1 - m)) + 100
+#        * mean_layers mse(D_l(y), D_l(x)) + 30 * sum_stages mse(phi_s(y),
+#        phi_s(x)), phi the dilated ResNet50, images in [0, 1] (lambda_l1 5
+#        on this system's [-1, 1]). Adam(0.9, 0.999): G 1e-3, D 1e-4.
+#    Departures: this Trainer's step (D's fake is G's own forward, G sees the
+#    D of the step's start, D's BatchNorm statistics advance twice a step)
+#    and its 0.5 on D's whole loss; D sees images in [-1, 1] and the penalty
+#    is scaled to [0, 1] units; the perceptual trunk's weights are seeded.
+_register(
+    Config(
+        name="big_lama",
+        model=ModelConfig(generator="lama", ngf=64, n_blocks=18, input_nc=4,
+                          norm="batch", ffc_ratio=0.75, ndf=64, num_D=1,
+                          n_layers_D=4, norm_d="batch",
+                          use_spectral_norm=False, get_interm_feat=True,
+                          use_compression_net=False, d_conditional=False),
+        loss=LossConfig(gan_mode="nonsaturating", gan_weight=10.0,
+                        gp_coef=0.001, lambda_feat=100.0, feat_mode="mse",
+                        lambda_vgg=0.0, lambda_tv=0.0, lambda_l1=5.0,
+                        lambda_hrf=30.0),
+        optim=OptimConfig(lr=1e-3, lr_d=1e-4, beta1=0.9, beta2=0.999,
+                          lr_policy="constant"),
+        data=DataConfig(dataset="places", image_size=256, batch_size=16),
+        parallel=ParallelConfig(mesh=MeshSpec(data=-1)),
     )
 )
 

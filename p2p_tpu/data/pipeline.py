@@ -156,10 +156,20 @@ class PairedImageDataset:
         dtype: str = "float32",
         label_input: bool = False,
         scale: int = 1,
+        mask_input: bool = False,
+        mask_seed: int = 0,
     ):
         self.label_input = label_input
         if scale > 1 and label_input:
             raise ValueError("a label-map input has the target's extent")
+        if mask_input and (label_input or scale > 1 or augment):
+            raise ValueError("an input made from the target and a mask has "
+                             "the target's extent and no augmentation")
+        self.mask_input = mask_input
+        # the run's seed: a mask is drawn per (seed, epoch, index), the
+        # epoch being what the trainer has added to ``aug_seed`` since
+        self.mask_seed = mask_seed
+        self.mask_shares: dict = {}
         self.scale = scale
         self.a_dir = os.path.join(root, split, "a")
         self.b_dir = os.path.join(root, split, "b")
@@ -173,7 +183,9 @@ class PairedImageDataset:
         # (functional-RNG stance of core/rng.py) while epochs still get
         # fresh crops. Set BEFORE building a loader: Grain pickles the
         # dataset into its worker processes at creation time.
-        self.aug_seed = aug_seed
+        # (a mask dataset augments nothing: its ``aug_seed`` starts at the
+        # run's seed, epoch 0, and carries the epoch from there)
+        self.aug_seed = mask_seed if mask_input else aug_seed
         self.names = sorted(f for f in os.listdir(self.a_dir) if is_image_file(f))
         if not self.names:
             raise RuntimeError(f"no images in {self.a_dir}")
@@ -200,8 +212,11 @@ class PairedImageDataset:
 
     @property
     def memo_full(self) -> bool:
-        """Every item is a memo hit: both sides of every pair are held."""
-        return self.cache_enabled and len(self._memo) >= 2 * len(self.names)
+        """Every item is a memo hit: both sides of every pair are held
+        (the target alone where the input is made from it)."""
+        sides = 1 if self.mask_input else 2
+        return (self.cache_enabled
+                and len(self._memo) >= sides * len(self.names))
 
     def _load(self, path: str, h: Optional[int] = None,
               w: Optional[int] = None, labels: bool = False) -> np.ndarray:
@@ -230,6 +245,15 @@ class PairedImageDataset:
         in_dir, tgt_dir = ((self.a_dir, self.b_dir)
                            if self.direction == "a2b"
                            else (self.b_dir, self.a_dir))
+        if self.mask_input:
+            from p2p_tpu.data.masks import draw_mask, masked_input
+
+            t = self._load(os.path.join(tgt_dir, name))
+            mask = draw_mask(
+                (self.mask_seed, self.aug_seed - self.mask_seed, idx),
+                self.h, self.w)
+            self.mask_shares[idx] = float(mask.mean())
+            return {"input": masked_input(t, mask), "target": t}
         if self.label_input:
             m = self._load(os.path.join(in_dir, name), labels=True)
             t = self._load(os.path.join(tgt_dir, name))

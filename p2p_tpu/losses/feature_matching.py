@@ -32,3 +32,19 @@ def feature_matching_loss(
             )
             total = total + d_w * feat_w * jnp.mean(diff) * lambda_feat
     return total
+
+
+def feature_matching_mse(
+    pred_fake: Sequence[Sequence[jax.Array]],
+    pred_real: Sequence[Sequence[jax.Array]],
+) -> jax.Array:
+    """The LaMa lineage's feature matching: the MEAN over every
+    intermediate D activation (all but the logits, all scales) of the mean
+    squared difference, real features stop-gradiented. Unweighted."""
+    terms = []
+    for scale_f, scale_r in zip(pred_fake, pred_real):
+        for f, r in zip(scale_f[:-1], scale_r[:-1]):
+            terms.append(jnp.mean(jnp.square(
+                f.astype(jnp.float32)
+                - jax.lax.stop_gradient(r).astype(jnp.float32))))
+    return jnp.mean(jnp.stack(terms))

@@ -77,3 +77,26 @@ def tap_distance(feats_x, feats_y, weights=VGG_SLICE_WEIGHTS) -> jax.Array:
             jnp.abs(fx.astype(jnp.float32) - fy.astype(jnp.float32))
         )
     return total
+
+
+def hrf_loss(params: Dict[str, Any], x: jax.Array, y: jax.Array
+             ) -> jax.Array:
+    """The LaMa lineage's high-receptive-field perceptual distance
+    (``ResNetPL``): the SUM over the four stages of the frozen dilated
+    ResNet50 (models/resnet_dilated.py) of the mean squared difference of
+    its features of ``x`` and ``y`` (``y`` stop-gradiented), images in
+    [-1, 1], ImageNet-normalised by the trunk. bf16 images keep the
+    trunk's activations in bf16, like ``vgg_loss``; the differences and
+    their means are float32. Under the named scope ``loss_hrf``."""
+    from p2p_tpu.models.resnet_dilated import ResNet50Dilated
+
+    store = jnp.bfloat16 if x.dtype == jnp.bfloat16 else None
+    model = ResNet50Dilated(store_dtype=store)
+    with jax.named_scope("loss_hrf"):
+        feats_x = model.apply({"params": params}, x)
+        feats_y = model.apply({"params": params}, jax.lax.stop_gradient(y))
+        total = jnp.zeros((), jnp.float32)
+        for fx, fy in zip(feats_x, feats_y):
+            total = total + jnp.mean(jnp.square(
+                fx.astype(jnp.float32) - fy.astype(jnp.float32)))
+        return total

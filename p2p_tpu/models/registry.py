@@ -108,7 +108,27 @@ def define_G(cfg: ModelConfig, dtype=None, remat=False) -> nn.Module:
         return SwinIR(embed=cfg.ngf, groups=cfg.n_blocks,
                       out_channels=cfg.output_nc, scale=cfg.scale,
                       dtype=dtype)
+    if cfg.generator == "lama":
+        from p2p_tpu.models.ffc import LamaGenerator
+
+        return LamaGenerator(ngf=cfg.ngf, n_blocks=cfg.n_blocks,
+                             ratio=cfg.ffc_ratio,
+                             out_channels=cfg.output_nc, dtype=dtype)
     raise ValueError(f"unknown generator {cfg.generator!r}")
+
+
+def input_mask_channel(cfg: ModelConfig) -> Optional[int]:
+    """The channel of the configured generator's INPUT that is a mask (1 =
+    a pixel to be filled, the other channels the image with those pixels
+    blanked), or None for a generator whose input is an image or a label
+    map. Where there is one the loader draws the masks and builds the
+    input from the target (``data/pipeline.py``), the train step weighs
+    its losses by it, and ``cli.infer`` reads masks beside its images."""
+    if cfg.generator == "lama":
+        from p2p_tpu.models.ffc import MASK_CHANNEL
+
+        return MASK_CHANNEL
+    return None
 
 
 class GeneratorSide(NamedTuple):
@@ -149,6 +169,10 @@ def input_extent_multiple(cfg: ModelConfig) -> int:
         from p2p_tpu.models.swinir import WINDOW
 
         return WINDOW
+    if cfg.generator == "lama":
+        from p2p_tpu.models.ffc import EXTENT_MULTIPLE
+
+        return EXTENT_MULTIPLE
     return 1
 
 
@@ -182,6 +206,18 @@ def generator_gauges(cfg: ModelConfig, h: int, w: int) -> Dict[str, float]:
         # backward)
         out["swinir_vgg_gflop_per_image"] = vgg_gflop_per_image(
             "vgg19_preact", h, w)
+        return out
+    if cfg.generator == "lama":
+        from p2p_tpu.models.ffc import ffc_arithmetic
+        from p2p_tpu.models.resnet_dilated import (
+            resnet50_dilated_gflop_per_image,
+        )
+
+        out = ffc_arithmetic(cfg.ngf, cfg.n_blocks, cfg.ffc_ratio, h, w)
+        # one forward of the dilated ResNet50 (the step runs two and one
+        # backward)
+        out["lama_hrf_gflop_per_image"] = resnet50_dilated_gflop_per_image(
+            h, w)
         return out
     return {}
 

@@ -266,6 +266,24 @@ def make_window_transformer_rules() -> Tuple:
     )
 
 
+def make_ffc_rules() -> Tuple:
+    """The leaves of a generator of fast Fourier convolutions (models/
+    ffc.py), REPLICATED over the ``model`` axis by name: the k3 kernels
+    between the local and the global branch, the spectral transform's
+    three 1x1 kernels (the Fourier unit's among them), the branches'
+    BatchNorm affines and the transposed convolutions. No Megatron pair
+    is declared for them (a split of the global branch's channels would
+    cut through the transforms' real / imaginary interleave), so a
+    ``model`` axis leaves these whole, by these rows and not by the
+    catch-all."""
+    return (
+        (r"block_\d+/conv[12]/(?:l2l|l2g|g2l)/kernel$", P()),
+        (r"g2g/(?:conv1|conv2|fu/conv)/kernel$", P()),
+        (r"(?:bn_l|bn_g|g2g/bn1|fu/bn)/BatchNorm_0/(?:scale|bias)$", P()),
+        (r"(?:^|/)up_\d+/kernel$", P()),
+    )
+
+
 def tp_equivalence_rules(cfg, axis_size: int = 2,
                          min_ch: int = 512) -> Optional[Rules]:
     """The declarative table reproducing ``tp_leaf_spec`` for ``cfg``'s
@@ -311,7 +329,8 @@ def make_tp_rules(axis_size: int = 2, min_ch: int = 512) -> Tuple:
     return (make_unet_tp_rules(axis_size, min_ch)
             + make_resnet_tp_rules(axis_size, min_ch)
             + make_patchgan_tp_rules(axis_size, min_ch)
-            + make_window_transformer_rules())
+            + make_window_transformer_rules()
+            + make_ffc_rules())
 
 
 #: the TrainState fields the FSDP table shards (ZeRO-1: pure per-device

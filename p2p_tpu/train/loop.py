@@ -51,7 +51,11 @@ import numpy as np
 from p2p_tpu.core.config import Config
 from p2p_tpu.core.mesh import local_batch_size, batch_sharding, make_mesh
 from p2p_tpu.data.pipeline import PairedImageDataset, device_prefetch, make_loader
-from p2p_tpu.models.registry import generator_gauges, generator_trace_gauges
+from p2p_tpu.models.registry import (
+    generator_gauges,
+    generator_trace_gauges,
+    input_mask_channel,
+)
 from p2p_tpu.models.vgg import load_vgg19_params
 from p2p_tpu.obs import (
     GcPauseMeter,
@@ -991,15 +995,19 @@ class Trainer:
         # memo RAM and PCIe traffic (DataConfig.uint8_pipeline)
         ds_dtype = "uint8" if cfg.data.uint8_pipeline else "float32"
         labels = cfg.model.label_classes > 0
+        # an input that carries a mask is MADE by the loader from the
+        # target and a mask drawn per (seed, epoch, index)
+        masks = input_mask_channel(cfg.model) is not None
         self.train_ds = PairedImageDataset(
             root, "train", cfg.data.direction, cfg.data.image_size,
             cfg.data.image_width, augment=cfg.data.augment,
             dtype=ds_dtype, label_input=labels, scale=cfg.model.scale,
+            mask_input=masks, mask_seed=cfg.train.seed,
         )
         self.test_ds = PairedImageDataset(
             root, "test", cfg.data.direction, cfg.data.image_size,
             cfg.data.image_width, dtype=ds_dtype, label_input=labels,
-            scale=cfg.model.scale,
+            scale=cfg.model.scale, mask_input=masks,
         )
         self.steps_per_epoch = max(1, len(self.train_ds) // cfg.data.batch_size)
         self.mesh = mesh if mesh is not None else (
@@ -1085,6 +1093,18 @@ class Trainer:
 
             # VGG16 + the five heads, in VGG19's place
             self.vgg_params = load_lpips_params()
+        if cfg.loss.lambda_hrf > 0:
+            if self.vgg_params is not None:
+                raise ValueError("lambda_hrf goes with no VGG term "
+                                 "(lambda_vgg, lambda_style, lambda_lpips, "
+                                 "eval_fid): the step is handed ONE frozen "
+                                 "tree")
+            from p2p_tpu.models.resnet_dilated import (
+                load_resnet50_dilated_params,
+            )
+
+            # the dilated ResNet50, in VGG19's place
+            self.vgg_params = load_resnet50_dilated_params()
         self.fid_feature_fn = None
         self.vgg_source = None
         self._trace_counts_logged = {}  # kind -> counts last written
@@ -1430,6 +1450,7 @@ class Trainer:
             seed = self.epoch if seed is None else seed
             seed = seed + getattr(self, "_seed_jitter", 0)
             self.train_ds.aug_seed = cfg.train.seed + seed
+            self.train_ds.mask_shares = {}
             # Worker processes are pickled a FRESH copy of the dataset each
             # epoch, which would empty the decode memo and re-decode every
             # image — when the split is cached, in-process loading keeps
@@ -1648,6 +1669,11 @@ class Trainer:
             loader_next_s=round(nested[0].sum - nested_before[0], 6),
             h2d_put_s=round(nested[1].sum - nested_before[1], 6),
         )
+        shares = list(self.train_ds.mask_shares.values())
+        if shares:
+            # the loader's own counter: the mean share of a sample's
+            # pixels its mask blanked, over the samples of this epoch
+            record["masked_share_mean"] = round(sum(shares) / len(shares), 6)
         for phase, secs in phase_s.items():
             record[f"{phase}_s"] = round(secs, 6)
             record[f"slowest_{phase}_s"] = round(slowest[phase][0], 6)

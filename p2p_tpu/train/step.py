@@ -66,7 +66,15 @@ from p2p_tpu.losses import (
     ssim,
     vgg_loss,
 )
-from p2p_tpu.models.registry import generator_side
+from p2p_tpu.losses.feature_matching import feature_matching_mse
+from p2p_tpu.losses.gan import (
+    final_preds,
+    nonsaturating,
+    r1_penalty,
+    resize_mask_nearest,
+)
+from p2p_tpu.losses.perceptual import hrf_loss
+from p2p_tpu.models.registry import generator_side, input_mask_channel
 from p2p_tpu.ops.quantize import quantize, quantize_ste
 from p2p_tpu.ops.tv import total_variation_loss
 from p2p_tpu.train.state import TrainState, build_models, make_optimizers
@@ -88,11 +96,15 @@ from p2p_tpu.utils.images import ingest, ingest_input
 #: adaptive adversarial weight (one weight-gradient convolution of the
 #: generator's last layer for two cotangents, and their norms),
 #: ``loss_codebook`` the code-usage numbers of a learned quantizer (its
-#: loss is computed inside ``G``, under ``vq``).
+#: loss is computed inside ``G``, under ``vq``). ``loss_hrf`` is the
+#: high-receptive-field perceptual term (the dilated ResNet50). The R1
+#: penalty's passes run under ``d_r1`` INSIDE ``D_real`` (its forward is
+#: D's real call), so a join by these names counts them as D's and a join
+#: by ``d_r1`` alone reads the penalty.
 STEP_SCOPES = ("compress", "G", "D_fake", "D_real", "loss_gan", "loss_fm",
                "loss_vgg", "loss_tv", "loss_pix", "C_branch", "opt_g",
                "opt_d", "opt_c", "loss_lpips", "loss_adaptive",
-               "loss_codebook")
+               "loss_codebook", "loss_hrf")
 
 
 #: weight of the codebook loss a generator with a learned quantizer hands
@@ -155,6 +167,68 @@ def single_forward_d_losses(d_apply, dvars0, params_d, fake_pair,
     )
 
 
+def masked_r1_d_losses(d_apply, dvars0, params_d, fake, real, mask,
+                       gp_coef: float):
+    """:func:`single_forward_d_losses` for the LaMa lineage's
+    ``NonSaturatingWithR1`` on an unconditional D: the same ONE D(fake)
+    forward whose vjp serves D's loss and (later, ``pull``) G's, the same
+    fake -> real order of D's threaded collections, the same 0.5 on the
+    whole. ``mask`` is ``[N, H, W, 1]``, 1 where the generated image was
+    filled in. D minimises
+
+        0.5 * ( softplus(-D(x)) + gp_coef * R1(x)
+                + softplus(D(y)) * m' + softplus(-D(y)) * (1 - m') )
+
+    ``m'`` the mask resized (nearest) to the logits: the known pixels of
+    a generated image count as real. ``R1`` is ``losses.gan.r1_penalty``
+    on the real call: its forward IS the real call (one forward, under
+    ``D_real``), its gradient with respect to the image one backward, and
+    the gradient of that with respect to D's parameters the step's only
+    second-order pass; all three under the scope ``d_r1``. Returns what
+    ``single_forward_d_losses`` returns and the penalty's value (before
+    ``gp_coef``)."""
+    def fake_primal(params, x):
+        with jax.named_scope("D_fake"):
+            pred, v1 = d_apply(params, dvars0, x)
+        return pred, v1
+
+    pred_fake, d_vjp, dvars1 = jax.vjp(fake_primal, params_d, fake,
+                                       has_aux=True)
+
+    def fake_loss(pred):
+        with jax.named_scope("loss_gan"):
+            total = jnp.zeros((), jnp.float32)
+            for logits in final_preds(pred):
+                known = 1.0 - resize_mask_nearest(mask, logits.shape[1:3])
+                total = total + nonsaturating(logits, known)
+            return 0.5 * total
+
+    loss_fake, ct_fake = jax.value_and_grad(fake_loss)(pred_fake)
+    gd_fake = d_vjp(ct_fake)[0]  # image cotangent dead → DCE
+
+    def real_fn(params):
+        def logits_sum(x):
+            pred, v2 = d_apply(params, dvars1, x.astype(real.dtype))
+            total = sum(jnp.sum(p.astype(jnp.float32))
+                        for p in final_preds(pred))
+            return total, (pred, v2)
+
+        with jax.named_scope("D_real"), jax.named_scope("d_r1"):
+            r1, (pred_real, v2) = r1_penalty(logits_sum, real)
+        with jax.named_scope("loss_gan"):
+            loss = 0.5 * (gan_loss(pred_real, True, "nonsaturating")
+                          + gp_coef * r1)
+        return loss, (v2, pred_real, r1)
+
+    (loss_real, (dvars2, pred_real, r1)), gd_real = jax.value_and_grad(
+        real_fn, has_aux=True)(params_d)
+    loss_d = loss_fake + loss_real
+    grads_d = jax.tree_util.tree_map(jnp.add, gd_fake, gd_real)
+    pred_real = jax.tree_util.tree_map(jax.lax.stop_gradient, pred_real)
+    return loss_d, grads_d, pred_fake, pred_real, dvars2, (
+        lambda ct: d_vjp(ct)[1]), jax.lax.stop_gradient(r1)
+
+
 def adaptive_gan_weight(last_input, last_kernel, ct_nll, ct_gan):
     """The VQGAN lineage's ``lambda = |grad_W nll| / (|grad_W g| + 1e-4)``,
     clipped to [0, 1e4] and held constant, ``W`` the generator's last
@@ -190,6 +264,10 @@ def make_g_loss_fn(cfg: Config, vgg_params: Optional[Any] = None,
     L = cfg.loss
     need_vgg = (L.lambda_vgg > 0) and vgg_params is not None
     need_lpips = (L.lambda_lpips > 0) and vgg_params is not None
+    need_hrf = (L.lambda_hrf > 0) and vgg_params is not None
+    # an input that carries a mask (inpainting): the L1 term counts the
+    # KNOWN pixels alone
+    mask_ch = input_mask_channel(cfg.model)
 
     def g_losses(fake_b, pred_fake_g, pred_real, real_a, real_b, step):
         with jax.named_scope("loss_gan"):
@@ -202,10 +280,14 @@ def make_g_loss_fn(cfg: Config, vgg_params: Optional[Any] = None,
         total = l_gan
         if L.lambda_feat > 0:
             with jax.named_scope("loss_fm"):
-                l_feat = feature_matching_loss(
-                    pred_fake_g, pred_real, cfg.model.n_layers_D,
-                    L.lambda_feat
-                )
+                if L.feat_mode == "mse":
+                    l_feat = feature_matching_mse(
+                        pred_fake_g, pred_real) * L.lambda_feat
+                else:
+                    l_feat = feature_matching_loss(
+                        pred_fake_g, pred_real, cfg.model.n_layers_D,
+                        L.lambda_feat
+                    )
             parts["g_feat"] = l_feat
             total = total + l_feat
         if need_vgg:
@@ -223,6 +305,10 @@ def make_g_loss_fn(cfg: Config, vgg_params: Optional[Any] = None,
                                      real_b) * L.lambda_lpips
             parts["g_lpips"] = l_lpips
             total = total + l_lpips
+        if need_hrf:
+            l_hrf = hrf_loss(vgg_params, fake_b, real_b) * L.lambda_hrf
+            parts["g_hrf"] = l_hrf
+            total = total + l_hrf
         if L.lambda_style > 0 and vgg_params is not None:
             from p2p_tpu.losses.style import style_loss
 
@@ -273,7 +359,17 @@ def make_g_loss_fn(cfg: Config, vgg_params: Optional[Any] = None,
             )) * lam
             parts["g_sobel"] = l_sobel
             total = total + l_sobel
-        if L.lambda_l1 > 0:
+        if L.lambda_l1 > 0 and mask_ch is not None:
+            # the LaMa lineage's masked L1 at weight_missing 0: the mean
+            # over EVERY element of |y - x| on the known pixels
+            known = (real_a[..., mask_ch:mask_ch + 1] <= 0).astype(
+                fake_b.dtype)
+            l_l1 = jnp.mean(
+                jnp.abs(fake_b - real_b) * known, dtype=jnp.float32
+            ) * L.lambda_l1
+            parts["g_l1_known"] = l_l1
+            total = total + l_l1
+        elif L.lambda_l1 > 0:
             # elementwise diff in the train dtype (bf16 cotangents),
             # accumulation in f32 — halves the loss-side HBM traffic
             # at 256²·bs128 vs an f32 elementwise chain.
@@ -348,6 +444,19 @@ def build_train_step(
         raise ValueError("the historical-fake pool holds conditional "
                          "pairs of a generator with one output: set "
                          "pool_size 0")
+    # an input that carries a mask (models/registry.input_mask_channel):
+    # D's loss is the masked non-saturating one with its R1 penalty
+    mask_ch = input_mask_channel(cfg.model)
+    if mask_ch is not None and (cfg.model.d_conditional
+                                or L.gan_mode != "nonsaturating"
+                                or cfg.train.pool_size > 0):
+        raise ValueError(
+            "a generator whose input carries a mask trains under the "
+            "masked non-saturating loss on an unconditional D: set "
+            "gan_mode 'nonsaturating', d_conditional False, pool_size 0")
+    if L.gp_coef > 0 and mask_ch is None:
+        raise ValueError("gp_coef (the R1 penalty) is wired for the masked "
+                         "non-saturating D loss alone")
     g_loss_fn = make_g_loss_fn(cfg, vgg_params, steps_per_epoch)
     # Self-healing (resilience/health.py, rung 1 of the recovery ladder):
     # a non-finite step SKIPS — gradients are zeroed before they can
@@ -504,12 +613,20 @@ def build_train_step(
             else:
                 fake_pair = _concat_pair(real_a, fake_b_primal)
                 real_pair = _concat_pair(real_a, real_b)
-            loss_d, grads_d, pred_fake, pred_real, dvars2, pull = (
-                single_forward_d_losses(
-                    d_fwd, dvars0, state.params_d,
-                    fake_pair, real_pair, L.gan_mode, L.gan_scale_mean,
+            if mask_ch is not None:
+                filled = (real_a[..., mask_ch:mask_ch + 1] > 0).astype(
+                    jnp.float32)
+                (loss_d, grads_d, pred_fake, pred_real, dvars2, pull,
+                 loss_d_r1) = masked_r1_d_losses(
+                    d_fwd, dvars0, state.params_d, fake_pair, real_pair,
+                    filled, L.gp_coef)
+            else:
+                loss_d, grads_d, pred_fake, pred_real, dvars2, pull = (
+                    single_forward_d_losses(
+                        d_fwd, dvars0, state.params_d,
+                        fake_pair, real_pair, L.gan_mode, L.gan_scale_mean,
+                    )
                 )
-            )
 
             (loss_g, g_parts), (ct_fake_direct, ct_pred) = jax.value_and_grad(
                 g_losses, argnums=(0, 1), has_aux=True
@@ -746,6 +863,9 @@ def build_train_step(
         }
         if adaptive:
             metrics["d_weight"] = d_weight.astype(jnp.float32)
+        if mask_ch is not None:
+            # the penalty's own value, before gp_coef
+            metrics["loss_d_r1"] = loss_d_r1.astype(jnp.float32)
         if side:
             with jax.named_scope("loss_codebook"):
                 used, perplexity = side.usage(vq_indices)

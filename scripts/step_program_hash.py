@@ -17,7 +17,8 @@ The configuration is built as ``benchmark/drivers/train.py`` builds it
 (the CLI's parser and ``config_from_flags`` on the cell's flags); the state
 is abstract (``jax.eval_shape``), the batch two uint8 images per example
 at the cell's extent (the input at that over the configuration's ``scale``
-where it states one), the mesh the cell's own over the described chips
+where it states one, with the mask as a fourth channel where the
+configuration's generator reads one), the mesh the cell's own over the described chips
 with the Pallas branch taken as on the chip. ``steps_per_epoch`` is the
 cell's ``dataset_pairs // batch_size``. VGG19's seeded weights are
 closed-over constants of the step: they are part of the text.
@@ -204,7 +205,10 @@ def main() -> None:
     image = jax.ShapeDtypeStruct((bs, h, w, 3), jnp.uint8)
     # a super-resolution cell's input has the target's extent over its scale
     scale = int(cfgf.get("scale", 1))
-    lq = jax.ShapeDtypeStruct((bs, h // scale, w // scale, 3), jnp.uint8)
+    # an inpainting cell's carries its mask as a fourth channel (a label
+    # map stands in as three channels here, as it always has)
+    in_c = 3 if cfg.model.label_classes else cfg.model.input_nc
+    lq = jax.ShapeDtypeStruct((bs, h // scale, w // scale, in_c), jnp.uint8)
     state = jax.eval_shape(
         lambda: create_train_state(
             cfg, jax.random.key(0),
@@ -221,6 +225,11 @@ def main() -> None:
             from p2p_tpu.losses.perceptual import VGG_TAPS
 
             vgg = load_vgg19_params(arch=VGG_TAPS[taps][0])
+    if getattr(cfg.loss, "lambda_hrf", 0) > 0:
+        from p2p_tpu.models.resnet_dilated import load_resnet50_dilated_params
+
+        # the dilated ResNet50, in VGG19's place
+        vgg = load_resnet50_dilated_params()
     step = make_parallel_train_step(cfg, mesh, vgg, steps_per_epoch, dtype)
     rep, bsh = replicated(mesh), batch_sharding(mesh)
 

@@ -667,9 +667,12 @@ def test_conv_layer_trace_reads_device_time_by_layer_and_direction():
 # in the residual blocks + 1 head and the compression net's 3; pix2pixHD's
 # enhancer and G1; the ResNet generator of cityscapes_spatial; the U-Nets
 # pad with zeros (facades_int8_full's 3 are its compression net's), and so
-# does SPADE.
+# does SPADE; the LaMa generator's stem, 3 stride-2 convolutions and head,
+# and in each of its 36 fast Fourier convolutions one pad a branch (the
+# local and the global one: each serves the two convolutions that read it).
 REFLECT_PAD_SITES = {"reference": 25, "pix2pixhd": 35,
-                     "cityscapes_spatial": 23, "facades_int8_full": 3}
+                     "cityscapes_spatial": 23, "facades_int8_full": 3,
+                     "big_lama": 77}
 
 
 @pytest.mark.parametrize("spatial", [1, 2], ids=["one_device", "spatial2"])

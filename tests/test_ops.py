@@ -880,8 +880,10 @@ def test_every_preset_sends_its_thin_convs_to_the_blocked_form(preset):
         if not in_blocked and strides == (1, 1) and kernel[0] >= 7
         and min(kernel[2:]) <= 16 and lhs[1] * lhs[2] >= _BLOCKED_MIN_PIXELS]
     assert lost == []
-    # the presets with such layers (U-Nets have none)
-    want = {"reference": 2, "pix2pixhd": 3, "cityscapes_spatial": 2}
+    # the presets with such layers (U-Nets have none); the LaMa
+    # generator's k7 stem (4 -> 64) and head (64 -> 3) at 256x256
+    want = {"reference": 2, "pix2pixhd": 3, "cityscapes_spatial": 2,
+            "big_lama": 2}
     assert len(blocked) == want.get(preset, 0)
 
 

@@ -725,9 +725,11 @@ def test_compiled_step_names_every_scope(scoped_step):
     owners = scope_time.instruction_scopes(
         scoped_step.compile().as_text(), STEP_SCOPES)
     # (the three a learned-quantizer preset alone enters are held in its
-    # own compiled step: tests/test_vqgan.py)
+    # own compiled step, tests/test_vqgan.py; the inpainting preset's
+    # perceptual term in tests/test_lama.py)
     assert set(STEP_SCOPES) - {"loss_lpips", "loss_adaptive",
-                               "loss_codebook"} <= set(owners.values())
+                               "loss_codebook", "loss_hrf"} <= set(
+        owners.values())
     assert scope_time.program_scopes() == STEP_SCOPES
 
 
