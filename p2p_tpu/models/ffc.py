@@ -44,11 +44,24 @@ authors' [0, 1] at its first layer (the mask channel's -1 / 1 become 0 /
 In training it returns the predicted image; in evaluation the COMPOSITE
 ``m * prediction + (1 - m) * input`` (the known pixels are the input's).
 
-Precision: convolutions in the compute dtype; the two transforms, their
-orthonormal scale and the complex tensor are float32 (XLA's ``fft`` takes
-no bfloat16), the 1x1 convolution between them reads real / imaginary
-channels in the compute dtype; BatchNorm's moments are float32
-(ops/norm.py).
+The transforms are real matrix products with constant DFT matrices on the
+NHWC tensor (:func:`rfft2`, :func:`irfft2`; channels stay on the lanes, no
+complex tensor is built and nothing of an FFT library runs): a 32-point
+transform is a ``[32, 32]`` matrix, nothing beside the convolutions, where
+XLA's ``fft`` expanded into thousands of small ops and layout changes
+(PERF.md section 6, PR 42). One path for every extent. Between them the
+spectrum is ``[N, H, W/2+1, C, 2]``, (real, imaginary) an axis of its own
+through the unit's 1x1 convolution, BatchNorm and ReLU: the STORED
+parameters are those of the ``2 C`` interleaved channels above (channel
+``2i`` the real part of channel ``i``, ``2i + 1`` its imaginary; the
+kernel ``[1, 1, 2C, 2C]``, BatchNorm's vectors of ``2C``), read as
+``[C, 2]``, so no pass brings the pairs side by side on the lanes.
+
+Precision: convolutions in the compute dtype; the two transforms are
+float32 products at ``Precision.HIGHEST`` (float64 where a test runs the
+module in it) with their orthonormal scale folded into a matrix, the 1x1
+convolution between them reads real / imaginary channels in the compute
+dtype; BatchNorm's moments are float32 (ops/norm.py).
 
 ``jax.named_scope``s: ``ffc_local`` (the k3 convolutions of a block's
 FFCs and their pads), ``ffc_spectral`` (the spectral transform) and,
@@ -57,10 +70,12 @@ inside it, ``ffc_fft`` (both transforms with their casts).
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 from p2p_tpu.ops.activations import relu_y
@@ -94,30 +109,104 @@ def _conv(features: int, kernel: int, dtype, name: str) -> nn.Conv:
                    kernel_init=torch_default_init, name=name)
 
 
+@functools.lru_cache(maxsize=32)
+def dft_matrices(h: int, w: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """The real DFT matrices of an ``h`` x ``w`` extent, float64, with
+    ``p`` / ``q`` = 0 the real and 1 the imaginary part:
+
+    ``along_w[p, l, w] = (cos, -sin)(2 pi l w / W)`` for the ``W/2 + 1``
+    columns ``l`` of a real input's spectrum; ``along_h[q, k, h, p]`` the
+    block matrix ``[[C, S], [-S, C]]`` of ``exp(-2 pi i k h / H)`` on a
+    (real, imaginary) pair, whose transpose is the inverse's; ``weight[l]``
+    how often column ``l`` stands in the full spectrum (its mirror ``W - l``
+    is its conjugate: 2, but for column 0 and an even ``W``'s Nyquist
+    column)."""
+    cols = w // 2 + 1
+    aw = 2.0 * np.pi * np.outer(np.arange(cols), np.arange(w)) / w
+    ah = 2.0 * np.pi * np.outer(np.arange(h), np.arange(h)) / h
+    along_w = np.stack([np.cos(aw), -np.sin(aw)])
+    along_h = np.empty((2, h, h, 2))
+    along_h[0, :, :, 0] = along_h[1, :, :, 1] = np.cos(ah)
+    along_h[0, :, :, 1] = np.sin(ah)
+    along_h[1, :, :, 0] = -np.sin(ah)
+    weight = np.full((cols,), 2.0)
+    weight[0] = 1.0
+    if w % 2 == 0:
+        weight[-1] = 1.0
+    return along_w, along_h, weight
+
+
+def _product(spec: str, matrix: np.ndarray, x):
+    """``einsum(spec, matrix, x)`` with a constant matrix in ``x``'s own
+    float32 (float64) at ``Precision.HIGHEST``."""
+    return jnp.einsum(spec, jnp.asarray(matrix, x.dtype), x,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def rfft2(x):
+    """``jnp.fft.rfft2(x, axes=(1, 2), norm="ortho")`` of a real
+    ``[n, h, w, c]`` as ``[n, h, w/2+1, c, 2]`` (real, imaginary): real
+    to complex along W, then complex to complex along H over the (h, real
+    / imaginary) pairs, ``c`` minor in both products."""
+    _, h, w, _ = x.shape
+    along_w, along_h, _ = dft_matrices(h, w)
+    return _product("qkhp,nhplc->nklcq", along_h / np.sqrt(h * w),
+                    _product("plw,nhwc->nhplc", along_w, x))
+
+
+def irfft2(z, w: int):
+    """``jnp.fft.irfft2(.., s=(h, w), axes=(1, 2), norm="ortho")`` of
+    ``[n, h, w/2+1, c, 2]`` (real, imaginary; Hermitian or not) as the
+    real ``[n, h, w, c]``: the inverse along H, then complex to real
+    along W, where the imaginary parts of column 0 and of the Nyquist
+    column meet a sine that is zero, as the library drops them."""
+    h = z.shape[1]
+    along_w, along_h, weight = dft_matrices(h, w)
+    return _product("plw,nhplc->nhwc",
+                    along_w * weight[None, :, None] / np.sqrt(h * w),
+                    _product("qkhp,nklcq->nhplc", along_h, z))
+
+
+class _PairConv(nn.Module):
+    """The unit's 1x1 convolution (no bias) on ``[N, H, W, C, 2]``, the
+    (real, imaginary) pairs an axis of their own: the kernel is stored as
+    the ``[1, 1, 2C, 2C]`` of a convolution over interleaved channels
+    (``2i`` real, ``2i + 1`` imaginary) and read as ``[C, 2, C, 2]``, so
+    the pairs are never brought side by side on the lanes."""
+
+    dtype: Optional[jnp.dtype] = None
+
+    @nn.compact
+    def __call__(self, z):
+        c = z.shape[-2]
+        kernel = self.param("kernel", torch_default_init,
+                            (1, 1, 2 * c, 2 * c), jnp.float32)
+        dtype = self.dtype or jnp.result_type(z.dtype, kernel.dtype)
+        return jnp.einsum("nhwcq,cqdr->nhwdr", z.astype(dtype),
+                          kernel.reshape(c, 2, c, 2).astype(dtype))
+
+
 class FourierUnit(nn.Module):
-    """``F(h)`` of the module docstring on ``[N, H, W, C]``."""
+    """``F(h)`` of the module docstring on ``[N, H, W, C]``. Between the
+    transforms the spectrum is ``[N, H, W/2+1, C, 2]``: the parameters
+    are those of the interleaved ``2C`` channels (a checkpoint holds
+    ``conv/kernel`` ``[1, 1, 2C, 2C]`` and BatchNorm's vectors of ``2C``),
+    read as ``[C, 2]``."""
 
     dtype: Optional[jnp.dtype] = None
 
     @nn.compact
     def __call__(self, h, train: bool):
-        n, hh, ww, c = h.shape
         # the transform's own dtype: float32 (float64 where a test runs
         # the module in it)
         wide = jnp.promote_types(h.dtype, jnp.float32)
         with jax.named_scope("ffc_fft"):
-            z = jnp.fft.rfft2(h.astype(wide), axes=(1, 2), norm="ortho")
-            # channel 2i the real part of channel i, 2i + 1 its imaginary
-            z = jnp.stack([z.real, z.imag], axis=-1).reshape(
-                n, hh, ww // 2 + 1, 2 * c).astype(h.dtype)
-        z = save_conv_out(_conv(2 * c, 1, self.dtype, "conv")(z))
-        z = relu_y(BatchNorm(use_running_average=not train,
-                             dtype=self.dtype, name="bn")(z))
+            z = rfft2(h.astype(wide)).astype(h.dtype)
+        z = save_conv_out(_PairConv(self.dtype, name="conv")(z))
+        z = relu_y(BatchNorm(use_running_average=not train, dtype=self.dtype,
+                             feature_axes=2, name="bn")(z))
         with jax.named_scope("ffc_fft"):
-            z = z.astype(wide).reshape(n, hh, ww // 2 + 1, c, 2)
-            out = jnp.fft.irfft2(jax.lax.complex(z[..., 0], z[..., 1]),
-                                 s=(hh, ww), axes=(1, 2), norm="ortho")
-            return out.astype(h.dtype)
+            return irfft2(z.astype(wide), h.shape[2]).astype(h.dtype)
 
 
 class SpectralTransform(nn.Module):
@@ -246,8 +335,9 @@ def ffc_arithmetic(ngf: int, n_blocks: int, ratio: float, h: int, w: int
     shapes: the FFC layers (every FFC_BN_ACT of the source: the stem, the
     downsamplings, two a block), how many of them hold a Fourier unit, the
     transforms a training step runs (one forward and one inverse a unit,
-    and as many again in the backward), the global branch's channels and
-    the forward pass's multiply-adds."""
+    and as many again in the backward), how many of them go by matrix
+    products (all: there is one path), the global branch's channels and the
+    forward pass's multiply-adds."""
     width = ngf * 2 ** N_DOWN
     c_l, c_g = split_channels(width, ratio)
     hb, wb = h // EXTENT_MULTIPLE, w // EXTENT_MULTIPLE
@@ -264,5 +354,6 @@ def ffc_arithmetic(ngf: int, n_blocks: int, ratio: float, h: int, w: int
     return {"ffc_layers": float(1 + N_DOWN + 2 * n_blocks),
             "ffc_fourier_units": float(units),
             "ffc_fft_calls_per_step": float(4 * units),
+            "ffc_dft_transforms_per_step": float(4 * units),
             "ffc_global_channels": float(c_g),
             "generator_gflop_per_image": 2.0 * macs / 1e9}

@@ -20,6 +20,7 @@ regions pass ``axis_name='data'`` to opt in explicitly.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -97,11 +98,16 @@ class _FastBatchNorm(nn.Module):
     dtype: Optional[jnp.dtype] = None
     # False: BatchNorm2d(affine=False), no params (what SPADE modulates)
     affine: bool = True
+    # how many trailing axes of ``x`` index channels. 2: ``[..., C, 2]``
+    # (models/ffc.py's (channel, real / imaginary) pairs) under the SAME
+    # flat vectors of 2C a ``[..., 2C]`` tensor would have, so the pairs
+    # never have to be brought side by side on the lanes
+    feature_axes: int = 1
 
     @nn.compact
     def __call__(self, x):
-        c = x.shape[-1]
-        reduce_axes = tuple(range(x.ndim - 1))
+        feat = x.shape[-self.feature_axes:]
+        c = math.prod(feat)
         if self.affine:
             scale = self.param("scale", _gamma_init, (c,), jnp.float32)
             bias = self.param("bias", nn.initializers.zeros, (c,),
@@ -124,16 +130,20 @@ class _FastBatchNorm(nn.Module):
             # subtraction is cancellation-safe where the naive E[x²]−E[x]²
             # form loses all precision for high-mean/low-variance channels.
             # Still a single read of x — the shift fuses into the reduces.
-            c = jax.lax.stop_gradient(ra_mean.value).astype(x.dtype)
-            xc = x - c
-            n = x.size // x.shape[-1]
-            sum_c, sumsq_c = dual_moments(xc)
-            mean_c = sum_c / n
-            msq_c = sumsq_c / n
+            shift = jax.lax.stop_gradient(ra_mean.value).astype(x.dtype)
+            xc = x - shift.reshape(feat)
+            n = x.size // c
+            moments = dual_moments
+            for _ in feat[1:]:
+                moments = jax.vmap(moments, in_axes=-1, out_axes=-1)
+            sum_c, sumsq_c = moments(xc)
+            mean_c = sum_c.reshape(c) / n
+            msq_c = sumsq_c.reshape(c) / n
             if self.axis_name is not None:
                 mean_c = jax.lax.pmean(mean_c, self.axis_name)
                 msq_c = jax.lax.pmean(msq_c, self.axis_name)
-            mean = mean_c + c.astype(jnp.float32)  # add back the exact shift
+            # add back the exact shift
+            mean = mean_c + shift.astype(jnp.float32)
             var = jnp.maximum(msq_c - jnp.square(mean_c), 0.0)
             if not init:
                 m = self.momentum
@@ -151,7 +161,8 @@ class _FastBatchNorm(nn.Module):
         # materialized fp32 copy of the activation (multiple consumers defeat
         # fusion of the convert). Per-channel a/b quantization to bf16 is
         # ~2⁻⁸ relative — noise for GAN training; fp32 inputs are unaffected.
-        y = x * a.astype(x.dtype) + b.astype(x.dtype)
+        y = (x * a.astype(x.dtype).reshape(feat)
+             + b.astype(x.dtype).reshape(feat))
         return y.astype(self.dtype or x.dtype)
 
 
@@ -169,6 +180,7 @@ class BatchNorm(nn.Module):
     axis_name: Optional[str] = None
     dtype: Optional[jnp.dtype] = None
     affine: bool = True
+    feature_axes: int = 1
 
     @nn.compact
     def __call__(self, x, use_running_average: Optional[bool] = None):
@@ -184,6 +196,7 @@ class BatchNorm(nn.Module):
             axis_name=self.axis_name,
             dtype=self.dtype,
             affine=self.affine,
+            feature_axes=self.feature_axes,
             name="BatchNorm_0",
         )(x)
 

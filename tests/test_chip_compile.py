@@ -210,6 +210,37 @@ def test_blocked_conv_layer_compiles_dense(one_chip, shape, features, k):
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 8 * 2 ** 30
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_fourier_unit_transforms_compile_as_products(one_chip, grad):
+    """``models/ffc.py``'s ``rfft2`` and ``irfft2`` at the shape the cell
+    ``big_lama_places256.train`` runs a unit (bf16 activations, float32
+    transforms), forward and backward, through the chip's compiler: two
+    products a transform (the compiler's ``convolution``), nothing of an
+    ``fft``, and a few dozen instructions where XLA's expansion of the
+    ``fft`` ops held thousands."""
+    from p2p_tpu.models import ffc
+
+    def pair(x):
+        z = ffc.rfft2(x.astype(jnp.float32)).astype(x.dtype)
+        return ffc.irfft2(jax.nn.relu(z).astype(jnp.float32),
+                          x.shape[2]).astype(x.dtype)
+
+    x = jax.ShapeDtypeStruct((16, 32, 32, 192), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def both(v, ct):
+        # a cotangent of its own: the compiler folds a constant one away
+        y, pullback = jax.vjp(pair, v)
+        return y, pullback(ct)[0]
+
+    text = _compiled_text(both, x, x) if grad else _compiled_text(pair, x)
+    assert not re.search(r"\bfft\(", text)
+    products = re.findall(r" convolution\(", text)
+    assert len(products) == (8 if grad else 4), len(products)
+    entry, _ = _entry_and_types(text)
+    assert len(entry) < 60, len(entry)
+
+
 def _entry_and_types(text):
     """The ENTRY computation's lines, and name -> result type of every
     instruction of the module."""

@@ -208,6 +208,115 @@ def test_the_transforms_round_trip_and_keep_the_norm():
         float(jnp.sum(x ** 2)), rel=1e-5)
 
 
+def library_pair():
+    """``jnp.fft``'s two transforms with the module's signatures: real and
+    imaginary parts as a last axis of 2."""
+    def rfft2(x):
+        z = jnp.fft.rfft2(x, axes=(1, 2), norm="ortho")
+        return jnp.stack([z.real, z.imag], axis=-1)
+
+    def irfft2(z, w):
+        return jnp.fft.irfft2(jax.lax.complex(z[..., 0], z[..., 1]),
+                              s=(z.shape[1], w), axes=(1, 2), norm="ortho")
+
+    return rfft2, irfft2
+
+
+@pytest.mark.parametrize("dtype", ["float64", "float32"])
+@pytest.mark.parametrize("what", ["rfft2", "irfft2", "rfft2_pullback",
+                                  "irfft2_pullback"])
+@pytest.mark.parametrize("hw", [(8, 12), (32, 32), (6, 9)],
+                         ids=["8x12", "32x32", "6x9"])
+def test_the_module_s_transforms_are_the_library_s(hw, what, dtype):
+    """``models/ffc.py``'s matrix products against ``jnp.fft`` run in
+    float64: the forward transform of a real tensor, the inverse of a
+    spectrum that is NOT Hermitian (the unit's comes out of a convolution,
+    BatchNorm and a ReLU: the library drops the imaginary parts of column
+    0 and of the Nyquist column, and an odd width has no such column), and
+    the pullbacks of both. In float64 to 1e-12; in float32 no further from
+    the float64 truth than twice the library's own float32 error (root
+    mean square: the largest of a few thousand errors is a draw, 0.6-2.5
+    of the library's over seeds; this reads 1.4-1.5 at 32x32)."""
+    from p2p_tpu.models import ffc
+
+    h, w = hw
+    rng = np.random.default_rng(5)
+    x = rng.normal(size=(2, h, w, 3))
+    z = rng.normal(size=(2, h, w // 2 + 1, 3, 2))
+    with jax.enable_x64(True):
+        def run(pair, dt):
+            rfft2, irfft2 = pair
+            fn, arg, ct = ((rfft2, x, z) if what.startswith("rfft2") else
+                           (lambda v: irfft2(v, w), z, x))
+            arg, ct = jnp.asarray(arg, dt), jnp.asarray(ct, dt)
+            if what.endswith("pullback"):
+                out, = jax.vjp(fn, arg)[1](ct)
+            else:
+                out = fn(arg)
+            assert out.dtype == dt
+            return np.asarray(out, np.float64)
+
+        truth = run(library_pair(), jnp.float64)
+        got = run((ffc.rfft2, ffc.irfft2), jnp.dtype(dtype))
+        assert got.shape == truth.shape
+        if dtype == "float64":
+            assert np.abs(got - truth).max() < 1e-12 * np.abs(truth).max()
+        else:
+            rms = lambda a: float(np.sqrt(np.mean(np.square(a))))  # noqa: E731
+            assert rms(got - truth) <= 2.0 * rms(
+                run(library_pair(), jnp.float32) - truth)
+
+
+@pytest.mark.parametrize("layer", ["conv", "bn_eval", "bn_train"])
+def test_the_unit_s_layers_on_pairs_are_the_layers_on_interleaved_channels(
+        layer):
+    """Between its transforms the unit keeps (real, imaginary) as a last
+    axis of 2 and reads the parameters of the interleaved ``2C`` channels
+    as ``[C, 2]``: its 1x1 convolution against ``nn.Conv`` and its
+    BatchNorm (``feature_axes=2``) against BatchNorm on ``[..., 2C]``, on
+    the SAME variables: outputs, gradients and the running statistics."""
+    from flax import linen as nn
+
+    from p2p_tpu.models.ffc import _PairConv, torch_default_init
+    from p2p_tpu.ops.norm import BatchNorm
+
+    c = 6
+    rng = np.random.default_rng(4)
+    z = jnp.asarray(rng.normal(1.0, 2.0, (2, 4, 3, c, 2)).astype(np.float32))
+    flat = z.reshape(2, 4, 3, 2 * c)
+    if layer == "conv":
+        on_pairs = _PairConv()
+        plain = nn.Conv(2 * c, (1, 1), use_bias=False,
+                        kernel_init=torch_default_init)
+    else:
+        running = layer == "bn_eval"
+        on_pairs = BatchNorm(use_running_average=running, feature_axes=2)
+        plain = BatchNorm(use_running_average=running)
+    variables = plain.init(jax.random.key(2), flat)
+    assert jax.tree_util.tree_all(jax.tree_util.tree_map(
+        lambda a, b: a.shape == b.shape and bool((a == b).all()), variables,
+        on_pairs.init(jax.random.key(2), z)))
+    if layer != "conv":
+        stats = variables["batch_stats"]["BatchNorm_0"]
+        variables = {**variables, "batch_stats": {"BatchNorm_0": {
+            "mean": stats["mean"] + jnp.arange(2.0 * c) / 10,
+            "var": stats["var"] + jnp.arange(2.0 * c) / 7}}}
+
+    def run(module, x):
+        def loss(v, xx):
+            y, mut = module.apply(v, xx, mutable=["batch_stats"])
+            return jnp.sum(jnp.sin(y.reshape(flat.shape))), (y, mut)
+        (_, (y, mut)), grads = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(variables, x)
+        return (y.reshape(flat.shape), mut, grads[0]["params"],
+                grads[1].reshape(flat.shape))
+
+    got, want = run(on_pairs, z), run(plain, flat)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
 # ------------------------------------------------- layers and the generator
 
 
@@ -289,6 +398,42 @@ def test_generator_against_the_reference(ref, toy, mode):
         assert set(after) == set(stats)
         for k, v in stats.items():
             np.testing.assert_allclose(after[k], v, atol=2e-3, err_msg=k)
+
+
+@pytest.mark.parametrize("train", [False, True], ids=["eval", "train"])
+def test_generator_paints_what_it_painted_on_the_library_s_transforms(
+        toy, monkeypatch, train):
+    """The parameter tree is what it was while the unit called ``jnp.fft``
+    (``fu/conv/kernel``, ``fu/bn/...``: a checkpoint from before loads),
+    and on the same variables the generator paints the same image on the
+    products as on the library's transforms: 1e-3 of a level of 255 in
+    float32, at an extent whose spectrum has an odd width (72x64: 9x8 at
+    the blocks)."""
+    from p2p_tpu.models import ffc
+    from p2p_tpu.train.state import build_models
+
+    cfg, state, _ = toy
+    g, _, _ = build_models(cfg, jnp.float32)
+    unit_leaves = {"/".join(str(getattr(k, "key", k)) for k in path)
+                   for path, _ in jax.tree_util.tree_flatten_with_path(
+                       state.params_g["block_0"]["conv1"]["g2g"]["fu"])[0]}
+    assert unit_leaves == {"conv/kernel", "bn/BatchNorm_0/scale",
+                           "bn/BatchNorm_0/bias"}
+    rng = np.random.default_rng(11)
+    x = rng.uniform(-1, 1, (2, 72, 64, 4)).astype(np.float32)
+    x[..., 3] = np.where(x[..., 3] > 0, 1.0, -1.0)
+    variables = {"params": state.params_g,
+                 "batch_stats": state.batch_stats_g}
+
+    def paint():
+        return np.asarray(g.apply(variables, jnp.asarray(x), train,
+                                  mutable=["batch_stats"])[0])
+
+    got = paint()
+    monkeypatch.setattr(ffc, "rfft2", library_pair()[0])
+    monkeypatch.setattr(ffc, "irfft2", library_pair()[1])
+    want = paint()
+    assert 127.5 * np.abs(got - want).max() < 1e-3
 
 
 def float64_moments(monkeypatch):
@@ -634,9 +779,16 @@ def test_compiled_step_names_the_new_scopes(toy, hrf):
     assert "loss_hrf" in STEP_SCOPES and "d_r1" not in STEP_SCOPES
     # the penalty's passes lie inside D's real call
     assert re.search(r"D_real[^\"\n]*d_r1", text)
-    # the transforms lie inside the spectral transform
-    assert re.search(r"ffc_spectral[^\"\n]*ffc_fft", text)
-    assert "stablehlo.fft" in text
+    # the transforms lie inside the spectral transform, as matrix products
+    # in the forward pass and in the transposed one; nothing of an FFT
+    assert "stablehlo.fft" not in text
+    products = [ln for ln in text.splitlines() if "dot_general" in ln
+                and re.search(r"ffc_spectral[^\"\n]*ffc_fft", ln)]
+    assert any("transpose(jvp(" in ln for ln in products)
+    assert any("transpose(" not in ln for ln in products)
+    # two stages a transform, a forward and an inverse a unit, each once
+    # more transposed: 2 blocks x 2 units
+    assert len(products) >= 2 * 2 * 2 * 4
 
 
 @pytest.mark.parametrize("fault", ["conditional_d", "lsgan", "pool",
@@ -877,6 +1029,7 @@ def test_cli_train_runs_the_preset_through_the_trainer(trained, image_root):
                   if k.startswith("ffc_")}
         assert gauges == {"ffc_layers": 6.0, "ffc_fourier_units": 2.0,
                           "ffc_fft_calls_per_step": 8.0,
+                          "ffc_dft_transforms_per_step": 8.0,
                           "ffc_global_channels": 48.0}
         # evaluation: the composite against the target
         out = trainer.evaluate()
@@ -1000,6 +1153,7 @@ def test_published_arithmetic_matches_the_issue_s_count():
     gauges = generator_gauges(get_preset("big_lama").model, 256, 256)
     assert gauges["ffc_layers"] == 40 and gauges["ffc_fourier_units"] == 36
     assert gauges["ffc_fft_calls_per_step"] == 144
+    assert gauges["ffc_dft_transforms_per_step"] == 144
     assert gauges["ffc_global_channels"] == 384
     # 54 GMAC an image forward (46 in the blocks, 8 around them)
     assert 104.0 < gauges["generator_gflop_per_image"] < 112.0
