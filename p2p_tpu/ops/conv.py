@@ -76,7 +76,9 @@ _NEAREST_UP2_MIN_PIXELS = 16_384
 def nearest_up2_engages(x, features: int) -> bool:
     """Whether an UpsampleConvLayer(k3, stride 1, upsample=2) site with
     the LOW-RES input ``x`` (N,H,W,C) takes the subpixel form
-    (:class:`_NearestUp2Conv`): below 128 output channels.
+    (:class:`_NearestUp2Conv`), whichever ring its pad mode gives it:
+    below 128 output channels, from ``_NEAREST_UP2_MIN_PIXELS``
+    post-upsample pixels of the batch up.
 
     There the plain conv writes a part of the 128 lanes onto the
     4x-materialised upsampled tensor, and the four phases of the subpixel
@@ -91,10 +93,24 @@ def nearest_up2_engages(x, features: int) -> bool:
     0.18): the form loses at no extent read, so training, one-image
     inference and serving of a preset take one form (a gate on ONE
     image's 300k pixels, a pre-round bs1 reading, kept ExpandNetwork's
-    sites out). At 128 channels ([2,64,128,256] -> 128) the form read 2.05
-    -> 1.32 alone, but was read in no step, and on a ``spatial`` > 1 mesh
-    every engaged site adds a whole-shard collective-permute in the
-    backward of its edge pad (PERF.md section 4 (3)): it stays plain."""
+    sites out). A zero-padded site follows the same rule since PR 43 (the
+    fold and the convolution are the reflect-padded site's, the ring is
+    zeros): SwinIR's two, [4,64,64,64] -> 64 0.67 -> 0.52 and
+    [4,128,128,64] -> 64 2.57 -> 1.36, and 36.61 -> 36.88 img/s in their
+    step (PERF.md section 6, PR 43).
+
+    From 128 output channels up the plain chain stays, read in PR 43 with
+    the ceiling lifted (PERF.md section 6): the VQGAN decoder's zero-padded
+    sites, C_in = C_out, lose alone ([12,128,128,128] -> 128 6.68 -> 8.46,
+    [12,64,64,256] -> 256 5.87 -> 7.13, [12,32,32,256] -> 256 1.46 -> 1.86)
+    and in their step (66.44 -> 65.21 img/s): at full lanes the saving and
+    the depth-to-space cancel and the dense folded kernel is left over.
+    G1's reflect-padded ones, C_in = 2 C_out, win alone ([2,64,128,256] ->
+    128 2.06 -> 1.32, [2,32,64,512] -> 256 1.54 -> 1.32) for under 1% of
+    their step, which no sound pair read; no width separates the two
+    groups, and on a ``spatial`` > 1 mesh every engaged reflect-padded
+    site adds a whole-shard collective-permute in the backward of its edge
+    pad (PERF.md section 4 (3))."""
     n, h, w, _ = x.shape
     return features < 128 and n * 4 * h * w >= _NEAREST_UP2_MIN_PIXELS
 
@@ -732,8 +748,8 @@ def depth_to_space_2x(out: jax.Array, features: int) -> jax.Array:
 
 
 def nearest_up2_kernel(w: jax.Array) -> jax.Array:
-    """The (3,3,ci,co) kernel of a (nearest x2 -> ReflectionPad(1) -> k3
-    conv) chain folded onto the LOW-RES grid: (3,3,ci,4*co) float32 with
+    """The (3,3,ci,co) kernel of a (nearest x2 -> pad 1 -> k3 conv) chain
+    folded onto the LOW-RES grid: (3,3,ci,4*co) float32 with
     the output channels in the order (u, v, o), phase (u,v) of the x2
     output at channel block u*2+v. ``Wc[r,c,i,(u,v,o)] = sum_{a,b}
     M[u,r,a] M[v,c,b] W[a,b,i,o]`` with the constant 0/1 folding matrix
@@ -748,13 +764,18 @@ def nearest_up2_kernel(w: jax.Array) -> jax.Array:
     return wc.reshape(3, 3, w.shape[2], 4 * w.shape[3])
 
 
-def nearest_up2_conv(x: jax.Array, w: jax.Array, dtype) -> jax.Array:
-    """(nearest x2 -> ReflectionPad(1) -> k3 conv) of ``x`` (N,H,W,ci) with
-    the HWIO kernel ``w`` (3,3,ci,co), no bias, in the subpixel form: one k3
-    conv ``ci -> 4*co`` of the edge-padded LOW-RES input with the folded
-    kernel, computed in ``dtype``, then :func:`depth_to_space_2x`."""
+def nearest_up2_conv(x: jax.Array, w: jax.Array, dtype,
+                     pad_mode: str) -> jax.Array:
+    """(nearest x2 -> pad 1 -> k3 conv) of ``x`` (N,H,W,ci) with the HWIO
+    kernel ``w`` (3,3,ci,co), no bias, in the subpixel form: one k3 conv
+    ``ci -> 4*co`` of the LOW-RES input padded by one ring with the folded
+    kernel, computed in ``dtype``, then :func:`depth_to_space_2x`.
+    ``pad_mode`` is UpsampleConvLayer's, the pad of the UPSAMPLED tensor:
+    the ring that stands for ``"reflect"`` is an edge pad of the low-res
+    input, the one for ``"zero"`` is zeros."""
     wc = nearest_up2_kernel(w)
-    xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)), mode="edge")
+    xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)),
+                 mode={"reflect": "edge", "zero": "constant"}[pad_mode])
     y = jax.lax.conv_general_dilated(
         xp.astype(dtype), wc.astype(dtype), window_strides=(1, 1),
         padding="VALID", dimension_numbers=("NHWC", "HWIO", "NHWC"),
@@ -764,7 +785,8 @@ def nearest_up2_conv(x: jax.Array, w: jax.Array, dtype) -> jax.Array:
 
 class _NearestUp2Conv(nn.Module):
     """EXACT subpixel decomposition of UpsampleConvLayer's
-    (nearest ×2 upsample → ReflectionPad(1) → 3×3 conv) chain.
+    (nearest ×2 upsample → pad 1 → 3×3 conv) chain, for a reflect pad and
+    a zero pad of the upsampled tensor (``pad_mode``) alike.
 
     With ``up(x)[p,q] = x[p//2, q//2]``, each output phase (u,v)∈{0,1}²
     reads low-res offsets ``o = floor((u+a)/2)`` per tap a∈{-1,0,1}, so
@@ -777,15 +799,19 @@ class _NearestUp2Conv(nn.Module):
     resolution + :func:`depth_to_space_2x`: the same FLOPs land on full
     128-lane MXU tiles (vs a 32-lane-wide conv over the 4×-materialized
     upsampled tensor) and the activation traffic drops ~4×.
-    Boundary: reflect-padding the UPSAMPLED
-    image equals EDGE-padding the low-res input for the single ring a 3×3
-    needs (up[-1]=up[0]=x[0], up[2H]=up[2H-2]=x[H-1]); k≥5 needs a second
-    ring where that identity breaks — hence the k==3 gate in the
-    dispatcher. Param tree identical to ``nn.Conv`` ("kernel" (3,3,ci,co)
-    [+ "bias"]), so checkpoints and the TP sharding rules are unchanged.
+    Boundary: the only taps that leave the image are up[-1] ↔ x[-1]
+    (phase 0, offset -1) and up[2H] ↔ x[H] (phase 1, offset +1), so the
+    single ring a 3×3 needs is a ring of the LOW-RES input: reflect-padding
+    the UPSAMPLED image equals EDGE-padding it (up[-1]=up[1]=x[0],
+    up[2H]=up[2H-2]=x[H-1]), zero-padding the upsampled image equals
+    zero-padding it; k≥5 needs a second ring where neither identity holds
+    — hence the k==3 gate in the dispatcher. Param tree identical to
+    ``nn.Conv`` ("kernel" (3,3,ci,co) [+ "bias"]), so checkpoints and the
+    TP sharding rules are unchanged.
     """
 
     features: int
+    pad_mode: str
     use_bias: bool = True
     dtype: Optional[jnp.dtype] = None
     kernel_init: Callable = normal_init()
@@ -800,7 +826,8 @@ class _NearestUp2Conv(nn.Module):
         # house convention for dispatch targets (cf. _SplitStemConv):
         # dtype=None computes in f32, as the plain nn.Conv path does
         with jax.named_scope("nearest_up2"):
-            y = nearest_up2_conv(x, kernel, self.dtype or jnp.float32)
+            y = nearest_up2_conv(x, kernel, self.dtype or jnp.float32,
+                                 self.pad_mode)
             if bias is not None:
                 y = y + bias.astype(y.dtype)
         return y
@@ -817,24 +844,24 @@ class UpsampleConvLayer(nn.Module):
     use_bias: bool = True
     dtype: Optional[jnp.dtype] = None
     kernel_init: Callable = normal_init()
-    # "zero": Conv2d(padding=k//2) after the upsample (models/vqgan.py).
-    # Such a site keeps the plain chain: the subpixel form's edge ring is
-    # the reflect pad's, and the one zero-padded user has no site under
-    # the 128 output channels it engages at
+    # "zero": Conv2d(padding=k//2) after the upsample (models/vqgan.py,
+    # models/swinir.py). A k3 upsample=2 site of either mode follows
+    # nearest_up2_engages; the subpixel form pads the low-res input with
+    # the mode's ring (nearest_up2_conv)
     pad_mode: str = "reflect"
 
     @nn.compact
     def __call__(self, x):
         if (self.upsample == 2 and self.kernel_size == 3 and self.stride == 1
-                and self.pad_mode == "reflect"
                 and nearest_up2_engages(x, self.features)):
             # subpixel decomposition of upsample→conv (ExpandNetwork's two
-            # upsamples, the pix2pixHD enhancer's and G1's last — see
-            # _NearestUp2Conv)
+            # upsamples, the pix2pixHD enhancer's and G1's last, SwinIR's
+            # two — see _NearestUp2Conv)
             _count_form("nearest_up2")
             return _NearestUp2Conv(
-                self.features, use_bias=self.use_bias, dtype=self.dtype,
-                kernel_init=self.kernel_init, name="Conv_0",
+                self.features, self.pad_mode, use_bias=self.use_bias,
+                dtype=self.dtype, kernel_init=self.kernel_init,
+                name="Conv_0",
             )(x)
         if self.upsample:
             x = upsample_nearest(x, self.upsample)

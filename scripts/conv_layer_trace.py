@@ -132,6 +132,22 @@ def by_layer(xplane_path, hlo_text, layers, top=4):
     }
 
 
+def thawed(live_trainer):
+    """``epoch_records.live_trainer`` behind a ``gc.unfreeze()``: the
+    Trainer freezes the collector after the epoch that compiled its step
+    (``train.loop.settle_collector``, PR 38) and ``gc.get_objects()`` lists
+    no frozen object, so the plain search finds no Trainer from then on
+    and ``trace_phases.py``'s join fails on every cell. The join runs
+    after the window, where a thaw costs the measurement nothing."""
+    import gc
+
+    def live_trainer_thawed():
+        gc.unfreeze()
+        return live_trainer()
+
+    return live_trainer_thawed
+
+
 def main(argv=None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
     layers = r"(Upsample)?ConvLayer_\d+"
@@ -140,7 +156,7 @@ def main(argv=None) -> int:
         layers = argv[i + 1]
         del argv[i:i + 2]
 
-    from benchmark import harness, scope_time
+    from benchmark import epoch_records, harness, scope_time
     from benchmark.tools import trace_phases
 
     by_scope = scope_time.by_scope
@@ -149,11 +165,14 @@ def main(argv=None) -> int:
         harness.say(**by_layer(xplane, text, layers))
         return by_scope(xplane, text, *a, **kw)
 
+    live_trainer = epoch_records.live_trainer
     scope_time.by_scope = by_scope_and_layer
+    epoch_records.live_trainer = thawed(live_trainer)
     try:
         return trace_phases.main(argv)
     finally:
         scope_time.by_scope = by_scope
+        epoch_records.live_trainer = live_trainer
 
 
 if __name__ == "__main__":

@@ -1,8 +1,9 @@
 """Time the two forms an ``UpsampleConvLayer(k3, upsample=2)`` site can
 take, one layer at a time on the attached chip: the plain chain (nearest
-x2 -> reflect pad -> k3 conv) against the subpixel form of ``ops/conv.py``
-(``nearest_up2_conv``: one k3 conv ``ci -> 4*co`` on the edge-padded
-LOW-RES input, then ``depth_to_space_2x``).
+x2 -> reflect or zero pad -> k3 conv) against the subpixel form of
+``ops/conv.py`` (``nearest_up2_conv``: one k3 conv ``ci -> 4*co`` on the
+LOW-RES input padded by the pad mode's ring, edge or zeros, then
+``depth_to_space_2x``).
 
     chiprun -- python scripts/up2_conv_bench.py [--only ref_up1,hd_enh] [--profile]
 
@@ -14,7 +15,7 @@ milliseconds of each program (``thin_conv_bench.time_ms``) and, on the
 chip at the cases' own extents, writes them all to
 ``chiprun_out/up2_conv_bench.jsonl``; a form that fails ends the run with
 its error. This is the reading the rule in ``ops/conv.nearest_up2_engages``
-is set from (PERF.md section 6, PR 28); the whole step's trace
+is set from (PERF.md section 6, PR 28 and PR 43); the whole step's trace
 (``scripts/conv_layer_trace.py``) has the last word.
 """
 
@@ -25,7 +26,8 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-#: name -> (batch, H, W, C_in, C_out) of the layer's LOW-RES input
+#: name -> (batch, H, W, C_in, C_out[, pad mode]) of the layer's LOW-RES
+#: input; the pad mode is UpsampleConvLayer's, "reflect" where not given
 CASES = {
     # preset reference at 256x256, bs32 (cell reference_256.train)
     "ref_up1": (32, 128, 128, 64, 32),     # UpsampleConvLayer_1
@@ -37,27 +39,43 @@ CASES = {
     "hd_enh": (2, 256, 512, 64, 32),       # the enhancer's upsample
     "hd_g1_last": (2, 128, 256, 128, 64),  # G1's fourth upsample
     "hd_g1_third": (2, 64, 128, 256, 128),
+    "hd_g1_second": (2, 32, 64, 512, 256),  # at the pixel floor
     # one shard of pix2pixhd at 2048x1024 on data=2,spatial=2, bs1
     "hd4_enh": (1, 256, 1024, 64, 32),
     "hd4_g1_last": (1, 128, 512, 128, 64),
     "hd4_g1_third": (1, 64, 256, 256, 128),
+    # preset swinir_realsr_x4 on 64x64 inputs, bs4 (cell
+    # swinir_m_realsr_x4_gan.train): both sites pad with zeros
+    "sr_up1": (4, 64, 64, 64, 64, "zero"),      # conv_up1
+    "sr_up2": (4, 128, 128, 64, 64, "zero"),    # conv_up2
+    # preset vqgan_imagenet_f16 at 256x256, bs12 (cell
+    # vqgan_imagenet_f16_16384.train): the decoder's four, zero-padded
+    "vq_up4": (12, 16, 16, 512, 512, "zero"),   # under the pixel floor
+    "vq_up3": (12, 32, 32, 256, 256, "zero"),
+    "vq_up2": (12, 64, 64, 256, 256, "zero"),
+    "vq_up1": (12, 128, 128, 128, 128, "zero"),
 }
 
 
-def forms():
+def forms(pad_mode="reflect"):
     """name -> f(x, w): the layer without its bias, x (N,H,W,ci) bf16, w
-    (3,3,ci,co) float32."""
+    (3,3,ci,co) float32, as UpsampleConvLayer builds each form for
+    ``pad_mode``."""
     import jax
+    import jax.numpy as jnp
 
     from p2p_tpu.ops import conv as C
 
     def plain(x, w):
+        up = C.upsample_nearest(x, 2)
+        up = (C.reflect_pad_2d(up, 1) if pad_mode == "reflect"
+              else jnp.pad(up, ((0, 0), (1, 1), (1, 1), (0, 0))))
         return jax.lax.conv_general_dilated(
-            C.reflect_pad_2d(C.upsample_nearest(x, 2), 1), w.astype(x.dtype),
-            (1, 1), "VALID", dimension_numbers=("NHWC", "HWIO", "NHWC"))
+            up, w.astype(x.dtype), (1, 1), "VALID",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
 
     def subpixel(x, w):
-        return C.nearest_up2_conv(x, w, x.dtype)
+        return C.nearest_up2_conv(x, w, x.dtype, pad_mode)
 
     return {"plain": plain, "subpixel": subpixel}
 
@@ -85,23 +103,24 @@ def main(argv=None) -> int:
         return 2
     only = [c for c in args.only.split(",") if c]
     rows = []
-    for name, (n, h, w, cin, cout) in CASES.items():
+    for name, (n, h, w, cin, cout, *pad_mode) in CASES.items():
         if only and name not in only:
             continue
+        (pad_mode,) = pad_mode or ("reflect",)
         h, w = h // args.scale, w // args.scale
         kx, kw_, kg = jax.random.split(jax.random.key(0), 3)
         x = jax.random.normal(kx, (n, h, w, cin), jnp.bfloat16)
         wt = 0.02 * jax.random.normal(kw_, (3, 3, cin, cout), jnp.float32)
         g = jax.random.normal(kg, (n, 2 * h, 2 * w, cout), jnp.bfloat16)
         ref = None
-        for form, fwd in forms().items():
+        for form, fwd in forms(pad_mode).items():
             def fwd_bwd(x, wt, g, fwd=fwd):
                 y, vjp = jax.vjp(fwd, x, wt)
                 dx, dw = vjp(g)
                 return y, dw, dx
 
             row = {"case": name, "form": form, "shape": [n, h, w, cin, cout],
-                   "device": dev.device_kind}
+                   "pad_mode": pad_mode, "device": dev.device_kind}
             row["fwd_ms"] = time_ms(jax.jit(fwd), (x, wt), args.iters)
             both = jax.jit(fwd_bwd)
             row["fwd_bwd_ms"] = time_ms(both, (x, wt, g), args.iters)

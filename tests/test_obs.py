@@ -662,6 +662,41 @@ def test_conv_layer_trace_reads_device_time_by_layer_and_direction():
         assert len(row["ops"]) == 2 and row["ops"][0][1] >= row["ops"][1][1]
 
 
+def test_conv_layer_trace_finds_the_trainer_of_a_frozen_collector():
+    """The Trainer freezes the collector after its first epoch
+    (``train.loop.settle_collector``) and ``gc.get_objects()`` lists no
+    frozen object: ``epoch_records.live_trainer()`` then finds none, which
+    is how ``trace_phases.py``'s join failed on the chip (PR 43). The
+    script's own lookup thaws first."""
+    import gc
+    import importlib.util
+    import types
+
+    from benchmark import epoch_records
+    from p2p_tpu.train.loop import Trainer
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "conv_layer_trace", os.path.join(root, "scripts",
+                                         "conv_layer_trace.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+
+    class Built(Trainer):
+        def __init__(self):     # what live_trainer reads, nothing else
+            self.spans = types.SimpleNamespace(
+                spans=[{"name": "train_epoch", "ts": 9e18}])
+
+    trainer = Built()
+    assert epoch_records.live_trainer() is trainer
+    gc.freeze()
+    try:
+        assert epoch_records.live_trainer() is not trainer
+        assert tool.thawed(epoch_records.live_trainer)() is trainer
+    finally:
+        gc.unfreeze()
+
+
 # preset -> the reflect-padded sites of G (and C where the preset has one),
 # traced at the preset's own extent: ExpandNetwork 1 stem + 2 stride-2 + 18
 # in the residual blocks + 1 head and the compression net's 3; pix2pixHD's

@@ -621,44 +621,130 @@ def test_depth_to_space_2x_is_the_phase_map(batch, f):
     np.testing.assert_array_equal(np.asarray(vjp(jnp.asarray(want))[0]), out)
 
 
-@pytest.mark.parametrize("shape,co", [((2, 12, 16, 64), 32),
-                                      ((32, 4, 6, 64), 32),
-                                      ((2, 8, 8, 128), 64),
-                                      ((1, 6, 10, 8), 6)],
-                         ids=["expand_up1_64to32", "expand_up1_64to32_bs32",
-                              "expand_up0_128to64", "odd_8to6"])
-def test_nearest_up2_conv_bf16_matches_plain_chain(shape, co):
-    """The subpixel form in bf16, as the presets compute, at
-    ExpandNetwork's widths: forward, input gradient and weight gradient
-    against the plain chain (upsample -> reflect pad -> ``nn.Conv``) on
-    the same float32 parameters, relative to each tensor's largest entry.
-    The folded kernel is rounded to bf16 once, after the float32 sums."""
+def _plain_up2_chain(conv, pad_mode):
+    """f(params, x): nearest x2 -> reflect or zero pad 1 -> ``conv``
+    (VALID), the chain UpsampleConvLayer runs where the subpixel form does
+    not engage, written out."""
+    def plain(p, xx):
+        up = upsample_nearest(xx, 2)
+        up = (reflect_pad_2d(up, 1) if pad_mode == "reflect"
+              else jnp.pad(up, ((0, 0), (1, 1), (1, 1), (0, 0))))
+        return conv.apply(p, up)
+    return plain
+
+
+def _up2_form_matches_plain(shape, co, pad_mode, dtype, tol):
+    """``_NearestUp2Conv`` with ``pad_mode``'s ring against the plain
+    chain on the same float32 parameters: output, bias gradient, kernel
+    gradient and input gradient agree in shape and dtype and to ``tol``
+    of each tensor's largest entry."""
     from flax import linen as nn
 
     from p2p_tpu.ops.conv import _NearestUp2Conv
 
     r = np.random.default_rng(co)
-    x = jnp.asarray(r.normal(size=shape), jnp.bfloat16)
+    x = jnp.asarray(r.normal(size=shape), dtype)
     n, h, w, _ = shape
-    ct = jnp.asarray(r.normal(size=(n, 2 * h, 2 * w, co)), jnp.bfloat16)
-    form = _NearestUp2Conv(co, dtype=jnp.bfloat16)
-    conv = nn.Conv(co, (3, 3), padding="VALID", dtype=jnp.bfloat16)
+    ct = jnp.asarray(r.normal(size=(n, 2 * h, 2 * w, co)), dtype)
+    form = _NearestUp2Conv(co, pad_mode, dtype=dtype)
+    conv = nn.Conv(co, (3, 3), padding="VALID", dtype=dtype)
     params = form.init(jax.random.key(1), x)
+    assert set(params["params"]) == {"kernel", "bias"}
     params = jax.tree_util.tree_map(
         lambda p: jnp.asarray(0.1 * r.normal(size=p.shape), p.dtype), params)
-
-    def plain(p, xx):
-        return conv.apply(p, reflect_pad_2d(upsample_nearest(xx, 2), 1))
-
-    want, want_vjp = jax.vjp(plain, params, x)
+    want, want_vjp = jax.vjp(_plain_up2_chain(conv, pad_mode), params, x)
     got, got_vjp = jax.vjp(form.apply, params, x)
-    assert got.shape == want.shape and got.dtype == want.dtype
-    pairs = zip(jax.tree_util.tree_leaves((got, got_vjp(ct))),
-                jax.tree_util.tree_leaves((want, want_vjp(ct))))
-    for a, b in pairs:
+    got = jax.tree_util.tree_leaves((got, got_vjp(ct)))
+    want = jax.tree_util.tree_leaves((want, want_vjp(ct)))
+    assert len(got) == len(want) == 4    # y, d bias, d kernel, dx
+    for a, b in zip(got, want):
         assert a.shape == b.shape and a.dtype == b.dtype
         a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-        assert np.abs(a - b).max() <= 2e-2 * np.abs(b).max()
+        assert np.abs(a - b).max() <= tol * np.abs(b).max()
+
+
+@pytest.mark.parametrize("pad_mode", ["reflect", "zero"])
+@pytest.mark.parametrize("shape,co", [((2, 12, 16, 64), 32),
+                                      ((32, 4, 6, 64), 32),
+                                      ((2, 8, 8, 128), 64),
+                                      ((1, 6, 10, 8), 6),
+                                      ((4, 8, 8, 64), 64),
+                                      ((2, 4, 4, 256), 256)],
+                         ids=["expand_up1_64to32", "expand_up1_64to32_bs32",
+                              "expand_up0_128to64", "odd_8to6",
+                              "swinir_up_64to64", "vqgan_up_256to256"])
+def test_nearest_up2_conv_bf16_matches_plain_chain(shape, co, pad_mode):
+    """The subpixel form in bf16, as the presets compute, at
+    ExpandNetwork's, SwinIR's and the VQGAN decoder's widths, with the
+    edge ring of a reflect-padded site and the zero ring of a zero-padded
+    one: forward, input gradient, weight gradient and bias gradient
+    against the plain chain (upsample -> pad -> ``nn.Conv``) on the same
+    float32 parameters, relative to each tensor's largest entry. The
+    folded kernel is rounded to bf16 once, after the float32 sums."""
+    _up2_form_matches_plain(shape, co, pad_mode, jnp.bfloat16, 2e-2)
+
+
+@pytest.mark.parametrize("pad_mode", ["reflect", "zero"])
+@pytest.mark.parametrize("shape,co", [((2, 7, 9, 8), 6), ((1, 8, 10, 16), 12),
+                                      ((3, 1, 1, 4), 5)],
+                         ids=["odd_7x9", "even_8x10", "one_pixel"])
+def test_nearest_up2_conv_f32_is_the_plain_chain(shape, co, pad_mode):
+    """In float32 the subpixel form IS the plain chain with either ring,
+    to 1e-6 of each tensor's largest entry: the output (boundary rows and
+    columns included, where the zero ring of the low-res input stands for
+    the zero pad of the upsampled one), the input's gradient, the
+    kernel's and the bias's; at an odd and an even extent, and at one
+    pixel, where every tap but the centre reads the ring."""
+    _up2_form_matches_plain(shape, co, pad_mode, jnp.float32, 1e-6)
+
+
+@pytest.mark.parametrize("pad_mode", ["reflect", "zero"])
+def test_a_checkpoint_of_the_plain_chain_restores_into_the_form(
+        pad_mode, monkeypatch):
+    """The bytes of a site's variables written while it ran the plain
+    chain (the parent's program for a zero-padded site: here the form's
+    floor is raised over the input) restore into the variables the engaged
+    site builds, key for key and shape for shape, and the engaged site
+    then computes the plain chain's output from them."""
+    from flax import serialization
+
+    from p2p_tpu.ops import conv
+
+    layer = UpsampleConvLayer(12, kernel_size=3, upsample=2,
+                              pad_mode=pad_mode)
+    x = jnp.asarray(rng(1, 64, 64, 8), jnp.float32)
+    with monkeypatch.context() as m:
+        m.setattr(conv, "_NEAREST_UP2_MIN_PIXELS", 10 ** 12)
+        before = conv.conv_form_sites()
+        written = layer.init(jax.random.key(7), x)
+        want = layer.apply(written, x)
+        assert conv.conv_form_sites() == before      # the plain chain
+    blob = serialization.to_bytes(written)
+    before = conv.conv_form_sites()["nearest_up2"]
+    target = jax.tree.map(jnp.zeros_like, layer.init(jax.random.key(8), x))
+    assert conv.conv_form_sites()["nearest_up2"] - before == 1
+    restored = serialization.from_bytes(target, blob)
+    assert jax.tree.structure(restored) == jax.tree.structure(written)
+    for a, b in zip(jax.tree.leaves(restored), jax.tree.leaves(written)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_allclose(np.asarray(layer.apply(restored, x)),
+                               np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+def test_a_zero_ring_is_not_an_edge_ring():
+    """The two rings differ on the border and nowhere else: a form handed
+    the wrong ring is caught by the comparisons above (the interior of an
+    image cannot tell them apart)."""
+    from p2p_tpu.ops.conv import nearest_up2_conv
+
+    r = np.random.default_rng(3)
+    x = jnp.asarray(r.normal(size=(1, 6, 6, 4)), jnp.float32)
+    w = jnp.asarray(r.normal(size=(3, 3, 4, 2)), jnp.float32)
+    edge = np.asarray(nearest_up2_conv(x, w, jnp.float32, "reflect"))
+    zero = np.asarray(nearest_up2_conv(x, w, jnp.float32, "zero"))
+    np.testing.assert_array_equal(edge[:, 1:-1, 1:-1], zero[:, 1:-1, 1:-1])
+    assert np.abs(edge[:, 0] - zero[:, 0]).max() > 0.1
+    assert np.abs(edge[:, :, -1] - zero[:, :, -1]).max() > 0.1
 
 
 # (k, C_in, C_out, block, H, W): the layers the blocked form is for, at toy
@@ -724,26 +810,44 @@ def _walk_convs(jaxpr):
                     yield from _walk_convs(inner)
 
 
-def _traced_convs(layer, shape):
+def _cpu_mesh(axes):
+    """A mesh with ``axes`` (name -> size) over the CPU's virtual devices,
+    or None for no axes."""
+    from p2p_tpu.core.mesh import MeshSpec, make_mesh
+
+    if not axes:
+        return None
+    return make_mesh(MeshSpec(**axes), devices=jax.devices()[
+        :int(np.prod(list(axes.values())))])
+
+
+def _traced_convs(layer, shape, mesh_axes=None):
     """(kernel shape, under the ``blocked_conv`` scope, rows of the conv's
     input) of every ``conv_general_dilated`` a layer traces to on an input
-    of ``shape``, and the ``conv_form_sites_total`` ticks the trace made.
+    of ``shape``, and the ``conv_form_sites_total`` ticks the trace made;
+    with ``mesh_axes``, traced inside ``mesh_context`` of such a mesh of
+    the CPU's virtual devices, as the parallel step traces its layers.
     Abstract evaluation only, no compute."""
+    from p2p_tpu.core.mesh import mesh_context
     from p2p_tpu.ops.conv import conv_form_sites
 
+    mesh = _cpu_mesh(mesh_axes)
     x = jax.ShapeDtypeStruct(shape, jnp.float32)
     variables = jax.eval_shape(layer.init, jax.random.key(0), x)
     before = conv_form_sites()
-    convs = [(kernel, blocked, lhs[1]) for kernel, blocked, lhs, _ in
-             _walk_convs(jax.make_jaxpr(layer.apply)(variables, x).jaxpr)]
+    with mesh_context(mesh):
+        convs = [(kernel, blocked, lhs[1]) for kernel, blocked, lhs, _ in
+                 _walk_convs(jax.make_jaxpr(layer.apply)(variables,
+                                                         x).jaxpr)]
     after = conv_form_sites()
     return convs, {f: after[f] - before[f] for f in after if
                    after[f] != before[f]}
 
 
 # layer, input shape -> (form counted, the one conv's kernel[, the rows of
-# that conv's input]). The shapes of the three benchmark cells, routed as
-# they read on the chip (PERF.md section 6, PR 24), and toy / odd shapes.
+# that conv's input[, the axes of a mesh the site is traced under]]). The
+# shapes of the benchmark cells, routed as they read on the chip (PERF.md
+# section 6, PR 24, PR 28 and PR 43), and toy / odd shapes.
 ROUTING_CASES = {
     # reference_256.train: ExpandNetwork's stem and head, C's k5 stem
     "ref_stem_k9_12to32": (ConvLayer(32, kernel_size=9), (32, 256, 256, 12),
@@ -789,9 +893,9 @@ ROUTING_CASES = {
     # UpsampleConvLayer(k3, upsample=2) either side of both bounds of its
     # rule (fewer than 128 output channels, and a batch of at least 16,384
     # post-upsample pixels = 4*64*64, the smallest read on the chip): in
-    # the plain chain the conv reads the reflect-padded UPSAMPLED tensor
-    # (2*H+2 rows); in the subpixel form one conv to 4*C_out channels
-    # reads the edge-padded LOW-RES input (H+2 rows) -- _NearestUp2Conv
+    # the plain chain the conv reads the padded UPSAMPLED tensor (2*H+2
+    # rows); in the subpixel form one conv to 4*C_out channels reads the
+    # LOW-RES input with its one ring (H+2 rows) -- _NearestUp2Conv
     "up2_k3_under_floor": (UpsampleConvLayer(6, kernel_size=3, upsample=2),
                            (1, 64, 63, 8), None, (3, 3, 8, 6), 130),
     "up2_k3_at_floor": (UpsampleConvLayer(6, kernel_size=3, upsample=2),
@@ -805,9 +909,51 @@ ROUTING_CASES = {
                             258),
     "up2_k3_128_channels": (UpsampleConvLayer(128, kernel_size=3, upsample=2),
                             (1, 256, 256, 8), None, (3, 3, 8, 128), 514),
+    # a zero-padded site follows the same rule with a ring of zeros (PR
+    # 43): either side of the floor, either side of the ceiling
+    "up2_k3_zero_under_floor": (UpsampleConvLayer(6, kernel_size=3,
+                                                  upsample=2,
+                                                  pad_mode="zero"),
+                                (1, 64, 63, 8), None, (3, 3, 8, 6), 130),
+    "up2_k3_zero_at_floor": (UpsampleConvLayer(6, kernel_size=3, upsample=2,
+                                               pad_mode="zero"),
+                             (1, 64, 64, 8), "nearest_up2", (3, 3, 8, 24),
+                             66),
+    "up2_k3_zero_127_channels": (UpsampleConvLayer(127, kernel_size=3,
+                                                   upsample=2,
+                                                   pad_mode="zero"),
+                                 (1, 256, 256, 8), "nearest_up2",
+                                 (3, 3, 8, 508), 258),
+    "up2_k3_zero_128_channels": (UpsampleConvLayer(128, kernel_size=3,
+                                                   upsample=2,
+                                                   pad_mode="zero"),
+                                 (1, 256, 256, 8), None, (3, 3, 8, 128),
+                                 514),
+    # the input sharded along H (a mesh with spatial > 1 made visible, as
+    # the parallel step traces its layers): the same rule. At the ceiling
+    # the plain chain stays, for either pad (its reflect-padded conv is a
+    # halo shard_map there, PR 35: a shard's 256 upsampled rows + 2; the
+    # zero-padded one GSPMD's); under it the form engages as off a mesh
+    "up2_k3_128_channels_h_sharded": (
+        UpsampleConvLayer(128, kernel_size=3, upsample=2),
+        (2, 256, 256, 8), "halo", (3, 3, 8, 128), 258,
+        dict(data=2, spatial=2)),
+    "up2_k3_zero_128_channels_h_sharded": (
+        UpsampleConvLayer(128, kernel_size=3, upsample=2, pad_mode="zero"),
+        (2, 256, 256, 8), None, (3, 3, 8, 128), 514,
+        dict(data=2, spatial=2)),
+    "up2_k3_127_channels_h_sharded": (
+        UpsampleConvLayer(127, kernel_size=3, upsample=2),
+        (2, 256, 256, 8), "nearest_up2", (3, 3, 8, 508), 258,
+        dict(data=2, spatial=2)),
+    "up2_k3_zero_127_channels_h_sharded": (
+        UpsampleConvLayer(127, kernel_size=3, upsample=2, pad_mode="zero"),
+        (2, 256, 256, 8), "nearest_up2", (3, 3, 8, 508), 258,
+        dict(data=2, spatial=2)),
     # the cells' own sites: ExpandNetwork's two at bs32, the pix2pixhd
-    # enhancer's and G1's last and third at bs2 and on one image of the
-    # paper's extent (PERF.md section 6, PR 28)
+    # enhancer's and G1's last, third and second at bs2 and G1's third on
+    # the paper's extent under the four-chip cell's mesh (PERF.md section
+    # 6, PR 28)
     "ref_up1_64to32": (UpsampleConvLayer(32, kernel_size=3, upsample=2),
                        (32, 128, 128, 64), "nearest_up2", (3, 3, 64, 128),
                        130),
@@ -822,10 +968,33 @@ ROUTING_CASES = {
     "hd_g1_third_256to128": (UpsampleConvLayer(128, kernel_size=3,
                                                upsample=2),
                              (2, 64, 128, 256), None, (3, 3, 256, 128), 130),
+    "hd_g1_second_512to256": (UpsampleConvLayer(256, kernel_size=3,
+                                                upsample=2),
+                              (2, 32, 64, 512), None, (3, 3, 512, 256), 66),
     "hd2048_g1_third_256to128": (UpsampleConvLayer(128, kernel_size=3,
                                                    upsample=2),
                                  (2, 128, 256, 256), None,
                                  (3, 3, 256, 128), 258),
+    "hd2048_g1_third_256to128_h_sharded": (
+        UpsampleConvLayer(128, kernel_size=3, upsample=2),
+        (2, 128, 256, 256), "halo", (3, 3, 256, 128), 130,
+        dict(data=2, spatial=2)),
+    # swinir_m_realsr_x4_gan.train's two zero-padded sites at bs4 (PR 43),
+    # and the VQGAN decoder's widest and narrowest at bs12: at the ceiling
+    # and over it, plain
+    "sr_up1_64to64": (UpsampleConvLayer(64, kernel_size=3, upsample=2,
+                                        pad_mode="zero"),
+                      (4, 64, 64, 64), "nearest_up2", (3, 3, 64, 256), 66),
+    "sr_up2_64to64": (UpsampleConvLayer(64, kernel_size=3, upsample=2,
+                                        pad_mode="zero"),
+                      (4, 128, 128, 64), "nearest_up2", (3, 3, 64, 256),
+                      130),
+    "vq_up3_256to256": (UpsampleConvLayer(256, kernel_size=3, upsample=2,
+                                          pad_mode="zero"),
+                        (12, 32, 32, 256), None, (3, 3, 256, 256), 66),
+    "vq_up1_128to128": (UpsampleConvLayer(128, kernel_size=3, upsample=2,
+                                          pad_mode="zero"),
+                        (12, 128, 128, 128), None, (3, 3, 128, 128), 258),
     # k5 after the upsample: the edge-pad identity holds for one ring only
     "up2_k5_stays_plain": (UpsampleConvLayer(6, kernel_size=5, upsample=2),
                            (1, 256, 256, 8), None, (5, 5, 8, 6), 516),
@@ -841,12 +1010,12 @@ def test_thin_conv_dispatch_routing(case):
     ``conv_form_sites_total`` counter, which ticks once a traced site;
     the subpixel form of an upsample by the rows its conv reads, its
     four-phase kernel and its own label of the counter."""
-    layer, shape, form, kernel, *rows = ROUTING_CASES[case]
-    convs, ticks = _traced_convs(layer, shape)
+    layer, shape, form, kernel, *rest = ROUTING_CASES[case]
+    convs, ticks = _traced_convs(layer, shape, *rest[1:])
     assert ticks == ({form: 1} if form else {})
     assert [c[:2] for c in convs] == [(kernel, form == "blocked")]
-    if rows:
-        assert [c[2] for c in convs] == rows
+    if rest:
+        assert [c[2] for c in convs] == rest[:1]
 
 
 @pytest.mark.parametrize("preset", list_presets())
@@ -887,10 +1056,11 @@ def test_every_preset_sends_its_thin_convs_to_the_blocked_form(preset):
     assert len(blocked) == want.get(preset, 0)
 
 
-# preset[@extent] -> (batch, (H, W) or None for the preset's own, the
-# (C_out, takes the subpixel form) of G's k3-up2 sites in call order). The
-# batch is the benchmark cell's where the preset has one (reference 32,
-# pix2pixhd 2), else the preset's own.
+# preset[@variant] -> (batch, (H, W) or None for the preset's own, the
+# (C_out, takes the subpixel form) of G's k3-up2 sites in call order[, the
+# axes of a mesh G is traced under]). The batch is the benchmark cell's
+# where the preset has one (reference 32, pix2pixhd 2, vqgan 12, swinir 4),
+# else the preset's own.
 UP2_SITE_CASES = {
     # reference_256.train: both of ExpandNetwork's upsamples (PR 28)
     "reference": (32, None, ((64, True), (32, True))),
@@ -899,22 +1069,27 @@ UP2_SITE_CASES = {
     # pix2pixhd_1024x512.train: G1's last (new in PR 28) and the enhancer's
     "pix2pixhd": (2, None, ((512, False), (256, False), (128, False),
                             (64, True), (32, True))),
-    # pix2pixhd_2048x1024.train_spatial4 (the global batch the step sees):
-    # the same two sites as before PR 28
+    # pix2pixhd_2048x1024.train_spatial4, under its mesh (the global batch
+    # the step sees): the same two sites
     "pix2pixhd@1024x2048": (2, (1024, 2048), (
-        (512, False), (256, False), (128, False), (64, True), (32, True))),
+        (512, False), (256, False), (128, False), (64, True), (32, True)),
+        dict(data=2, spatial=2)),
     # no cell: follows the rule unmeasured
-    "cityscapes_spatial": (4, None, ((128, False), (64, True))),
-    # vqgan_imagenet_f16_16384.train: the decoder's four sites pad with
-    # ZEROS, and the subpixel form's edge ring is the reflect pad's: all
-    # plain, the last (128 wide at 256x256) too
+    "cityscapes_spatial": (4, None, ((128, False), (64, True)),
+                           dict(data=2, spatial=2)),
+    # vqgan_imagenet_f16_16384.train: the decoder's four zero-padded sites
+    # are 512, 256, 256 and 128 wide, at the ceiling or over it: plain, at
+    # the cell's batch and at one image
     "vqgan_imagenet_f16": (12, None, ((512, False), (256, False),
                                       (256, False), (128, False))),
+    "vqgan_imagenet_f16@bs1": (1, None, ((512, False), (256, False),
+                                         (256, False), (128, False))),
     # swinir_m_realsr_x4_gan.train (G reads the 64x64 LQ image): the
-    # 'nearest+conv' upsampler's two sites pad with ZEROS too, 64 wide at
-    # 128x128 and 256x256: plain, the second customer of a zero-ring
-    # subpixel form (PERF.md section 7)
-    "swinir_realsr_x4": (4, (64, 64), ((64, False), (64, False))),
+    # 'nearest+conv' upsampler's two zero-padded sites, 64 wide at 128x128
+    # and 256x256, take the form since PR 43; one image's first site holds
+    # exactly the floor's 16,384 pixels
+    "swinir_realsr_x4": (4, (64, 64), ((64, True), (64, True))),
+    "swinir_realsr_x4@bs1": (1, (64, 64), ((64, True), (64, True))),
 }
 
 
@@ -922,18 +1097,22 @@ UP2_SITE_CASES = {
     p for p in list_presets() if p not in UP2_SITE_CASES])
 def test_every_preset_sends_its_up2_convs_where_the_rule_says(case):
     """G traced abstractly at the preset's own extent and its cell's
-    batch: which ``UpsampleConvLayer(k3, upsample=2)`` sites take the
-    subpixel form (``conv_form_sites_total{form=nearest_up2}`` ticks
-    inside the site's call) is pinned site by site, so PR 28's gain
+    batch (and at one image; under the cell's mesh where that shards H):
+    which ``UpsampleConvLayer(k3, upsample=2)`` sites take the subpixel
+    form (``conv_form_sites_total{form=nearest_up2}`` ticks inside the
+    site's call) is pinned site by site, so PR 28's and PR 43's gains
     cannot be lost silently and no site changes form unnoticed. The
     U-Net presets have no such site."""
     from flax import linen as nn
 
     from p2p_tpu.core.config import get_preset
+    from p2p_tpu.core.mesh import mesh_context
     from p2p_tpu.ops.conv import conv_form_sites
     from p2p_tpu.train.state import build_models
 
-    batch, extent, want = UP2_SITE_CASES.get(case, (None, None, ()))
+    batch, extent, want, *mesh_axes = UP2_SITE_CASES.get(
+        case, (None, None, ()))
+    mesh = _cpu_mesh(*mesh_axes or (None,))
     cfg = get_preset(case.split("@")[0])
     g, _, _ = build_models(cfg, jnp.bfloat16)
     h, w = extent or (cfg.data.image_size,
@@ -954,7 +1133,7 @@ def test_every_preset_sends_its_up2_convs_where_the_rule_says(case):
                       conv_form_sites()["nearest_up2"] - before == 1))
         return out
 
-    with nn.intercept_methods(watch):
+    with nn.intercept_methods(watch), mesh_context(mesh):
         jax.eval_shape(lambda x: g.init(jax.random.key(0), x, False), x)
     assert tuple(sites) == want
 

@@ -321,6 +321,31 @@ def test_upsampler_against_the_reference(ref, toy_g):
         flat, x01, upto="body")), 1e-4)
 
 
+def test_upsampler_in_the_subpixel_form_against_the_reference(
+        ref, toy_g, monkeypatch):
+    """At the cell's extent ``conv_up1`` and ``conv_up2`` take the subpixel
+    form with a ring of ZEROS (PR 43); the toy extent lies under the
+    form's pixel floor, so the floor is taken away here: both sites tick
+    the counter, read the SAME parameters (a checkpoint of the plain chain
+    loads) and the image still equals the reference's upsampler, which
+    upsamples, pads with zeros and convolves."""
+    from p2p_tpu.ops import conv
+
+    g, params, flat, x, x01 = toy_g
+    plain = g.apply({"params": params}, x, False)
+    monkeypatch.setattr(conv, "_NEAREST_UP2_MIN_PIXELS", 0)
+    before = conv.conv_form_sites()["nearest_up2"]
+    y = g.apply({"params": params}, x, False)
+    assert conv.conv_form_sites()["nearest_up2"] - before == 2
+    close((y + 1.0) * 0.5, ref.upsampler(flat, ref.generator(
+        flat, x01, upto="body")), 1e-4)
+    close(y, plain, 1e-5)
+    # the engaged module builds the tree the plain chain built
+    built = jax.eval_shape(lambda: g.init(jax.random.key(0), x, False))
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), built["params"]) == \
+        jax.tree.map(lambda a: (a.shape, a.dtype), params)
+
+
 @pytest.mark.parametrize("depth", ["off", "on"])
 def test_generator_against_the_reference(ref, toy_g, depth):
     """The whole G, value and the gradient of every leaf, with the keep
